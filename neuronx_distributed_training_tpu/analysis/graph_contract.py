@@ -257,6 +257,16 @@ def declared_source_classes(d: DeclaredComms) -> list[tuple]:
                 "a sequence-parallel activation is consumed at a shifted "
                 "index (halo); check seq-dim slicing under SP",
                 src=_src_any("slice", "pad", "concatenate", "roll"))
+    if d.tp > 1:
+        # the fused qkv / gate_up projection is column-sharded over model and
+        # then split at head boundaries that need not fall on shard
+        # boundaries: the partitioner moves the straddling columns with
+        # neighbour permutes (jax 0.9 names the op ``split``)
+        add("tp fused-projection split exchange", ("collective-permute",),
+            lambda a: a == {"model"},
+            "the split of a model-sharded fused projection moved; check "
+            "fuse_qkv and the qkv/gate_up column PartitionSpecs",
+            src=_src_any("split"))
     if d.tp > 1 or d.pp > 1:
         # vocab-parallel embedding: the token gather (and its scatter-add
         # transpose) crosses the model axis — composed with the batch axes,
@@ -267,7 +277,7 @@ def declared_source_classes(d: DeclaredComms) -> list[tuple]:
             lambda a: bool(a) and a <= (_BATCH_AXES | {"model", "pipe"}),
             "vocab-parallel embedding lookup traffic changed; check the "
             "embed/lm_head PartitionSpecs",
-            src=_src_any("_take", "embed"))
+            src=_src_any("_take", "embed", "gather"))
     if d.dp_total > 1 or d.cp > 1:
         add("dp gradient/loss all-reduce", ("all-reduce",),
             lambda a: a and a <= _BATCH_AXES,
@@ -379,8 +389,9 @@ def declared_source_classes(d: DeclaredComms) -> list[tuple]:
         # dropped-mode dispatch/combine einsums contract the token dim
         # (sharded over batch axes and, under SP, the model axis): their
         # partial sums all-reduce over those axes; router aux losses reduce
-        # the same way
-        add("MoE dispatch/combine reduction", ("all-reduce",),
+        # the same way (the partitioner may instead all-gather the sharded
+        # operand of the same einsum — one mechanism, either collective)
+        add("MoE dispatch/combine reduction", ("all-reduce", "all-gather"),
             lambda a: bool(a) and a <= (_BATCH_AXES | {"model"}),
             "MoE dispatch/combine einsum or router-loss reduction changed; "
             "check ops/moe.py and the router aux-loss path",

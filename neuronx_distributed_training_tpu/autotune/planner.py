@@ -576,12 +576,9 @@ def _resolve_calibration(source: Any) -> tuple[Optional[dict],
 
 
 def _first_device():
-    try:
-        import jax
+    import jax
 
-        return jax.devices()[0]
-    except Exception:  # noqa: BLE001 — planning must work with no backend
-        return None
+    return jax.devices()[0]
 
 
 def apply_plan(source: str | Path, dest: str | Path, plan: Plan,

@@ -117,9 +117,11 @@ TOPOLOGIES: dict[str, ChipTopology] = {
 
 def resolve_topology(name: Optional[str] = None,
                      device: Optional[Any] = None) -> ChipTopology:
-    """Topology by explicit name, else detected from a live jax device, else
-    the ``cpu`` fallback.  Unknown names raise with the valid set (the CLI's
-    ``--topology`` funnels through here)."""
+    """Topology by explicit name, else from a jax device's ``device_kind``.
+    Unknown names and unknown kinds raise with the valid set (the CLI's
+    ``--topology`` funnels through here): a default table would approve
+    plans that OOM on the real chip.  A CPU device resolves to the ``cpu``
+    row, the planner's off-hardware table."""
     if name:
         key = str(name).lower()
         if key not in TOPOLOGIES:
@@ -128,18 +130,19 @@ def resolve_topology(name: Optional[str] = None,
                 f"{'/'.join(sorted(TOPOLOGIES))}"
             )
         return TOPOLOGIES[key]
-    if device is not None:
-        kind = getattr(device, "device_kind", device.platform).lower()
-        for key in ("v6e", "v6", "v5p", "v5e", "v4"):
-            if key in kind or (key == "v5e" and "lite" in kind):
-                return TOPOLOGIES["v6e" if key.startswith("v6") else key]
-        if device.platform == "tpu":
-            # an unrecognized generation priced with the wrong HBM table
-            # would approve plans that OOM — be loud, not silently wrong
-            logger.warning(
-                "unrecognized TPU device_kind %r: pricing as v5p — pass an "
-                "explicit topology (known: %s) if that table is wrong for "
-                "this chip", kind, "/".join(sorted(TOPOLOGIES)),
-            )
-            return TOPOLOGIES["v5p"]
-    return TOPOLOGIES["cpu"]
+    if device is None:
+        raise ValueError(
+            "resolve_topology needs a topology name or a device; known "
+            f"names: {'/'.join(sorted(TOPOLOGIES))}"
+        )
+    if device.platform == "cpu":
+        return TOPOLOGIES["cpu"]
+    kind = device.device_kind.lower()
+    for key in ("v6e", "v6", "v5p", "v5e", "v4"):
+        if key in kind or (key == "v5e" and "lite" in kind):
+            return TOPOLOGIES["v6e" if key.startswith("v6") else key]
+    raise ValueError(
+        f"unrecognized device_kind {device.device_kind!r} "
+        f"({device.platform}): pass an explicit topology (known: "
+        f"{'/'.join(sorted(TOPOLOGIES))})"
+    )

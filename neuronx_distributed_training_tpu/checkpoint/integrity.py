@@ -444,7 +444,7 @@ def verify_step(directory, step: int, *, mgr: Any = None) -> StepVerification:
 
             ckpt = ocp.PyTreeCheckpointer()
             try:
-                md = ckpt.metadata(sdir / item)
+                md = ckpt.metadata(sdir / item).item_metadata.tree
                 is_arr = lambda x: hasattr(x, "shape")  # noqa: E731
                 ra = _jax.tree_util.tree_map(
                     lambda x: ocp.RestoreArgs(restore_type=np.ndarray),

@@ -203,61 +203,30 @@ class Checkpointer:
     def __init__(self, config: CheckpointConfig, *, keep_last: bool = True):
         self.config = config
         directory = resolve_checkpoint_dir(config.dir)
-        try:
-            from orbax.checkpoint.checkpoint_managers import (  # noqa: F401
-                preservation_policy as _pp,
+        preservation = None
+        if config.save_top_k > 0:
+            from orbax.checkpoint.checkpoint_managers import (
+                preservation_policy as pp,
             )
 
-            have_preservation = True
-        except Exception:  # noqa: BLE001 — older orbax: module absent
-            have_preservation = False
-        #: does this orbax ship the preservation-policy retention API?
-        #: (best-N-by-metric + latest).  Without it we degrade to newest-N
-        #: retention instead of refusing to construct — an elastic resume on
-        #: an old image must still be able to save and restore.
-        self.preservation_api = have_preservation
+            def metric_fn(metrics: Any) -> float:
+                return float((metrics or {}).get(self.config.monitor, float("inf")))
 
-        if have_preservation:
-            preservation = None
-            if config.save_top_k > 0:
-                from orbax.checkpoint.checkpoint_managers import (
-                    preservation_policy as pp,
-                )
+            policies = [
+                # reverse=True keeps the *lowest* metric values (loss-like)
+                pp.BestN(get_metric_fn=metric_fn, n=config.save_top_k, reverse=True),
+            ]
+            if keep_last:
+                # "last" must survive top-k eviction for auto-resume correctness
+                # (the reference keeps top-k AND last, exp_manager.py:517-579)
+                policies.append(pp.LatestN(n=1))
+            preservation = pp.AnyPreservationPolicy(policies)
 
-                def metric_fn(metrics: Any) -> float:
-                    return float((metrics or {}).get(self.config.monitor, float("inf")))
-
-                policies = [
-                    # reverse=True keeps the *lowest* metric values (loss-like)
-                    pp.BestN(get_metric_fn=metric_fn, n=config.save_top_k, reverse=True),
-                ]
-                if keep_last:
-                    # "last" must survive top-k eviction for auto-resume correctness
-                    # (the reference keeps top-k AND last, exp_manager.py:517-579)
-                    policies.append(pp.LatestN(n=1))
-                preservation = pp.AnyPreservationPolicy(policies)
-
-            options = ocp.CheckpointManagerOptions(
-                preservation_policy=preservation,
-                enable_async_checkpointing=config.async_save,
-                save_interval_steps=1,  # step gating is the trainer's job
-            )
-        else:
-            # legacy retention: newest (top_k + 1) checkpoints — the "+1"
-            # approximates the keep-last guarantee; best-by-metric needs the
-            # preservation API (those tests stay environment-gated)
-            if config.save_top_k > 0:
-                logger.warning(
-                    "orbax without preservation_policy: retention degrades "
-                    "to newest-%d (best-by-%s needs a newer orbax)",
-                    config.save_top_k + int(keep_last), config.monitor,
-                )
-            options = ocp.CheckpointManagerOptions(
-                max_to_keep=(config.save_top_k + int(keep_last)
-                             if config.save_top_k > 0 else None),
-                enable_async_checkpointing=config.async_save,
-                save_interval_steps=1,
-            )
+        options = ocp.CheckpointManagerOptions(
+            preservation_policy=preservation,
+            enable_async_checkpointing=config.async_save,
+            save_interval_steps=1,  # step gating is the trainer's job
+        )
         self._mgr = ocp.CheckpointManager(directory, options=options)
         #: integrity bookkeeping — the restore/audit trail the trainer
         #: persists into ``run_summary.json``'s ``integrity`` section

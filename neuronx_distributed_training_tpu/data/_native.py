@@ -33,5 +33,9 @@ def compile_and_load(src: Path) -> Optional[ctypes.CDLL]:
             os.replace(tmp, lib_path)
         return ctypes.CDLL(str(lib_path))
     except Exception as e:  # noqa: BLE001 — the numpy fallback is always correct
-        logger.debug("native helper unavailable (%s): %s", src.name, e)
+        # host-side and exact either way, but slower: say so, once per helper
+        # (callers cache the handle, so this runs once per source file)
+        logger.warning("native helper %s did not build (%s: %s); the numpy "
+                       "index builder takes over", src.name,
+                       type(e).__name__, e)
         return None

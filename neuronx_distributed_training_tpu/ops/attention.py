@@ -23,22 +23,14 @@ from __future__ import annotations
 
 from typing import Optional
 
-import logging
-
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-logger = logging.getLogger(__name__)
-_warned: set = set()
+from jax.sharding import PartitionSpec as P
 
-
-def _warn_fallback(impl: str) -> None:
-    if impl not in _warned:
-        _warned.add(impl)
-        logger.warning(
-            "%s attention kernel unavailable; falling back to core attention", impl
-        )
+from neuronx_distributed_training_tpu.parallel import sharding as shd
+from neuronx_distributed_training_tpu.parallel.mesh import DATA_AXES
 
 
 def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
@@ -128,6 +120,57 @@ def segment_mask_bias(segment_ids: jax.Array, dtype=jnp.float32) -> jax.Array:
     return jnp.where(same, jnp.asarray(0, dtype), neg)[:, None, :, :]
 
 
+def _flash_on_mesh(q, k, v, attention_mask, segment_ids, **kw):
+    """``flash_attention`` made legal on a mesh.  The Pallas kernel is a
+    Mosaic custom call, which GSPMD refuses to partition while any mesh axis
+    is automatic, so with a mesh active the call is made per shard inside a
+    fully-manual ``shard_map``: batch over ``data``/``expert``, heads over
+    ``model``, replicated over the rest.  Attention needs nothing from
+    another batch row or head, so the body holds no collective.  When
+    tp exceeds the KV heads they are repeated until each rank owns one (the
+    rule ``parallel.ulysses`` uses), keeping q/kv head groups aligned."""
+    from neuronx_distributed_training_tpu.ops.flash_attention import flash_attention
+
+    mesh = shd.active_mesh()
+    if mesh is None:
+        return flash_attention(
+            q, k, v, attention_mask=attention_mask, segment_ids=segment_ids, **kw
+        )
+    tp = int(mesh.shape.get("model", 1))
+    nh, nkv = q.shape[2], k.shape[2]
+    if nh % tp or (nkv % tp and tp % nkv):
+        raise ValueError(
+            f"flash attention on a mesh: tp={tp} must divide num_heads={nh}, "
+            f"and kv_heads={nkv} and tp must divide one another"
+        )
+    if nkv % tp:
+        k, v = repeat_kv(k, tp // nkv), repeat_kv(v, tp // nkv)
+    heads = P(DATA_AXES, None, "model", None)
+    rows = P(DATA_AXES, None)
+    extras = {"attention_mask": attention_mask, "segment_ids": segment_ids}
+    names = [n for n, x in extras.items() if x is not None]
+
+    def body(q, k, v, *row_args):
+        return flash_attention(q, k, v, **dict(zip(names, row_args)), **kw)
+
+    axis_names = frozenset()  # every axis of ``mesh``
+    manual = shd.manual_axes()
+    if manual:
+        # already inside a manual region (the pipeline body, manual over
+        # ``pipe``): take the remaining axes of the context mesh manual too
+        mesh = jax.sharding.get_abstract_mesh()
+        axis_names = frozenset(mesh.axis_names) - manual
+    fn = shd.shard_map(
+        body,
+        mesh=mesh,
+        in_specs=(heads, heads, heads) + (rows,) * len(names),
+        out_specs=heads,
+        axis_names=axis_names,
+        check_vma=False,
+    )
+    return fn(q, k, v, *(extras[n] for n in names))
+
+
 def attention(
     q: jax.Array,
     k: jax.Array,
@@ -144,9 +187,7 @@ def attention(
     block_kv: Optional[int] = None,  # a per-chip tuning knob, fusions.flash_block_*)
 ) -> jax.Array:
     """Dispatch mirroring the reference's flash/ring/Core selection
-    (``modeling_llama.py:482-489``).  Falls back to ``core_attention`` (with a
-    one-time warning) if the requested kernel is unavailable, so reference
-    configs with ``fusions.flash_attention: true`` still run.
+    (``modeling_llama.py:482-489``).
 
     ``attention_mask`` (padded KEYS, the HF contract) is supported in-kernel
     by the flash, ring, ulysses, and core paths — padded SFT/DPO batches stay
@@ -169,46 +210,35 @@ def attention(
             f"flash and core paths only, not {impl!r}"
         )
     if impl == "flash":
-        try:
-            from neuronx_distributed_training_tpu.ops.flash_attention import flash_attention
-        except ImportError:
-            _warn_fallback("flash")
-        else:
-            return flash_attention(
-                q, k, v, causal=causal, sliding_window=sliding_window,
-                q_offset=q_offset, attention_mask=attention_mask,
-                segment_ids=segment_ids, block_q=block_q, block_kv=block_kv,
-            )
+        return _flash_on_mesh(
+            q, k, v, attention_mask, segment_ids, causal=causal,
+            sliding_window=sliding_window, q_offset=q_offset,
+            block_q=block_q, block_kv=block_kv,
+        )
     if impl == "ring":
-        try:
-            from neuronx_distributed_training_tpu.parallel.ring_attention import ring_attention
-        except ImportError:
-            _warn_fallback("ring")
-        else:
-            if q_offset:
-                raise ValueError(
-                    "ring attention derives global positions from the mesh; "
-                    "an explicit q_offset is not meaningful here"
-                )
-            return ring_attention(
-                q, k, v, causal=causal, sliding_window=sliding_window,
-                block_kv=block_kv or 512, attention_mask=attention_mask,
+        from neuronx_distributed_training_tpu.parallel.ring_attention import ring_attention
+
+        if q_offset:
+            raise ValueError(
+                "ring attention derives global positions from the mesh; "
+                "an explicit q_offset is not meaningful here"
             )
+        return ring_attention(
+            q, k, v, causal=causal, sliding_window=sliding_window,
+            block_kv=block_kv or 512, attention_mask=attention_mask,
+        )
     if impl == "ulysses":
-        try:
-            from neuronx_distributed_training_tpu.parallel.ulysses import ulysses_attention
-        except ImportError:
-            _warn_fallback("ulysses")
-        else:
-            if q_offset:
-                raise ValueError(
-                    "ulysses attention derives global positions from the mesh; "
-                    "an explicit q_offset is not meaningful here"
-                )
-            return ulysses_attention(
-                q, k, v, causal=causal, sliding_window=sliding_window,
-                block_kv=block_kv or 512, attention_mask=attention_mask,
+        from neuronx_distributed_training_tpu.parallel.ulysses import ulysses_attention
+
+        if q_offset:
+            raise ValueError(
+                "ulysses attention derives global positions from the mesh; "
+                "an explicit q_offset is not meaningful here"
             )
+        return ulysses_attention(
+            q, k, v, causal=causal, sliding_window=sliding_window,
+            block_kv=block_kv or 512, attention_mask=attention_mask,
+        )
     if impl == "zigzag_ring":
         from neuronx_distributed_training_tpu.parallel.ring_attention import (
             zigzag_ring_attention,

@@ -30,6 +30,7 @@ Layout contract matches ``core_attention``: q [b, sq, nh, d], k/v
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
@@ -37,28 +38,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the TPU compiler-params dataclass was renamed TPUCompilerParams ->
-# CompilerParams across pallas versions; accept either spelling
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
+logger = logging.getLogger(__name__)
 
 LANES = 128  # TPU lane width; scratch minor dims and block sizes align to it
 SUBLANES = 8  # minor dim for per-row stats (lse/delta): the smallest legal
 # Mosaic block minor dim — 16x less HBM than a full 128-lane broadcast
 DEFAULT_BLOCK_Q = 512
-# v5e sweep (Llama-3-8B layer shapes, seq 8192, 2026-07-30, recorded in
-# bench_results/r2_v5e_measured.jsonl): kv 2048 beats 512 by ~3 MFU points in
-# both regimes (68.3->71.4 bf16, 64.0->66.6 mixed); 4096 fails to fit.  Larger
-# KV blocks amortize the q-block revisit cost; still a per-chip knob via
-# fusions.flash_block_kv.
+# the TPU compiler accepts kv 2048 at every shape tests/test_tpu_compile.py
+# lowers (Llama widths, d 128, seq 4096/8192, fwd + dq + dkv); its speed
+# against other tiles has not been measured on the current code.  Still a
+# per-chip knob via fusions.flash_block_kv.
 DEFAULT_BLOCK_KV = 2048
 NEG_INF = -1e30
 
 
-def _block_sizes(sq: int, skv: int, bq: Optional[int], bkv: Optional[int]):
+def _block_sizes(sq: int, skv: int, bq: Optional[int], bkv: Optional[int],
+                 dtype=jnp.bfloat16):
     bq = bq or min(DEFAULT_BLOCK_Q, sq)
-    bkv = bkv or min(DEFAULT_BLOCK_KV, skv)
+    # 4-byte operands get half the kv block: at 2048 the dkv kernel of an
+    # MHA layer (s 4096, d 128, float32) needs 17.08 MiB of the TPU's 16 MiB
+    # scoped VMEM and the compiler refuses it
+    default_kv = DEFAULT_BLOCK_KV // max(jnp.dtype(dtype).itemsize // 2, 1)
+    bkv = bkv or min(default_kv, skv)
     while sq % bq:
         bq //= 2
     while skv % bkv:
@@ -130,11 +131,11 @@ def _fwd_kernel(
     vis = _visible(qi, ki, bq, bkv, causal, window, q_offset)
     if kvm_ref is not None:
         # skip kv blocks that are entirely padding (long pad tails cost 0 MXU)
-        vis = jnp.logical_and(vis, jnp.any(kvm_ref[...] > 0))
+        vis = jnp.logical_and(vis, jnp.any(kvm_ref[0] > 0))
     if segq_ref is not None:
         # packed-chunk segments are contiguous non-decreasing runs: a kv
         # block strictly ahead of every query segment can't match anything
-        vis = jnp.logical_and(vis, jnp.min(segk_ref[...]) <= jnp.max(segq_ref[...]))
+        vis = jnp.logical_and(vis, jnp.min(segk_ref[0]) <= jnp.max(segq_ref[0]))
 
     @pl.when(vis)
     def _compute():
@@ -151,12 +152,12 @@ def _fwd_kernel(
         if kvm_ref is not None:
             # padded KEYS masked (the HF attention_mask contract) — [1, bkv]
             # broadcasts over query rows
-            s = jnp.where(kvm_ref[...] > 0, s, NEG_INF)
+            s = jnp.where(kvm_ref[0] > 0, s, NEG_INF)
         if segq_ref is not None:
             # block-diagonal packed-sequence mask: attend only within the
             # same segment ([bq, 1] vs [1, bkv] broadcast)
             s = jnp.where(
-                segq_ref[...].reshape(-1, 1) == segk_ref[...].reshape(1, -1),
+                segq_ref[0].reshape(-1, 1) == segk_ref[0].reshape(1, -1),
                 s, NEG_INF,
             )
         m_prev = m_scr[:, :1]  # [bq, 1]
@@ -190,8 +191,8 @@ def _fwd_kernel(
 
 def _fwd_pallas(q, k, v, kvm, seg, *, sm_scale, causal, window, q_offset, bq, bkv,
                 interpret):
-    """q [b, nh, sq, d]; k/v [b, nkv, skv, d]; kvm None or [b, skv] int32
-    (1 = real key); seg None or [b, s] int32 segment ids (self-attention
+    """q [b, nh, sq, d]; k/v [b, nkv, skv, d]; kvm None or [b, 1, skv] int32
+    (1 = real key); seg None or [b, 1, s] int32 segment ids (self-attention
     packed chunks) -> (o [b, nh, sq, d], lse [b, nh, sq, SUBLANES])."""
     b, nh, sq, d = q.shape
     nkv, skv = k.shape[1], k.shape[2]
@@ -212,13 +213,13 @@ def _fwd_pallas(q, k, v, kvm, seg, *, sm_scale, causal, window, q_offset, bq, bk
     ]
     in_arrays = [q, k, v]
     if kvm is not None:
-        in_specs.append(pl.BlockSpec((1, bkv), lambda bi, hi, qi, ki: (bi, ki)))
+        in_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, hi, qi, ki: (bi, 0, ki)))
         in_arrays.append(kvm)
     if seg is not None:
-        # same [b, s] array read twice: query rows and key cols
-        in_specs.append(pl.BlockSpec((1, bq), lambda bi, hi, qi, ki: (bi, qi)))
+        # same [b, 1, s] array read twice: query rows and key cols
+        in_specs.append(pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki: (bi, 0, qi)))
         in_arrays.append(seg)
-        in_specs.append(pl.BlockSpec((1, bkv), lambda bi, hi, qi, ki: (bi, ki)))
+        in_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, hi, qi, ki: (bi, 0, ki)))
         in_arrays.append(seg)
     o, lse = pl.pallas_call(
         kernel,
@@ -237,7 +238,7 @@ def _fwd_pallas(q, k, v, kvm, seg, *, sm_scale, causal, window, q_offset, bq, bk
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -268,9 +269,9 @@ def _dq_kernel(
 
     vis = _visible(qi, ki, bq, bkv, causal, window, q_offset)
     if kvm_ref is not None:
-        vis = jnp.logical_and(vis, jnp.any(kvm_ref[...] > 0))
+        vis = jnp.logical_and(vis, jnp.any(kvm_ref[0] > 0))
     if segq_ref is not None:
-        vis = jnp.logical_and(vis, jnp.min(segk_ref[...]) <= jnp.max(segq_ref[...]))
+        vis = jnp.logical_and(vis, jnp.min(segk_ref[0]) <= jnp.max(segq_ref[0]))
 
     @pl.when(vis)
     def _compute():
@@ -289,10 +290,10 @@ def _dq_kernel(
         if kvm_ref is not None:
             # re-apply the key padding mask — p must be 0 on padded keys or
             # dq leaks gradient through them
-            s = jnp.where(kvm_ref[...] > 0, s, NEG_INF)
+            s = jnp.where(kvm_ref[0] > 0, s, NEG_INF)
         if segq_ref is not None:
             s = jnp.where(
-                segq_ref[...].reshape(-1, 1) == segk_ref[...].reshape(1, -1),
+                segq_ref[0].reshape(-1, 1) == segk_ref[0].reshape(1, -1),
                 s, NEG_INF,
             )
         # rows with no visible key anywhere carry lse = NEG_INF; exp(s - lse)
@@ -335,9 +336,9 @@ def _dkv_kernel(
 
     vis = _visible(qi, ki, bq, bkv, causal, window, q_offset)
     if kvm_ref is not None:
-        vis = jnp.logical_and(vis, jnp.any(kvm_ref[...] > 0))
+        vis = jnp.logical_and(vis, jnp.any(kvm_ref[0] > 0))
     if segq_ref is not None:
-        vis = jnp.logical_and(vis, jnp.min(segk_ref[...]) <= jnp.max(segq_ref[...]))
+        vis = jnp.logical_and(vis, jnp.min(segk_ref[0]) <= jnp.max(segq_ref[0]))
 
     @pl.when(vis)
     def _compute():
@@ -354,10 +355,10 @@ def _dkv_kernel(
         if mask is not None:
             s = s + mask
         if kvm_ref is not None:
-            s = jnp.where(kvm_ref[...] > 0, s, NEG_INF)
+            s = jnp.where(kvm_ref[0] > 0, s, NEG_INF)
         if segq_ref is not None:
             s = jnp.where(
-                segq_ref[...].reshape(-1, 1) == segk_ref[...].reshape(1, -1),
+                segq_ref[0].reshape(-1, 1) == segk_ref[0].reshape(1, -1),
                 s, NEG_INF,
             )
         p = jnp.where(lse > NEG_INF / 2, jnp.exp(s - lse), 0.0)  # [bq, bkv]
@@ -414,10 +415,10 @@ def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpre
         pl.BlockSpec((1, 1, bq, SUBLANES), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
     ]
     if kvm is not None:
-        dq_specs.append(pl.BlockSpec((1, bkv), lambda bi, hi, qi, ki: (bi, ki)))
+        dq_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, hi, qi, ki: (bi, 0, ki)))
     if seg is not None:
-        dq_specs.append(pl.BlockSpec((1, bq), lambda bi, hi, qi, ki: (bi, qi)))
-        dq_specs.append(pl.BlockSpec((1, bkv), lambda bi, hi, qi, ki: (bi, ki)))
+        dq_specs.append(pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki: (bi, 0, qi)))
+        dq_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, hi, qi, ki: (bi, 0, ki)))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, num_kv=num_kv, **common),
         grid=(b, nh, num_q, num_kv),
@@ -425,7 +426,7 @@ def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpre
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, nh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -443,10 +444,10 @@ def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpre
         pl.BlockSpec((1, 1, bq, SUBLANES), lambda bi, kh, ki, g, qi: (bi, kh * group + g, qi, 0)),
     ]
     if kvm is not None:
-        dkv_specs.append(pl.BlockSpec((1, bkv), lambda bi, kh, ki, g, qi: (bi, ki)))
+        dkv_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, kh, ki, g, qi: (bi, 0, ki)))
     if seg is not None:
-        dkv_specs.append(pl.BlockSpec((1, bq), lambda bi, kh, ki, g, qi: (bi, qi)))
-        dkv_specs.append(pl.BlockSpec((1, bkv), lambda bi, kh, ki, g, qi: (bi, ki)))
+        dkv_specs.append(pl.BlockSpec((1, 1, bq), lambda bi, kh, ki, g, qi: (bi, 0, qi)))
+        dkv_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, kh, ki, g, qi: (bi, 0, ki)))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, num_q=num_q, group=group, **common),
         grid=(b, nkv, num_kv, group, num_q),
@@ -463,7 +464,7 @@ def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpre
             pltpu.VMEM((bkv, d), jnp.float32),
             pltpu.VMEM((bkv, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -558,6 +559,14 @@ def _flash_lse_bwd(causal, window, q_offset, bq, bkv, interpret, res, g):
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+@functools.lru_cache(maxsize=None)
+def _warn_core_route(shapes: str) -> None:
+    logger.warning(
+        "flash_attention asked for shapes that do not tile the Pallas kernel "
+        "(%s): running core_attention instead — allowed off the TPU only; on "
+        "a TPU this raises", shapes)
+
+
 def flash_tileable(sq: int, skv: int, d: int, nh: int, nkv: int,
                    block_q: Optional[int] = None,
                    block_kv: Optional[int] = None) -> bool:
@@ -566,16 +575,19 @@ def flash_tileable(sq: int, skv: int, d: int, nh: int, nkv: int,
     return _tileable(sq, skv, d, bq, bkv) and nh % nkv == 0
 
 
-def _prep_mask(attention_mask, b, skv):
-    """Normalize ``attention_mask`` [b, skv] (1 = real key) to int32 or None."""
-    if attention_mask is None:
+def _prep_rows(x, b, s, name):
+    """Normalize a per-token ``[b, s]`` operand (``attention_mask``, 1 = real
+    key; ``segment_ids``) to the kernels' int32 ``[b, 1, s]`` layout, or None.
+    The unit middle dim is what lets a ``(1, 1, block)`` BlockSpec tile it for
+    b > 1: Mosaic wants a block's second-to-last dim to be a multiple of 8 or
+    the whole dim."""
+    if x is None:
         return None
-    if attention_mask.shape != (b, skv):
+    if x.shape != (b, s):
         raise ValueError(
-            f"attention_mask must be [batch, kv_len] = ({b}, {skv}); got "
-            f"{attention_mask.shape}"
+            f"{name} must be [batch, seq] = ({b}, {s}); got {x.shape}"
         )
-    return attention_mask.astype(jnp.int32)
+    return x.astype(jnp.int32)[:, None, :]
 
 
 def flash_attention_with_lse(
@@ -601,7 +613,7 @@ def flash_attention_with_lse(
     # NOTE: unlike ``flash_attention``, sliding_window is honored even when
     # causal=False — the ring's fully-visible past chunks need exactly that
     # (window mask at a static relative offset, no causal mask)
-    bq, bkv = _block_sizes(sq, skv, block_q, block_kv)
+    bq, bkv = _block_sizes(sq, skv, block_q, block_kv, q.dtype)
     if not _tileable(sq, skv, d, bq, bkv) or nh % nkv != 0:
         raise ValueError(
             f"flash_attention_with_lse: shapes not tileable "
@@ -612,7 +624,7 @@ def flash_attention_with_lse(
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    kvm = _prep_mask(attention_mask, b, skv)
+    kvm = _prep_rows(attention_mask, b, skv, "attention_mask")
     o, lse = _flash_lse(qt, kt, vt, kvm, None, causal, sliding_window, q_offset,
                         bq, bkv, interpret)
     return jnp.swapaxes(o, 1, 2), lse
@@ -641,16 +653,26 @@ def flash_attention(
     (tokens attend only within their own record) — a correctness upgrade over
     the reference's ConcatDataset, whose packed records causally attend
     ACROSS record boundaries.
-    Falls back to ``core_attention`` when shapes don't tile (tiny test models,
-    odd head dims) — the dispatch contract of ``ops.attention``.
+    Shapes that do not tile the kernel raise on a TPU.  Off the TPU (the CPU
+    test mesh, where toy models have head dims far below a lane) they run
+    ``core_attention`` with a warning per shape.
     ``interpret`` defaults to True off-TPU so tests run on CPU.
     """
     b, sq, nh, d = q.shape
     skv, nkv = k.shape[1], k.shape[2]
     if not causal:
         sliding_window = None  # window is causal-only, matching core_attention
-    bq, bkv = _block_sizes(sq, skv, block_q, block_kv)
+    bq, bkv = _block_sizes(sq, skv, block_q, block_kv, q.dtype)
     if not _tileable(sq, skv, d, bq, bkv) or nh % nkv != 0:
+        shapes = f"sq={sq}, skv={skv}, d={d}, nh={nh}, nkv={nkv}"
+        # a host query, not a traced value
+        if jax.default_backend() == "tpu":  # jaxlint: disable=JL102
+            raise ValueError(
+                f"flash_attention: shapes do not tile the Pallas kernel "
+                f"({shapes}: seq blocks and head_dim must be multiples of "
+                f"{LANES}); set fusions.flash_attention: false for this model"
+            )
+        _warn_core_route(shapes)
         from neuronx_distributed_training_tpu.ops.attention import (
             core_attention,
             padding_mask_bias,
@@ -672,20 +694,13 @@ def flash_attention(
     qt = jnp.swapaxes(q, 1, 2)  # [b, nh, sq, d]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    kvm = _prep_mask(attention_mask, b, skv)
-    seg = None
-    if segment_ids is not None:
-        if sq != skv:
-            raise ValueError(
-                "segment_ids need self-attention (sq == skv); got "
-                f"sq={sq}, skv={skv}"
-            )
-        if segment_ids.shape != (b, sq):
-            raise ValueError(
-                f"segment_ids must be [batch, seq] = ({b}, {sq}); got "
-                f"{segment_ids.shape}"
-            )
-        seg = segment_ids.astype(jnp.int32)
+    kvm = _prep_rows(attention_mask, b, skv, "attention_mask")
+    if segment_ids is not None and sq != skv:
+        raise ValueError(
+            "segment_ids need self-attention (sq == skv); got "
+            f"sq={sq}, skv={skv}"
+        )
+    seg = _prep_rows(segment_ids, b, sq, "segment_ids")
     o = _flash(qt, kt, vt, kvm, seg, causal, sliding_window, q_offset, bq, bkv,
                interpret)
     return jnp.swapaxes(o, 1, 2)

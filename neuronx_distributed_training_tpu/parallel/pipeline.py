@@ -208,17 +208,11 @@ def pipeline_loss(
         # pipe-sharded on dim 0.  (params themselves are not an operand —
         # the embed and loss hooks, the only consumers, run outside.)
         in_specs=(layer_spec, P(), P(PIPE_AXIS)),
-        # aux comes back as a [pp] pipe-tiled vector summed OUTSIDE the manual
-        # region (not an in-body psum + replicated-scalar out): the replicated
-        # scalar's transpose trips legacy shard_map's spec check when a
-        # nonzero aux cotangent flows (MoE router loss under jax.grad), while
-        # the tiled sum transposes cleanly on every jax version
-        out_specs=(P(PIPE_AXIS), P(PIPE_AXIS)),
+        out_specs=(P(PIPE_AXIS), P()),
         axis_names={PIPE_AXIS},
         check_vma=False,
     )
-    parked, aux_ranks = fn(layer_params, microbatches, emb)
-    aux_total = jnp.sum(aux_ranks)
+    parked, aux_total = fn(layer_params, microbatches, emb)
 
     # ---- head + CE, once, outside the manual region --------------------
     # parked row g holds microbatch m_of_g's last-stage output (same layout
@@ -273,9 +267,7 @@ def _pipeline_body(local_layers, microbatches, emb, *, stage_fn,
     Returns ``(parked, aux)``: ``parked [slots, mb, s, h]`` holds the
     final-chunk outputs of the microbatches this rank parks (same layout as
     ``emb``) — the caller computes the loss over them outside the manual
-    region — and ``aux [1]`` is this rank's MoE router-aux contribution (the
-    caller sums the pipe-tiled vector; summing outside instead of an in-body
-    psum keeps the backward legal on legacy shard_map).
+    region — and ``aux`` is the MoE router-aux total, psum-closed over pipe.
     """
     rank = jax.lax.axis_index(PIPE_AXIS)
     is_first = rank == 0
@@ -405,7 +397,7 @@ def _pipeline_body(local_layers, microbatches, emb, *, stage_fn,
         (zeros, circ0, park0, jnp.zeros((), jnp.float32)),
         jnp.arange(nm * vp + pp - 1),
     )
-    return park, aux_acc[None]
+    return park, jax.lax.psum(aux_acc, PIPE_AXIS)
 
 
 # ---------------------------------------------------------------------------
@@ -1002,6 +994,20 @@ def predicted_bubble_fraction(schedule: Optional[str], pp: int, nm: int,
     return b / (1.0 + b)
 
 
+def _bcast_from(x, is_src):
+    """Broadcast the one source rank's ``x`` over the pipe ring: a psum to
+    which every other rank contributes zeros.  The sum runs on the bit
+    pattern (unsigned, same width), which is exact with a single contributor
+    and keeps the wire bytes of ``x.dtype``.  A bf16 psum here does not
+    compile on XLA:CPU (jax 0.9.0): inside a partially-manual region JAX puts
+    a sharding constraint in the reducer, it becomes a ``copy`` at the
+    reducer's root, and the CPU's bf16 all-reduce promotion aborts on it."""
+    bits = jnp.dtype(f"uint{8 * jnp.dtype(x.dtype).itemsize}")
+    raw = jax.lax.bitcast_convert_type(x, bits)
+    out = jax.lax.psum(jnp.where(is_src, raw, jnp.zeros((), bits)), PIPE_AXIS)
+    return jax.lax.bitcast_convert_type(out, x.dtype)
+
+
 def _tree_index(tree, i):
     return jax.tree_util.tree_map(
         lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False), tree
@@ -1355,13 +1361,10 @@ def _onef1b_body(local_layers, head_params, microbatches, w_r, emb, denom,
             # its fresh output over the pipe ring, then every rank computes
             # logits for its V/pp vocab slice
             m_H = xt["h_m"]
-            y_bcast = jax.lax.psum(
-                jnp.where(
-                    jnp.logical_and(is_last,
-                                    jnp.logical_and(f_valid, c_F == vp - 1)),
-                    y, 0.0,
-                ),
-                PIPE_AXIS,
+            y_bcast = _bcast_from(
+                y,
+                jnp.logical_and(is_last,
+                                jnp.logical_and(f_valid, c_F == vp - 1)),
             )
             mbH = _tree_index(microbatches, m_H)
             # hidden fn under vjp over BOTH (hp, y) so the norm-weight grad

@@ -261,16 +261,7 @@ def in_manual_region() -> bool:
     exact).  CP attention therefore must NOT open an inner shard_map there —
     callers switch to the pure-GSPMD blockwise body instead.
     """
-    if shd.manual_fallback_active():
-        # legacy-jax fully-manual fallback (shd.shard_map): no abstract-mesh
-        # query exists there, the thread-local flag IS the signal
-        return True
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract_mesh is None:
-        return False  # legacy jax outside the fallback: no manual context
-    cur = get_abstract_mesh()
-    return bool(getattr(cur, "axis_names", None)
-                and any("Manual" in str(t) for t in cur.axis_types))
+    return bool(shd.manual_axes())
 
 
 def pick_bkv(s: int, block_kv: int) -> tuple[int, bool]:

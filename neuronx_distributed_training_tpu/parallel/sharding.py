@@ -20,7 +20,6 @@ function also runs unsharded (unit tests, single host).
 from __future__ import annotations
 
 import contextlib
-import functools
 import threading
 from typing import Optional
 
@@ -32,58 +31,27 @@ from neuronx_distributed_training_tpu.parallel.mesh import DATA_AXES
 _STATE = threading.local()
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check_vma=True):
-    """Version-portable ``shard_map``.
-
-    New JAX (``jax.shard_map``): passes through unchanged, including partial
-    manualness via ``axis_names`` (e.g. the pipeline body is Manual over
-    ``pipe`` only; GSPMD keeps sharding data/model inside).
-
-    Old JAX (``jax.experimental.shard_map``, no ``axis_names``/``check_vma``):
-    partial-auto shard_map is unusable there (``axis_index`` lowers to a bare
-    PartitionId the SPMD partitioner rejects, and operand transfers CHECK-fail
-    on manual-subgroup mismatches), so the fallback runs the body manual over
-    ALL mesh axes.  ``in_specs`` keep their meaning — axes not named in a spec
-    are replicated — so the body computes the same values, merely without
-    GSPMD re-sharding its internals over the auto axes (each data/model rank
-    redundantly holds the full replicated slice).  Collectives over the named
-    axes are identical.  ``constrain`` calls inside the body become no-ops via
-    a thread-local flag set for the duration of the body trace (their specs
-    name axes that are Manual in the fallback, which old wsc cannot express).
-    """
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    @functools.wraps(f)
-    def body(*args, **kwargs):
-        prev = getattr(_STATE, "manual_all", False)
-        _STATE.manual_all = True
-        try:
-            return f(*args, **kwargs)
-        finally:
-            _STATE.manual_all = prev
-
-    return _legacy_shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=bool(check_vma),
-    )
+def shard_map(f, *, mesh, in_specs, out_specs, axis_names=frozenset(),
+              check_vma=True):
+    """``jax.shard_map``.  ``axis_names`` empty means every mesh axis (fully
+    manual); a set gives partial manualness (the pipeline body is Manual over
+    ``pipe`` only, and GSPMD keeps sharding data/model inside)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=axis_names, check_vma=check_vma)
 
 
 def active_mesh() -> Optional[Mesh]:
     return getattr(_STATE, "mesh", None)
 
 
-def manual_fallback_active() -> bool:
-    """True while tracing inside the legacy fully-manual ``shard_map``
-    fallback (see ``shard_map`` below) — the signal ``constrain`` and
-    nested-manual-region checks use on jax versions without an abstract-mesh
-    query."""
-    return bool(getattr(_STATE, "manual_all", False))
+def manual_axes() -> frozenset:
+    """Mesh axes that are Manual where this is traced: empty outside any
+    ``shard_map``, ``{"pipe"}`` inside the pipeline body."""
+    cur = jax.sharding.get_abstract_mesh()
+    return frozenset(
+        n for n, t in zip(cur.axis_names, cur.axis_types)
+        if t == jax.sharding.AxisType.Manual
+    )
 
 
 @contextlib.contextmanager
@@ -115,20 +83,13 @@ def constrain(x, spec: Optional[P], mesh: Optional[Mesh] = None):
     """
     if spec is None:
         return x
-    if manual_fallback_active():
-        # inside the legacy fully-manual shard_map fallback (see shard_map
-        # above): every mesh axis is Manual there, so sharding constraints are
-        # inexpressible — and unnecessary, the values are already per-device
-        return x
     try:
         return jax.lax.with_sharding_constraint(x, spec)
     except RuntimeError as e:
         # ONLY the no-context-mesh case falls through (plain jit under the
         # legacy `with mesh:` manager); a genuine spec error (bad axis, rank
         # mismatch — ValueError) must propagate, not silently return
-        # unconstrained activations.  The no-mesh message has drifted across
-        # jax versions ("non-empty mesh in context" vs "requires a non-empty
-        # mesh if you are passing"), so match the stable stem.
+        # unconstrained activations.
         if "non-empty mesh" not in str(e):
             raise
         m = mesh or active_mesh()

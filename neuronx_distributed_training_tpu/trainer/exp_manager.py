@@ -394,7 +394,8 @@ class ExpManager:
         once with the analytic per-family FLOPs estimate
         (``utils.perf.flops_for_model`` x3 for fwd+2xbwd); from then on every
         ``log_metrics`` derives ``mfu`` from the throughput window's
-        ``tokens_per_sec`` — one source of truth, no second timer."""
+        ``tokens_per_sec`` — one source of truth, no second timer.  A peak of
+        0 (off the TPU) logs tokens/s/chip and no ``mfu``."""
         self._mfu_ref = (
             float(train_step_flops_per_token), max(int(n_chips), 1),
             float(peak_tflops_per_chip),

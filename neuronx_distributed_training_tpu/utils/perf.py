@@ -15,7 +15,7 @@ import jax
 
 # Peak bf16 TFLOP/s per chip by TPU generation (public figures).
 # Ordered most-specific-first: device_kind strings like "TPU v5 lite" must
-# match their own entry before the bare-generation fallback.
+# match their own entry before the bare-generation one.
 PEAK_TFLOPS_PER_CHIP = {
     "v5 lite": 197.0,  # v5e device_kind spells it out
     "v5e": 197.0,
@@ -25,19 +25,29 @@ PEAK_TFLOPS_PER_CHIP = {
     "v6": 918.0,
     "v4": 275.0,
     "v5": 459.0,
-    "cpu": 0.5,  # nominal; keeps MFU finite in CPU smoke runs
+    # nominal figure for the autotune planner's off-hardware "cpu" topology
+    # only; ``detect_peak_tflops`` never returns it (no MFU on a CPU)
+    "cpu": 0.5,
 }
 
 
-def detect_peak_tflops(device: jax.Device | None = None) -> float:
+def detect_peak_tflops(device: jax.Device | None = None) -> float | None:
+    """Peak bf16 TFLOP/s of ``device``'s TPU generation.  ``None`` off the
+    TPU: there is no peak to hold a CPU run against, so no MFU is derived
+    there.  A TPU whose ``device_kind`` is not in the table raises — a
+    default would price it with another chip's peak."""
     device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", device.platform).lower()
+    if device.platform != "tpu":
+        return None
+    kind = device.device_kind.lower()
     for key, tf in PEAK_TFLOPS_PER_CHIP.items():
         if key in kind:
             return tf
-    if device.platform == "tpu":
-        return PEAK_TFLOPS_PER_CHIP["v5p"]
-    return PEAK_TFLOPS_PER_CHIP["cpu"]
+    raise ValueError(
+        f"unknown TPU device_kind {device.device_kind!r}: add its peak to "
+        f"utils.perf.PEAK_TFLOPS_PER_CHIP (known: "
+        f"{', '.join(k for k in PEAK_TFLOPS_PER_CHIP if k != 'cpu')})"
+    )
 
 
 def llama_flops_per_token(

@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from conftest import requires_orbax_preservation
 
 from neuronx_distributed_training_tpu.alignment import (
     compute_reference_logprobs,
@@ -412,9 +411,6 @@ class TestKTOMismatchedKL:
             np.testing.assert_array_equal(kl_comp, comp_j)
             assert kl_comp.size > 0
 
-    @requires_orbax_preservation  # the sidecar lives next to the checkpoints,
-    # so this path constructs a real Checkpointer (enable_checkpointing
-    # defaults True)
     def test_stale_sidecar_column_set_recomputes(self, tmp_path, devices8):
         """A batch_mean sidecar resumed under mismatched must recompute, not
         KeyError in the jitted step."""

@@ -588,3 +588,57 @@ class TestAutotuneKnobBlock:
                            "hbm_headroom": 0.85, "max_micro_batch_size": 4}
         cfg = load_config(raw)
         assert cfg["autotune"]["top_k"] == 3
+
+
+# ---------------------------------------------------------------------------
+# device identity: an unknown chip is an error, never another chip's numbers
+# ---------------------------------------------------------------------------
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+class TestDeviceIdentity:
+    def test_known_tpu_kinds_resolve(self):
+        from neuronx_distributed_training_tpu.utils import perf
+
+        v5e = _FakeDevice("tpu", "TPU v5 lite")
+        assert perf.detect_peak_tflops(v5e) == 197.0
+        assert resolve_topology(device=v5e).name == "v5e"
+        assert resolve_topology(device=_FakeDevice("tpu", "TPU v4")).name == "v4"
+
+    def test_unknown_tpu_kind_raises(self):
+        from neuronx_distributed_training_tpu.utils import perf
+
+        dev = _FakeDevice("tpu", "TPU v9 zeta")
+        with pytest.raises(ValueError, match="v9 zeta"):
+            perf.detect_peak_tflops(dev)
+        with pytest.raises(ValueError, match="v9 zeta"):
+            resolve_topology(device=dev)
+
+    def test_cpu_has_no_peak_and_other_platforms_raise(self):
+        from neuronx_distributed_training_tpu.utils import perf
+
+        assert perf.detect_peak_tflops(jax.devices()[0]) is None
+        assert resolve_topology(device=jax.devices()[0]).name == "cpu"
+        with pytest.raises(ValueError, match="unrecognized"):
+            resolve_topology(device=_FakeDevice("gpu", "H100"))
+        with pytest.raises(ValueError, match="name or a device"):
+            resolve_topology()
+
+    def test_trainer_refuses_an_unknown_tpu(self, monkeypatch):
+        """The fit loop consumes both tables; an unknown chip must stop the
+        trainer, not be swallowed by the observability try-blocks."""
+        from neuronx_distributed_training_tpu.trainer.loop import Trainer
+        from neuronx_distributed_training_tpu.utils import perf
+
+        def unknown(device=None):
+            raise ValueError("unknown TPU device_kind 'TPU v9 zeta'")
+
+        monkeypatch.setattr(perf, "detect_peak_tflops", unknown)
+        with pytest.raises(ValueError, match="v9 zeta"):
+            Trainer.from_config(load_config(tiny_raw()),
+                                devices=jax.devices()[:4],
+                                enable_checkpointing=False)

@@ -12,8 +12,6 @@ from neuronx_distributed_training_tpu.checkpoint import (
     TrainState,
 )
 
-from conftest import requires_orbax_preservation
-
 
 def make_state(step=0, consumed=0, scale=1.0):
     params = {
@@ -25,7 +23,6 @@ def make_state(step=0, consumed=0, scale=1.0):
                       extra={"lr": 0.1})
 
 
-@requires_orbax_preservation
 class TestRoundTrip:
     def test_save_restore(self, tmp_path):
         cfg = CheckpointConfig(dir=tmp_path, async_save=False, save_top_k=2)
@@ -65,7 +62,6 @@ class TestRoundTrip:
             assert ck.latest_step() == 1
 
 
-@requires_orbax_preservation
 class TestRetention:
     def test_topk_keeps_best_and_latest(self, tmp_path):
         cfg = CheckpointConfig(dir=tmp_path, async_save=False, save_top_k=2, monitor="loss")
@@ -102,7 +98,6 @@ class TestRetention:
                 ck.restore(s.params, s.opt_state)
 
 
-@requires_orbax_preservation
 class TestWarmStart:
     def test_params_only(self, tmp_path):
         cfg = CheckpointConfig(dir=tmp_path, async_save=False)
@@ -146,7 +141,6 @@ class TestPrecisionKnobs:
         return TrainState(params=params, opt_state=opt, step=3,
                           consumed_samples=24)
 
-    @requires_orbax_preservation
     def test_save_bf16_halves_and_restores_cast_up(self, tmp_path):
         cfg = CheckpointConfig(dir=tmp_path, async_save=False, save_bf16=True)
         st = self._state()
@@ -163,7 +157,6 @@ class TestPrecisionKnobs:
         # integer leaves (opt step) untouched
         assert int(restored.opt_state["step"]) == 3
 
-    @requires_orbax_preservation
     def test_drop_master_reseeds_from_params(self, tmp_path):
         cfg = CheckpointConfig(dir=tmp_path, async_save=False,
                                use_master_weights_in_ckpt=False)
@@ -191,7 +184,6 @@ class TestPrecisionKnobs:
         })
         assert cfg.save_bf16 and not cfg.use_master_weights_in_ckpt
 
-    @requires_orbax_preservation
     def test_bitwise_default_unchanged(self, tmp_path):
         """Default knobs keep the bitwise round-trip (the resume-exactness
         contract other tests pin)."""
@@ -231,7 +223,6 @@ class TestRemoteStylePath:
         with pytest.raises(ValueError, match="URI scheme"):
             resolve_checkpoint_dir("file:///tmp/x")
 
-    @requires_orbax_preservation
     def test_epath_round_trip(self, tmp_path):
         """Full save/restore through etils epath.Path — the same class the
         gs:// path uses, exercising the TensorStore-facing path plumbing."""

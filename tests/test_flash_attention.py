@@ -51,12 +51,81 @@ def test_flash_matches_core_fwd_and_grad(sq, skv, nh, nkv, window, causal):
         assert err < 2e-3, f"d{name} rel err {err}"
 
 
-def test_flash_untileable_falls_back():
-    # head_dim 64 is not lane-aligned -> silently uses core attention
+def test_flash_untileable_off_tpu_warns_and_runs_core(caplog):
+    # head_dim 64 is not lane-aligned: off the TPU (toy test models) the
+    # core path runs, and says so
+    from neuronx_distributed_training_tpu.ops import flash_attention as fa
+
+    fa._warn_core_route.cache_clear()
     q, k, v = _make_qkv(jax.random.PRNGKey(1), 1, 64, 64, 2, 2, 64)
-    o = flash_attention(q, k, v, causal=True, interpret=True)
+    with caplog.at_level("WARNING", logger=fa.logger.name):
+        o = flash_attention(q, k, v, causal=True, interpret=True)
+    assert "do not tile" in caplog.text and "d=64" in caplog.text
     ref = core_attention(q, k, v, causal=True)
     assert jnp.allclose(o, ref, rtol=1e-5, atol=1e-5)
+
+
+def test_flash_untileable_raises_on_tpu(monkeypatch):
+    # with flash_attention: true on a TPU there is no quiet core route
+    from neuronx_distributed_training_tpu.ops import attention as attn_ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q, k, v = _make_qkv(jax.random.PRNGKey(1), 1, 64, 64, 2, 2, 64)
+    with pytest.raises(ValueError, match="do not tile"):
+        attn_ops.attention(q, k, v, impl="flash")
+
+
+@pytest.mark.parametrize("nkv", [2, 1], ids=["kv_divides_tp", "kv_below_tp"])
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked"])
+def test_flash_impl_on_dp2_tp2_mesh_matches_core(devices8, nkv, masked):
+    """``impl="flash"`` on a mesh goes through the shard_map wrap (batch over
+    data, heads over model; KV heads repeated when tp exceeds them) — a
+    Mosaic call GSPMD cannot partition on a TPU, so parity must hold through
+    the wrap and not only through interpret mode's partitionable jnp ops."""
+    from conftest import ragged_right_pad_mask
+
+    from neuronx_distributed_training_tpu.ops import attention as attn_ops
+    from neuronx_distributed_training_tpu.parallel import sharding as shd
+    from neuronx_distributed_training_tpu.parallel.mesh import (
+        MeshConfig,
+        build_mesh,
+    )
+
+    mesh = build_mesh(MeshConfig(tensor_model_parallel_size=2),
+                      devices=devices8[:4])
+    b, s = 4, 256
+    q, k, v = _make_qkv(jax.random.PRNGKey(3), b, s, s, 4, nkv, 128)
+    mask = ragged_right_pad_mask(b, s, [256, 200, 100, 256]) if masked else None
+
+    def loss(impl):
+        def f(q, k, v):
+            o = attn_ops.attention(q, k, v, impl=impl, attention_mask=mask,
+                                   block_q=128, block_kv=128)
+            return jnp.sum(o * o)
+        return f
+
+    with mesh, shd.use_mesh(mesh):
+        lf, gf = jax.jit(jax.value_and_grad(loss("flash"), argnums=(0, 1, 2)))(q, k, v)
+        lc, gc = jax.jit(jax.value_and_grad(loss("core"), argnums=(0, 1, 2)))(q, k, v)
+    assert jnp.allclose(lf, lc, rtol=2e-4), (lf, lc)
+    for a, b_, name in zip(gf, gc, "qkv"):
+        err = jnp.max(jnp.abs(a - b_)) / (jnp.max(jnp.abs(b_)) + 1e-9)
+        assert err < 2e-3, f"d{name} rel err {err}"
+
+
+def test_flash_on_mesh_rejects_heads_tp_cannot_split(devices8):
+    from neuronx_distributed_training_tpu.ops import attention as attn_ops
+    from neuronx_distributed_training_tpu.parallel import sharding as shd
+    from neuronx_distributed_training_tpu.parallel.mesh import (
+        MeshConfig,
+        build_mesh,
+    )
+
+    mesh = build_mesh(MeshConfig(tensor_model_parallel_size=2),
+                      devices=devices8[:4])
+    q, k, v = _make_qkv(jax.random.PRNGKey(3), 2, 128, 128, 3, 3, 128)
+    with mesh, shd.use_mesh(mesh), pytest.raises(ValueError, match="tp=2"):
+        attn_ops.attention(q, k, v, impl="flash")
 
 
 def test_flash_q_offset_matches_core():
@@ -251,3 +320,28 @@ class TestSegmentedFlash:
             flash_attention(q, k, v, causal=False,
                             segment_ids=jnp.zeros((1, 128), jnp.int32),
                             interpret=True)
+
+
+def test_flash_inside_pipeline_region_matches_core(devices8, tmp_path):
+    """pp2 x dp2 x tp2 through the trainer: inside the pipeline's
+    pipe-manual region the flash call opens a nested shard_map over the
+    remaining axes.  An earlier nested shard_map there (the ring's) summed
+    cotangents across pipe — so the gate is the gradient norm, over steps
+    that move the parameters, against the same run on core attention."""
+    from neuronx_distributed_training_tpu.config.loader import load_config
+    from neuronx_distributed_training_tpu.trainer.loop import Trainer
+    from test_autotune import tiny_raw
+
+    def run(flash):
+        raw = tiny_raw(tp=2, pp=2, sched="1f1b", h=256, heads=2, kv=2,
+                       ffn=512, seq=128, layers=4, gbs=8,
+                       fusions={"flash_attention": flash})
+        raw["trainer"]["max_steps"] = 3
+        raw["exp_manager"] = {"exp_dir": str(tmp_path / f"flash_{flash}"),
+                              "create_checkpoint_callback": False}
+        return Trainer.from_config(load_config(raw), devices=devices8,
+                                   enable_checkpointing=False).fit()
+
+    flash, core = run(True), run(False)
+    assert flash["loss"] == pytest.approx(core["loss"], rel=2e-3)
+    assert flash["grad_norm"] == pytest.approx(core["grad_norm"], rel=5e-3)

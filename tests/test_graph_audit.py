@@ -192,7 +192,11 @@ class TestRuleInjections:
 
         def step(p, o, b, k):
             # [8, 4096] f32 fully replicated = 128 KiB/device vs a ~KB budget
-            blob = jnp.broadcast_to(p["w"].reshape(-1)[:1], (8, 4096)) + b.sum()
+            # (a sort, so that XLA has to materialize it: a plain
+            # broadcast + sum is folded into one fused reduction)
+            blob = jnp.sort(jnp.sin(
+                jnp.arange(8 * 4096, dtype=jnp.float32).reshape(8, 4096)
+                * p["w"][0, 0]) + b.sum(), axis=-1)
             return ({"w": p["w"] + 1}, {"m": o["m"] * 2},
                     {"loss": blob.sum()})
 

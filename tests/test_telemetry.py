@@ -371,12 +371,15 @@ class TestTrainerTelemetry:
         boundary = [r for r in records if "step_time" in r]
         assert boundary, records
         last = boundary[-1]
-        for key in ("mfu", "tokens_per_sec_per_chip", "goodput_fraction",
+        for key in ("tokens_per_sec_per_chip", "goodput_fraction",
                     "time/data_wait", "time/dispatch", "time/host_sync",
                     "throughput_seqs_per_sec", "loss", "lr"):
             assert key in last, (key, sorted(last))
         assert 0.0 <= last["goodput_fraction"] <= 1.0
-        assert last["mfu"] > 0.0
+        assert last["tokens_per_sec_per_chip"] > 0.0
+        # a CPU has no peak to be held against: MFU is a device metric and
+        # is not emitted off the TPU
+        assert "mfu" not in last
         assert np.isfinite(metrics["val_loss"])
 
     def test_first_boundary_carries_compile_span(self, telemetry_run):
@@ -538,4 +541,4 @@ class TestMetricsReport:
         t, _, _, _ = telemetry_run
         assert mr.main([str(t.exp.log_dir)]) == 0
         out = capsys.readouterr().out
-        assert "mfu" in out and "compile census" in out
+        assert "tokens_per_sec_per_chip" in out and "compile census" in out

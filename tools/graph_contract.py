@@ -43,11 +43,10 @@ def _fingerprint_worker(args: tuple) -> dict:
     """One config -> fingerprint dict (or an ``error`` payload).  Runs in a
     worker process under --jobs: the parent exported XLA_FLAGS/JAX_PLATFORMS
     before the pool spawned, so each worker sizes its own CPU world."""
-    path, shrink, platform = args
+    path, shrink = args
     import jax
 
-    if platform == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_platforms", "cpu")
     from neuronx_distributed_training_tpu.analysis.graph_contract import (
         ContractError,
         fingerprint_config,
@@ -94,9 +93,6 @@ def main() -> None:
     ap.add_argument("--json", metavar="PATH",
                     help="machine-readable report ('-' for stdout, "
                          "guaranteed last line)")
-    ap.add_argument("--platform", default="cpu", choices=["cpu", "tpu"],
-                    help="jax platform for the abstract lowering (default "
-                         "cpu: the check is static)")
     args = ap.parse_args()
 
     configs = list(args.config)
@@ -108,22 +104,20 @@ def main() -> None:
     if not configs:
         ap.error("nothing to do: pass --config and/or --all-examples")
 
-    # Size the virtual device world BEFORE any jax initializes (parent or
-    # --jobs workers — the env is inherited across the spawn).
-    if args.platform == "cpu":
-        from preflight_audit import _required_world
+    # A static CPU analysis, pinned to CPU: neither this process nor a --jobs
+    # worker may take a chip.  Size the virtual device world BEFORE any jax
+    # initializes (the env is inherited across the spawn).
+    from preflight_audit import _required_world
 
-        world = max(_required_world(configs, args.shrink), 8)
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count={world}"
-            ).strip()
-        # exported (not setdefault): spawned --jobs workers must come up on
-        # CPU even when the parent env pins a TPU plugin platform
-        os.environ["JAX_PLATFORMS"] = "cpu"
+    world = max(_required_world(configs, args.shrink), 8)
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={world}"
+        ).strip()
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
-    work = [(p, args.shrink, args.platform) for p in configs]
+    work = [(p, args.shrink) for p in configs]
     if args.jobs > 1 and len(work) > 1:
         import multiprocessing as mp
         from concurrent.futures import ProcessPoolExecutor

@@ -247,27 +247,6 @@ def trace_section(trace: dict) -> str:
     return "\n".join(lines)
 
 
-def provenance_section(summary: dict) -> str:
-    """Bench provenance (bench.py acquire_device): the acquire mode, the
-    watchdog phase tag actually reached, PJRT handshake timing, and backend
-    identity — what makes a dead bench round diagnosable from its JSON
-    artifact alone."""
-    prov = summary.get("provenance")
-    if not isinstance(prov, dict) or not prov:
-        return ""
-    lines = ["", "bench provenance (acquire/backend forensics)"]
-    for key in ("acquire_mode", "connect_phase", "requested_platform",
-                "platform", "device_kind", "jax_version",
-                "plugin_init_seconds", "first_rpc_seconds",
-                "probe_seconds", "probe_attempts",
-                "connect_timeout_seconds", "error"):
-        if prov.get(key) is not None:
-            v = prov[key]
-            lines.append(f"  {key:<22} "
-                         f"{_fmt(v) if isinstance(v, (int, float)) else v}")
-    return "\n".join(lines)
-
-
 def perf_contract_section(summary: dict) -> str:
     """Perf-contract verdict (analysis.perf_contract): whether this line's
     measured numbers were checked against the committed per-topology
@@ -568,7 +547,6 @@ def render(metrics_path: str | None, summary_path: str | None,
         parts.append(control_section(summary))
         parts.append(census_section(summary))
         parts.append(comms_section(summary))
-        parts.append(provenance_section(summary))
         parts.append(perf_contract_section(summary))
     parts.append(memory_section(summary, run_dir))
     parts.append(fleet_section(run_dir))

@@ -77,11 +77,10 @@ def _audit_worker(args: tuple) -> dict:
     exported XLA_FLAGS / JAX_PLATFORMS before the pool spawned, so each
     worker initializes its own correctly-sized CPU world; results carry the
     pre-rendered text so the parent can merge output deterministically."""
-    path, shrink, slack, platform, contracts = args
+    path, shrink, slack, contracts = args
     import jax
 
-    if platform == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_platforms", "cpu")
     from neuronx_distributed_training_tpu.analysis.graph_audit import (
         audit_config,
     )
@@ -164,9 +163,6 @@ def main() -> None:
     ap.add_argument("--update-baseline", action="store_true",
                     help="rewrite the jaxlint ratchet baseline from the "
                          "current findings (review the diff!)")
-    ap.add_argument("--platform", default="cpu", choices=["cpu", "tpu"],
-                    help="jax platform for the abstract lowering (default "
-                         "cpu: the audit is static)")
     args = ap.parse_args()
 
     configs = list(args.config)
@@ -181,9 +177,10 @@ def main() -> None:
         ap.error("--update-baseline only makes sense with --lint (the "
                  "baseline is regenerated from the lint findings)")
 
-    # Size the virtual device world BEFORE jax initializes its backend
-    # (parent AND any --jobs worker: the env crosses the spawn).
-    if configs and args.platform == "cpu":
+    # A static CPU analysis, pinned to CPU: neither this process nor a
+    # --jobs worker may take a chip.  Size the virtual device world BEFORE
+    # jax initializes its backend (the env crosses the spawn).
+    if configs:
         world = max(_required_world(configs, args.shrink), 8)
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:
@@ -195,8 +192,8 @@ def main() -> None:
     failed = False
     out: dict = {"reports": []}
 
-    work = [(p, args.shrink, args.replication_slack, args.platform,
-             args.contracts) for p in configs]
+    work = [(p, args.shrink, args.replication_slack, args.contracts)
+            for p in configs]
     if args.jobs > 1 and len(work) > 1:
         import multiprocessing as mp
         from concurrent.futures import ProcessPoolExecutor
@@ -222,8 +219,7 @@ def main() -> None:
 
     import jax
 
-    if args.platform == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_platforms", "cpu")
 
     from neuronx_distributed_training_tpu.analysis import jaxlint
 
