@@ -293,19 +293,22 @@ def _mlp_block(lp, x):
 def _decoder_layer(layer_params, x, cos, sin, cfg: LlamaConfig, policy: DtypePolicy,
                    attention_mask=None, segment_ids=None, return_kv=False):
     aspec = shd.act_spec(cfg.sequence_parallel, cfg.context_parallel)
-    residual = x
-    hidden = norm_ops.apply_rms_norm(layer_params["input_norm"], x, eps=cfg.rms_norm_eps)
-    hidden = _attention_block(layer_params["attn"], hidden, cos, sin, cfg, policy,
-                              attention_mask=attention_mask,
-                              segment_ids=segment_ids, return_kv=return_kv)
-    kv = None
-    if return_kv:
-        hidden, kv = hidden
-    x = shd.constrain(residual + hidden, aspec)
-    residual = x
-    hidden = norm_ops.apply_rms_norm(layer_params["post_attn_norm"], x, eps=cfg.rms_norm_eps)
-    hidden = _mlp_block(layer_params["mlp"], hidden)
-    x = shd.constrain(residual + hidden, aspec)
+    # scope names: telemetry.spans.DEVICE_SCOPES
+    with jax.named_scope("attention"):
+        residual = x
+        hidden = norm_ops.apply_rms_norm(layer_params["input_norm"], x, eps=cfg.rms_norm_eps)
+        hidden = _attention_block(layer_params["attn"], hidden, cos, sin, cfg, policy,
+                                  attention_mask=attention_mask,
+                                  segment_ids=segment_ids, return_kv=return_kv)
+        kv = None
+        if return_kv:
+            hidden, kv = hidden
+        x = shd.constrain(residual + hidden, aspec)
+    with jax.named_scope("mlp"):
+        residual = x
+        hidden = norm_ops.apply_rms_norm(layer_params["post_attn_norm"], x, eps=cfg.rms_norm_eps)
+        hidden = _mlp_block(layer_params["mlp"], hidden)
+        x = shd.constrain(residual + hidden, aspec)
     if return_kv:
         return x, kv
     return x
@@ -365,7 +368,8 @@ def hidden_states(
     if remat is not None:
         body = jax.checkpoint(body, policy=remat, prevent_cse=False)
     x, _ = jax.lax.scan(body, x, layer_stack)
-    return norm_ops.apply_rms_norm(params["final_norm"], x, eps=cfg.rms_norm_eps)
+    with jax.named_scope("ce_head"):
+        return norm_ops.apply_rms_norm(params["final_norm"], x, eps=cfg.rms_norm_eps)
 
 
 def logits_fn(params, hidden: jax.Array, cfg: LlamaConfig, policy: DtypePolicy) -> jax.Array:
@@ -558,6 +562,15 @@ def forward(
     hidden = hidden_states(params, input_ids, cfg, policy, positions=positions,
                            attention_mask=attention_mask,
                            segment_ids=segment_ids)
+    with jax.named_scope("ce_head"):
+        return _head_loss(params, hidden, batch, cfg, policy,
+                          shift_labels=shift_labels, return_logits=return_logits)
+
+
+def _head_loss(params, hidden, batch, cfg: LlamaConfig, policy: DtypePolicy, *,
+               shift_labels: bool, return_logits: bool):
+    """``forward``'s head: logits (or the fused chunked head) and the loss."""
+    attention_mask = batch.get("attention_mask")
     labels = batch.get("labels")
     head_plain = cfg.tie_word_embeddings or (
         "lm_head" in params and "lora_a" not in params["lm_head"]

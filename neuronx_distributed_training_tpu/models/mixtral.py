@@ -185,23 +185,30 @@ def _decoder_layer(lp, x, cos, sin, cfg: MixtralConfig, policy: DtypePolicy,
     """Pre-LN attention + MoE block; returns (x, aux_loss[, (k, v)])."""
     lc = cfg.llama
     aspec = shd.act_spec(lc.sequence_parallel, lc.context_parallel)
+    # scope names: telemetry.spans.DEVICE_SCOPES
+    with jax.named_scope("attention"):
+        residual = x
+        hidden = norm_ops.apply_rms_norm(lp["input_norm"], x, eps=lc.rms_norm_eps)
+        hidden = llama._attention_block(lp["attn"], hidden, cos, sin, lc, policy,
+                                        attention_mask=attention_mask,
+                                        segment_ids=segment_ids,
+                                        return_kv=return_kv)
+        kv = None
+        if return_kv:
+            hidden, kv = hidden
+        x = shd.constrain(residual + hidden, aspec)
     residual = x
-    hidden = norm_ops.apply_rms_norm(lp["input_norm"], x, eps=lc.rms_norm_eps)
-    hidden = llama._attention_block(lp["attn"], hidden, cos, sin, lc, policy,
-                                    attention_mask=attention_mask,
-                                    segment_ids=segment_ids,
-                                    return_kv=return_kv)
-    kv = None
-    if return_kv:
-        hidden, kv = hidden
-    x = shd.constrain(residual + hidden, aspec)
-    residual = x
-    hidden = norm_ops.apply_rms_norm(lp["post_attn_norm"], x, eps=lc.rms_norm_eps)
+    # moe_block opens the "moe" scope itself (models/gpt.py calls it too); the
+    # norm before it and the router loss and residual after it belong with it
+    with jax.named_scope("moe"):
+        hidden = norm_ops.apply_rms_norm(lp["post_attn_norm"], x, eps=lc.rms_norm_eps)
     hidden, aux = moe_ops.moe_block(
         lp["mlp"], hidden, cfg.moe, compute_dtype=policy.compute_dtype
     )
-    aux_loss = moe_ops.weighted_router_loss(aux["router_logits"], aux["expert_idx"], cfg.moe)
-    x = shd.constrain(residual + hidden, aspec)
+    with jax.named_scope("moe"):
+        aux_loss = moe_ops.weighted_router_loss(
+            aux["router_logits"], aux["expert_idx"], cfg.moe)
+        x = shd.constrain(residual + hidden, aspec)
     if return_kv:
         return x, aux_loss, kv
     return x, aux_loss
@@ -322,24 +329,24 @@ def forward(
     if remat is not None:
         body = jax.checkpoint(body, policy=remat, prevent_cse=False)
     (x, aux_sum), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
-    hidden = norm_ops.apply_rms_norm(params["final_norm"], x, eps=lc.rms_norm_eps)
-    logits = llama.logits_fn(params, hidden, lc, policy)
-
     # router_aux_loss is already coefficient-weighted (weighted_router_loss);
     # averaged over the layers that HAVE routers
     aux: dict[str, Any] = {"router_aux_loss": aux_sum / num_moe_layers(cfg)}
-    if return_logits:
-        aux["logits"] = logits
-    labels = batch.get("labels")
-    if labels is None:
-        return logits, aux
-    loss_mask = batch.get("loss_mask")
-    if attention_mask is not None:
-        am = attention_mask.astype(jnp.float32)
-        loss_mask = am if loss_mask is None else loss_mask * am
-    if shift_labels:
-        logits, labels, loss_mask = ce_ops.shift_for_next_token(logits, labels, loss_mask)
-    lm_loss = ce_ops.cross_entropy_loss(logits, labels, loss_mask=loss_mask)
+    with jax.named_scope("ce_head"):
+        hidden = norm_ops.apply_rms_norm(params["final_norm"], x, eps=lc.rms_norm_eps)
+        logits = llama.logits_fn(params, hidden, lc, policy)
+        if return_logits:
+            aux["logits"] = logits
+        labels = batch.get("labels")
+        if labels is None:
+            return logits, aux
+        loss_mask = batch.get("loss_mask")
+        if attention_mask is not None:
+            am = attention_mask.astype(jnp.float32)
+            loss_mask = am if loss_mask is None else loss_mask * am
+        if shift_labels:
+            logits, labels, loss_mask = ce_ops.shift_for_next_token(logits, labels, loss_mask)
+        lm_loss = ce_ops.cross_entropy_loss(logits, labels, loss_mask=loss_mask)
     loss = lm_loss + aux["router_aux_loss"]
     aux["lm_loss"] = lm_loss
     return loss, aux

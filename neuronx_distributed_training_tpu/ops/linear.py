@@ -115,14 +115,17 @@ def apply_embedding(params, ids: jax.Array, *, compute_dtype=None,
     vocab-parallel embedding (mask-local-vocab + all-reduce), done by GSPMD.
     """
     table = params["embedding"]
-    if via_matmul:
-        dtype = compute_dtype or table.dtype
-        oh = jax.nn.one_hot(ids, table.shape[0], dtype=dtype)
-        return oh @ table.astype(dtype)
-    out = jnp.take(table, ids, axis=0)
-    if compute_dtype is not None:
-        out = out.astype(compute_dtype)
-    return out
+    # scope names: telemetry.spans.DEVICE_SCOPES (the backward of the gather,
+    # a scatter-add into the table's gradient, lands under it too)
+    with jax.named_scope("embed"):
+        if via_matmul:
+            dtype = compute_dtype or table.dtype
+            oh = jax.nn.one_hot(ids, table.shape[0], dtype=dtype)
+            return oh @ table.astype(dtype)
+        out = jnp.take(table, ids, axis=0)
+        if compute_dtype is not None:
+            out = out.astype(compute_dtype)
+        return out
 
 
 def pad_vocab_size(vocab_size: int, make_divisible_by: int, tp: int) -> int:

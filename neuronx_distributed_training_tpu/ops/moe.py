@@ -218,19 +218,23 @@ def moe_dropped(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloa
     t, h = x.shape
     e, k = cfg.num_experts, cfg.top_k
     cap = int(max(1, round((cfg.capacity_factor or 1.0) * t * k / e)))
-    probs, idx, logits = route(params["router"], x, cfg)
+    # inner scopes of "moe": telemetry.spans.DEVICE_SCOPES
+    with jax.named_scope("router"):
+        probs, idx, logits = route(params["router"], x, cfg)
 
-    onehot = jax.nn.one_hot(idx, e, dtype=jnp.float32)  # [T, k, E]
-    # position of each (token, k) within its expert's queue
-    pos = jnp.cumsum(onehot.reshape(t * k, e), axis=0).reshape(t, k, e) - 1.0
-    keep = (pos < cap) * onehot  # drop over-capacity
-    pos_cap = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=jnp.float32)  # [T,k,E,cap]
-    dispatch = jnp.einsum("tke,tkec->tec", keep, pos_cap)  # [T, E, cap] 0/1
-    combine = jnp.einsum("tk,tke,tkec->tec", probs.astype(jnp.float32), keep, pos_cap)
-
-    x_e = jnp.einsum("tec,th->ech", dispatch.astype(compute_dtype), x.astype(compute_dtype))
-    y_e = _swiglu_experts(params["experts"], x_e, compute_dtype)
-    y = jnp.einsum("tec,ech->th", combine.astype(compute_dtype), y_e)
+    with jax.named_scope("dispatch"):
+        onehot = jax.nn.one_hot(idx, e, dtype=jnp.float32)  # [T, k, E]
+        # position of each (token, k) within its expert's queue
+        pos = jnp.cumsum(onehot.reshape(t * k, e), axis=0).reshape(t, k, e) - 1.0
+        keep = (pos < cap) * onehot  # drop over-capacity
+        pos_cap = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=jnp.float32)  # [T,k,E,cap]
+        dispatch = jnp.einsum("tke,tkec->tec", keep, pos_cap)  # [T, E, cap] 0/1
+        combine = jnp.einsum("tk,tke,tkec->tec", probs.astype(jnp.float32), keep, pos_cap)
+        x_e = jnp.einsum("tec,th->ech", dispatch.astype(compute_dtype), x.astype(compute_dtype))
+    with jax.named_scope("experts"):
+        y_e = _swiglu_experts(params["experts"], x_e, compute_dtype)
+    with jax.named_scope("combine"):
+        y = jnp.einsum("tec,ech->th", combine.astype(compute_dtype), y_e)
     return y.astype(x.dtype), (probs, idx, logits)
 
 
@@ -242,13 +246,16 @@ def moe_dropless(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bflo
     """
     t, h = x.shape
     e, k = cfg.num_experts, cfg.top_k
-    probs, idx, logits = route(params["router"], x, cfg)
+    # inner scopes of "moe": telemetry.spans.DEVICE_SCOPES
+    with jax.named_scope("router"):
+        probs, idx, logits = route(params["router"], x, cfg)
 
-    flat_expert = idx.reshape(-1)  # [T*k]
-    order = jnp.argsort(flat_expert)  # stable sort by expert
-    token_of = order // k  # original token index per sorted row
-    xs = x.astype(compute_dtype)[token_of]  # [T*k, h] gathered rows
-    group_sizes = jnp.bincount(flat_expert, length=e)
+    with jax.named_scope("dispatch"):
+        flat_expert = idx.reshape(-1)  # [T*k]
+        order = jnp.argsort(flat_expert)  # stable sort by expert
+        token_of = order // k  # original token index per sorted row
+        xs = x.astype(compute_dtype)[token_of]  # [T*k, h] gathered rows
+        group_sizes = jnp.bincount(flat_expert, length=e)
 
     # XLA's SPMD partitioner has no rule for ragged_dot's GROUP dimension:
     # with the expert dim sharded it computes each shard's local expert
@@ -262,20 +269,22 @@ def moe_dropless(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bflo
     # preserved.  Sharded-vs-unsharded parity: tests/test_mixtral.py.
     from neuronx_distributed_training_tpu.parallel import sharding as shd
 
-    gu_w = shd.constrain(
-        params["experts"]["gate_up"].astype(compute_dtype),
-        P(None, None, "model"))
-    down_w = shd.constrain(
-        params["experts"]["down"].astype(compute_dtype),
-        P(None, "model", None))
+    with jax.named_scope("experts"):
+        gu_w = shd.constrain(
+            params["experts"]["gate_up"].astype(compute_dtype),
+            P(None, None, "model"))
+        down_w = shd.constrain(
+            params["experts"]["down"].astype(compute_dtype),
+            P(None, "model", None))
 
-    gu = jax.lax.ragged_dot(xs, gu_w, group_sizes)
-    gate, up = jnp.split(gu, 2, axis=-1)
-    act = jax.nn.silu(gate) * up
-    ys = jax.lax.ragged_dot(act, down_w, group_sizes)  # [T*k, h]
+        gu = jax.lax.ragged_dot(xs, gu_w, group_sizes)
+        gate, up = jnp.split(gu, 2, axis=-1)
+        act = jax.nn.silu(gate) * up
+        ys = jax.lax.ragged_dot(act, down_w, group_sizes)  # [T*k, h]
 
-    w = probs.reshape(-1)[order].astype(compute_dtype)  # gate weight per row
-    y = jnp.zeros((t, h), compute_dtype).at[token_of].add(ys * w[:, None])
+    with jax.named_scope("combine"):
+        w = probs.reshape(-1)[order].astype(compute_dtype)  # gate weight per row
+        y = jnp.zeros((t, h), compute_dtype).at[token_of].add(ys * w[:, None])
     return y.astype(x.dtype), (probs, idx, logits)
 
 
@@ -300,16 +309,17 @@ def _shuffle_permutation(t: int, group: int) -> jnp.ndarray:
 def moe_block(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat16):
     """[b, s, h] wrapper dispatching dropped/dropless; returns (y, router_logits)."""
     b, s, h = x.shape
-    flat = x.reshape(b * s, h)
-    shuffle = (not cfg.dropless) and (cfg.token_shuffle_group_size or 0) > 1
-    if shuffle:
-        # only the dropped path is order-dependent (queue-position cumsum);
-        # dropless processes every token, so shuffling there is a no-op cost
-        perm = _shuffle_permutation(b * s, int(cfg.token_shuffle_group_size))
-        inv = jnp.argsort(perm)
-        flat = flat[perm]
-    fn = moe_dropless if cfg.dropless else moe_dropped
-    y, (probs, idx, logits) = fn(params, flat, cfg, compute_dtype=compute_dtype)
-    if shuffle:
-        y, idx, logits = y[inv], idx[inv], logits[inv]
-    return y.reshape(b, s, h), {"router_logits": logits, "expert_idx": idx}
+    with jax.named_scope("moe"):
+        flat = x.reshape(b * s, h)
+        shuffle = (not cfg.dropless) and (cfg.token_shuffle_group_size or 0) > 1
+        if shuffle:
+            # only the dropped path is order-dependent (queue-position cumsum);
+            # dropless processes every token, so shuffling there is a no-op cost
+            perm = _shuffle_permutation(b * s, int(cfg.token_shuffle_group_size))
+            inv = jnp.argsort(perm)
+            flat = flat[perm]
+        fn = moe_dropless if cfg.dropless else moe_dropped
+        y, (probs, idx, logits) = fn(params, flat, cfg, compute_dtype=compute_dtype)
+        if shuffle:
+            y, idx, logits = y[inv], idx[inv], logits[inv]
+        return y.reshape(b, s, h), {"router_logits": logits, "expert_idx": idx}

@@ -248,32 +248,35 @@ def adamw_update(
 
         grad_group_fn = grad_group_of
     step = opt_state["step"] + 1
-    grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
-    if trainable_mask is not None:
-        grads = jax.tree_util.tree_map(lambda g, m: g * m, grads, trainable_mask)
-    group_sq = None
-    if grad_group_fn is not None:
-        group_sq = grouped_sq_norms(grads, grad_group_fn)
-        total = None
-        for s in group_sq.values():
-            total = s if total is None else total + s
-        gnorm = jnp.sqrt(total if total is not None else jnp.zeros((), jnp.float32))
-    else:
-        gnorm = global_norm(grads)
-    track_finite = skip_nonfinite or grad_group_fn is not None \
-        or extra_finite is not None
-    updates_finite = None
-    if track_finite:
-        # any non-finite grad leaf poisons the squared-sum chain, so one
-        # isfinite on the global norm covers the whole grad tree
-        updates_finite = jnp.isfinite(gnorm)
-        if extra_finite is not None:
-            updates_finite = jnp.logical_and(
-                updates_finite, jnp.asarray(extra_finite, bool))
-    grads_preclip = grads  # tensorstats pre-clip view (a reference, no copy)
-    if cfg.grad_clip_norm is not None and cfg.grad_clip_norm > 0:
-        clip = jnp.minimum(1.0, cfg.grad_clip_norm / (gnorm + 1e-6))
-        grads = jax.tree_util.tree_map(lambda g: g * clip, grads)
+    # "clip" and "adamw" are inner scopes of the caller's "optimizer"
+    # (telemetry.spans.DEVICE_SCOPES)
+    with jax.named_scope("clip"):
+        grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
+        if trainable_mask is not None:
+            grads = jax.tree_util.tree_map(lambda g, m: g * m, grads, trainable_mask)
+        group_sq = None
+        if grad_group_fn is not None:
+            group_sq = grouped_sq_norms(grads, grad_group_fn)
+            total = None
+            for s in group_sq.values():
+                total = s if total is None else total + s
+            gnorm = jnp.sqrt(total if total is not None else jnp.zeros((), jnp.float32))
+        else:
+            gnorm = global_norm(grads)
+        track_finite = skip_nonfinite or grad_group_fn is not None \
+            or extra_finite is not None
+        updates_finite = None
+        if track_finite:
+            # any non-finite grad leaf poisons the squared-sum chain, so one
+            # isfinite on the global norm covers the whole grad tree
+            updates_finite = jnp.isfinite(gnorm)
+            if extra_finite is not None:
+                updates_finite = jnp.logical_and(
+                    updates_finite, jnp.asarray(extra_finite, bool))
+        grads_preclip = grads  # tensorstats pre-clip view (a reference, no copy)
+        if cfg.grad_clip_norm is not None and cfg.grad_clip_norm > 0:
+            clip = jnp.minimum(1.0, cfg.grad_clip_norm / (gnorm + 1e-6))
+            grads = jax.tree_util.tree_map(lambda g: g * clip, grads)
 
     b1, b2 = cfg.beta1, cfg.beta2
     c1 = 1.0 - b1 ** step.astype(jnp.float32)
@@ -301,32 +304,34 @@ def adamw_update(
     packed_payloads = (
         {} if (tstats is not None and tstats.buckets
                and bucket_plan is not None and bucket_plan.buckets) else None)
-    if bucket_plan is not None and bucket_plan.buckets:
-        from neuronx_distributed_training_tpu.optim.overlap import (
-            bucketed_update,
-        )
-
-        new_mu, new_nu, new_master, new_params = bucketed_update(
-            bucket_plan, params, grads, opt_state["mu"], opt_state["nu"],
-            master, masks, mu_fn=mu_fn, nu_fn=nu_fn, upd_fn=upd,
-            prefetch=prefetch_ag, collect_packed=packed_payloads,
-        )
-    else:
-        new_mu = jax.tree_util.tree_map(mu_fn, opt_state["mu"], grads)
-        new_nu = jax.tree_util.tree_map(nu_fn, opt_state["nu"], grads)
-        new_master = jax.tree_util.tree_map(upd, master, new_mu, new_nu, masks)
-        new_params = jax.tree_util.tree_map(
-            lambda x, p: x.astype(p.dtype), new_master, params
-        )
-
     odt = policy.optimizer_dtype
-    new_state = {
-        "step": step,
-        "mu": jax.tree_util.tree_map(lambda x: x.astype(odt), new_mu),
-        "nu": jax.tree_util.tree_map(lambda x: x.astype(odt), new_nu),
-    }
-    if "master" in opt_state:
-        new_state["master"] = jax.tree_util.tree_map(lambda x: x.astype(odt), new_master)
+    with jax.named_scope("adamw"):
+        if bucket_plan is not None and bucket_plan.buckets:
+            from neuronx_distributed_training_tpu.optim.overlap import (
+                bucketed_update,
+            )
+
+            new_mu, new_nu, new_master, new_params = bucketed_update(
+                bucket_plan, params, grads, opt_state["mu"], opt_state["nu"],
+                master, masks, mu_fn=mu_fn, nu_fn=nu_fn, upd_fn=upd,
+                prefetch=prefetch_ag, collect_packed=packed_payloads,
+            )
+        else:
+            new_mu = jax.tree_util.tree_map(mu_fn, opt_state["mu"], grads)
+            new_nu = jax.tree_util.tree_map(nu_fn, opt_state["nu"], grads)
+            new_master = jax.tree_util.tree_map(upd, master, new_mu, new_nu, masks)
+            new_params = jax.tree_util.tree_map(
+                lambda x, p: x.astype(p.dtype), new_master, params
+            )
+
+        new_state = {
+            "step": step,
+            "mu": jax.tree_util.tree_map(lambda x: x.astype(odt), new_mu),
+            "nu": jax.tree_util.tree_map(lambda x: x.astype(odt), new_nu),
+        }
+        if "master" in opt_state:
+            new_state["master"] = jax.tree_util.tree_map(
+                lambda x: x.astype(odt), new_master)
     if "ema" in opt_state:
         e = ema_cfg or EMAConfig()
         apply = jnp.logical_and(

@@ -13,16 +13,45 @@ This detects the CAUSE (a signature change) at dispatch time rather than the
 symptom (a stalled step) minutes later; when the trainer has swapped in an
 AOT-compiled step, the same check turns XLA's opaque "argument mismatch"
 error into a readable shape diff.
+
+The signature check sees the host's side only.  ``watch_compiles`` counts what
+XLA actually did: every backend compile (or persistent-cache read, which the
+same jax event wraps) with the trainer step it hit and its seconds — the
+``compile_events`` of ``run_summary.json``.  A stall the size of a compile
+inside the steady window shows there with its step.
 """
 
 from __future__ import annotations
 
 import logging
-from typing import Any
+from typing import Any, Callable, Optional
 
 import jax
 
 logger = logging.getLogger(__name__)
+
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# jax.monitoring has no unregister: ONE listener per process, registered on
+# first use, forwards to whichever detector the running fit() pointed it at.
+# It is called only when jax records an event duration (trace, lower,
+# compile), never on a steady step.
+_compile_sink: Optional[Callable[[float], None]] = None
+_listening = False
+
+
+def _on_event_duration(event: str, duration_secs: float, **_: Any) -> None:
+    sink = _compile_sink
+    if sink is not None and event == _BACKEND_COMPILE_EVENT:
+        sink(duration_secs)
+
+
+def _point_compile_listener(sink: Optional[Callable[[float], None]]) -> None:
+    global _compile_sink, _listening
+    if not _listening and sink is not None:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_event_duration)
+        _listening = True
+    _compile_sink = sink
 
 
 def _leaf_sig(x: Any) -> str:
@@ -51,11 +80,29 @@ class RecompileDetector:
     #: retained event cap — a loader alternating between two signatures fires
     #: every step; the tail is what run_summary.json reports anyway
     MAX_EVENTS = 100
+    #: newest backend compiles kept (a run's first steps compile dozens of
+    #: small programs; the ones that matter are the late ones)
+    MAX_COMPILE_EVENTS = 50
 
     def __init__(self) -> None:
         self._seen: dict[str, dict[str, str]] = {}
         self._warned: set[str] = set()
         self.events: list[str] = []
+        self.compile_events: list[dict[str, float]] = []
+
+    def watch_compiles(self, step_of: Callable[[], int]) -> None:
+        """Record every backend compile from now on as ``{"step":
+        step_of(), "seconds": s}`` in ``compile_events`` (takes the
+        process's listener over from any earlier detector)."""
+        def sink(seconds: float) -> None:
+            self.compile_events.append(
+                {"step": int(step_of()), "seconds": round(seconds, 4)})
+            del self.compile_events[:-self.MAX_COMPILE_EVENTS]
+
+        _point_compile_listener(sink)
+
+    def unwatch_compiles(self) -> None:
+        _point_compile_listener(None)
 
     def check(self, name: str, *args: Any) -> bool:
         """Record ``args``' signature under ``name``; returns True (and

@@ -12,7 +12,16 @@ dispatch-ahead contract is untouched:
 - ``host_sync``  the boundary metric fetch (the only place device time that
   outran the host gets absorbed)
 - ``compile``    first-step lower+compile (when the census runs it explicitly)
+- ``log_metrics``  the metric sinks' writes after the fetch (productive; it
+  runs after the boundary's drain, so it lands in the NEXT row's
+  ``time/log_metrics``)
 - ``validate`` / ``checkpoint`` / ``restart``  non-productive phases
+
+Every span also enters a ``jax.profiler.TraceAnnotation`` of the same name, so
+while a profiler window is open the spans sit on the host plane of the trace,
+on the device trace's clock; with no window open that is one disabled-TraceMe
+check.  ``DEVICE_SCOPES`` is the device side of the same table: the
+``jax.named_scope`` names the step's layers carry into every op's metadata.
 
 Two accounting windows run in parallel: per-boundary totals (``drain`` — the
 ``time/<span>`` metrics) and cumulative totals since construction (goodput).
@@ -27,11 +36,28 @@ import time
 from contextlib import contextmanager
 from typing import Iterator
 
+import jax
+
 #: spans counted against goodput AND excluded from the throughput window
 #: ("replan" is the restart-time autotune re-plan on a changed world size —
 #: docs/elasticity.md)
 NON_PRODUCTIVE_SPANS = ("compile", "validate", "checkpoint", "restart",
                         "replan")
+
+
+#: ``jax.named_scope`` names inside the compiled step: top-level scope ->
+#: the inner scopes it holds (docs/observability.md "Named scopes").  An op's
+#: scope path reaches the profiler as the ``tf_op`` stat of its event metadata,
+#: wrapped as ``jvp(<scope>)`` forward and ``transpose(jvp(<scope>))`` backward.
+DEVICE_SCOPES: dict[str, tuple[str, ...]] = {
+    "embed": (),
+    "attention": ("flash_fwd", "flash_dq", "flash_dkv"),
+    "mlp": (),
+    "moe": ("router", "dispatch", "experts", "combine"),
+    "ce_head": (),
+    "grad_accum": (),
+    "optimizer": ("clip", "adamw", "zero1_bucket_ag"),
+}
 
 
 class SpanTimer:
@@ -53,7 +79,8 @@ class SpanTimer:
             return
         t0 = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name):
+                yield
         finally:
             self.add(name, time.perf_counter() - t0)
 

@@ -21,11 +21,12 @@ dispatch-ahead contract tests hold with the knob on or off.
                             # after analysis — the summary is the product
 
 The profiler session is process-global in jax — only one trace can be live.
-``start_session``/``stop_session`` guard it with an owner token so the
-legacy ``profile_start_step`` window, this capture, and teardown can never
-double-start or double-stop it (a ``stop_trace`` on an already-closed
-session raises deep in teardown otherwise — the exact hazard the old
-``exp_manager`` stop-at-window-end vs stop-at-close pair carried).
+``start_session``/``stop_session`` guard it with an owner token so this
+capture, ``trace_steps`` and teardown can never double-start or double-stop
+it (a ``stop_trace`` on an already-closed session raises deep in teardown
+otherwise).  The reference's ``exp_manager.profile_start_step`` /
+``profile_num_steps`` is an alias that builds this block with ``keep_raw``
+(``ExpManager.__init__``): one window, one session.
 """
 
 from __future__ import annotations
@@ -169,8 +170,8 @@ class TraceConfig:
 class TraceCapture:
     """Drives one capture window over the training loop's step counter.
 
-    The trainer calls :meth:`maybe_update` once per step (before dispatch,
-    same cadence as ``maybe_profile``) and :meth:`close` at teardown; the
+    The trainer calls :meth:`maybe_update` once per step (before dispatch)
+    and :meth:`close` at teardown; the
     window [start_step, start_step + num_steps) is traced, analyzed, and
     summarized exactly once.  Every failure degrades to a warning.
     """
@@ -207,8 +208,8 @@ class TraceCapture:
         if not self.active and self.cfg.start_step <= step < end:
             # a refused session (another owner holds the global profiler)
             # is retried at the NEXT in-window step — the window gate
-            # bounds retries, and e.g. a legacy profile window may free
-            # the session mid-way through ours
+            # bounds retries, and the other owner may free the session
+            # mid-way through ours
             self.active = start_session(str(self.raw_dir), self._OWNER)
             return None
         if self.active and step >= end:

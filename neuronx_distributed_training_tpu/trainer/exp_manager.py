@@ -134,18 +134,34 @@ class ExpManager:
         # an instrumentation bug — but warning every boundary is log spam)
         self._warned_nonscalar: set[str] = set()
 
-        self.profile_start_step = profile_start_step
-        self.profile_num_steps = profile_num_steps
-        self._profiling = False
         # windowed device-time capture (telemetry.trace): summary lands in
-        # trace_summary.json next to run_summary.json
+        # trace_summary.json next to run_summary.json.  The reference's
+        # profile_start_step / profile_num_steps is an alias for the same
+        # window with the raw artifacts kept (<log_dir>/trace, what the
+        # profile plugin opens); jax allows one profiler session, so with
+        # both set there is still one: telemetry.trace's, the alias skipped
         self._trace: Optional[Any] = None
-        if self.telemetry.trace.enabled:
+        trace_cfg = self.telemetry.trace
+        if profile_start_step:
+            if trace_cfg.enabled:
+                logger.warning(
+                    "exp_manager.profile_start_step=%d skipped: "
+                    "exp_manager.telemetry.trace already holds the run's one "
+                    "profiler window", profile_start_step)
+            else:
+                from neuronx_distributed_training_tpu.telemetry.trace import (
+                    TraceConfig,
+                )
+
+                trace_cfg = TraceConfig(
+                    enabled=True, start_step=int(profile_start_step),
+                    num_steps=int(profile_num_steps), keep_raw=True)
+        if trace_cfg.enabled:
             from neuronx_distributed_training_tpu.telemetry.trace import (
                 TraceCapture,
             )
 
-            self._trace = TraceCapture(self.telemetry.trace, self.log_dir)
+            self._trace = TraceCapture(trace_cfg, self.log_dir)
 
         self._tb = None
         if create_tensorboard_logger:
@@ -250,30 +266,6 @@ class ExpManager:
 
     # -- profiling (jax.profiler -> TensorBoard profile plugin; the TPU-native
     # replacement for neuron-top/neuron-monitor, SURVEY.md §5.1) --------------
-
-    _PROFILE_OWNER = "exp_manager.profile"
-
-    def maybe_profile(self, step: int) -> None:
-        """Start/stop a ``jax.profiler`` trace around the configured window.
-
-        Start/stop go through the telemetry.trace session guard: the jax
-        profiler session is process-global, and the unguarded window-end
-        stop here vs the teardown stop in :meth:`close` could double-stop
-        (raising out of teardown) — or stomp a live ``telemetry.trace``
-        capture window."""
-        if not self.profile_start_step:
-            return
-        from neuronx_distributed_training_tpu.telemetry.trace import (
-            start_session,
-            stop_session,
-        )
-
-        if step == self.profile_start_step and not self._profiling:
-            self._profiling = start_session(
-                str(self.log_dir / "profile"), self._PROFILE_OWNER)
-        elif self._profiling and step >= self.profile_start_step + self.profile_num_steps:
-            self._profiling = False
-            stop_session(self._PROFILE_OWNER)
 
     def set_pipeline_facts(self, facts: Optional[dict[str, Any]]) -> None:
         """Arm the trace capture's pipeline-timeline reconstruction with the
@@ -524,15 +516,6 @@ class ExpManager:
             logger.warning("tensorstats.jsonl write failed: %s", e)
 
     def close(self) -> None:
-        if self._profiling:
-            # guarded: a window that already closed (or was stopped
-            # out-of-band) makes this a logged no-op, not a teardown raise
-            from neuronx_distributed_training_tpu.telemetry.trace import (
-                stop_session,
-            )
-
-            self._profiling = False
-            stop_session(self._PROFILE_OWNER)
         if self._trace is not None:
             summary = self._trace.close()
             if summary is not None:

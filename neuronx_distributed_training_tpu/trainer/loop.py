@@ -1544,6 +1544,10 @@ class Trainer:
         # the final fleet beacon
         fit_exc: Optional[BaseException] = None
         try:
+            # real backend compiles with the step they hit -> compile_events
+            # (inside the teardown scope, which detaches the listener again:
+            # the process-wide listener must not outlive this fit())
+            detector.watch_compiles(lambda: self.step)
             # the restart phase runs INSIDE the teardown scope: a restore
             # failure (corrupt checkpoint, drill restore-kill) must still
             # restore the SIGTERM handler, write the teardown summaries, and
@@ -1611,7 +1615,6 @@ class Trainer:
                 first_dispatch = True
                 last_fetch = self.step
                 while self.step < self.max_steps:
-                    self.exp.maybe_profile(self.step)
                     # device-time capture window (telemetry.trace): start/
                     # stop rides the same per-step cadence; steps outside
                     # the window are untouched (no syncs, no graph changes)
@@ -1824,12 +1827,16 @@ class Trainer:
                         # would otherwise reset the accumulator into a
                         # record every sink drops
                         last_metrics.update(batch_stats.drain())
-                    self.exp.log_metrics(self.step, last_metrics)
-                    if ts_payload:
-                        # structured observatory record -> tensorstats.jsonl
-                        # (the per-step tensorstats/ SCALARS already rode
-                        # last_metrics into every scalar sink above)
-                        self.exp.log_tensorstats(self.step, ts_payload)
+                    # the sinks' writes, after this boundary's drain: the
+                    # span lands in the NEXT row's time/log_metrics
+                    with spans.span("log_metrics"):
+                        self.exp.log_metrics(self.step, last_metrics)
+                        if ts_payload:
+                            # structured observatory record ->
+                            # tensorstats.jsonl (the per-step tensorstats/
+                            # SCALARS already rode last_metrics into every
+                            # scalar sink above)
+                            self.exp.log_tensorstats(self.step, ts_payload)
                     fleet_metrics: dict[str, float] = {}
                     if fleet is not None:
                         # this host's beacon + (rank 0) the fleet fold; a
@@ -2030,15 +2037,19 @@ class Trainer:
         """fit() teardown after the checkpoint drain: persist the goodput and
         elastic sections of ``run_summary.json`` and close the exp manager.
         Runs even when the drain raised."""
-        if tel.goodput:
-            try:
-                summary: dict[str, Any] = {
-                    "goodput": spans.goodput_summary()}
+        detector.unwatch_compiles()
+        try:
+            # beside the census's compile_seconds: every backend compile (or
+            # cache read) this fit() saw, with the step it hit
+            summary: dict[str, Any] = {
+                "compile_events": detector.compile_events}
+            if tel.goodput:
+                summary["goodput"] = spans.goodput_summary()
                 if detector.events:
                     summary["retrace_events"] = detector.events[-20:]
-                self.exp.write_run_summary(summary)
-            except Exception as e:  # noqa: BLE001 — teardown must finish
-                logger.warning("goodput summary write failed: %s", e)
+            self.exp.write_run_summary(summary)
+        except Exception as e:  # noqa: BLE001 — teardown must finish
+            logger.warning("compile/goodput summary write failed: %s", e)
         last_ts = getattr(self.exp, "last_tensorstats", None)
         if last_ts:
             # the final cumulative observatory record — the snapshot
@@ -2143,10 +2154,13 @@ class Trainer:
         # minutes on TPU, and a sync-tuned timeout would false-abort it
         try:
             t0 = _time.perf_counter()
-            lowered = self.train_step.lower(
-                self.params, self.opt_state, batch, key
-            )
-            compiled = lowered.compile()
+            # spans.add below has no annotation of its own: name the compile
+            # on the profiler's clock here, as SpanTimer.span does
+            with jax.profiler.TraceAnnotation("compile"):
+                lowered = self.train_step.lower(
+                    self.params, self.opt_state, batch, key
+                )
+                compiled = lowered.compile()
             dt = _time.perf_counter() - t0
         except Exception as e:  # noqa: BLE001 — census is best-effort
             logger.warning(

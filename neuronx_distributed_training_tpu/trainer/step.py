@@ -132,21 +132,24 @@ def make_train_step(
             def body(carry, mb):
                 loss_sum, grad_sum = carry
                 (loss, aux), grads = grad_one_microbatch(params, mb, step_key)
-                grad_sum = jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(policy.grad_accum_dtype), grad_sum, grads
-                )
-                if param_specs is not None:
-                    # Pin the accumulation carry to the param sharding, not
-                    # just the post-loop grads (line ~161): the carry's
-                    # layout is otherwise re-solved from its consumers, and
-                    # extra read-only uses of the grads (the tensorstats
-                    # reductions) can tip the partitioner into a different
-                    # carry sharding that reshards the embedding-backward
-                    # scatter-add INSIDE the loop on every microbatch
+                with jax.named_scope("grad_accum"):
                     grad_sum = jax.tree_util.tree_map(
-                        lambda s, g: shd.constrain(g, s), param_specs,
-                        grad_sum, is_leaf=lambda x: isinstance(x, P),
+                        lambda a, g: a + g.astype(policy.grad_accum_dtype),
+                        grad_sum, grads
                     )
+                    if param_specs is not None:
+                        # Pin the accumulation carry to the param sharding,
+                        # not just the post-loop grads (line ~161): the
+                        # carry's layout is otherwise re-solved from its
+                        # consumers, and extra read-only uses of the grads
+                        # (the tensorstats reductions) can tip the
+                        # partitioner into a different carry sharding that
+                        # reshards the embedding-backward scatter-add INSIDE
+                        # the loop on every microbatch
+                        grad_sum = jax.tree_util.tree_map(
+                            lambda s, g: shd.constrain(g, s), param_specs,
+                            grad_sum, is_leaf=lambda x: isinstance(x, P),
+                        )
                 return (loss_sum + loss, grad_sum), aux
 
             zeros = jax.tree_util.tree_map(
@@ -157,7 +160,8 @@ def make_train_step(
             )
             inv = 1.0 / num_microbatches
             loss = loss_sum * inv
-            grads = jax.tree_util.tree_map(lambda g: g * inv, grad_sum)
+            with jax.named_scope("grad_accum"):
+                grads = jax.tree_util.tree_map(lambda g: g * inv, grad_sum)
             aux = {k: jnp.mean(v) for k, v in aux_stack.items()}
 
         if param_specs is not None:
@@ -175,18 +179,21 @@ def make_train_step(
             )
 
         lr = lr_schedule(opt_state["step"])
-        new_params, new_opt_state, opt_metrics = adamw_update(
-            params, grads, opt_state, lr, opt_cfg, policy,
-            trainable_mask=trainable_mask, ema_cfg=ema_cfg,
-            grad_group_fn=(grad_group_of
-                           if (health is not None or tstats is not None)
-                           else None),
-            skip_nonfinite=(health is not None
-                            and health.policy == "skip_update"),
-            extra_finite=(jnp.isfinite(loss) if health is not None else None),
-            bucket_plan=bucket_plan, prefetch_ag=prefetch_ag,
-            tensorstats_cfg=tstats,
-        )
+        # scope names: telemetry.spans.DEVICE_SCOPES
+        with jax.named_scope("optimizer"):
+            new_params, new_opt_state, opt_metrics = adamw_update(
+                params, grads, opt_state, lr, opt_cfg, policy,
+                trainable_mask=trainable_mask, ema_cfg=ema_cfg,
+                grad_group_fn=(grad_group_of
+                               if (health is not None or tstats is not None)
+                               else None),
+                skip_nonfinite=(health is not None
+                                and health.policy == "skip_update"),
+                extra_finite=(jnp.isfinite(loss) if health is not None
+                              else None),
+                bucket_plan=bucket_plan, prefetch_ag=prefetch_ag,
+                tensorstats_cfg=tstats,
+            )
         metrics = {
             "loss": loss,
             "lr": jnp.asarray(lr, jnp.float32),

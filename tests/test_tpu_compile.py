@@ -180,3 +180,45 @@ def test_two_micro_batches_do_not_fit_one_chip(topo):
     with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
         _compile_step(topo, config, n_devices,
                       {**overrides, "data.global_batch_size": 2})
+
+
+# --------------------------------------------------------------------------
+# named scopes (telemetry.spans.DEVICE_SCOPES) are metadata
+# --------------------------------------------------------------------------
+
+
+def test_named_scopes_leave_the_v5e_program_the_same(topo, monkeypatch):
+    """One dense step (7B widths, one layer, two accumulated micro-batches)
+    compiled with the step's ``jax.named_scope``s and without them: the same
+    instructions by opcode and the same ``memory_analysis()``.  With them
+    every scope is in the compiled text's ``op_name``s and the three flash
+    kernels carry their own names."""
+    import contextlib
+    import re
+
+    from test_scopes import memory_totals, opcode_census
+
+    config, n_devices, overrides = STEP_CASES["one_chip_7b_widths"]
+    overrides = {**overrides, "model.num_layers": 1,
+                 "data.global_batch_size": 2}
+    scoped = _compile_step(topo, config, n_devices, overrides)
+    text = scoped.as_text()
+    for scope in ("embed", "attention", "mlp", "ce_head"):
+        assert f"jvp({scope})" in text or f"/{scope}/" in text, scope
+        assert f"transpose(jvp({scope}))" in text or (
+            f"/{scope}/" in text and "transpose(jvp())" in text), scope
+    for scope in ("grad_accum", "optimizer/clip", "optimizer/adamw",
+                  "attention/flash_fwd", "attention/flash_dq",
+                  "attention/flash_dkv"):
+        assert scope in text, scope
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        # pallas_call(name=...): the custom call's instruction is named so
+        assert re.search(rf"%{kernel}[.\d]* = .*tpu_custom_call", text), kernel
+
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda _name: contextlib.nullcontext())
+    bare = _compile_step(topo, config, n_devices, overrides)
+    assert "optimizer/adamw" not in bare.as_text()
+    assert opcode_census(bare) == opcode_census(scoped)
+    assert sum(opcode_census(scoped).values()) > 300
+    assert memory_totals(bare) == memory_totals(scoped)

@@ -269,7 +269,10 @@ class TestSessionGuard:
         assert not trace_mod.stop_session("a")  # logged, not raised
 
 
-class TestExpManagerProfileGuard:
+class TestExpManagerProfileAlias:
+    """``exp_manager.profile_start_step`` / ``profile_num_steps`` is an alias
+    for the one ``telemetry.trace`` window (raw artifacts kept)."""
+
     def _exp(self, tmp_path, **kw):
         from neuronx_distributed_training_tpu.trainer.exp_manager import (
             ExpManager,
@@ -280,36 +283,49 @@ class TestExpManagerProfileGuard:
 
     def test_teardown_after_closed_window_does_not_double_stop(
             self, tmp_path, fake_profiler):
-        """The regression: the profile window's stop at window end vs the
-        teardown stop in close() — close() after a closed window must be a
-        no-op, not a second stop_trace (which raises)."""
+        """The regression the legacy window carried: its stop at window end
+        vs the teardown stop in close() — close() after a closed window must
+        be a no-op, not a second stop_trace (which raises)."""
         exp = self._exp(tmp_path, profile_start_step=1, profile_num_steps=1)
-        exp.maybe_profile(1)   # window opens
-        assert fake_profiler.starts == 1
-        exp.maybe_profile(2)   # window closes
-        assert fake_profiler.stops == 1
+        assert exp._trace.cfg == TraceConfig(
+            enabled=True, start_step=1, num_steps=1, keep_raw=True)
+        exp.maybe_trace(0)     # before the window: untouched
+        assert fake_profiler.starts == 0
+        exp.maybe_trace(1)     # window opens
+        assert fake_profiler.starts == 1 and exp.trace_active
+        exp.maybe_trace(2)     # window closes
+        assert fake_profiler.stops == 1 and not exp.trace_active
         exp.close()            # must not stop again (and must not raise)
         assert fake_profiler.stops == 1
 
     def test_teardown_closes_a_still_open_window_once(self, tmp_path,
                                                       fake_profiler):
         exp = self._exp(tmp_path, profile_start_step=1, profile_num_steps=5)
-        exp.maybe_profile(1)
+        exp.maybe_trace(1)
         exp.close()
         assert fake_profiler.stops == 1
         exp.close()  # idempotent
         assert fake_profiler.stops == 1
 
-    def test_profile_window_yields_to_live_trace_capture(self, tmp_path,
-                                                         fake_profiler):
-        # jax allows one global session: a trace capture holding it must
-        # make the legacy profile window skip, not crash
-        trace_mod.start_session(str(tmp_path / "t"), "telemetry.trace")
-        exp = self._exp(tmp_path, profile_start_step=1, profile_num_steps=1)
-        exp.maybe_profile(1)
-        assert fake_profiler.starts == 1  # only the capture's
+    def test_alias_yields_to_a_configured_trace_block(self, tmp_path,
+                                                      fake_profiler, caplog):
+        # jax allows one global session: with both set there is one window,
+        # telemetry.trace's; the alias is logged and skipped
+        from neuronx_distributed_training_tpu.telemetry import TelemetryConfig
+
+        block = TraceConfig(enabled=True, start_step=5, num_steps=1)
+        with caplog.at_level("WARNING"):
+            exp = self._exp(tmp_path, profile_start_step=1,
+                            profile_num_steps=1,
+                            telemetry=TelemetryConfig(trace=block))
+        assert "profile_start_step=1 skipped" in caplog.text
+        assert exp._trace.cfg is block
+        exp.maybe_trace(1)
+        assert fake_profiler.starts == 0  # the alias's step: no session
+        exp.maybe_trace(5)
+        assert fake_profiler.starts == 1  # only the block's
         exp.close()
-        assert fake_profiler.stops == 0   # capture still owns the session
+        assert fake_profiler.stops == 1
 
 
 class TestTraceCaptureWindow:
