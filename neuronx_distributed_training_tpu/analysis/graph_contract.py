@@ -179,14 +179,18 @@ class DeclaredComms:
     ring: bool
     accum: bool = False  # gradient accumulation (num_microbatches > 1)
     zero1_bucket: bool = False  # engineered overlap: bucketed ZeRO-1 gathers
+    moe_dropless: bool = False  # the sort + ragged_dot block (else capacity)
 
     @classmethod
     def from_ctx(cls, ctx: Any) -> "DeclaredComms":
+        from neuronx_distributed_training_tpu.ops.moe import MoEConfig
+
         fus = ctx.fusions
         dp_total = ctx.axis("data") * ctx.axis("expert")
         gbs = int(ctx.sched.get("global_batch_size", 1) or 1)
         mbs = int(ctx.sched.get("micro_batch_size", 1) or 1)
         overlap = ctx.ds.get("overlap") or {}
+        moe_block = (ctx.cfg.get("model", {}) or {}).get("moe")
         return cls(
             tp=ctx.axis("model"), pp=ctx.axis("pipe"),
             cp=ctx.axis("context"), ep=ctx.axis("expert"),
@@ -195,7 +199,9 @@ class DeclaredComms:
             zero1_bucket=(bool(ctx.ds.get("zero1", True))
                           and float(overlap.get("zero1_bucket_mb", 0) or 0) > 0),
             seq_par=bool(ctx.ds.get("sequence_parallel", False)),
-            moe=bool((ctx.cfg.get("model", {}) or {}).get("moe")),
+            moe=bool(moe_block),
+            moe_dropless=bool(moe_block) and MoEConfig.from_config(
+                moe_block).dropless,
             ulysses=bool(fus.get("ulysses_attention")),
             ring=bool(fus.get("ring_attention")
                       or fus.get("zigzag_ring_attention")),
@@ -239,6 +245,36 @@ def declared_source_classes(d: DeclaredComms) -> list[tuple]:
     def add(label, kinds, pred, hint, src=None):
         rules.append((label, tuple(kinds), pred, src, hint))
 
+    if d.moe_dropless:
+        # routing runs on the global tokens under GSPMD, ahead of the
+        # per-shard expert block (sinkhorn normalises over all of them).  The
+        # CPU partitioner these contracts lower for replicates top_k and
+        # regathers the [tokens, E] float32 logits; the TPU compiler splits
+        # it by rows (tests/test_tpu_compile.py).  The sort of the rows is
+        # per shard: a "sort" gather is no declared cost of this path.  Ahead
+        # of ZeRO-1's class, which claims any gather over the batch axes
+        add("MoE router top-k gather", ("all-gather",),
+            lambda a: bool(a),
+            "the router's top-k regathers more than the logits; check "
+            "ops/moe.py _dropless_on_mesh (routing ahead of the region)",
+            src=_src_any("router/top_k"))
+    if d.moe_dropless and d.ep > 1:
+        # weight-gather EP (ops/moe.py _dropless_on_mesh): each token shard
+        # all-gathers the expert weights over 'expert' once per MoE layer and
+        # reduce-scatters their gradients back, and nothing on the token path
+        # crosses chips.  Ahead of the classes whose source needles
+        # ("gather") or axis sets (ZeRO-1's) would claim the two.
+        add("ep expert weight gather", ("all-gather",),
+            lambda a: a == {"expert"},
+            "weight-gather EP changed; ops/moe.py gathers each expert "
+            "weight over 'expert' exactly once per MoE layer",
+            src=_src_any("experts/shard_map/all_gather"))
+        add("ep expert gradient reduce-scatter", ("reduce-scatter",),
+            lambda a: a == {"expert"},
+            "the cross-shard sum of the expert-weight gradients changed; "
+            "ops/moe.py reduce-scatters each over 'expert' exactly once per "
+            "MoE layer, in reduce_dtype",
+            src=_src_any("experts/shard_map/reduce_scatter"))
     if d.tp > 1:
         add("tp/SP layer collective", AK["tp"],
             lambda a: a == {"model"},
@@ -362,30 +398,22 @@ def declared_source_classes(d: DeclaredComms) -> list[tuple]:
             "the CP fusion's shard_map boundary resharding changed; check "
             "the in/out specs of the ring/ulysses shard_map",
             src=_src_any("shard_map", "shmap"))
-    if d.moe and d.ep > 1:
-        add("ep token all-to-all", AK["ep"],
-            lambda a: "expert" in a and a <= (_DP_AXES | {"model"}),
-            "expert token dispatch changed; check moe_param_specs and the "
-            "routing path (ops/moe.py)")
-        add("ep expert weight gather", ("all-gather",),
-            lambda a: a == {"expert"},
-            "weight-gather EP changed; ops/moe.py moe_dropless gathers "
-            "expert weights over 'expert' exactly once per MoE layer")
-    if d.moe:
-        # dropless routing sorts/top-ks token assignments against the
-        # whole batch: the sort workspace regathers across every sharded
-        # axis, and the combine scatter-adds back — declared cost of
-        # dropless MoE (ops/moe.py), not a stray reshard
-        add("MoE dropless routing gather", ("all-gather",),
+    if d.moe and not d.moe_dropless:
+        if d.ep > 1:
+            add("ep token all-to-all", AK["ep"],
+                lambda a: "expert" in a and a <= (_DP_AXES | {"model"}),
+                "expert token dispatch changed; check moe_param_specs and "
+                "the routing path (ops/moe.py moe_dropped)")
+        # capacity-factor routing ranks token assignments against the whole
+        # batch (its capacity is a global cumsum): the top-k / cumsum /
+        # one-hot workspace regathers across every sharded axis — declared
+        # cost of dropped MoE (ops/moe.py), not a stray reshard
+        add("MoE dropped routing gather", ("all-gather",),
             lambda a: bool(a),
-            "dropless routing's sort/top-k workspace traffic changed; "
-            "check the routing path (ops/moe.py moe_dropless)",
+            "dropped routing's top-k/cumsum workspace traffic changed; "
+            "check the routing path (ops/moe.py moe_dropped)",
             src=_src_any("top_k", "sort", "argsort", "cumsum", "one_hot"))
-        add("MoE dropless combine", ("all-reduce",),
-            lambda a: bool(a),
-            "dropless combine (scatter-add of expert outputs) changed; "
-            "check ops/moe.py moe_dropless",
-            src=_src_any("scatter", "add"))
+    if d.moe:
         # dropped-mode dispatch/combine einsums contract the token dim
         # (sharded over batch axes and, under SP, the model axis): their
         # partial sums all-reduce over those axes; router aux losses reduce
@@ -398,8 +426,8 @@ def declared_source_classes(d: DeclaredComms) -> list[tuple]:
             src=_src_any("dot_general", "reduce_sum", "einsum"))
         add("MoE permute", ("collective-permute", "all-to-all"),
             lambda a: a and "expert" in a,
-            "MoE token permute pattern changed; check the dropless "
-            "routing path (ops/moe.py)")
+            "MoE token permute pattern changed; check the routing path "
+            "(ops/moe.py)")
     return rules
 
 

@@ -419,7 +419,9 @@ def _attention_block(cfg, lp, x, cos, sin, policy, attention_mask=None,
 
 def _mlp_block(cfg, lp, x, policy, mid_norm=None):
     if cfg.moe is not None and "router" in lp:
-        y, aux = moe_ops.moe_block(lp, x, cfg.moe, compute_dtype=policy.compute_dtype)
+        # gpt has no context parallelism: moe_block's default act_spec holds
+        y, aux = moe_ops.moe_block(lp, x, cfg.moe, compute_dtype=policy.compute_dtype,
+                                   reduce_dtype=policy.reduce_dtype)
         aux_loss = moe_ops.weighted_router_loss(
             aux["router_logits"], aux["expert_idx"], cfg.moe
         )

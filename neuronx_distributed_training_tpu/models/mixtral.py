@@ -203,7 +203,8 @@ def _decoder_layer(lp, x, cos, sin, cfg: MixtralConfig, policy: DtypePolicy,
     with jax.named_scope("moe"):
         hidden = norm_ops.apply_rms_norm(lp["post_attn_norm"], x, eps=lc.rms_norm_eps)
     hidden, aux = moe_ops.moe_block(
-        lp["mlp"], hidden, cfg.moe, compute_dtype=policy.compute_dtype
+        lp["mlp"], hidden, cfg.moe, compute_dtype=policy.compute_dtype,
+        reduce_dtype=policy.reduce_dtype, act_spec=aspec,
     )
     with jax.named_scope("moe"):
         aux_loss = moe_ops.weighted_router_loss(

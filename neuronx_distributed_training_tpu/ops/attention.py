@@ -131,7 +131,7 @@ def _flash_on_mesh(q, k, v, attention_mask, segment_ids, **kw):
     rule ``parallel.ulysses`` uses), keeping q/kv head groups aligned."""
     from neuronx_distributed_training_tpu.ops.flash_attention import flash_attention
 
-    mesh = shd.active_mesh()
+    mesh, manual = shd.region_mesh()
     if mesh is None:
         return flash_attention(
             q, k, v, attention_mask=attention_mask, segment_ids=segment_ids, **kw
@@ -153,13 +153,9 @@ def _flash_on_mesh(q, k, v, attention_mask, segment_ids, **kw):
     def body(q, k, v, *row_args):
         return flash_attention(q, k, v, **dict(zip(names, row_args)), **kw)
 
-    axis_names = frozenset()  # every axis of ``mesh``
-    manual = shd.manual_axes()
-    if manual:
-        # already inside a manual region (the pipeline body, manual over
-        # ``pipe``): take the remaining axes of the context mesh manual too
-        mesh = jax.sharding.get_abstract_mesh()
-        axis_names = frozenset(mesh.axis_names) - manual
+    # inside a manual region (the pipeline body, manual over ``pipe``) take
+    # the remaining axes manual too; otherwise every axis of ``mesh``
+    axis_names = frozenset(mesh.axis_names) - manual if manual else frozenset()
     fn = shd.shard_map(
         body,
         mesh=mesh,

@@ -18,9 +18,17 @@ the dropped-vs-dropless validation at ``training_orchestrator.py:60-102``):
 - **aux load-balancing loss**: Mixtral's ``load_balancing_loss_func``
   (reference ``modeling_mixtral.py:872-878``) — mean(expert_fraction *
   router_prob_fraction) * num_experts, plus optional router z-loss;
-- **EP**: expert-major weight tensors carry their expert dim sharded over the
-  ``expert`` mesh axis (see ``expert_specs``); GSPMD inserts the
-  all-to-alls the reference gets from NxD's token-shuffle machinery.
+- **EP**: expert-major weight tensors are resident with their expert dim
+  sharded over the ``expert`` mesh axis (``moe_param_specs``).  The dropless
+  block routes on the global tokens (plain GSPMD code: sinkhorn normalises
+  over all of them), then is a per-shard computation over the mesh axes that
+  shard its tokens (``data``, ``expert``, and ``context`` under cp): each
+  shard sorts and multiplies only its own rows, against all experts, whose
+  weights it all-gathers over ``expert`` in the compute dtype; the gather's
+  transpose reduce-scatters the shards' partial weight gradients in
+  ``reduce_dtype`` (weight-gather EP; ``_dropless_on_mesh``).  There is no collective on the
+  token path, and no all-to-all: sending each token to its experts' chip
+  (the reference's NxD token shuffle) moves fewer bytes and is not built.
 
 SwiGLU experts (``glu_mlp`` in the reference): w_gate/w_up fused as one
 ``[E, h, 2*ff]`` tensor, w_down ``[E, ff, h]``.
@@ -29,11 +37,15 @@ SwiGLU experts (``glu_mlp`` in the reference): w_gate/w_up fused as one
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from neuronx_distributed_training_tpu.parallel import sharding as shd
 
 
 @dataclasses.dataclass(frozen=True)
@@ -238,18 +250,64 @@ def moe_dropped(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloa
     return y.astype(x.dtype), (probs, idx, logits)
 
 
-def moe_dropless(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat16):
-    """Dropless MoE: sort tokens by expert, grouped-matmul via ``lax.ragged_dot``.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _gather_experts(w: jax.Array, axis: str, compute_dtype) -> jax.Array:
+    """All-gather expert-major weights over the manual mesh axis ``axis``, in
+    ``compute_dtype``.
 
-    Every token is processed (the reference's ``dropless=True``); group sizes
-    are data-dependent but shapes are static ([T*k] rows).
+    The transpose is the cross-shard sum of the partial weight gradients,
+    each shard's from its own rows.  Before the block was per-shard that sum
+    happened inside one kernel's float32 accumulator, so it is carried in the
+    dtype ``w`` arrives in (``_dropless_on_mesh`` hands it over in
+    ``reduce_dtype``) and reduce-scattered straight back to the resident
+    layout."""
+    return jax.lax.all_gather(w.astype(compute_dtype), axis, axis=0, tiled=True)
+
+
+def _gather_experts_fwd(w, axis, compute_dtype):
+    # the residual is a zero-size carrier of w's dtype
+    return _gather_experts(w, axis, compute_dtype), jnp.zeros((0,), w.dtype)
+
+
+def _gather_experts_bwd(axis, compute_dtype, res, ct):
+    return (jax.lax.psum_scatter(
+        ct.astype(res.dtype), axis, scatter_dimension=0, tiled=True),)
+
+
+_gather_experts.defvjp(_gather_experts_fwd, _gather_experts_bwd)
+
+
+def _gather_experts_on_mesh(w, axis: str, compute_dtype, spec: P):
+    """``_gather_experts`` where ``axis`` is manual and ``spec`` lays ``w``
+    out over the axes that are still automatic (the ffn dim over ``model``).
+    The partitioner has no rule for a collective whose operand is sharded
+    over an automatic axis: it would replicate the weights over ``model``
+    around the gather, and their gradients around the reduce-scatter.  So the
+    two collectives run in a nested region that is manual over ``model`` too,
+    each tp rank gathering the ffn slice it owns."""
+    auto = frozenset(a for a in jax.tree_util.tree_leaves(tuple(spec)) if a)
+    return shd.shard_map(
+        functools.partial(_gather_experts, axis=axis, compute_dtype=compute_dtype),
+        mesh=jax.sharding.get_abstract_mesh(), in_specs=spec, out_specs=spec,
+        axis_names=auto, check_vma=False,
+    )(w)
+
+
+def _dropless_experts(experts, x: jax.Array, probs: jax.Array, idx: jax.Array,
+                      cfg: MoEConfig, *, compute_dtype,
+                      expert_axis: Optional[str] = None) -> jax.Array:
+    """The routed half of the dropless block: sort rows by expert, grouped
+    matmul via ``lax.ragged_dot``, weighted scatter-add back.
+
+    x [T, h], probs / idx [T, k] -> y [T, h] in ``compute_dtype``.  A function
+    of one token set: given its routing, a row's output depends on the row,
+    its experts' weights and its gate weights only, so ``_dropless_on_mesh``
+    runs it once per token shard, with ``expert_axis`` the manual mesh axis
+    the expert weights arrive sharded over.
     """
     t, h = x.shape
     e, k = cfg.num_experts, cfg.top_k
     # inner scopes of "moe": telemetry.spans.DEVICE_SCOPES
-    with jax.named_scope("router"):
-        probs, idx, logits = route(params["router"], x, cfg)
-
     with jax.named_scope("dispatch"):
         flat_expert = idx.reshape(-1)  # [T*k]
         order = jnp.argsort(flat_expert)  # stable sort by expert
@@ -261,21 +319,21 @@ def moe_dropless(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bflo
     # with the expert dim sharded it computes each shard's local expert
     # slice against the GLOBAL group offsets — silently wrong values, no
     # error (full-signal corruption on any mesh where the expert axis is
-    # strided, e.g. EP x TP; verified empirically on jax 0.4.x).  Constrain
-    # the weights to be gathered over 'expert' for the compute — weight-
-    # gather EP: the resident weights and optimizer state stay sharded per
-    # expert_specs, GSPMD inserts one all-gather per layer, and the ffn
-    # dim's 'model' sharding (which ragged_dot partitions correctly) is
-    # preserved.  Sharded-vs-unsharded parity: tests/test_mixtral.py.
-    from neuronx_distributed_training_tpu.parallel import sharding as shd
-
+    # strided, e.g. EP x TP; verified empirically on jax 0.4.x).  So the
+    # compute sees every expert — weight-gather EP: the resident weights and
+    # optimizer state stay sharded per moe_param_specs, gathered over
+    # 'expert' once per layer (by hand where the axis is manual, by the
+    # constraint where it is not), and the ffn dim's 'model' sharding (which
+    # ragged_dot partitions correctly) is preserved.
+    # Sharded-vs-unsharded parity: tests/test_moe.py, tests/test_mixtral.py.
     with jax.named_scope("experts"):
-        gu_w = shd.constrain(
-            params["experts"]["gate_up"].astype(compute_dtype),
-            P(None, None, "model"))
-        down_w = shd.constrain(
-            params["experts"]["down"].astype(compute_dtype),
-            P(None, "model", None))
+        gu_w, down_w = experts["gate_up"], experts["down"]
+        gu_spec, down_spec = P(None, None, "model"), P(None, "model", None)
+        if expert_axis is not None:
+            gu_w = _gather_experts_on_mesh(gu_w, expert_axis, compute_dtype, gu_spec)
+            down_w = _gather_experts_on_mesh(down_w, expert_axis, compute_dtype, down_spec)
+        gu_w = shd.constrain(gu_w.astype(compute_dtype), gu_spec)
+        down_w = shd.constrain(down_w.astype(compute_dtype), down_spec)
 
         gu = jax.lax.ragged_dot(xs, gu_w, group_sizes)
         gate, up = jnp.split(gu, 2, axis=-1)
@@ -284,8 +342,106 @@ def moe_dropless(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bflo
 
     with jax.named_scope("combine"):
         w = probs.reshape(-1)[order].astype(compute_dtype)  # gate weight per row
-        y = jnp.zeros((t, h), compute_dtype).at[token_of].add(ys * w[:, None])
+        return jnp.zeros((t, h), compute_dtype).at[token_of].add(ys * w[:, None])
+
+
+def moe_dropless(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat16):
+    """Dropless MoE: route, then sort tokens by expert and grouped-matmul via
+    ``lax.ragged_dot`` (``_dropless_experts``).
+
+    x [tokens, hidden] -> (y [tokens, hidden], (probs, idx, router_logits)).
+    Every token is processed (the reference's ``dropless=True``); group sizes
+    are data-dependent but shapes are static ([T*k] rows).
+    """
+    with jax.named_scope("router"):
+        probs, idx, logits = route(params["router"], x, cfg)
+    y = _dropless_experts(params["experts"], x, probs, idx, cfg,
+                          compute_dtype=compute_dtype)
     return y.astype(x.dtype), (probs, idx, logits)
+
+
+#: mesh axes that can shard the tokens of a block-boundary activation: the
+#: batch over DATA_AXES, the sequence over ``context`` (``shd.act_spec``);
+#: ``model`` under SP stays automatic
+_TOKEN_AXES = shd.DATA_AXES + ("context",)
+
+
+def _token_axes(mesh, act_spec: P, b: int, s: int, outer_manual: frozenset):
+    """The mesh axes of size > 1 that shard the batch and the sequence
+    dimension of a ``[b, s, h]`` activation laid out by ``act_spec``, as two
+    tuples; both empty where they do not divide it evenly."""
+    dims = []
+    for entry, size in zip(tuple(act_spec)[:2], (b, s)):
+        names = entry if isinstance(entry, tuple) else (entry,)
+        axes = tuple(a for a in names if a in _TOKEN_AXES
+                     and a not in outer_manual and mesh.shape.get(a, 1) > 1)
+        if size % math.prod(mesh.shape[a] for a in axes):
+            return (), ()
+        dims.append(axes)
+    return tuple(dims)
+
+
+def _dropless_on_mesh(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype,
+                      reduce_dtype, act_spec: Optional[P]):
+    """``moe_dropless`` of ``[b, s, h]``, its expert half partitioned by tokens.
+
+    Routing is not row-local (sinkhorn normalises over the whole token set),
+    so it runs first, on the global tokens, under GSPMD.  The routed half
+    (``_dropless_experts``) left to GSPMD is replicated: the sort is over the
+    global token list and ``ragged_dot`` has no partitioning rule for globally
+    sorted rows, so every chip would multiply the whole batch.  Instead it
+    runs per shard inside a ``shard_map`` that is manual over the axes
+    sharding the tokens (read from the mesh and ``act_spec``) and automatic
+    over the rest, as ``ops.attention._flash_on_mesh`` does for the flash
+    kernel.  With no mesh, or no such axis, it is called directly.
+    Returns ``(y [b, s, h], expert_idx [b*s, k], router_logits [b*s, E])``.
+    """
+    b, s, h = x.shape
+    mesh, outer_manual = shd.region_mesh()
+    act_spec = shd.act_spec() if act_spec is None else act_spec
+    batch_axes, seq_axes = ((), ()) if mesh is None else _token_axes(
+        mesh, act_spec, b, s, outer_manual)
+    manual = frozenset(batch_axes + seq_axes)
+    shards = math.prod(mesh.shape[a] for a in manual)
+    facts = shd.trace_facts()
+    if facts is not None:  # 1 anywhere = some block multiplies every row
+        facts["moe_token_shards"] = min(shards, facts.get("moe_token_shards", shards))
+
+    tokens = P(batch_axes or None, seq_axes or None, None)
+    if manual:
+        # one layout for the router and the region: under SP the sequence is
+        # gathered over ``model`` once, and routing stays split by batch
+        x = shd.constrain(x, tokens)
+    flat = x.reshape(b * s, h)
+    with jax.named_scope("router"):
+        probs, idx, logits = route(params["router"], flat, cfg)
+    if not manual:
+        y = _dropless_experts(params["experts"], flat, probs, idx, cfg,
+                              compute_dtype=compute_dtype)
+        return y.reshape(b, s, h).astype(x.dtype), idx, logits
+
+    expert_axis = "expert" if "expert" in manual else None
+
+    def body(experts, x, probs, idx):
+        bl, sl, _ = x.shape
+        y = _dropless_experts(
+            experts, x.reshape(bl * sl, h), probs.reshape(bl * sl, -1),
+            idx.reshape(bl * sl, -1), cfg, compute_dtype=compute_dtype,
+            expert_axis=expert_axis)
+        return y.reshape(bl, sl, h)
+
+    # the cotangent of an input is summed over the manual axes it is
+    # replicated over (and, by _gather_experts, over ``expert``) in the
+    # input's own dtype: hand the weights over in reduce_dtype
+    experts = jax.tree_util.tree_map(
+        lambda w: w.astype(reduce_dtype), params["experts"])
+    y = shd.shard_map(
+        body, mesh=mesh,
+        in_specs=({"gate_up": P(expert_axis), "down": P(expert_axis)},
+                  tokens, tokens, tokens),
+        out_specs=tokens, axis_names=manual, check_vma=False,
+    )(experts, x, probs.reshape(b, s, -1), idx.reshape(b, s, -1))
+    return y.astype(x.dtype), idx, logits
 
 
 def _shuffle_permutation(t: int, group: int) -> jnp.ndarray:
@@ -306,20 +462,29 @@ def _shuffle_permutation(t: int, group: int) -> jnp.ndarray:
     return jnp.arange(t).reshape(t // g, g).T.reshape(-1)
 
 
-def moe_block(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat16):
-    """[b, s, h] wrapper dispatching dropped/dropless; returns (y, router_logits)."""
+def moe_block(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat16,
+              reduce_dtype=jnp.float32, act_spec: Optional[P] = None):
+    """[b, s, h] wrapper dispatching dropped/dropless; returns (y, router_logits).
+
+    ``act_spec`` is the block-boundary spec ``x`` is laid out by (default
+    ``shd.act_spec()``: batch over the data axes); the dropless block is
+    partitioned by the token axes it names.  ``reduce_dtype`` carries the
+    cross-shard sum of the expert-weight gradients (``policy.reduce_dtype``)."""
     b, s, h = x.shape
     with jax.named_scope("moe"):
+        if cfg.dropless:
+            y, idx, logits = _dropless_on_mesh(
+                params, x, cfg, compute_dtype=compute_dtype,
+                reduce_dtype=reduce_dtype, act_spec=act_spec)
+            return y, {"router_logits": logits, "expert_idx": idx}
         flat = x.reshape(b * s, h)
-        shuffle = (not cfg.dropless) and (cfg.token_shuffle_group_size or 0) > 1
+        shuffle = (cfg.token_shuffle_group_size or 0) > 1
         if shuffle:
-            # only the dropped path is order-dependent (queue-position cumsum);
-            # dropless processes every token, so shuffling there is a no-op cost
+            # the dropped path is order-dependent (queue-position cumsum)
             perm = _shuffle_permutation(b * s, int(cfg.token_shuffle_group_size))
             inv = jnp.argsort(perm)
             flat = flat[perm]
-        fn = moe_dropless if cfg.dropless else moe_dropped
-        y, (probs, idx, logits) = fn(params, flat, cfg, compute_dtype=compute_dtype)
+        y, (probs, idx, logits) = moe_dropped(params, flat, cfg, compute_dtype=compute_dtype)
         if shuffle:
             y, idx, logits = y[inv], idx[inv], logits[inv]
         return y.reshape(b, s, h), {"router_logits": logits, "expert_idx": idx}

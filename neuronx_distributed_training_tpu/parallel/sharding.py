@@ -54,6 +54,21 @@ def manual_axes() -> frozenset:
     )
 
 
+def region_mesh():
+    """``(mesh, outer_manual)`` for a ``shard_map`` opened where this is
+    traced: the active mesh and no manual axes, or, inside a manual region
+    (the pipeline body, manual over ``pipe``), the context's abstract mesh and
+    the axes already manual there, which the new region must leave out of its
+    ``axis_names``.  ``(None, frozenset())`` with no mesh active."""
+    mesh = active_mesh()
+    if mesh is None:
+        return None, frozenset()
+    manual = manual_axes()
+    if manual:
+        mesh = jax.sharding.get_abstract_mesh()
+    return mesh, manual
+
+
 @contextlib.contextmanager
 def use_mesh(mesh: Optional[Mesh]):
     """Activate a mesh for ``constrain``/``named_sharding`` inside the block."""
@@ -63,6 +78,24 @@ def use_mesh(mesh: Optional[Mesh]):
         yield mesh
     finally:
         _STATE.mesh = prev
+
+
+def trace_facts() -> Optional[dict]:
+    """The dict ``collect_trace_facts`` is filling on this thread, else None."""
+    return getattr(_STATE, "facts", None)
+
+
+@contextlib.contextmanager
+def collect_trace_facts():
+    """Collect what code traced inside the block records about how it was
+    partitioned (``ops.moe``: ``moe_token_shards``).  The facts are of the
+    trace: a function whose jaxpr is already cached records nothing."""
+    prev = trace_facts()
+    _STATE.facts = facts = {}
+    try:
+        yield facts
+    finally:
+        _STATE.facts = prev
 
 
 def named_sharding(spec: P, mesh: Optional[Mesh] = None) -> NamedSharding:

@@ -940,6 +940,11 @@ class Trainer:
                 pp_schedule, pp, int(sched["num_microbatches"]),
                 int(mesh_cfg.virtual_pipeline_model_parallel_size or 1)), 6),
         }
+        moe_cfg = getattr(model_cfg, "moe", None)
+        if moe_cfg is not None and moe_cfg.dropless:
+            # null = not known (the census's trace did not run); the census
+            # fills in how the block was partitioned.  Absent = no such block
+            run_facts["moe_token_shards"] = None
         # the manual-vjp schedules run the WORK-COMPACTED executor: record
         # its per-step tick counts (compacted span + per-kind active ticks
         # vs the old lockstep trip count) so the measured timelines are
@@ -2156,7 +2161,8 @@ class Trainer:
             t0 = _time.perf_counter()
             # spans.add below has no annotation of its own: name the compile
             # on the profiler's clock here, as SpanTimer.span does
-            with jax.profiler.TraceAnnotation("compile"):
+            with jax.profiler.TraceAnnotation("compile"), \
+                    shd.collect_trace_facts() as traced:
                 lowered = self.train_step.lower(
                     self.params, self.opt_state, batch, key
                 )
@@ -2174,6 +2180,15 @@ class Trainer:
         # compile is non-productive wall time: goodput + the throughput
         # window's exclusion both see it through the span
         spans.add("compile", dt)
+        # how the trace partitioned what it could (ops/moe.py:
+        # moe_token_shards) joins the run's static facts
+        self.run_facts.update(traced)
+        for name, value in sorted(traced.items()):
+            logger.info("traced: %s %s", name, value)
+        if self.run_facts.get("moe_token_shards", 0) is None:
+            logger.warning(
+                "traced: moe_token_shards unknown (the step's jaxpr was "
+                "cached, so the expert block recorded nothing)")
         try:
             census = compile_census(
                 compiled,

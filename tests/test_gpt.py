@@ -107,6 +107,34 @@ class TestShardedGPT:
             np.asarray(ref_grads["embed"]["embedding"]), rtol=1e-3, atol=1e-5,
         )
 
+    def test_moe_sinkhorn_ep_parity(self, devices8):
+        """GPT-MoE with the documented sinkhorn top-1 dropless router on a
+        dp2 x ep4 mesh against one device.  Sinkhorn normalises over the whole
+        token set: routing has to see the global tokens even though the
+        expert block runs per token shard (ops/moe.py::_dropless_on_mesh)."""
+        cfg = gpt.GPTConfig(**BASE, activation="swiglu", moe=moe_ops.MoEConfig(
+            num_experts=4, top_k=1, router_type="sinkhorn", dropless=True))
+        params = gpt.init_params(jax.random.PRNGKey(0), cfg, FP32)
+        batch = _batch(jax.random.PRNGKey(1), b=8)
+
+        def loss_fn(p, b):
+            return gpt.forward(p, b, cfg, FP32)[0]
+
+        ref_loss, ref_grads = jax.value_and_grad(loss_fn)(params, batch)
+        mesh = build_mesh(MeshConfig(expert_model_parallel_size=4))
+        ns = functools.partial(NamedSharding, mesh)
+        sh_params = jax.device_put(params, jax.tree_util.tree_map(
+            ns, gpt.param_specs(cfg), is_leaf=lambda x: isinstance(x, P)))
+        sh_batch = jax.device_put(batch, ns(P(("data", "expert"))))
+        with mesh, shd.use_mesh(mesh), shd.collect_trace_facts() as traced:
+            loss, grads = jax.jit(jax.value_and_grad(loss_fn))(sh_params, sh_batch)
+        assert traced == {"moe_token_shards": 8}
+        np.testing.assert_allclose(float(loss), float(ref_loss), rtol=2e-5)
+        for a, b in zip(jax.tree_util.tree_leaves(ref_grads),
+                        jax.tree_util.tree_leaves(grads)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-3, atol=1e-5)
+
     def test_pipeline_specs_exist(self):
         cfg = gpt.GPTConfig(**BASE)
         specs = gpt.param_specs(cfg, pipeline=True)

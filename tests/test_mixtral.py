@@ -84,41 +84,61 @@ class TestMixtralForward:
 
 
 class TestMixtralSharded:
-    def test_ep_tp_parity(self, devices8):
-        """EP=2 x TP=2 x DP=2 sharded loss/grads match unsharded.
+    #: 8 devices each: MeshConfig fields -> token shards of the expert block
+    MESHES = {
+        "ep2_tp2_dp2": (dict(tensor_model_parallel_size=2,
+                             expert_model_parallel_size=2), 4),
+        "ep4_dp2": (dict(expert_model_parallel_size=4), 8),
+        "ep2_tp2_sp": (dict(tensor_model_parallel_size=2,
+                            expert_model_parallel_size=2,
+                            sequence_parallel=True), 4),
+    }
+
+    @pytest.mark.parametrize("name", list(MESHES))
+    def test_ep_tp_parity(self, devices8, name):
+        """Sharded loss and every gradient match unsharded, on meshes that
+        combine EP with TP, SP and DP; the expert block runs once per token
+        shard (``moe_token_shards``).
 
         Regression pin for the ragged_dot EP hazard: XLA's SPMD partitioner
         has no rule for ragged_dot's GROUP dimension — with the expert dim
         sharded on a strided mesh axis (any EP x TP mesh) it computed each
         shard's local expert slice against the GLOBAL group offsets,
         silently corrupting forward AND backward (loss off ~7e-5, grads off
-        ~100% of signal, no error raised).  ``moe_dropless`` now gathers the
-        expert weights over 'expert' for the compute (weight-gather EP;
-        resident weights/opt state stay sharded), which restores bit-level
-        SPMD parity — so the tolerances here are tight: a reappearance of
-        the partitioner hole fails loudly."""
-        params = mixtral.init_params(jax.random.PRNGKey(0), CFG, FP32)
-        batch = _batch(jax.random.PRNGKey(1))
+        ~100% of signal, no error raised).  ``moe_dropless`` sees the expert
+        weights gathered over 'expert' (weight-gather EP; resident weights/opt
+        state stay sharded), which restores bit-level SPMD parity — so the
+        tolerances here are tight: a reappearance of the partitioner hole
+        fails loudly."""
+        import dataclasses
+
+        fields, shards = self.MESHES[name]
+        cfg = dataclasses.replace(CFG, llama=dataclasses.replace(
+            CFG.llama, sequence_parallel=fields.get("sequence_parallel", False)))
+        params = mixtral.init_params(jax.random.PRNGKey(0), cfg, FP32)
+        batch = _batch(jax.random.PRNGKey(1), b=8)
 
         def loss_fn(p, b):
-            return mixtral.forward(p, b, CFG, FP32)[0]
+            return mixtral.forward(p, b, cfg, FP32)[0]
 
         ref_loss, ref_grads = jax.value_and_grad(loss_fn)(params, batch)
 
-        mesh = build_mesh(MeshConfig(tensor_model_parallel_size=2,
-                                     expert_model_parallel_size=2))
-        specs = mixtral.param_specs(CFG)
+        mesh = build_mesh(MeshConfig(**fields), devices=devices8)
+        specs = mixtral.param_specs(cfg)
         ns = functools.partial(NamedSharding, mesh)
         sh_params = jax.device_put(
             params, jax.tree_util.tree_map(ns, specs, is_leaf=lambda x: isinstance(x, P))
         )
         sh_batch = jax.device_put(batch, ns(P(("data", "expert"))))
-        with mesh, shd.use_mesh(mesh):
+        with mesh, shd.use_mesh(mesh), shd.collect_trace_facts() as traced:
             loss, grads = jax.jit(jax.value_and_grad(loss_fn))(sh_params, sh_batch)
+        assert traced == {"moe_token_shards": shards}
         np.testing.assert_allclose(float(loss), float(ref_loss), rtol=2e-5)
-        g = grads["layers"]["mlp"]["experts"]["down"]
-        rg = ref_grads["layers"]["mlp"]["experts"]["down"]
-        np.testing.assert_allclose(np.asarray(g), np.asarray(rg), rtol=1e-3, atol=1e-5)
+        ref_leaves, treedef = jax.tree_util.tree_flatten_with_path(ref_grads)
+        for (path, rg), g in zip(ref_leaves, treedef.flatten_up_to(grads)):
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(rg), rtol=1e-3, atol=1e-5,
+                err_msg=jax.tree_util.keystr(path))
 
 
 def test_mixtral_left_padded_matches_unpadded():

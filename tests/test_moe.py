@@ -7,6 +7,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from neuronx_distributed_training_tpu.ops import moe
+from neuronx_distributed_training_tpu.parallel import sharding as shd
 from neuronx_distributed_training_tpu.parallel.mesh import MeshConfig, build_mesh
 
 CFG = moe.MoEConfig(num_experts=4, top_k=2, dropless=True)
@@ -125,15 +126,87 @@ class TestEP:
             )(sh_params, x)
         np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=1e-4)
 
-    def test_ep_tp_sharded_dropless_matches(self, devices8):
-        """Regression: dropless on an EP x TP mesh (STRIDED expert axis).
+    #: name -> (MeshConfig fields, devices, context-parallel activations)
+    TOKEN_MESHES = {
+        "ep4": (dict(expert_model_parallel_size=4), 4, False),
+        "dp2_ep2": (dict(expert_model_parallel_size=2), 4, False),
+        "ep2_tp2": (dict(expert_model_parallel_size=2,
+                         tensor_model_parallel_size=2), 4, False),
+        "ep2_cp2": (dict(expert_model_parallel_size=2,
+                         context_parallel_size=2), 4, True),
+        "no_mesh": None,
+    }
 
-        XLA's SPMD partitioner has no rule for ragged_dot's group dim; with
-        the expert dim sharded it silently computed local expert slices
-        against global group offsets — full-signal corruption (forward off
-        by the magnitude of y) with no error.  moe_dropless now gathers the
-        expert weights over 'expert' for the compute; parity must be tight
-        and the gradient path exact too."""
+    #: router -> MoEConfig fields; sinkhorn normalises over the whole token
+    #: set, so it is the case that routing per shard would break
+    ROUTERS = {
+        "top_k": dict(top_k=2),
+        "sinkhorn": dict(top_k=1, router_type="sinkhorn"),
+    }
+
+    @pytest.mark.parametrize("router", list(ROUTERS))
+    @pytest.mark.parametrize("name", list(TOKEN_MESHES))
+    def test_token_sharded_dropless_matches(self, devices8, name, router):
+        """The dropless block partitioned by tokens against the unsharded one:
+        forward, router outputs and every gradient, at tight tolerance, on
+        each mesh shape that shards tokens (and on none), under both routers.
+
+        Also the regression pin of the ragged_dot EP hazard: XLA's SPMD
+        partitioner has no rule for ragged_dot's group dim; with the expert
+        dim sharded on a strided axis (ep2_tp2) it silently computed local
+        expert slices against global group offsets — full-signal corruption
+        (forward off by the magnitude of y) with no error.  The compute sees
+        the expert weights gathered over 'expert'."""
+        cfg = moe.MoEConfig(num_experts=4, dropless=True, **self.ROUTERS[router])
+        params, x = params_and_x(jax.random.PRNGKey(9), cfg=cfg)
+        x = x.reshape(4, 8, -1)
+
+        def run(p, xx, act_spec=None):
+            def loss(p, xx):
+                y, aux = moe.moe_block(p, xx, cfg, act_spec=act_spec, **FP32)
+                total = (y ** 2).sum() + moe.weighted_router_loss(
+                    aux["router_logits"], aux["expert_idx"], cfg)
+                return total, (y, aux)
+
+            return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, xx)
+
+        (_, (y_ref, aux_ref)), g_ref = run(params, x)
+        if self.TOKEN_MESHES[name] is None:
+            with shd.collect_trace_facts() as traced:
+                (_, (y, aux)), g = jax.jit(run)(params, x)
+            shards = 1
+        else:
+            fields, n, cp = self.TOKEN_MESHES[name]
+            mesh = build_mesh(MeshConfig(**fields), devices=devices8[:n])
+            act_spec = shd.act_spec(False, cp)
+            ns = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+            sh_params = jax.device_put(params, jax.tree_util.tree_map(
+                ns, moe.moe_param_specs(cfg), is_leaf=lambda s: isinstance(s, P)))
+            sh_x = jax.device_put(x, ns(act_spec))
+            with mesh, shd.use_mesh(mesh), shd.collect_trace_facts() as traced:
+                (_, (y, aux)), g = jax.jit(
+                    lambda p, xx: run(p, xx, act_spec))(sh_params, sh_x)
+            shards = n // fields.get("tensor_model_parallel_size", 1)
+        # the mechanism engaged: manual over every axis that shards tokens
+        assert traced == {"moe_token_shards": shards}
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(aux["expert_idx"]),
+                                      np.asarray(aux_ref["expert_idx"]))
+        np.testing.assert_allclose(np.asarray(aux["router_logits"]),
+                                   np.asarray(aux_ref["router_logits"]),
+                                   rtol=1e-5, atol=1e-6)
+        for a, b in zip(jax.tree_util.tree_leaves(g_ref),
+                        jax.tree_util.tree_leaves(g)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-6)
+
+    def test_ep_tp_sharded_dropless_matches(self, devices8):
+        """Regression: ``moe_dropless`` left to GSPMD on an EP x TP mesh
+        (STRIDED expert axis) — the path a batch the token axes do not divide
+        still takes.  The constraint gathers the expert weights over 'expert'
+        for the compute (the ragged_dot group-dim hazard above); parity must
+        be tight and the gradient path exact too."""
         cfg = moe.MoEConfig(num_experts=4, top_k=2, dropless=True)
         params, x = params_and_x(jax.random.PRNGKey(9), cfg=cfg)
 
@@ -166,6 +239,21 @@ class TestEP:
                         jax.tree_util.tree_leaves(g)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-6)
+
+    def test_batch_that_does_not_divide_takes_the_global_path(self, devices8):
+        """A batch the token axes cannot split evenly (one decode row on an
+        ep2 mesh) runs the block unpartitioned, and says so."""
+        cfg = moe.MoEConfig(num_experts=4, top_k=2, dropless=True)
+        params, x = params_and_x(jax.random.PRNGKey(3), t=8, cfg=cfg)
+        ref, _ = moe.moe_block(params, x[None], cfg, **FP32)
+        mesh = build_mesh(MeshConfig(expert_model_parallel_size=2),
+                          devices=devices8[:2])
+        with mesh, shd.use_mesh(mesh), shd.collect_trace_facts() as traced:
+            y, _ = jax.jit(
+                lambda p, xx: moe.moe_block(p, xx, cfg, **FP32))(params, x[None])
+        assert traced == {"moe_token_shards": 1}
+        np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-6)
 
 
 class TestTokenShuffle:

@@ -265,8 +265,11 @@ class TestVirtualPipeline:
         sh_params = jax.device_put(
             params, jax.tree_util.tree_map(ns, specs, is_leaf=lambda x: isinstance(x, P))
         )
-        with mesh, shd.use_mesh(mesh):
+        with mesh, shd.use_mesh(mesh), shd.collect_trace_facts() as traced:
             loss, grads = jax.jit(jax.value_and_grad(pl, argnums=0))(sh_params, mbs)
+        # inside the pipe-manual stage body the dropless expert block nests
+        # its own region over the axes that shard the micro-batch (data 4)
+        assert traced == {"moe_token_shards": 4}
         np.testing.assert_allclose(float(loss), float(ref_l), rtol=2e-5)
         for path in (
             ("layers", "mlp", "router", "w"),

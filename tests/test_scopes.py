@@ -220,9 +220,10 @@ def test_log_metrics_span_is_productive_and_lands_in_the_next_row(
 # -- the compile counter ----------------------------------------------------
 
 
-def fit_tiny(tmp_path, *, max_steps, at_step=None):
+def fit_tiny(tmp_path, *, max_steps, at_step=None, overrides=None):
     """A tiny Llama ``fit()`` logging every step; ``at_step(step)`` runs
-    inside the metric sink.  Returns (metrics rows, run summary)."""
+    inside the metric sink, ``overrides`` are dotted config keys.  Returns
+    (metrics rows, run summary)."""
     from neuronx_distributed_training_tpu.config.loader import load_config
     from neuronx_distributed_training_tpu.trainer.loop import Trainer
 
@@ -240,7 +241,7 @@ def fit_tiny(tmp_path, *, max_steps, at_step=None):
                   "max_position_embeddings": 32,
                   "optim": {"name": "adamw_fp32OptState", "lr": 1e-3}},
         "precision": {"type": "mixed_precision"},
-    })
+    }, overrides)
     trainer = Trainer.from_config(cfg, enable_checkpointing=False)
     if at_step is not None:
         inner = trainer.exp.log_metrics
@@ -275,3 +276,30 @@ def test_a_forced_second_compile_shows_with_its_step(tmp_path):
     from neuronx_distributed_training_tpu.telemetry import recompile
 
     assert recompile._compile_sink is None
+
+
+# -- how the trace partitioned the expert block -----------------------------
+
+
+@pytest.mark.parametrize("family", ["dense", "mixtral_ep2", "mixtral_ep2_untraced"])
+def test_moe_token_shards_is_a_static_fact_of_the_run(tmp_path, family, monkeypatch):
+    """``moe_token_shards``: the shards the dropless expert block was traced
+    under (here all 8 devices shard the batch: data 4 x expert 2), written
+    with the compile census.  A dense model carries no such key; an MoE run
+    whose trace recorded nothing carries null, never a key left out."""
+    overrides = {} if family == "dense" else {
+        "model.architecture": "mixtral",
+        "model.moe": {"num_experts": 4, "top_k": 2, "dropless": True},
+        "distributed_strategy.expert_model_parallel_size": 2,
+    }
+    if family == "mixtral_ep2_untraced":
+        from neuronx_distributed_training_tpu.parallel import sharding as shd
+        monkeypatch.setattr(shd, "trace_facts", lambda: None)
+    _, summary = fit_tiny(tmp_path, max_steps=2, overrides=overrides)
+    assert summary["n_chips"] == 8
+    if family == "dense":
+        assert "moe_token_shards" not in summary
+    elif family == "mixtral_ep2_untraced":
+        assert summary["moe_token_shards"] is None
+    else:
+        assert summary["moe_token_shards"] == 8

@@ -222,3 +222,88 @@ def test_named_scopes_leave_the_v5e_program_the_same(topo, monkeypatch):
     assert opcode_census(bare) == opcode_census(scoped)
     assert sum(opcode_census(scoped).values()) > 300
     assert memory_totals(bare) == memory_totals(scoped)
+
+
+# --------------------------------------------------------------------------
+# the dropless expert block is partitioned by tokens (ops/moe.py)
+# --------------------------------------------------------------------------
+
+
+def test_dropless_block_is_partitioned_by_tokens_on_v5e(topo):
+    """``jax.grad`` of the dropless block at Mixtral widths on ``(data 1,
+    expert 4, model 1)``, 16 384 tokens sharded over the data axes: each chip
+    sorts and multiplies its own 4 096 tokens x top-2 = 8 192 rows (not the
+    global 32 768), nothing on the token path crosses chips, each expert
+    weight is gathered over ``expert`` once and each expert-weight gradient
+    is reduce-scattered once, in float32.  Master weights are float32 and
+    cast per layer inside the differentiated function, as the step does."""
+    import collections
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from neuronx_distributed_training_tpu.ops import moe
+    from neuronx_distributed_training_tpu.parallel import sharding as shd
+    from neuronx_distributed_training_tpu.parallel.mesh import (
+        MeshConfig,
+        build_mesh,
+    )
+
+    cfg = moe.MoEConfig(num_experts=8, top_k=2, dropless=True)
+    hidden, ffn, batch, seq = 4096, 14336, 4, 4096
+    mesh = build_mesh(MeshConfig(expert_model_parallel_size=4),
+                      devices=topo.devices[:4])
+
+    def shaped(a, spec):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    params = jax.tree_util.tree_map(
+        shaped,
+        jax.eval_shape(lambda k: moe.init_moe_params(k, hidden, ffn, cfg),
+                       jax.random.PRNGKey(0)),
+        moe.moe_param_specs(cfg))
+    x = jax.ShapeDtypeStruct((batch, seq, hidden), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, shd.act_spec()))
+
+    def loss(p, xx):
+        p = jax.tree_util.tree_map(lambda w: w.astype(jnp.bfloat16), p)
+        y, aux = moe.moe_block(p, xx, cfg)
+        return (y.astype(jnp.float32) ** 2).sum() + moe.weighted_router_loss(
+            aux["router_logits"], aux["expert_idx"], cfg)
+
+    with mesh, shd.use_mesh(mesh):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, x).compile().as_text()
+
+    rows = batch * seq * cfg.top_k // 4
+    experts = {"gate_up": f"[8,{hidden},{2 * ffn}]", "down": f"[8,{ffn},{hidden}]"}
+    ragged = re.findall(r"= (\w+\[[\d,]+\])\S* custom-call\(.*ragged", text)
+    assert sorted(ragged) == sorted(
+        [f"bf16[{rows},{2 * ffn}]", f"bf16[{rows},{ffn}]",
+         f"bf16[{rows},{hidden}]", f"bf16[{rows},{hidden}]",
+         "bf16" + experts["gate_up"], "bf16" + experts["down"]]), ragged
+
+    # the compiler spreads one async collective over several fused
+    # computations that share its channel_id: count channels per shape
+    channels = collections.defaultdict(set)
+    for shape, kind, channel in re.findall(
+            r"= \(?(?:\w+\[[\d,]*\]\S* )*?(\w+\[[\d,]*\])\S*\)? "
+            r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
+            r"(?:-start)?\(.*channel_id=(\d+)", text):
+        channels[(kind, shape)].add(channel)
+    by_kind = collections.Counter(kind for kind, _ in channels)
+    # no sorted rows (global or local), no global token list ([b*s, ...] or
+    # [b, s, ...]: routing runs outside the region, on the global tokens,
+    # and has to stay partitioned by them)
+    token_dims = (batch * seq * cfg.top_k, rows, batch * seq)
+    assert not any(shape.startswith(f"[{batch},{seq},", shape.index("["))
+                   or any(f"[{n}," in shape for n in token_dims)
+                   for _, shape in channels), channels
+    assert by_kind["all-to-all"] == by_kind["collective-permute"] == 0
+    for name, shape in experts.items():
+        gathered = "bf16" + shape
+        assert len(channels[("all-gather", gathered)]) == 1, (name, channels)
+        scattered = f"f32[2,{shape[3:]}"
+        assert len(channels[("reduce-scatter", scattered)]) == 1, (name, channels)
+    assert by_kind["all-gather"] == by_kind["reduce-scatter"] == 2, channels
