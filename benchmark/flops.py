@@ -114,6 +114,18 @@ def flash_call(model: Mapping[str, Any], seq_len: int, batch: int,
             for k, m in FLASH_MATMULS.items()}
 
 
+def kernel_calls(model: Mapping[str, Any], traffic: Mapping[str, Any],
+                 data_parallel: int) -> dict:
+    """Per flash kernel: ``flops`` and ``bytes`` of one call
+    (:func:`flash_call` on the rows one chip holds of a micro-batch) and the
+    ``calls`` a step makes of it: micro-batches x layers."""
+    rows = (int(traffic["global_batch_size"]) // int(traffic["micro_batches"])
+            // int(data_parallel))
+    calls = int(traffic["micro_batches"]) * int(model["num_layers"])
+    return {kind: {**need, "calls": calls} for kind, need in flash_call(
+        model, int(traffic["seq_length"]), rows).items()}
+
+
 def roofline_seconds(flops: float, bytes_: float, peaks: Mapping[str, Any]) -> dict:
     """Least time the chip could take, and which bound holds."""
     t_c = flops / float(peaks["bf16_flops_per_s"])

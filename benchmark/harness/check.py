@@ -1,8 +1,10 @@
 """What ``correct`` compares, and its limits.
 
 Program side: per-leaf norms read from the live trainer during set-up.
-Reference side: ``benchmark/reference.py``.  Limits: ``benchmark/limits.json``
-per configuration, each set from chip readings that PERF.md lists.
+Reference side: the configuration's reference module (``cell.reference``,
+``harness/cell.py``).  Limits: ``benchmark/limits/<configuration>.json``, else
+the configuration's entry in ``benchmark/limits.json``; each set from chip
+readings that PERF.md lists.
 """
 
 from __future__ import annotations
@@ -10,14 +12,14 @@ from __future__ import annotations
 import json
 import re
 import statistics
+from pathlib import Path
 from typing import Any, Mapping, Optional
 
-from benchmark import reference
 from benchmark.harness import say
-from benchmark.harness.cell import HERE
+from benchmark.harness.cell import ROOT
 
 
-def _leaf_norms(tree) -> dict:
+def _leaf_norms(reference, tree) -> dict:
     import jax
     import jax.numpy as jnp
 
@@ -27,14 +29,17 @@ def _leaf_norms(tree) -> dict:
                     (float(x) for x in jax.tree_util.tree_leaves(norms))))
 
 
-def first_gradient_norms(opt_state: Mapping[str, Any], beta1: float) -> dict:
+def first_gradient_norms(reference, opt_state: Mapping[str, Any],
+                         beta1: float) -> dict:
     """Per-leaf norm of the first gradient as the optimizer got it (after
     clipping), worked out from its state after ONE update: the first moment
     is then ``(1 - beta1) g``."""
-    return {k: v / (1.0 - beta1) for k, v in _leaf_norms(opt_state["mu"]).items()}
+    return {k: v / (1.0 - beta1)
+            for k, v in _leaf_norms(reference, opt_state["mu"]).items()}
 
 
-def parameter_change_norms(params, model: Mapping[str, Any], seed: int) -> dict:
+def parameter_change_norms(reference, params, model: Mapping[str, Any],
+                           seed: int) -> dict:
     """Per-leaf norm of ``params - weights(seed)``, the seeded weights made
     again by the reference's recipe, leaf by leaf where the trainer's lie."""
     import jax
@@ -80,11 +85,21 @@ def sharder(devices):
         lambda a: jax.lax.with_sharding_constraint(a, spec(a)), tree)
 
 
-def limits_for(config_name: str) -> dict:
-    with open(HERE / "limits.json") as f:
+def limits_for(config_name: str, root: Path = ROOT) -> dict:
+    """The configuration's limits: its own file where it has one, else its
+    entry in the table.  One place only: a name in both is an error."""
+    own = root / "benchmark" / "limits" / f"{config_name}.json"
+    with open(root / "benchmark" / "limits.json") as f:
         table = json.load(f)
+    if own.exists():
+        if config_name in table:
+            raise ValueError(f"{config_name!r} has limits in benchmark/limits.json "
+                             f"and in benchmark/limits/{own.name}")
+        with open(own) as f:
+            return json.load(f)
     if config_name not in table:
-        raise KeyError(f"benchmark/limits.json has no limits for {config_name!r}")
+        raise KeyError(f"neither benchmark/limits/{own.name} nor "
+                       f"benchmark/limits.json has limits for {config_name!r}")
     return table[config_name]
 
 
@@ -124,18 +139,23 @@ def numbers(program: Mapping[str, Any], ref: Mapping[str, Any],
     return out
 
 
+def beside_limits(compared: Mapping[str, Any]) -> dict:
+    """Each number compared beside its limit, as a line of text by its name."""
+    return {name: f"check: {name} {compared[name]:.4e} limit {limit:.4e} "
+                  f"{'ok' if compared[name] <= limit else 'FAILED'}"
+            for name, limit in compared["limits"].items()}
+
+
 def compare(program: Mapping[str, Any], ref: Mapping[str, Any],
             limits: Mapping[str, Any]) -> tuple:
-    """Print each number beside its limit.  Returns (all inside, the numbers)."""
-    ok = True
+    """Print each number beside its limit.  Returns (all inside, the numbers,
+    with their limits under ``limits``)."""
     found = numbers(program, ref, limits.get("routed_leaves"))
-    for name, (value, detail) in found.items():
-        limit = float(limits[name.split("_step")[0]])
-        inside = value == value and value <= limit
-        ok = ok and inside
-        say(f"check: {name} {value:.4e} limit {limit:.4e} "
-            f"{'ok' if inside else 'FAILED'} ({detail})")
     compared = {k: v for k, (v, _) in found.items()}
+    compared["limits"] = {k: float(limits[k.split("_step")[0]]) for k in found}
+    for name, line in beside_limits(compared).items():
+        say(f"{line} ({found[name][1]})")
+    ok = all(compared[k] <= limit for k, limit in compared["limits"].items())
     compared["leaves"] = {what: leaf_gaps(program[what], ref[what])
                           for what in ("grad1", "dparam")}
     return ok, compared

@@ -22,7 +22,7 @@ import time
 from pathlib import Path
 from typing import Any, Callable, Optional
 
-from benchmark import flops, reference
+from benchmark import flops
 from benchmark.harness import say
 from benchmark.harness import check as checks
 from benchmark.harness import traffic as traffic_gen
@@ -90,6 +90,9 @@ def print_cuts(cell: Cell) -> None:
     for k, v in c["assumed"].items():
         say(f"  assumed: {k}: {v}")
     say(f"  stands for: {c['deployment']}")
+    say(f"  modules: reference {cell.reference.__name__} "
+        f"({cell.reference.__file__}), operations {cell.operations.__name__} "
+        f"({cell.operations.__file__})")
     say(f"  traffic: {cell.traffic['why']}")
 
 
@@ -104,6 +107,7 @@ class StepClock:
         self.check_steps = int(cell.traffic["check_steps"])
         self.open_step = self.check_steps + int(cell.traffic["warmup_steps"])
         self.model, self.seed = model, seed
+        self.reference = cell.reference
         self.stamps: dict[int, float] = {}
         self.losses: dict[int, float] = {}
         self.grad_norms: dict[int, float] = {}
@@ -126,12 +130,13 @@ class StepClock:
         self.grad_norms[step] = float(metrics.get("grad_norm", math.nan))
         if step == 1:
             self.grad1 = checks.first_gradient_norms(
-                self.trainer.opt_state, float(self.model["optim"]["betas"][0]))
+                self.reference, self.trainer.opt_state,
+                float(self.model["optim"]["betas"][0]))
             self.read_seconds += time.perf_counter() - now
         if step == self.check_steps:
             t_read = time.perf_counter()
             self.dparam = checks.parameter_change_norms(
-                self.trainer.params, self.model, self.seed)
+                self.reference, self.trainer.params, self.model, self.seed)
             self.read_seconds += time.perf_counter() - t_read
         if step == self.open_step:
             # the reads above are set-up; the window opens after them
@@ -292,7 +297,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
 
         # -- the check, outside the window, after the trainer is freed -----
         t_ref = time.perf_counter()
-        ref = reference.run(
+        ref = cell.reference.run(
             model, model["optim"], as_run["trainer"].get("gradient_clip_val"),
             check_tokens(cell, model, seed), seed, shard=checks.sharder(devices))
         say(f"reference: {clock.check_steps} float32 steps in "
@@ -302,7 +307,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
             "grad_norm": [clock.grad_norms[k + 1] for k in range(clock.check_steps)],
             "grad1": clock.grad1, "dparam": clock.dparam}
         verdict, compared = checks.compare(
-            program, ref, limits or checks.limits_for(cell.config_name))
+            program, ref,
+            limits or checks.limits_for(cell.config_name, cell.root))
         facts = {
             "finite loss at every step": all(
                 math.isfinite(clock.losses[s]) for s in clock.stamps),
@@ -329,10 +335,10 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
             "metrics": {}, "device": device, "compared": compared}
         if not trace:
             per_chip = tokens / window_s / len(devices)
-            need = flops.train_flops_per_token(cell.model, seq)
+            need = cell.operations.train_flops_per_token(cell.model, seq)
+            others = ", ".join(f"{k} {v:.6g}" for k, v in need.items() if k != "total")
             say(f"required operations per token: {need['total'] / 1e9:.4f} G "
-                f"(attention {need['attention'] / 1e9:.4f} G at "
-                f"{need['mean_keys']:.1f} mean keys, head {need['head'] / 1e9:.4f} G)")
+                f"by {cell.operations.__name__} ({others})")
             say(f"step time: {len(dts)} samples, p95 {quantile95(dts) * 1e3:.2f} ms")
             for dt, s_ in sorted(zip(dts, steps), reverse=True)[:3]:
                 spans = {k[5:]: round(float(v) * 1e3, 1)
@@ -354,6 +360,12 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
             from benchmark.harness import layers
 
             result.update(layers.read_all(ctx, result))
+        # each number compared beside its limit: the last lines of stderr, and
+        # the last key of the result's line
+        result["compared"] = compared = result.pop("compared")
+        broken = [f"check: {what}: FAILED" for what, ok in facts.items() if not ok]
+        print(*checks.beside_limits(compared).values(), *broken, sep="\n",
+              file=sys.stderr, flush=True)
         return result
     finally:
         shutil.rmtree(run_dir, ignore_errors=True)

@@ -2,9 +2,10 @@
 the chip could take for the calls the step makes (``benchmark/flops.py``,
 the chip's peaks) over the time the trace shows for them.
 
-The kernels are the three of ``flops.flash_call``, found in the trace by
-``trace_reduce.flash_kind``.  Calls per step are counted from the traffic
-(micro-batches x layers, each on the rows one chip holds)."""
+The kernels, their operations and bytes a call and their calls a step on one
+chip are the configuration's ``operations.kernel_calls`` (``harness/cell.py``);
+their time is what ``trace_reduce.flash_kind`` finds in the trace under the
+same kinds."""
 
 from benchmark import flops
 from benchmark.harness import say
@@ -15,13 +16,11 @@ def read(ctx):
     if tr is None or not tr["steps"] or ctx["peaks"] is None:
         return None
     cell = ctx["cell"]
-    t = cell.traffic
-    rows_per_call = (int(t["global_batch_size"]) // int(t["micro_batches"])
-                     // ctx["data_parallel"])
-    calls = int(t["micro_batches"]) * int(cell.model["num_layers"])
-    need = flops.flash_call(cell.model, int(t["seq_length"]), rows_per_call)
+    need = cell.operations.kernel_calls(
+        cell.model, cell.traffic, ctx["data_parallel"])
     least = took = 0.0
     for kind in need:
+        calls = need[kind]["calls"]
         seconds = tr["flash_s"].get(kind, 0.0) / tr["steps"]
         if seconds <= 0:
             return None

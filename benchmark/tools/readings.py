@@ -37,12 +37,12 @@ def main() -> None:
 
     import jax
 
-    from benchmark import reference
     from benchmark.harness import cell as cells
     from benchmark.harness import check as checks
     from benchmark.harness import drive
 
     cell = cells.load_cell(args.workload)
+    reference = cell.reference
     devices = jax.devices()[:cell.chips]
     for seed in (int(s) for s in args.seeds.split(",")):
         as_run = drive.merged_config(
@@ -56,7 +56,8 @@ def main() -> None:
                                 shard=checks.sharder(devices))
             ctl = reference.run(model, model["optim"], clip, tokens, seed,
                                 quant=args.control, shard=checks.sharder(devices))
-            routed = checks.limits_for(cell.config_name).get("routed_leaves")
+            routed = checks.limits_for(
+                cell.config_name, cell.root).get("routed_leaves")
             found = {k: v for k, (v, _) in checks.numbers(ctl, ref, routed).items()}
             found["leaves"] = {w: checks.leaf_gaps(ctl[w], ref[w])
                                for w in ("grad1", "dparam")}

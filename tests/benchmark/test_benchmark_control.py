@@ -12,7 +12,6 @@ import sys
 import pytest
 from benchmark_toy import toy, toy_limits
 
-from benchmark import reference
 from benchmark.harness import cell as cells
 from benchmark.harness import check as checks
 from benchmark.harness import drive
@@ -28,9 +27,9 @@ def three_steps(cell, seed, quant=None):
     as_run = drive.merged_config(
         cell, drive.overrides_for(cell, seed, False, drive.WORK / "unused"))
     model = as_run["model"]
-    return reference.run(model, model["optim"],
-                         as_run["trainer"]["gradient_clip_val"],
-                         drive.check_tokens(cell, model, seed), seed, quant=quant)
+    return cell.reference.run(model, model["optim"],
+                              as_run["trainer"]["gradient_clip_val"],
+                              drive.check_tokens(cell, model, seed), seed, quant=quant)
 
 
 @pytest.mark.parametrize("config", sorted(CELLS))

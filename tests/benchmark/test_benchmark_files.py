@@ -15,7 +15,13 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
 def stems(sub):
+    if not (HERE / sub).is_dir():
+        return set()
     return {p.stem for p in (HERE / sub).iterdir() if p.is_file()}
+
+
+CONFIG_FILES = {c["name"]: cells.load_config_file(BENCH, c["name"])
+                for c in BENCH["configs"]}
 
 
 def test_every_data_file_is_referenced():
@@ -25,6 +31,11 @@ def test_every_data_file_is_referenced():
     assert {w["config"] for w in BENCH["workloads"]} == {c["name"] for c in BENCH["configs"]}
     readers = {cells.load_layer_metric(m["name"])["reader"] for m in BENCH["per_layer"]}
     assert readers == stems("readers") - {"__init__"}
+    # what a configuration brings of its own is named by it, and by nothing else
+    named = [cfg.get("modules") or {} for cfg in CONFIG_FILES.values()]
+    assert {m["reference"] for m in named if "reference" in m} == stems("references")
+    assert {m["operations"] for m in named if "operations" in m} == stems("operations")
+    assert stems("limits") <= set(CONFIG_FILES)
 
 
 def test_a_stray_file_fails_loudly(tmp_path):
@@ -87,17 +98,35 @@ def test_configuration_header(name):
     entry = next(c for c in BENCH["configs"] if c["name"] == name)
     assert cfg["source"] == entry["source"] and len(cfg["source"]) <= 200
     assert sorted(cfg["reduced"]) == sorted(entry["reduced"]) and len(entry["reduced"]) <= 16
-    assert cfg["assumed"] and cfg["deployment"]
-    width = re.compile(r"hidden|intermediate|latent|state|proj|_dim$|_rank$|head_|top_k|per_tok")
-    assert not any(width.search(k) for k in entry["reduced"])
-    # published widths are kept
-    m, pub = cfg["trainer_config"]["model"], cfg["published"]
-    for k in ("hidden_size", "intermediate_size", "num_attention_heads",
-              "num_key_value_heads", "head_dim", "vocab_size", "rope_theta"):
-        assert m[k] == pub[k], k
-    if "moe" in m:
-        assert m["moe"]["num_experts"] == pub["num_local_experts"]
-        assert m["moe"]["top_k"] == pub["num_experts_per_tok"]
+    assert cfg["assumed"] and cfg["deployment"] and cfg["published"]
+    # published widths are kept: the file's own ``widths`` map, else the default
+    # one, every key of which the model block then has (``load_config_file``
+    # refuses a fault too; said here so that a failure names it)
+    assert cells.header_faults(cfg, entry["reduced"]) == []
+
+
+@pytest.mark.parametrize("kind", sorted(cells.CONTRACT))
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_configuration_modules_keep_the_contract(name, kind):
+    cfg = cells.load_config_file(BENCH, name)
+    module = cells.load_modules(cfg)[kind]
+    for function in cells.CONTRACT[kind]:
+        assert callable(getattr(module, function)), (module.__name__, function)
+    if kind not in (cfg.get("modules") or {}):
+        assert module.__name__ == cells.DEFAULT_MODULES[kind]
+    if kind == "reference":
+        # it imports nothing of the program
+        assert "neuronx_distributed_training_tpu" not in open(module.__file__).read()
+
+
+@pytest.mark.parametrize("name", [w["name"] for w in BENCH["workloads"]])
+def test_operations_count_what_the_cell_needs(name):
+    c = cells.load_cell(name)
+    need = c.operations.train_flops_per_token(c.model, c.traffic["seq_length"])
+    assert need["total"] > 0
+    calls = c.operations.kernel_calls(c.model, c.traffic, 1)
+    assert calls and all(
+        k["flops"] > 0 and k["bytes"] > 0 and k["calls"] >= 1 for k in calls.values())
 
 
 @pytest.mark.parametrize("name", [w["name"] for w in BENCH["workloads"]])
@@ -111,9 +140,51 @@ def test_cell_loads_with_its_metrics(name):
             "warmup_steps", "trace_steps", "why"} <= set(t)
 
 
+HARNESS = [HERE / "run.py", *sorted((HERE / "harness").glob("*.py")),
+           *sorted((HERE / "readers").glob("*.py"))]
+
+
 def test_harness_names_no_model_and_no_cell():
+    # harness/cell.py holds the loaders of references/, operations/ and limits/
     words = re.compile(r"mistral|mixtral|llama|pretrain-", re.I)
-    for path in [HERE / "run.py", *sorted((HERE / "harness").glob("*.py")),
-                 *sorted((HERE / "readers").glob("*.py"))]:
+    for path in HARNESS:
         for n, line in enumerate(path.read_text().splitlines(), 1):
             assert not words.search(line), f"{path.name}:{n}: {line.strip()}"
+
+
+def test_harness_reaches_a_configuration_through_the_cell_alone():
+    """No per-configuration function is called through a module imported by
+    name (no reference module is imported at all: where the name stands, it is
+    an argument); the one default lives in the loader.  ``flops.peaks_for`` and
+    ``flops.roofline_seconds`` are the chip's and stay a plain import."""
+    by_name = re.compile(
+        r"\bflops\.(train_flops_per_token|flash_call|kernel_calls|model_dims)\b")
+    imports = re.compile(r"^\s*(from benchmark import .*\breference\b|"
+                         r"import benchmark\.reference|from benchmark\.reference)")
+    for path in HARNESS + sorted((HERE / "tools").glob("*.py")):
+        for n, line in enumerate(path.read_text().splitlines(), 1):
+            assert not by_name.search(line), f"{path.name}:{n}: {line.strip()}"
+            assert not imports.search(line), f"{path.name}:{n}: {line.strip()}"
+    defaults = [p.name for p in HARNESS
+                if any(d in p.read_text() for d in cells.DEFAULT_MODULES.values())]
+    assert defaults == ["cell.py"]
+
+
+def test_limits_come_from_one_place(tmp_path):
+    from benchmark.harness import check as checks
+
+    (tmp_path / "benchmark" / "limits").mkdir(parents=True)
+    table = tmp_path / "benchmark" / "limits.json"
+    table.write_text(json.dumps({"in-table": {"loss_gap": 1.0}}))
+    (tmp_path / "benchmark" / "limits" / "own-file.json").write_text(
+        json.dumps({"loss_gap": 2.0}))
+    assert checks.limits_for("in-table", tmp_path) == {"loss_gap": 1.0}
+    assert checks.limits_for("own-file", tmp_path) == {"loss_gap": 2.0}
+    with pytest.raises(KeyError, match="has limits for 'absent'"):
+        checks.limits_for("absent", tmp_path)
+    (tmp_path / "benchmark" / "limits" / "in-table.json").write_text("{}")
+    with pytest.raises(ValueError, match="in benchmark/limits.json and in"):
+        checks.limits_for("in-table", tmp_path)
+    # the accepted configurations are held to the table, as they were
+    with open(HERE / "limits.json") as f:
+        assert {c: checks.limits_for(c) for c in CONFIG_FILES} == json.load(f)

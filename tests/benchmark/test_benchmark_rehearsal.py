@@ -30,8 +30,13 @@ def test_cell_rehearses(name, capsys):
     result = rehearse(name)
     line = json.loads(json.dumps(result))      # what run.py prints last
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
-    lines = capsys.readouterr().out.splitlines()
+    captured = capsys.readouterr()
+    lines = captured.out.splitlines()
     assert line["correct"] is True, "\n".join(l for l in lines if l.startswith("check"))
+    # each number compared beside its limit: last key of the line, last lines of stderr
+    assert list(line)[-1] == "compared" and len(line["compared"]["limits"]) >= 5
+    last = captured.err.splitlines()[-len(line["compared"]["limits"]):]
+    assert all(l.startswith("check: ") and " limit " in l for l in last), last
     assert line["failed"] == 0 and line["attempted"] >= 2
     assert set(line["metrics"]) == {"tokens_per_s_per_chip", "step_ms_p95", "setup_s"}
     assert all(m["value"] > 0 for m in line["metrics"].values())
@@ -49,6 +54,7 @@ def test_traced_rehearsal_prints_no_device_number_from_a_cpu():
     assert {"compile_s", "data_wait_ms_p95"} <= set(result["metrics"])
     assert not DEVICE_METRICS & set(result["metrics"])
     assert "busy_s" not in result["device"] and "breakdown" not in result
+    assert list(result)[-1] == "compared"
 
 
 def test_a_step_that_returns_its_state_unchanged_is_not_correct(capsys):

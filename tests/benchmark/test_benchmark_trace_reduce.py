@@ -31,6 +31,19 @@ def test_flash_and_collective_time(reduced):
     assert reduced["collective_s"] == 0.0      # one chip: none
 
 
+def test_roofline_share_through_the_cells_own_operations(reduced):
+    # the recorded trace of PR 23 reads 51.1 % (PERF.md); the reader takes the
+    # kernels' operations, bytes and calls from ``cell.operations``
+    from benchmark import flops
+    from benchmark.harness import cell as cells
+    from benchmark.readers import roofline
+
+    ctx = {"trace": reduced, "peaks": flops.peaks_for("TPU v5 lite"),
+           "cell": cells.load_cell("mistral7b-pretrain-4k"), "data_parallel": 1}
+    assert roofline.read(ctx) == pytest.approx(51.10520325837087, rel=1e-12)
+    assert roofline.read({**ctx, "peaks": None}) is None
+
+
 def test_op_times_are_self_times(reduced):
     # the while loop over micro-batches holds nearly every operation; its own
     # time must not count them twice
