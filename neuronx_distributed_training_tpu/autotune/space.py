@@ -120,7 +120,7 @@ class ModelFacts:
     """Everything the lattice + cost model need, extracted once from a
     loaded config mapping — no arrays, no lowering."""
 
-    family: str                      # llama | mistral | mixtral | gpt
+    family: str                      # llama | mistral | mixtral | gpt | ouro
     model_cfg: Any                   # the family's config dataclass
     num_layers: int
     num_heads: int
@@ -197,16 +197,22 @@ class ModelFacts:
             tied = bool(getattr(mc, "share_embeddings_and_output_weights",
                                 True))
         else:
-            from neuronx_distributed_training_tpu.models import llama
+            from neuronx_distributed_training_tpu.models import llama, ouro
 
-            mc = llama.LlamaConfig.from_config(model, ds)
-            family = "mistral" if arch == "mistral" else "llama"
+            if arch == "ouro":
+                # the looped stack: llama's layout; its passes reach the cost
+                # model through utils.perf.flops_breakdown_for_model only
+                mc = ouro.OuroConfig.from_config(model, ds)
+                lc, family = mc.llama, "ouro"
+            else:
+                mc = lc = llama.LlamaConfig.from_config(model, ds)
+                family = "mistral" if arch == "mistral" else "llama"
             experts = top_k = 0
             moe_freq = 1
-            heads, kv = mc.num_attention_heads, mc.kv_heads
-            head_dim, hidden = mc.head_size, mc.hidden_size
-            ffn, vocab = mc.intermediate_size, mc.vocab_size
-            layers, tied = mc.num_layers, mc.tie_word_embeddings
+            heads, kv = lc.num_attention_heads, lc.kv_heads
+            head_dim, hidden = lc.head_size, lc.hidden_size
+            ffn, vocab = lc.intermediate_size, lc.vocab_size
+            layers, tied = lc.num_layers, lc.tie_word_embeddings
 
         if fusions.get("ulysses_attention"):
             cp_fusion: Optional[str] = "ulysses"

@@ -301,6 +301,12 @@ def validate_config(cfg: ConfigDict) -> None:
                 f"pipeline slices whole groups per stage chunk"
             )
 
+    # ---- looped stack (model.architecture: ouro) ---------------------------
+    if str(model.get("architecture", model.get("model_type", ""))).lower() == "ouro":
+        from neuronx_distributed_training_tpu.models.ouro import OuroConfig
+
+        OuroConfig.from_config(model, ds)  # refuses what the loop is not wired for
+
     # ---- context parallelism & attention kernels --------------------------
     seq = data.get("seq_length")
     zigzag = bool(fusions.get("zigzag_ring_attention"))

@@ -471,6 +471,11 @@ def _family(cfg):
     """(prefill_fn, decode_fn, logits_cfg_for_head) by config type."""
     from neuronx_distributed_training_tpu.models import gpt, mixtral
 
+    if getattr(cfg, "total_ut_steps", None) is not None:
+        raise NotImplementedError(
+            "model.architecture: ouro (total_ut_steps passes over one stack) "
+            "has no cached decode: every pass needs a KV cache of its own "
+            "(models/decode.py holds one per layer)")
     if isinstance(cfg, mixtral.MixtralConfig):
         return (prefill_mixtral, decode_step_mixtral,
                 lambda params, h, policy: llama.logits_fn(

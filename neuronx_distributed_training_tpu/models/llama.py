@@ -60,6 +60,10 @@ class LlamaConfig:
     tie_word_embeddings: bool = False
     initializer_range: float = 0.02
     sliding_window: Optional[int] = None
+    # a second RMSNorm on each sub-layer's OUTPUT, before the residual add
+    # (leaves ``input_norm_2`` / ``post_attn_norm_2``); set by the families
+    # built on this block that have them (models/ouro.py), no YAML key
+    post_sublayer_norms: bool = False
     # parallel / fusion behavior
     fuse_qkv: bool = True
     attention_impl: str = "core"  # "core" | "flash" | "ring" | "ulysses"
@@ -142,6 +146,9 @@ def _init_layer(key: jax.Array, cfg: LlamaConfig, dtype):
 
     params["input_norm"], specs["input_norm"] = norm_ops.init_rms_norm(h, dtype=dtype)
     params["post_attn_norm"], specs["post_attn_norm"] = norm_ops.init_rms_norm(h, dtype=dtype)
+    if cfg.post_sublayer_norms:
+        for name in ("input_norm_2", "post_attn_norm_2"):
+            params[name], specs[name] = norm_ops.init_rms_norm(h, dtype=dtype)
 
     std = cfg.initializer_range
     attn_p: dict[str, Any] = {}
@@ -214,9 +221,10 @@ def _layer_specs(cfg: LlamaConfig):
         }
     )
     attn_s["o"] = {"w": P("model", None)}
+    norms = ("input_norm", "post_attn_norm") + (
+        ("input_norm_2", "post_attn_norm_2") if cfg.post_sublayer_norms else ())
     return {
-        "input_norm": {"scale": P(None)},
-        "post_attn_norm": {"scale": P(None)},
+        **{name: {"scale": P(None)} for name in norms},
         "attn": attn_s,
         "mlp": {"gate_up": {"w": P(None, "model")}, "down": {"w": P("model", None)}},
     }
@@ -303,11 +311,17 @@ def _decoder_layer(layer_params, x, cos, sin, cfg: LlamaConfig, policy: DtypePol
         kv = None
         if return_kv:
             hidden, kv = hidden
+        if cfg.post_sublayer_norms:
+            hidden = norm_ops.apply_rms_norm(
+                layer_params["input_norm_2"], hidden, eps=cfg.rms_norm_eps)
         x = shd.constrain(residual + hidden, aspec)
     with jax.named_scope("mlp"):
         residual = x
         hidden = norm_ops.apply_rms_norm(layer_params["post_attn_norm"], x, eps=cfg.rms_norm_eps)
         hidden = _mlp_block(layer_params["mlp"], hidden)
+        if cfg.post_sublayer_norms:
+            hidden = norm_ops.apply_rms_norm(
+                layer_params["post_attn_norm_2"], hidden, eps=cfg.rms_norm_eps)
         x = shd.constrain(residual + hidden, aspec)
     if return_kv:
         return x, kv
@@ -326,18 +340,18 @@ def _remat_policy(granularity: Optional[str]):
     return None
 
 
-def hidden_states(
+def embed_and_rope(
     params,
-    input_ids: jax.Array,  # [batch, seq] (seq may be the per-CP-shard slice)
+    input_ids: jax.Array,
     cfg: LlamaConfig,
     policy: DtypePolicy,
     *,
     positions: Optional[jax.Array] = None,
-    layers: Optional[Any] = None,  # override stacked layer params (pipeline stages)
-    attention_mask: Optional[jax.Array] = None,  # [b, s] 1 = real token
-    segment_ids: Optional[jax.Array] = None,  # [b, s] packed-record segments
-) -> jax.Array:
-    """Embedding + scanned decoder stack + final norm -> [batch, seq, hidden]."""
+    attention_mask: Optional[jax.Array] = None,
+    segment_ids: Optional[jax.Array] = None,
+):
+    """Embedded tokens ``[batch, seq, hidden]`` and the RoPE ``(cos, sin)``
+    of their positions: what the decoder stack is applied to."""
     aspec = shd.act_spec(cfg.sequence_parallel, cfg.context_parallel)
     x = linear_ops.apply_embedding(params["embed"], input_ids, compute_dtype=policy.compute_dtype)
     x = shd.constrain(x, aspec)
@@ -346,14 +360,16 @@ def hidden_states(
         # HF position_ids convention for padded batches (see positions_for);
         # packed chunks (segment_ids) reset RoPE phases per record
         positions = positions_for(input_ids, attention_mask, segment_ids)
-    inv_freq = rope_ops.rope_frequencies(
-        cfg.head_size,
-        theta=cfg.rope_theta,
-        position_interpolation_factor=cfg.rope_interpolation_factor,
-    )
-    cos, sin = rope_ops.rope_cos_sin(positions, inv_freq, dtype=jnp.float32)
+    cos, sin = _rope_for(input_ids, cfg, positions)
+    return x, cos, sin
 
-    layer_stack = params["layers"] if layers is None else layers
+
+def decoder_stack(layer_stack, x: jax.Array, cos, sin, cfg: LlamaConfig,
+                  policy: DtypePolicy, *, attention_mask=None,
+                  segment_ids=None) -> jax.Array:
+    """One application of the scanned (and rematerialized) decoder stack to
+    ``x``.  A stack applied several times (models/ouro.py) calls this once a
+    pass with the same ``layer_stack``."""
 
     def body(carry, lp):
         # cast INSIDE the scan body (and remat boundary): only one layer's
@@ -368,6 +384,26 @@ def hidden_states(
     if remat is not None:
         body = jax.checkpoint(body, policy=remat, prevent_cse=False)
     x, _ = jax.lax.scan(body, x, layer_stack)
+    return x
+
+
+def hidden_states(
+    params,
+    input_ids: jax.Array,  # [batch, seq] (seq may be the per-CP-shard slice)
+    cfg: LlamaConfig,
+    policy: DtypePolicy,
+    *,
+    positions: Optional[jax.Array] = None,
+    layers: Optional[Any] = None,  # override stacked layer params (pipeline stages)
+    attention_mask: Optional[jax.Array] = None,  # [b, s] 1 = real token
+    segment_ids: Optional[jax.Array] = None,  # [b, s] packed-record segments
+) -> jax.Array:
+    """Embedding + scanned decoder stack + final norm -> [batch, seq, hidden]."""
+    x, cos, sin = embed_and_rope(params, input_ids, cfg, policy, positions=positions,
+                                 attention_mask=attention_mask, segment_ids=segment_ids)
+    x = decoder_stack(params["layers"] if layers is None else layers, x, cos, sin,
+                      cfg, policy, attention_mask=attention_mask,
+                      segment_ids=segment_ids)
     with jax.named_scope("ce_head"):
         return norm_ops.apply_rms_norm(params["final_norm"], x, eps=cfg.rms_norm_eps)
 

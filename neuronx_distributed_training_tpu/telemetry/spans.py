@@ -49,6 +49,10 @@ NON_PRODUCTIVE_SPANS = ("compile", "validate", "checkpoint", "restart",
 #: the inner scopes it holds (docs/observability.md "Named scopes").  An op's
 #: scope path reaches the profiler as the ``tf_op`` stat of its event metadata,
 #: wrapped as ``jvp(<scope>)`` forward and ``transpose(jvp(<scope>))`` backward.
+#: ``models/ouro.py`` alone opens one more inner scope, ``ce_head/exit_gate``;
+#: a reader that does not know it counts its time under ``ce_head``.  It joins
+#: this table with the benchmark's copy (``benchmark/readers/scope_time.py``,
+#: which a test holds equal to this one): PERF.md section 7.
 DEVICE_SCOPES: dict[str, tuple[str, ...]] = {
     "embed": (),
     "attention": ("flash_fwd", "flash_dq", "flash_dkv"),

@@ -945,6 +945,13 @@ class Trainer:
             # null = not known (the census's trace did not run); the census
             # fills in how the block was partitioned.  Absent = no such block
             run_facts["moe_token_shards"] = None
+        passes = getattr(model_cfg, "total_ut_steps", None)
+        if passes is not None:
+            # a stack applied several times with shared weights (models/ouro.py)
+            run_facts["loop_passes"] = int(passes)
+            run_facts["layer_applications_per_step"] = (
+                int(passes) * int(model_cfg.num_layers)
+                * int(sched["num_microbatches"]))
         # the manual-vjp schedules run the WORK-COMPACTED executor: record
         # its per-step tick counts (compacted span + per-kind active ticks
         # vs the old lockstep trip count) so the measured timelines are
@@ -2433,6 +2440,20 @@ def build_model(cfg: ConfigDict, policy: DtypePolicy, *, shift_labels: bool = Tr
             loss_fn,
             lambda key: mixtral.init_params(key, xc, policy),
             lambda **kw: mixtral.param_specs(xc, **kw),
+        )
+    if arch == "ouro":
+        from neuronx_distributed_training_tpu.models import ouro
+
+        oc = ouro.OuroConfig.from_config(model_block, ds_block)
+
+        def loss_fn(p, batch, key):
+            return ouro.forward(p, batch, oc, policy, shift_labels=shift_labels)
+
+        return (
+            oc,
+            loss_fn,
+            lambda key: ouro.init_params(key, oc, policy),
+            lambda **kw: ouro.param_specs(oc, **kw),
         )
     if arch == "gpt" or source == "megatron":
         from neuronx_distributed_training_tpu.models import gpt

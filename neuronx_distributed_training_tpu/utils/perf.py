@@ -242,8 +242,10 @@ def flops_breakdown_for_model(model_cfg: Any, seq_len: int) -> dict[str, float]:
             "router": 0.0,
             "head": head,
         }
-    # llama/mistral (and anything exposing the same shape attributes)
-    attn = model_cfg.num_layers * _attention_flops_per_token(
+    # llama/mistral (and anything exposing the same shape attributes); a stack
+    # applied several times (models/ouro.py) multiplies its work, heads included
+    passes = int(getattr(model_cfg, "total_ut_steps", 1) or 1)
+    attn = passes * model_cfg.num_layers * _attention_flops_per_token(
         hidden_size=model_cfg.hidden_size,
         num_attention_heads=model_cfg.num_attention_heads,
         num_kv_heads=getattr(model_cfg, "num_kv_heads", None),
@@ -253,9 +255,9 @@ def flops_breakdown_for_model(model_cfg: Any, seq_len: int) -> dict[str, float]:
     mlp = 2 * model_cfg.hidden_size * 3 * model_cfg.intermediate_size
     return {
         "attention": attn,
-        "mlp": float(model_cfg.num_layers * mlp),
+        "mlp": float(passes * model_cfg.num_layers * mlp),
         "router": 0.0,
-        "head": 2.0 * model_cfg.hidden_size * model_cfg.vocab_size,
+        "head": 2.0 * passes * model_cfg.hidden_size * model_cfg.vocab_size,
     }
 
 

@@ -39,6 +39,8 @@ CASES = {
     "llama": ("hf_llama_7B_config.yaml", {}),
     "mixtral": ("hf_mixtral_8x7b_config.yaml", {
         "distributed_strategy.expert_model_parallel_size": 2}),
+    # the looped stack: a head and an exit gate at the end of every pass
+    "ouro": ("hf_ouro_2_6b_config.yaml", {}),
 }
 
 
@@ -99,20 +101,43 @@ def has_scope(names, scope, *, wrapped_by=None, inside=None):
     return False
 
 
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """The persistent compilation cache keys a program without its metadata,
+    so a compile with the scopes taken out would be answered by the entry of
+    the compile with them in (names and all), whenever an earlier test of the
+    worker turned the cache on and the first compile took long enough to be
+    kept: the way ``test_scopes_are_metadata_the_program_is_the_same`` failed
+    under the driver's six workers and passed alone (ROADMAP, PR 25)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    previous = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", previous)
+    cc.reset_cache()
+
+
 @pytest.fixture(scope="module", params=list(CASES))
-def toy(request):
+def toy(request, no_persistent_cache):
     return request.param, compile_toy_step(request.param)
 
 
 def test_every_scope_of_the_table_is_in_the_compiled_step(toy):
     name, compiled = toy
     names = op_names(compiled)
-    blocks = {"llama": ["mlp"], "mixtral": ["moe"]}[name]
+    blocks = {"llama": ["mlp"], "mixtral": ["moe"], "ouro": ["mlp"]}[name]
     for top in ["embed", "attention", "ce_head", *blocks]:
         assert has_scope(names, top, wrapped_by="jvp("), top
         assert has_scope(names, top, wrapped_by="transpose("), top
         for inner in DEVICE_SCOPES[top]:
             assert has_scope(names, inner, inside=top), (top, inner)
+    # the looped stack alone opens ce_head/exit_gate: forward and backward
+    # there, nowhere else
+    for transform in ("jvp(", "transpose("):
+        assert has_scope(names, "exit_gate", wrapped_by=transform,
+                         inside="ce_head") == (name == "ouro"), (name, transform)
     # the backward kernels run only transposed, the forward one both ways
     # (recomputed under the layer's checkpoint)
     assert has_scope(names, "flash_fwd", wrapped_by="jvp(")

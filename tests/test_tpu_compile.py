@@ -153,6 +153,16 @@ STEP_CASES = {
         "distributed_strategy.pipeline_model_parallel_size": 2,
         "data.global_batch_size": 8,
     }),
+    # the benchmark's looped-stack cell (benchmark/configs/ouro-2.6b.json):
+    # published widths, 8 of 48 layers x 4 passes, seq 4096; every layer
+    # application keeps only its input, the per-pass head is rematerialized
+    "ouro_8_layers_4_passes": ("hf_ouro_2_6b_config.yaml", 1, {
+        "model.num_layers": 8,
+        "model.activations_checkpoint_granularity": "full",
+        "distributed_strategy.tensor_model_parallel_size": 1,
+        "distributed_strategy.sequence_parallel": False,
+        "data.global_batch_size": 1,
+    }),
 }
 
 
@@ -180,6 +190,17 @@ def test_two_micro_batches_do_not_fit_one_chip(topo):
     with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
         _compile_step(topo, config, n_devices,
                       {**overrides, "data.global_batch_size": 2})
+
+
+def test_a_pass_that_keeps_its_layers_residuals_does_not_fit_one_chip(topo):
+    """Why the looped-stack cell runs ``full``: under ``selective`` the pass
+    is rematerialized whole and its 8 layers keep their residuals at once,
+    which with 6.84 GiB of state the compiler refuses for one v5e (17.46 GiB);
+    under ``full`` the same step takes 13.55 GiB."""
+    config, n_devices, overrides = STEP_CASES["ouro_8_layers_4_passes"]
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
+        _compile_step(topo, config, n_devices, {
+            **overrides, "model.activations_checkpoint_granularity": "selective"})
 
 
 # --------------------------------------------------------------------------
