@@ -259,21 +259,39 @@ def declared_source_classes(d: DeclaredComms) -> list[tuple]:
             "ops/moe.py _dropless_on_mesh (routing ahead of the region)",
             src=_src_any("router/top_k"))
     if d.moe_dropless and d.ep > 1:
-        # weight-gather EP (ops/moe.py _dropless_on_mesh): each token shard
-        # all-gathers the expert weights over 'expert' once per MoE layer and
-        # reduce-scatters their gradients back, and nothing on the token path
-        # crosses chips.  Ahead of the classes whose source needles
-        # ("gather") or axis sets (ZeRO-1's) would claim the two.
-        add("ep expert weight gather", ("all-gather",),
+        # the expert exchange (ops/moe.py _exchange_experts), all of it over
+        # 'expert'.  While no chip would receive more than its bound the rows
+        # travel: dispatch all-gathers each peer's token shard (rows, gate
+        # weights, choices), combine sends every peer its block of the
+        # gate-weighted outputs and sums it there (all-to-all + local sum);
+        # each is the other's transpose, so both kinds appear under both
+        # scopes.  Past the bound (a branch only where a chip can receive
+        # more than twice its share) the weights travel instead: each expert
+        # weight all-gathered once, its float32 gradient reduce-scattered
+        # once.  (The largest received row count is max-reduced over the
+        # region's axes: one more all-reduce of the dp class.)  Ahead of the
+        # classes whose source needles ("gather") or axis sets (ZeRO-1's)
+        # would claim them.
+        add("ep exchange all-gather", ("all-gather",),
             lambda a: a == {"expert"},
-            "weight-gather EP changed; ops/moe.py gathers each expert "
-            "weight over 'expert' exactly once per MoE layer",
-            src=_src_any("experts/shard_map/all_gather"))
+            "the expert exchange changed; ops/moe.py gathers each token "
+            "shard (rows, gate weights, choices) over 'expert' under "
+            "moe/dispatch in either pass and the outputs' cotangent under "
+            "moe/combine, and past the row bound each expert weight once",
+            src=_src_any("moe/shard_map/dispatch/", "moe/shard_map/combine/",
+                         "experts/shard_map/all_gather"))
+        add("ep exchange all-to-all", ("all-to-all",),
+            lambda a: a == {"expert"},
+            "the expert exchange changed; ops/moe.py returns each peer's "
+            "block of expert outputs over 'expert' by one all-to-all per MoE "
+            "layer under moe/combine, and the gathered rows' and gate "
+            "weights' cotangents by one each under moe/dispatch",
+            src=_src_any("moe/shard_map/dispatch/", "moe/shard_map/combine/"))
         add("ep expert gradient reduce-scatter", ("reduce-scatter",),
             lambda a: a == {"expert"},
-            "the cross-shard sum of the expert-weight gradients changed; "
-            "ops/moe.py reduce-scatters each over 'expert' exactly once per "
-            "MoE layer, in reduce_dtype",
+            "the weights' way of the expert exchange changed; past the row "
+            "bound ops/moe.py reduce-scatters each expert-weight gradient "
+            "over 'expert' exactly once per MoE layer, in reduce_dtype",
             src=_src_any("experts/shard_map/reduce_scatter"))
     if d.tp > 1:
         add("tp/SP layer collective", AK["tp"],

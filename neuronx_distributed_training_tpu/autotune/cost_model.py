@@ -123,6 +123,10 @@ _SCORE_BUFFERS = 3.0
 #: dropless-MoE routing workspace: f32 gate/up/activation rows plus
 #: gather/scatter hidden copies per routed token ([T*k, ffn] and [T*k, h])
 _MOE_ROUTE_BUFFERS = 6.0
+#: the same under ep > 1, where the block is ops/moe.py's exchange: its
+#: written-out backward keeps the pre-activations and the expert outputs per
+#: row alone between the passes (of up to twice the rows)
+_MOE_EXCHANGE_BUFFERS = 3.0
 #: pipeline stage-loop buffering per LOCAL layer per microbatch-token: the
 #: tick loop's stacked carries + per-tick vjp residuals.  Empirically
 #: nm-independent and IDENTICAL across schedules and remat policies on the
@@ -195,7 +199,8 @@ def hbm_breakdown(facts: ModelFacts, plan: Plan,
     if is_moe:
         # dropless routing workspace rides every MoE layer in f32
         moe_share = 1.0 / max(facts.moe_frequency, 1)
-        c_tok += _MOE_ROUTE_BUFFERS * moe_share * max(facts.top_k, 1) \
+        buffers = _MOE_EXCHANGE_BUFFERS if plan.ep > 1 else _MOE_ROUTE_BUFFERS
+        c_tok += buffers * moe_share * max(facts.top_k, 1) \
             * (ffn + h) / plan.tp * 4
 
     act = layers_local * c_tok * tokens_mb
@@ -250,10 +255,17 @@ def hbm_breakdown(facts: ModelFacts, plan: Plan,
     if pipe_rings:
         out["pipeline_rings"] = pipe_rings
     if facts.num_experts and plan.ep > 1:
-        # dropless MoE computes against the ep-GATHERED expert weights
-        # (ops/moe.py weight-gather EP); the gathered copy is a transient
-        comp = param_components(facts, plan)
-        out["gathered_experts"] = comp["experts"] * plan.ep * abytes
+        # past its row bound dropless MoE computes against the ep-GATHERED
+        # expert weights (ops/moe.py _exchange_experts, the weights' way), and
+        # a step holds room for them either way; the gathered copy is a
+        # transient.  Where twice a chip's fair share is every row it can
+        # receive (ep 2) the rows always travel and nothing is gathered
+        from neuronx_distributed_training_tpu.ops.moe import _EXCHANGE_ROWS
+
+        k = max(facts.top_k, 1)
+        if plan.ep * min(k, facts.num_experts // plan.ep) > _EXCHANGE_ROWS * k:
+            comp = param_components(facts, plan)
+            out["gathered_experts"] = comp["experts"] * plan.ep * abytes
     if calibration:
         for cat, ratio in calibration.items():
             if cat in out:
@@ -706,7 +718,10 @@ def estimate_plan(facts: ModelFacts, plan: Plan, topo: ChipTopology,
             comms["cp"] = 3.0 * facts.num_layers / plan.pp * _ring_seconds(
                 kv_bytes, plan.cp, axis_topo("cp"))
 
-    # ep: token dispatch + combine all-to-alls, fwd + 2x bwd
+    # ep: token dispatch + combine transfers, fwd + 2x bwd: what the dropless
+    # block moves while routing is balanced (ops/moe.py _exchange_experts;
+    # past twice a chip's fair share the expert weights travel instead,
+    # which is not priced here)
     if plan.ep > 1 and facts.num_experts:
         n_moe = facts.num_layers // max(facts.moe_frequency, 1)
         route_bytes = tokens_chip * max(facts.top_k, 1) * h * abytes

@@ -190,7 +190,7 @@ def prefill_mixtral(params, input_ids, cfg, policy, *, max_len=None):
     if cfg.moe_frequency > 1:
 
         def gbody(x, gp):
-            x, _aux, (k0, v0) = mixtral._decoder_layer(
+            x, _aux, _stats, (k0, v0) = mixtral._decoder_layer(
                 gp["moe"], x, cos, sin, cfg, policy, return_kv=True
             )
 
@@ -212,7 +212,7 @@ def prefill_mixtral(params, input_ids, cfg, policy, *, max_len=None):
     else:
 
         def body(x, lp):
-            x, _aux, (k, v) = mixtral._decoder_layer(
+            x, _aux, _stats, (k, v) = mixtral._decoder_layer(
                 lp, x, cos, sin, cfg, policy, return_kv=True
             )
             return x, (jnp.pad(k, pad), jnp.pad(v, pad))

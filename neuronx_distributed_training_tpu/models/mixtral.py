@@ -151,6 +151,16 @@ def _group_xs(cfg: MixtralConfig, layer_stack):
     return moe_ops.group_interleaved_stack(cfg.moe_frequency, layer_stack)
 
 
+def _cast_layer(lp, policy: DtypePolicy):
+    """The per-layer cast to the compute dtype (see llama) of all but the
+    expert weights: ``moe_block`` casts those where it multiplies them and
+    hands their gradients back in the dtype they arrive in, so the float32
+    sum over rows (and over chips) reaches the master weights without a
+    round trip through the compute dtype."""
+    cast = policy.cast_to_compute(lp)
+    return {**cast, "mlp": {**cast["mlp"], "experts": lp["mlp"]["experts"]}}
+
+
 def _grouped_scan(cfg: MixtralConfig, layer_stack, cos, sin, policy,
                   attention_mask=None, segment_ids=None):
     """(xs, body) for the dense/MoE interleave scan over [G] groups.
@@ -164,9 +174,9 @@ def _grouped_scan(cfg: MixtralConfig, layer_stack, cos, sin, policy,
     def body(carry, gp):
         x, aux_acc = carry
         # per-group cast inside the scan (one group's bf16 copy live at a time)
-        x, aux = _decoder_layer(policy.cast_to_compute(gp["moe"]), x, cos, sin,
-                                cfg, policy, attention_mask=attention_mask,
-                                segment_ids=segment_ids)
+        x, aux, stats = _decoder_layer(
+            _cast_layer(gp["moe"], policy), x, cos, sin, cfg, policy,
+            attention_mask=attention_mask, segment_ids=segment_ids)
 
         def dense_body(x2, dlp):
             return llama._decoder_layer(
@@ -175,14 +185,15 @@ def _grouped_scan(cfg: MixtralConfig, layer_stack, cos, sin, policy,
             ), None
 
         x, _ = jax.lax.scan(dense_body, x, gp["dense"])
-        return (x, aux_acc + aux), None
+        return (x, aux_acc + aux), stats
 
     return xs, body
 
 
 def _decoder_layer(lp, x, cos, sin, cfg: MixtralConfig, policy: DtypePolicy,
                    attention_mask=None, segment_ids=None, return_kv=False):
-    """Pre-LN attention + MoE block; returns (x, aux_loss[, (k, v)])."""
+    """Pre-LN attention + MoE block; returns (x, aux_loss, stats[, (k, v)]),
+    ``stats`` the block's per-step scalars (``ops.moe.moe_block``)."""
     lc = cfg.llama
     aspec = shd.act_spec(lc.sequence_parallel, lc.context_parallel)
     # scope names: telemetry.spans.DEVICE_SCOPES
@@ -211,8 +222,8 @@ def _decoder_layer(lp, x, cos, sin, cfg: MixtralConfig, policy: DtypePolicy,
             aux["router_logits"], aux["expert_idx"], cfg.moe)
         x = shd.constrain(residual + hidden, aspec)
     if return_kv:
-        return x, aux_loss, kv
-    return x, aux_loss
+        return x, aux_loss, aux["stats"], kv
+    return x, aux_loss, aux["stats"]
 
 
 def pipeline_hooks(cfg: MixtralConfig, policy: DtypePolicy, *,
@@ -243,9 +254,9 @@ def pipeline_hooks(cfg: MixtralConfig, policy: DtypePolicy, *,
 
             def body(carry, lp):
                 x, aux_acc = carry
-                lp = policy.cast_to_compute(lp)  # per-layer cast (see llama)
-                x, aux = _decoder_layer(lp, x, cos, sin, cfg, policy)
-                return (x, aux_acc + aux), None
+                lp = _cast_layer(lp, policy)
+                x, aux, stats = _decoder_layer(lp, x, cos, sin, cfg, policy)
+                return (x, aux_acc + aux), stats
 
             xs = ll
         else:
@@ -314,11 +325,11 @@ def forward(
 
         def body(carry, lp):
             x, aux_acc = carry
-            lp = policy.cast_to_compute(lp)  # per-layer cast (see llama)
-            x, aux = _decoder_layer(lp, x, cos, sin, cfg, policy,
-                                    attention_mask=attention_mask,
-                                    segment_ids=segment_ids)
-            return (x, aux_acc + aux), None
+            lp = _cast_layer(lp, policy)
+            x, aux, stats = _decoder_layer(lp, x, cos, sin, cfg, policy,
+                                           attention_mask=attention_mask,
+                                           segment_ids=segment_ids)
+            return (x, aux_acc + aux), stats
 
         xs = layer_stack
     else:
@@ -329,10 +340,12 @@ def forward(
 
     if remat is not None:
         body = jax.checkpoint(body, policy=remat, prevent_cse=False)
-    (x, aux_sum), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    (x, aux_sum), stats = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
     # router_aux_loss is already coefficient-weighted (weighted_router_loss);
     # averaged over the layers that HAVE routers
     aux: dict[str, Any] = {"router_aux_loss": aux_sum / num_moe_layers(cfg)}
+    # the expert blocks' scalars (moe/...), the largest over the layers
+    aux.update({name: jnp.max(v) for name, v in stats.items()})
     with jax.named_scope("ce_head"):
         hidden = norm_ops.apply_rms_norm(params["final_norm"], x, eps=lc.rms_norm_eps)
         logits = llama.logits_fn(params, hidden, lc, policy)

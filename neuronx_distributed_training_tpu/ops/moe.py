@@ -22,13 +22,19 @@ the dropped-vs-dropless validation at ``training_orchestrator.py:60-102``):
   sharded over the ``expert`` mesh axis (``moe_param_specs``).  The dropless
   block routes on the global tokens (plain GSPMD code: sinkhorn normalises
   over all of them), then is a per-shard computation over the mesh axes that
-  shard its tokens (``data``, ``expert``, and ``context`` under cp): each
-  shard sorts and multiplies only its own rows, against all experts, whose
-  weights it all-gathers over ``expert`` in the compute dtype; the gather's
-  transpose reduce-scatters the shards' partial weight gradients in
-  ``reduce_dtype`` (weight-gather EP; ``_dropless_on_mesh``).  There is no collective on the
-  token path, and no all-to-all: sending each token to its experts' chip
-  (the reference's NxD token shuffle) moves fewer bytes and is not built.
+  shard its tokens (``data``, ``expert``, and ``context`` under cp;
+  ``_dropless_on_mesh``).  Over ``expert`` it is an exchange
+  (``_exchange_experts``), fixed shapes, no row dropped.  While no chip
+  would receive more than twice its fair share of the rows, the rows travel
+  and the weights stay: each chip all-gathers its peers' token shards,
+  multiplies the rows that chose its resident experts, and the gate-weighted
+  outputs are summed back to the tokens' home chips (the reference's NxD
+  token shuffle, in collectives XLA:CPU also runs); no collective carries
+  an expert weight or its gradient.  Past that (a router that sends most
+  rows to one chip's experts makes that chip do most of the work) the
+  weights travel: each chip multiplies its own rows against all experts,
+  gathered in the compute dtype, their gradients reduce-scattered in
+  ``reduce_dtype``.
 
 SwiGLU experts (``glu_mlp`` in the reference): w_gate/w_up fused as one
 ``[E, h, 2*ff]`` tensor, w_down ``[E, ff, h]``.
@@ -250,99 +256,339 @@ def moe_dropped(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloa
     return y.astype(x.dtype), (probs, idx, logits)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def _gather_experts(w: jax.Array, axis: str, compute_dtype) -> jax.Array:
-    """All-gather expert-major weights over the manual mesh axis ``axis``, in
-    ``compute_dtype``.
+def _expert_rows(x, probs, order, group_sizes, gu_w, down_w, *, k: int,
+                 count=None):
+    """The sorted (token, choice) rows ``order`` of ``x`` through their
+    experts (``lax.ragged_dot`` over the groups ``group_sizes`` of ``gu_w`` /
+    ``down_w``), weighted by their gate and scatter-added onto ``x``'s tokens.
+    Returns ``(y like x, (gu, ys))``: the pre-activations and the expert
+    outputs per row, what ``_expert_rows_back`` needs kept.
 
-    The transpose is the cross-shard sum of the partial weight gradients,
-    each shard's from its own rows.  Before the block was per-shard that sum
-    happened inside one kernel's float32 accumulator, so it is carried in the
-    dtype ``w`` arrives in (``_dropless_on_mesh`` hands it over in
-    ``reduce_dtype``) and reduce-scattered straight back to the resident
-    layout."""
-    return jax.lax.all_gather(w.astype(compute_dtype), axis, axis=0, tiled=True)
-
-
-def _gather_experts_fwd(w, axis, compute_dtype):
-    # the residual is a zero-size carrier of w's dtype
-    return _gather_experts(w, axis, compute_dtype), jnp.zeros((0,), w.dtype)
-
-
-def _gather_experts_bwd(axis, compute_dtype, res, ct):
-    return (jax.lax.psum_scatter(
-        ct.astype(res.dtype), axis, scatter_dimension=0, tiled=True),)
-
-
-_gather_experts.defvjp(_gather_experts_fwd, _gather_experts_bwd)
+    ``count``: where ``order`` can hold more rows than the groups do, the
+    number they hold.  XLA's TPU ``ragged-dot`` skips the rows past
+    ``sum(group_sizes)``, reads and leaves them unwritten (XLA:CPU writes
+    zeros), so they are zeroed going in and coming out."""
+    head = functools.partial(_head_rows, count=count)
+    # inner scopes of "moe": telemetry.spans.DEVICE_SCOPES
+    with jax.named_scope("dispatch"):
+        token_of = order // k  # token index per sorted row
+        xs = head(x[token_of])  # [rows, h] gathered rows
+    with jax.named_scope("experts"):
+        gu = jax.lax.ragged_dot(xs, gu_w, group_sizes)
+        ys = head(jax.lax.ragged_dot(_swiglu(gu), down_w, group_sizes))  # [rows, h]
+    with jax.named_scope("combine"):
+        w = probs.reshape(-1)[order].astype(x.dtype)  # gate weight per row
+        return jnp.zeros_like(x).at[token_of].add(ys * w[:, None]), (gu, ys)
 
 
-def _gather_experts_on_mesh(w, axis: str, compute_dtype, spec: P):
-    """``_gather_experts`` where ``axis`` is manual and ``spec`` lays ``w``
-    out over the axes that are still automatic (the ffn dim over ``model``).
-    The partitioner has no rule for a collective whose operand is sharded
-    over an automatic axis: it would replicate the weights over ``model``
-    around the gather, and their gradients around the reduce-scatter.  So the
-    two collectives run in a nested region that is manual over ``model`` too,
-    each tp rank gathering the ffn slice it owns."""
+def _swiglu(gu: jax.Array) -> jax.Array:
+    gate, up = jnp.split(gu, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+def _head_rows(a: jax.Array, count) -> jax.Array:
+    """``a`` with the rows from ``count`` on zeroed (``None``: all of it)."""
+    if count is None:
+        return a
+    return jnp.where((jnp.arange(a.shape[0]) < count)[:, None], a, 0)
+
+
+#: ``lax.ragged_dot``'s transpose for the grouped operand: rows contracted
+#: group by group, ``[rows, a] x [rows, b] -> [groups, a, b]``
+_WEIGHT_GRAD = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=(0,), rhs_group_dimensions=())
+
+
+def _expert_rows_back(ct, kept, x, probs, order, group_sizes, gu_w, down_w, *,
+                      k: int, count=None, grad_dtype):
+    """The cotangents of ``_expert_rows`` for ``x``, ``probs``, ``gu_w`` and
+    ``down_w`` from ``ct``, that of ``y``, and ``kept``; the weights' come
+    straight out of the kernel's float32 accumulator in ``grad_dtype``.
+    Written out, where autodiff would do: so that ``kept`` is all a caller
+    has to hold between the passes, whatever branch it took."""
+    gu, ys = kept
+    head = functools.partial(_head_rows, count=count)
+    with jax.named_scope("combine"):
+        token_of = order // k
+        ct_rows = ct[token_of]
+        w = probs.reshape(-1)[order].astype(x.dtype)
+        d_w = jnp.sum(ct_rows * ys, axis=-1, dtype=probs.dtype)
+        d_probs = jnp.zeros(probs.size, probs.dtype).at[order].add(d_w).reshape(probs.shape)
+        d_ys = head(ct_rows * w[:, None])
+    with jax.named_scope("experts"):
+        act, act_back = jax.vjp(_swiglu, gu)
+        d_down = jax.lax.ragged_dot_general(
+            act, d_ys, group_sizes, _WEIGHT_GRAD, preferred_element_type=grad_dtype)
+        (d_gu,) = act_back(jax.lax.ragged_dot(
+            d_ys, down_w.swapaxes(1, 2), group_sizes))
+        d_gate_up = jax.lax.ragged_dot_general(
+            head(x[token_of]), d_gu, group_sizes, _WEIGHT_GRAD,
+            preferred_element_type=grad_dtype)
+        d_xs = head(jax.lax.ragged_dot(d_gu, gu_w.swapaxes(1, 2), group_sizes))
+    with jax.named_scope("dispatch"):
+        return jnp.zeros_like(x).at[token_of].add(d_xs), d_probs, d_gate_up, d_down
+
+
+def _sorted_rows(expert_of: jax.Array, groups: int, rows: int):
+    """``(order [rows], group_sizes [groups])`` of the (token, choice) rows
+    ``expert_of`` (the group each chose; ``groups`` = none of them): a stable
+    sort by group, cut to the first ``rows``."""
+    with jax.named_scope("dispatch"):
+        return (jnp.argsort(expert_of)[:rows],
+                jnp.bincount(expert_of, length=groups + 1)[:groups])
+
+
+#: the expert rows a chip multiplies when the rows travel to the experts, as
+#: a multiple of its fair share ``T * k``.  Past it the experts' weights
+#: travel instead (``_exchange_experts``).  XLA's TPU ``ragged-dot`` skips the
+#: rows past ``sum(group_sizes)``, but the gathers, the elementwise passes
+#: and the scatter around it do not: at Mixtral's widths the expert MLP,
+#: forward and backward, on 8 192 rows in 2 groups costs 1.4 % more in an
+#: operand of 1.25 x the rows, 7.7 % more in one of 2 x, 21 % more in one of
+#: 4 x (one v5e; PERF.md section 6, PR 28).  And a chip that receives ``s``
+#: times its share works ``s`` times as long while its peers wait: by 2 x the
+#: weights' journey (about 90 ms of a 310 ms step at ep 4) is the cheaper.
+_EXCHANGE_ROWS = 2.0
+
+
+def _on_auto_axes(f, spec: P):
+    """``f``, a collective over a manual mesh axis, applied to an array that
+    ``spec`` lays out over axes still automatic (an expert weight's ffn dim
+    over ``model``).  The partitioner has no rule for a collective whose
+    operand is sharded over an automatic axis: it would replicate the
+    operand over ``model`` around it.  So ``f`` runs in a nested region that
+    is manual over those axes too, each tp rank moving the ffn slice it
+    owns."""
     auto = frozenset(a for a in jax.tree_util.tree_leaves(tuple(spec)) if a)
-    return shd.shard_map(
-        functools.partial(_gather_experts, axis=axis, compute_dtype=compute_dtype),
-        mesh=jax.sharding.get_abstract_mesh(), in_specs=spec, out_specs=spec,
-        axis_names=auto, check_vma=False,
-    )(w)
+    return shd.shard_map(f, mesh=jax.sharding.get_abstract_mesh(), in_specs=spec,
+                         out_specs=spec, axis_names=auto, check_vma=False)
+
+
+_GATE_UP_SPEC, _DOWN_SPEC = P(None, None, "model"), P(None, "model", None)
+
+
+def _peers_rows(a: jax.Array, axis: str) -> jax.Array:
+    """``[T, ...]`` -> ``[ep * T, ...]``: the rows of every peer over the
+    manual mesh axis ``axis``, peer-major."""
+    return jax.lax.all_gather(a, axis, axis=0, tiled=True)
+
+
+def _home_sum(a: jax.Array, axis: str) -> jax.Array:
+    """``[ep * T, ...]`` -> ``[T, ...]``: block ``j`` of the rows goes to peer
+    ``j``, which sums what arrives in float32.  ``_peers_rows``'s transpose;
+    not the reduce-scatter JAX would make of it, which in the rows' own 16
+    bits aborts XLA:CPU inside a partly automatic region (the reducer carries
+    a sharding there, and ``AllReducePromotion`` cannot clone it).  Four v5e,
+    one [4096, 4096] bf16 shard a chip (PERF.md section 6, PR 28): all-gather
+    1.65 ms, reduce-scatter 1.73 ms in bf16 and 3.90 in float32, this 1.86."""
+    ep = jax.lax.axis_size(axis)
+    parts = jax.lax.all_to_all(a.reshape((ep, -1) + a.shape[1:]), axis, 0, 0)
+    return jnp.sum(parts, axis=0, dtype=jnp.float32).astype(a.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _exchange_experts(experts, x, probs, chosen, rows_travel, cfg: MoEConfig,
+                      bound: int, expert_axis: str, compute_dtype, reduce_dtype):
+    """The dropless block's routed half for one token shard of ``ep`` over
+    the manual mesh axis ``expert_axis``, each holding ``E / ep`` resident
+    experts (``experts``: their weights, as this chip holds them; ``chosen``
+    ``[ep * T * k]``: the expert of every (token, choice) row of every peer).
+
+    ``rows_travel`` (the same on every chip): every chip's (token, expert)
+    rows go to the chips that hold their experts.  The shards of all peers
+    are all-gathered, a chip multiplies the rows that chose its residents (at
+    most ``bound``, static) and the gate-weighted outputs are summed back to
+    each token's home chip (``_home_sum``).  The weights are read where they
+    live and their gradients made there, summed over all rows inside one
+    kernel's float32 accumulator: no collective carries either.
+
+    Else the weights travel (a chip that received most of the rows would do
+    most of the work, its peers waiting): each chip multiplies its own rows
+    against all ``E`` experts, gathered over ``expert_axis`` in
+    ``compute_dtype``, and the partial weight gradients, float32 straight
+    from the kernel, are reduce-scattered back in ``reduce_dtype``.
+
+    Both ways keep ``(gu, ys)`` of ``bound`` rows and the weights as
+    multiplied between the passes, in the same shapes, so a step holds room
+    for one of them.  ``experts`` may arrive in any float dtype (the master's:
+    ``models/mixtral.py``); their gradients leave in it."""
+    return _exchange_pass(0, rows_travel, cfg, bound, expert_axis, compute_dtype,
+                          reduce_dtype, experts, x, probs, chosen)[0]
+
+
+def _exchange_sides(cfg: MoEConfig, bound: int, axis: str, compute_dtype,
+                    reduce_dtype, t: int, both: bool):
+    """``((forward, backward) where the rows travel, (forward, backward)
+    where the weights do)`` of ``_exchange_experts``: forward
+    ``(experts, x, probs, chosen) -> (y, kept)``, backward ``(ct, kept,
+    experts, x, probs, chosen) -> (d_experts, d_x, d_probs)``; with ``both``
+    in use, ``kept`` is of one structure and shapes on both sides."""
+    e, k = cfg.num_experts, cfg.top_k
+    ep = jax.lax.axis_size(axis)
+    e_local = e // ep
+
+    def cast(experts):
+        return (shd.constrain(experts["gate_up"].astype(compute_dtype), _GATE_UP_SPEC),
+                shd.constrain(experts["down"].astype(compute_dtype), _DOWN_SPEC))
+
+    def gathered(x, probs, chosen):
+        """Every peer's tokens, and their rows sorted by this chip's
+        residents (every other chip's sort last)."""
+        with jax.named_scope("dispatch"):
+            x, probs = (_peers_rows(a, axis) for a in (x.astype(compute_dtype), probs))
+            chosen = chosen - jax.lax.axis_index(axis) * e_local
+            chosen = jnp.where((chosen >= 0) & (chosen < e_local), chosen, e_local)
+        order, group_sizes = _sorted_rows(chosen, e_local, bound)
+        return x, probs, order, group_sizes, jnp.sum(group_sizes)
+
+    def rows_forward(experts, x, probs, chosen):
+        gu_w, down_w = cast(experts)
+        x, probs, order, group_sizes, count = gathered(x, probs, chosen)
+        y, kept = _expert_rows(x, probs, order, group_sizes, gu_w, down_w,
+                               k=k, count=count)
+        with jax.named_scope("combine"):
+            # the weights as multiplied; with both sides, at the head of the
+            # other's gathered ones
+            rest = ((0, e - e_local if both else 0), (0, 0), (0, 0))
+            return _home_sum(y, axis), kept + tuple(
+                jnp.pad(w, rest) for w in (gu_w, down_w))
+
+    def rows_backward(ct, kept, experts, x, probs, chosen):
+        gu_w, down_w = (w[:e_local] for w in kept[2:])
+        x_all, probs_all, order, group_sizes, count = gathered(x, probs, chosen)
+        with jax.named_scope("combine"):
+            ct = _peers_rows(ct, axis)
+        d_x, d_probs, d_gu, d_down = _expert_rows_back(
+            ct, kept[:2], x_all, probs_all, order, group_sizes, gu_w, down_w,
+            k=k, count=count, grad_dtype=experts["gate_up"].dtype)
+        with jax.named_scope("dispatch"):
+            return ({"gate_up": d_gu, "down": d_down},
+                    _home_sum(d_x, axis).astype(x.dtype), _home_sum(d_probs, axis))
+
+    def own(x, chosen):
+        """This chip's tokens, and their rows sorted by expert."""
+        with jax.named_scope("dispatch"):
+            chosen = jax.lax.dynamic_slice_in_dim(
+                chosen, jax.lax.axis_index(axis) * t * k, t * k)
+        order, group_sizes = _sorted_rows(chosen, e, t * k)
+        return x.astype(compute_dtype), order, group_sizes
+
+    def weights_forward(experts, x, probs, chosen):
+        with jax.named_scope("experts"):
+            gu_w, down_w = (
+                _on_auto_axes(functools.partial(_peers_rows, axis=axis), spec)(w)
+                for w, spec in zip(cast(experts), (_GATE_UP_SPEC, _DOWN_SPEC)))
+        xc, order, group_sizes = own(x, chosen)
+        y, kept = _expert_rows(xc, probs, order, group_sizes, gu_w, down_w, k=k)
+        return y, (*(jnp.pad(a, ((0, bound - t * k), (0, 0))) for a in kept),
+                   gu_w, down_w)
+
+    def weights_backward(ct, kept, experts, x, probs, chosen):
+        xc, order, group_sizes = own(x, chosen)
+        d_x, d_probs, *d_weights = _expert_rows_back(
+            ct, [a[:t * k] for a in kept[:2]], xc, probs, order, group_sizes,
+            *kept[2:], k=k, grad_dtype=reduce_dtype)
+        with jax.named_scope("experts"):
+            d_gu, d_down = (
+                _on_auto_axes(lambda g: jax.lax.psum_scatter(
+                    g, axis, scatter_dimension=0, tiled=True), spec)(g).astype(w.dtype)
+                for g, spec, w in zip(d_weights, (_GATE_UP_SPEC, _DOWN_SPEC),
+                                      (experts["gate_up"], experts["down"])))
+        return {"gate_up": d_gu, "down": d_down}, d_x.astype(x.dtype), d_probs
+
+    return (rows_forward, rows_backward), (weights_forward, weights_backward)
+
+
+def _exchange_pass(back: int, rows_travel, cfg, bound, expert_axis, compute_dtype,
+                   reduce_dtype, *operands):
+    """The forward (0) or backward (1) pass of ``_exchange_experts``, the way
+    ``rows_travel`` says; ``None``: the bound holds every case."""
+    x = operands[-3]  # (..., x, probs, chosen) on either pass
+    rows, weights = _exchange_sides(cfg, bound, expert_axis, compute_dtype,
+                                    reduce_dtype, x.shape[0], rows_travel is not None)
+    if rows_travel is None:
+        return rows[back](*operands)
+    return jax.lax.cond(rows_travel, rows[back], weights[back], *operands)
+
+
+def _exchange_fwd(experts, x, probs, chosen, rows_travel, *static):
+    y, kept = _exchange_pass(0, rows_travel, *static, experts, x, probs, chosen)
+    return y, (kept, experts, x, probs, chosen, rows_travel)
+
+
+def _exchange_bwd(*args):
+    *static, (kept, *operands, rows_travel), ct = args
+    return (*_exchange_pass(1, rows_travel, *static, ct, kept, *operands), None, None)
+
+
+_exchange_experts.defvjp(_exchange_fwd, _exchange_bwd)
 
 
 def _dropless_experts(experts, x: jax.Array, probs: jax.Array, idx: jax.Array,
-                      cfg: MoEConfig, *, compute_dtype,
-                      expert_axis: Optional[str] = None) -> jax.Array:
+                      cfg: MoEConfig, *, compute_dtype, reduce_dtype=jnp.float32,
+                      expert_axis: Optional[str] = None,
+                      region_axes: tuple[str, ...] = ()):
     """The routed half of the dropless block: sort rows by expert, grouped
     matmul via ``lax.ragged_dot``, weighted scatter-add back.
 
-    x [T, h], probs / idx [T, k] -> y [T, h] in ``compute_dtype``.  A function
-    of one token set: given its routing, a row's output depends on the row,
-    its experts' weights and its gate weights only, so ``_dropless_on_mesh``
-    runs it once per token shard, with ``expert_axis`` the manual mesh axis
-    the expert weights arrive sharded over.
+    x [T, h], probs / idx [T, k] -> (y [T, h] in ``compute_dtype``, stats).
+    A function of one token set: given its routing, a row's output depends on
+    the row, its experts' weights and its gate weights only, so
+    ``_dropless_on_mesh`` runs it once per token shard.
+
+    With ``expert_axis`` (a manual mesh axis that shards both these tokens
+    and the expert dim of ``experts``; ``region_axes``: every manual axis of
+    the region) it is ``_exchange_experts``: the rows travel to the chips
+    that hold their experts while no chip of the region would receive more
+    than ``_EXCHANGE_ROWS`` times its fair share ``T * k``, and past that the
+    weights travel.  The count is of the data, max-reduced over the region
+    so that every chip goes the same way.  ``stats``:
+    ``moe/recv_rows_share_max``, that largest count over the fair share,
+    and, where both ways exist, ``moe/row_bound``: 0 where the rows
+    travelled, 1 past the bound; empty without ``expert_axis``.
     """
     t, h = x.shape
     e, k = cfg.num_experts, cfg.top_k
-    # inner scopes of "moe": telemetry.spans.DEVICE_SCOPES
+    if expert_axis is None:
+        # XLA's SPMD partitioner has no rule for ragged_dot's GROUP dimension:
+        # with the expert dim sharded it computes each shard's local expert
+        # slice against the GLOBAL group offsets — silently wrong values, no
+        # error (full-signal corruption on any mesh where the expert axis is
+        # strided, e.g. EP x TP; verified empirically on jax 0.4.x).  So the
+        # kernel only ever sees a whole expert dim: here all of them,
+        # gathered over 'expert' by the constraint (the unpartitioned path a
+        # batch the token axes do not divide takes); under ``expert_axis``
+        # a chip's residents or a gathered copy, inside the manual region.
+        # The ffn dim's 'model' sharding, which ragged_dot partitions
+        # correctly, is preserved.
+        # Sharded-vs-unsharded parity: tests/test_moe.py, tests/test_mixtral.py.
+        gu_w = shd.constrain(experts["gate_up"].astype(compute_dtype), _GATE_UP_SPEC)
+        down_w = shd.constrain(experts["down"].astype(compute_dtype), _DOWN_SPEC)
+        order, group_sizes = _sorted_rows(idx.reshape(-1), e, t * k)
+        return _expert_rows(x.astype(compute_dtype), probs, order, group_sizes,
+                            gu_w, down_w, k=k)[0], {}
+
+    ep = jax.lax.axis_size(expert_axis)
+    e_local = e // ep
+    worst = ep * t * min(k, e_local)  # every peer's every token on this chip
+    bound = min(math.ceil(_EXCHANGE_ROWS * t * k), worst)
     with jax.named_scope("dispatch"):
-        flat_expert = idx.reshape(-1)  # [T*k]
-        order = jnp.argsort(flat_expert)  # stable sort by expert
-        token_of = order // k  # original token index per sorted row
-        xs = x.astype(compute_dtype)[token_of]  # [T*k, h] gathered rows
-        group_sizes = jnp.bincount(flat_expert, length=e)
-
-    # XLA's SPMD partitioner has no rule for ragged_dot's GROUP dimension:
-    # with the expert dim sharded it computes each shard's local expert
-    # slice against the GLOBAL group offsets — silently wrong values, no
-    # error (full-signal corruption on any mesh where the expert axis is
-    # strided, e.g. EP x TP; verified empirically on jax 0.4.x).  So the
-    # compute sees every expert — weight-gather EP: the resident weights and
-    # optimizer state stay sharded per moe_param_specs, gathered over
-    # 'expert' once per layer (by hand where the axis is manual, by the
-    # constraint where it is not), and the ffn dim's 'model' sharding (which
-    # ragged_dot partitions correctly) is preserved.
-    # Sharded-vs-unsharded parity: tests/test_moe.py, tests/test_mixtral.py.
-    with jax.named_scope("experts"):
-        gu_w, down_w = experts["gate_up"], experts["down"]
-        gu_spec, down_spec = P(None, None, "model"), P(None, "model", None)
-        if expert_axis is not None:
-            gu_w = _gather_experts_on_mesh(gu_w, expert_axis, compute_dtype, gu_spec)
-            down_w = _gather_experts_on_mesh(down_w, expert_axis, compute_dtype, down_spec)
-        gu_w = shd.constrain(gu_w.astype(compute_dtype), gu_spec)
-        down_w = shd.constrain(down_w.astype(compute_dtype), down_spec)
-
-        gu = jax.lax.ragged_dot(xs, gu_w, group_sizes)
-        gate, up = jnp.split(gu, 2, axis=-1)
-        act = jax.nn.silu(gate) * up
-        ys = jax.lax.ragged_dot(act, down_w, group_sizes)  # [T*k, h]
-
-    with jax.named_scope("combine"):
-        w = probs.reshape(-1)[order].astype(compute_dtype)  # gate weight per row
-        return jnp.zeros((t, h), compute_dtype).at[token_of].add(ys * w[:, None])
+        chosen = _peers_rows(idx.reshape(-1), expert_axis)
+        received = jax.lax.pmax(jnp.sum(
+            chosen // e_local == jax.lax.axis_index(expert_axis)), region_axes)
+    stats = {"moe/recv_rows_share_max": received / (t * k)}
+    rows_travel = None
+    if bound < worst:
+        rows_travel = received <= bound
+        stats["moe/row_bound"] = 1 - rows_travel
+    facts = shd.trace_facts()
+    if facts is not None:
+        facts["moe_expert_exchange"] = "tokens"
+        facts["moe_row_bounds"] = [bound]
+    y = _exchange_experts(experts, x, probs, chosen, rows_travel, cfg, bound,
+                          expert_axis, compute_dtype, reduce_dtype)
+    return y, jax.tree_util.tree_map(lambda v: v.astype(jnp.float32), stats)
 
 
 def moe_dropless(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat16):
@@ -355,8 +601,8 @@ def moe_dropless(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bflo
     """
     with jax.named_scope("router"):
         probs, idx, logits = route(params["router"], x, cfg)
-    y = _dropless_experts(params["experts"], x, probs, idx, cfg,
-                          compute_dtype=compute_dtype)
+    y, _ = _dropless_experts(params["experts"], x, probs, idx, cfg,
+                             compute_dtype=compute_dtype)
     return y.astype(x.dtype), (probs, idx, logits)
 
 
@@ -393,15 +639,18 @@ def _dropless_on_mesh(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype,
     runs per shard inside a ``shard_map`` that is manual over the axes
     sharding the tokens (read from the mesh and ``act_spec``) and automatic
     over the rest, as ``ops.attention._flash_on_mesh`` does for the flash
-    kernel.  With no mesh, or no such axis, it is called directly.
-    Returns ``(y [b, s, h], expert_idx [b*s, k], router_logits [b*s, E])``.
+    kernel.  Where ``expert`` is one of those axes the expert weights enter
+    the region as they are resident, ``E / ep`` a chip, and rows or weights
+    cross it inside (``_exchange_experts``).  With no mesh, or no such axis,
+    it is called directly.  Returns ``(y [b, s, h], expert_idx [b*s, k], router_logits
+    [b*s, E], stats)``, ``stats`` the scalars of ``_dropless_experts``.
     """
     b, s, h = x.shape
     mesh, outer_manual = shd.region_mesh()
     act_spec = shd.act_spec() if act_spec is None else act_spec
     batch_axes, seq_axes = ((), ()) if mesh is None else _token_axes(
         mesh, act_spec, b, s, outer_manual)
-    manual = frozenset(batch_axes + seq_axes)
+    manual = batch_axes + seq_axes
     shards = math.prod(mesh.shape[a] for a in manual)
     facts = shd.trace_facts()
     if facts is not None:  # 1 anywhere = some block multiplies every row
@@ -416,32 +665,33 @@ def _dropless_on_mesh(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype,
     with jax.named_scope("router"):
         probs, idx, logits = route(params["router"], flat, cfg)
     if not manual:
-        y = _dropless_experts(params["experts"], flat, probs, idx, cfg,
-                              compute_dtype=compute_dtype)
-        return y.reshape(b, s, h).astype(x.dtype), idx, logits
+        y, stats = _dropless_experts(params["experts"], flat, probs, idx, cfg,
+                                     compute_dtype=compute_dtype)
+        return y.reshape(b, s, h).astype(x.dtype), idx, logits, stats
 
     expert_axis = "expert" if "expert" in manual else None
 
     def body(experts, x, probs, idx):
         bl, sl, _ = x.shape
-        y = _dropless_experts(
+        y, stats = _dropless_experts(
             experts, x.reshape(bl * sl, h), probs.reshape(bl * sl, -1),
             idx.reshape(bl * sl, -1), cfg, compute_dtype=compute_dtype,
-            expert_axis=expert_axis)
-        return y.reshape(bl, sl, h)
+            reduce_dtype=reduce_dtype, expert_axis=expert_axis, region_axes=manual)
+        return y.reshape(bl, sl, h), stats
 
-    # the cotangent of an input is summed over the manual axes it is
-    # replicated over (and, by _gather_experts, over ``expert``) in the
-    # input's own dtype: hand the weights over in reduce_dtype
-    experts = jax.tree_util.tree_map(
-        lambda w: w.astype(reduce_dtype), params["experts"])
-    y = shd.shard_map(
+    experts = params["experts"]
+    if any(a != expert_axis for a in manual):
+        # the cotangent of an input is summed over the manual axes it is
+        # replicated over (every one but ``expert``) in the input's own
+        # dtype: hand the weights over in reduce_dtype
+        experts = jax.tree_util.tree_map(lambda w: w.astype(reduce_dtype), experts)
+    y, stats = shd.shard_map(
         body, mesh=mesh,
         in_specs=({"gate_up": P(expert_axis), "down": P(expert_axis)},
                   tokens, tokens, tokens),
-        out_specs=tokens, axis_names=manual, check_vma=False,
+        out_specs=(tokens, P()), axis_names=frozenset(manual), check_vma=False,
     )(experts, x, probs.reshape(b, s, -1), idx.reshape(b, s, -1))
-    return y.astype(x.dtype), idx, logits
+    return y.astype(x.dtype), idx, logits, stats
 
 
 def _shuffle_permutation(t: int, group: int) -> jnp.ndarray:
@@ -464,19 +714,24 @@ def _shuffle_permutation(t: int, group: int) -> jnp.ndarray:
 
 def moe_block(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat16,
               reduce_dtype=jnp.float32, act_spec: Optional[P] = None):
-    """[b, s, h] wrapper dispatching dropped/dropless; returns (y, router_logits).
+    """[b, s, h] wrapper dispatching dropped/dropless; returns ``(y, aux)``,
+    ``aux`` the ``router_logits``, the ``expert_idx`` and ``stats``, per-step
+    scalars of the block under their metric names (``_dropless_experts``).
 
     ``act_spec`` is the block-boundary spec ``x`` is laid out by (default
     ``shd.act_spec()``: batch over the data axes); the dropless block is
-    partitioned by the token axes it names.  ``reduce_dtype`` carries the
-    cross-shard sum of the expert-weight gradients (``policy.reduce_dtype``)."""
+    partitioned by the token axes it names.  ``reduce_dtype``
+    (``policy.reduce_dtype``) carries the sum of the expert-weight gradients
+    over the token axes that replicate the experts, and over ``expert`` where
+    the weights travelled (``_exchange_experts``); where the rows did, there
+    is none over ``expert``."""
     b, s, h = x.shape
     with jax.named_scope("moe"):
         if cfg.dropless:
-            y, idx, logits = _dropless_on_mesh(
+            y, idx, logits, stats = _dropless_on_mesh(
                 params, x, cfg, compute_dtype=compute_dtype,
                 reduce_dtype=reduce_dtype, act_spec=act_spec)
-            return y, {"router_logits": logits, "expert_idx": idx}
+            return y, {"router_logits": logits, "expert_idx": idx, "stats": stats}
         flat = x.reshape(b * s, h)
         shuffle = (cfg.token_shuffle_group_size or 0) > 1
         if shuffle:
@@ -487,4 +742,5 @@ def moe_block(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat1
         y, (probs, idx, logits) = moe_dropped(params, flat, cfg, compute_dtype=compute_dtype)
         if shuffle:
             y, idx, logits = y[inv], idx[inv], logits[inv]
-        return y.reshape(b, s, h), {"router_logits": logits, "expert_idx": idx}
+        return y.reshape(b, s, h), {"router_logits": logits, "expert_idx": idx,
+                                    "stats": {}}

@@ -128,7 +128,10 @@ class TestShardedGPT:
         sh_batch = jax.device_put(batch, ns(P(("data", "expert"))))
         with mesh, shd.use_mesh(mesh), shd.collect_trace_facts() as traced:
             loss, grads = jax.jit(jax.value_and_grad(loss_fn))(sh_params, sh_batch)
-        assert traced == {"moe_token_shards": 8}
+        # dp2 x ep4, 4 experts, one row of 16 tokens a chip, one choice each:
+        # rows travel up to twice the fair 16 (a chip could receive 64)
+        assert traced == {"moe_token_shards": 8, "moe_expert_exchange": "tokens",
+                          "moe_row_bounds": [32]}
         np.testing.assert_allclose(float(loss), float(ref_loss), rtol=2e-5)
         for a, b in zip(jax.tree_util.tree_leaves(ref_grads),
                         jax.tree_util.tree_leaves(grads)):

@@ -237,21 +237,34 @@ class TestProvenance:
     @pytest.mark.parametrize("dropless", [True, False],
                              ids=["dropless", "dropped"])
     def test_moe_source_classes(self, dropless):
-        """Dropless MoE under EP declares the expert weights' gather and
-        their gradients' reduce-scatter over 'expert' (by their source ops,
-        ahead of the embedding and ZeRO-1 classes) and nothing on the token
-        path; the capacity-factor path keeps its global routing gather."""
+        """Dropless MoE under EP declares the expert exchange over 'expert':
+        the rows' transfers (all-gathers and all-to-alls, by their source ops
+        under moe/dispatch and moe/combine) and, for the branch past the row
+        bound, the expert weights' gather and their gradients' reduce-scatter,
+        ahead of the embedding and ZeRO-1 classes; the capacity-factor path
+        keeps its global routing gather."""
         d = DeclaredComms(tp=2, pp=1, cp=1, ep=2, dp=2, zero1=True,
                           seq_par=True, moe=True, ulysses=False, ring=False,
                           moe_dropless=dropless)
         rules = gc.declared_source_classes(d)
         scope = "jit(train_step)/jvp()/while/body/closed_call/moe/shard_map"
+        back = ("jit(train_step)/transpose(jvp())/while/body/closed_call/"
+                "checkpoint/moe/shard_map")
         gather = gc.attribute(
             "all-gather", ("expert",),
-            [f"{scope}/experts/shard_map/all_gather"], rules)[0]
+            [f"{scope}/dispatch/all_gather", f"{back}/combine/all_gather"],
+            rules)[0]
+        weights = gc.attribute(
+            "all-gather", ("expert",),
+            [f"{scope}/cond/branch_1_fun/experts/shard_map/all_gather"], rules)[0]
+        returned = gc.attribute(
+            "all-to-all", ("expert",),
+            [f"{scope}/combine/all_to_all", f"{back}/dispatch/all_to_all"],
+            rules)[0]
         scatter = gc.attribute(
             "reduce-scatter", ("expert",),
-            [f"{scope}/experts/shard_map/reduce_scatter"], rules)[0]
+            [f"{back}/cond/branch_1_fun/experts/shard_map/reduce_scatter"],
+            rules)[0]
         sort = gc.attribute(
             "all-gather", ("data", "expert", "model"),
             ["jit(train_step)/jvp()/moe/dispatch/jit(argsort)/sort"], rules)
@@ -260,12 +273,14 @@ class TestProvenance:
             ["jit(train_step)/jvp()/moe/router/top_k"], rules)[0]
         if dropless:
             assert top_k == "MoE router top-k gather"
-            assert gather == "ep expert weight gather"
+            assert gather == weights == "ep exchange all-gather"
+            assert returned == "ep exchange all-to-all"
             assert scatter == "ep expert gradient reduce-scatter"
-            # a gather of the global token list is no declared cost any more
+            # a gather of the global token list is no declared cost
             assert sort is None
         else:
-            assert gather != "ep expert weight gather"
+            assert gather != "ep exchange all-gather"
+            assert returned == "ep token all-to-all"  # the dropped path's own
             assert "ZeRO-1" in scatter
             assert sort[0] == top_k == "MoE dropped routing gather"
         # without the block's own source op the classes do not over-claim

@@ -84,35 +84,37 @@ class TestMixtralForward:
 
 
 class TestMixtralSharded:
-    #: 8 devices each: MeshConfig fields -> token shards of the expert block
+    #: 8 devices each: MeshConfig fields -> (token shards of the expert
+    #: block, its static row bound: 4 experts, 2 a token, 8 rows of 16 tokens;
+    #: twice a chip's fair share is every row there can be, on all three)
     MESHES = {
         "ep2_tp2_dp2": (dict(tensor_model_parallel_size=2,
-                             expert_model_parallel_size=2), 4),
-        "ep4_dp2": (dict(expert_model_parallel_size=4), 8),
+                             expert_model_parallel_size=2), 4, [128]),
+        "ep4_dp2": (dict(expert_model_parallel_size=4), 8, [64]),
         "ep2_tp2_sp": (dict(tensor_model_parallel_size=2,
                             expert_model_parallel_size=2,
-                            sequence_parallel=True), 4),
+                            sequence_parallel=True), 4, [128]),
     }
 
     @pytest.mark.parametrize("name", list(MESHES))
     def test_ep_tp_parity(self, devices8, name):
         """Sharded loss and every gradient match unsharded, on meshes that
         combine EP with TP, SP and DP; the expert block runs once per token
-        shard (``moe_token_shards``).
+        shard (``moe_token_shards``), its rows sent to the chips that hold
+        their experts (``moe_expert_exchange``).
 
         Regression pin for the ragged_dot EP hazard: XLA's SPMD partitioner
         has no rule for ragged_dot's GROUP dimension — with the expert dim
         sharded on a strided mesh axis (any EP x TP mesh) it computed each
         shard's local expert slice against the GLOBAL group offsets,
         silently corrupting forward AND backward (loss off ~7e-5, grads off
-        ~100% of signal, no error raised).  ``moe_dropless`` sees the expert
-        weights gathered over 'expert' (weight-gather EP; resident weights/opt
-        state stay sharded), which restores bit-level SPMD parity — so the
-        tolerances here are tight: a reappearance of the partitioner hole
-        fails loudly."""
+        ~100% of signal, no error raised).  The kernel sees only a chip's
+        resident experts, inside the region that is manual over ``expert``,
+        which restores bit-level SPMD parity — so the tolerances here are
+        tight: a reappearance of the partitioner hole fails loudly."""
         import dataclasses
 
-        fields, shards = self.MESHES[name]
+        fields, shards, bounds = self.MESHES[name]
         cfg = dataclasses.replace(CFG, llama=dataclasses.replace(
             CFG.llama, sequence_parallel=fields.get("sequence_parallel", False)))
         params = mixtral.init_params(jax.random.PRNGKey(0), cfg, FP32)
@@ -132,7 +134,8 @@ class TestMixtralSharded:
         sh_batch = jax.device_put(batch, ns(P(("data", "expert"))))
         with mesh, shd.use_mesh(mesh), shd.collect_trace_facts() as traced:
             loss, grads = jax.jit(jax.value_and_grad(loss_fn))(sh_params, sh_batch)
-        assert traced == {"moe_token_shards": shards}
+        assert traced == {"moe_token_shards": shards,
+                          "moe_expert_exchange": "tokens", "moe_row_bounds": bounds}
         np.testing.assert_allclose(float(loss), float(ref_loss), rtol=2e-5)
         ref_leaves, treedef = jax.tree_util.tree_flatten_with_path(ref_grads)
         for (path, rg), g in zip(ref_leaves, treedef.flatten_up_to(grads)):
@@ -233,3 +236,72 @@ class TestMoEFrequency:
                                   llama=dataclasses.replace(CFG.llama, num_layers=3))
         with pytest.raises(ValueError, match="frequency"):
             mixtral.init_params(jax.random.PRNGKey(0), cfg, FP32)
+
+
+@pytest.mark.parametrize("ep", [2, 4])
+def test_what_crosses_the_expert_axis_in_the_example_step(devices8, ep):
+    """The Mixtral example's compiled step (shrunk to 8 devices, ZeRO-1), by
+    its collectives over ``expert``.  At ep 2 (x data 2 x model 2) twice a
+    chip's fair share of rows is all it can receive: the rows travel
+    (all-gathers and all-to-alls under ``moe/dispatch`` and ``moe/combine``)
+    and nothing over that axis has an expert weight's shape, whole or split
+    over ``model``: no gather of the weights, no reduce-scatter of their
+    gradients.  At ep 4 (x model 2) a chip can receive more, and the step
+    holds the weights' way as well: there, and only there (a branch, under
+    ``experts``), each expert weight is gathered in bf16 and its gradient
+    reduce-scattered in float32.  (ZeRO-1 regathers every updated parameter
+    over ``data``: not over ``expert``.)"""
+    import os
+    import re
+
+    from neuronx_distributed_training_tpu.analysis import graph_contract as gc
+    from neuronx_distributed_training_tpu.analysis.graph_audit import (
+        lower_step_program,
+        shrink_overrides,
+    )
+    from neuronx_distributed_training_tpu.config.loader import load_config
+    from neuronx_distributed_training_tpu.telemetry.census import (
+        collective_ops_from_texts,
+    )
+    from neuronx_distributed_training_tpu.trainer.loop import assemble_step_program
+
+    source = os.path.join(os.path.dirname(__file__), "..", "examples", "conf",
+                          "hf_mixtral_8x7b_config.yaml")
+    # the shrunk widths but for the ffn's, chosen so that no shape of rows
+    # (64 tokens a chip, 128 or 256 gathered, hidden 64) reads like a weight's
+    cfg = load_config(source, {
+        **shrink_overrides(load_config(source), max_devices=8),
+        "model.intermediate_size": 160, "model.moe.num_experts": 8,
+        "distributed_strategy.expert_model_parallel_size": ep})
+    asm = assemble_step_program(cfg, devices=devices8, build_data=False)
+    _, compiled = lower_step_program(asm)
+    mesh, m = asm.mesh, cfg.model
+    assert mesh.shape["expert"] == ep and mesh.shape["model"] == 2
+    h, ff, tp = m.hidden_size, m.intermediate_size, mesh.shape["model"]
+    weight_tails = {(h, 2 * ff), (h, 2 * ff // tp), (ff, h), (ff // tp, h)}
+    partitions, coords = gc._mesh_partitions(mesh), gc._device_coords(mesh)
+
+    rows, weights = set(), set()
+    for line in compiled.as_text().splitlines():
+        for op in collective_ops_from_texts([line]):
+            axes = gc._axes_of_op(op, mesh, partitions, coords) or ()
+            if "expert" not in axes:
+                continue
+            head = line.partition("metadata=")[0]
+            shapes = [tuple(int(d) for d in dims.split(","))
+                      for dims in re.findall(r"\w+\[([\d,]+)\]", head)]
+            if any(len(s) >= 3 and s[-2:] in weight_tails for s in shapes):
+                # an expert weight crosses: the weights' way, its branch alone
+                assert axes == ("expert",) and re.search(
+                    r"moe/shard_map/cond/branch_\d_fun/experts/", op["source_op"]), line
+                # (XLA:CPU widens the bf16 gather; tests/test_tpu_compile.py
+                # pins its dtype on the chip's compiler)
+                dtype = re.match(r"\s*\S+ = \(?(\w+)\[", head).group(1)
+                assert op["kind"] == "all-gather" or (
+                    op["kind"], dtype) == ("reduce-scatter", "f32"), line
+                weights.add(op["kind"])
+            elif axes == ("expert",) and "moe/shard_map/" in op["source_op"]:
+                assert op["kind"] in ("all-gather", "all-to-all", "all-reduce"), line
+                rows.add(op["kind"])
+    assert {"all-gather", "all-to-all"} <= rows
+    assert weights == (set() if ep == 2 else {"all-gather", "reduce-scatter"})

@@ -1,5 +1,7 @@
 """MoE: routing, dropped vs dropless numerics, aux loss, EP sharding."""
 
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -104,6 +106,30 @@ class TestExpertCompute:
         assert aux["router_logits"].shape == (16, 4)
 
 
+def _unwritten_tail(ragged_dot):
+    """``ragged_dot`` as XLA's TPU kernel leaves its results: the rows past
+    ``sum(group_sizes)`` are skipped and never written (NaN here), in the
+    result and in the operand's cotangent.  XLA:CPU writes zeros there."""
+    def poison(a, group_sizes):
+        return jnp.where((jnp.arange(a.shape[0]) < group_sizes.sum())[:, None], a, jnp.nan)
+
+    @jax.custom_vjp
+    def dot(lhs, rhs, group_sizes):
+        return poison(ragged_dot(lhs, rhs, group_sizes), group_sizes)
+
+    def fwd(lhs, rhs, group_sizes):
+        return dot(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+    def bwd(res, ct):
+        lhs, rhs, group_sizes = res
+        d_lhs, d_rhs = jax.vjp(
+            lambda a, b: ragged_dot(a, b, group_sizes), lhs, rhs)[1](ct)
+        return poison(d_lhs, group_sizes), d_rhs, None
+
+    dot.defvjp(fwd, bwd)
+    return dot
+
+
 class TestEP:
     def test_ep_sharded_dropped_matches(self, devices8):
         """Expert-parallel (expert axis 4) dropped-MoE matches unsharded."""
@@ -134,6 +160,8 @@ class TestEP:
                          tensor_model_parallel_size=2), 4, False),
         "ep2_cp2": (dict(expert_model_parallel_size=2,
                          context_parallel_size=2), 4, True),
+        "ep4_tp2": (dict(expert_model_parallel_size=4,
+                         tensor_model_parallel_size=2), 8, False),
         "no_mesh": None,
     }
 
@@ -144,22 +172,71 @@ class TestEP:
         "sinkhorn": dict(top_k=1, router_type="sinkhorn"),
     }
 
-    @pytest.mark.parametrize("router", list(ROUTERS))
-    @pytest.mark.parametrize("name", list(TOKEN_MESHES))
-    def test_token_sharded_dropless_matches(self, devices8, name, router):
-        """The dropless block partitioned by tokens against the unsharded one:
-        forward, router outputs and every gradient, at tight tolerance, on
-        each mesh shape that shards tokens (and on none), under both routers.
+    #: forced routing of 8 experts, 2 a token -> the share of all tokens that
+    #: choose the two first experts (both on chip 0 of every expert group);
+    #: the rest go round the other chips (all of them when the share is 0).
+    #: Sinkhorn would undo the forcing.
+    SKEWS = {"even": 0.0, "half": 0.5, "3to1": 0.75, "one_chip": 1.0}
 
-        Also the regression pin of the ragged_dot EP hazard: XLA's SPMD
-        partitioner has no rule for ragged_dot's group dim; with the expert
-        dim sharded on a strided axis (ep2_tp2) it silently computed local
-        expert slices against global group offsets — full-signal corruption
-        (forward off by the magnitude of y) with no error.  The compute sees
-        the expert weights gathered over 'expert'."""
-        cfg = moe.MoEConfig(num_experts=4, dropless=True, **self.ROUTERS[router])
+    @staticmethod
+    def _forced(params, x, share, ep, cfg):
+        """``params`` and ``x [b, s, h]`` with the routing forced: the router
+        reads the first E features alone, which name each token's two
+        experts, near enough in weight that both rows count."""
+        e, (b, s, h) = cfg.num_experts, x.shape
+        tok = np.arange(b * s)
+        rest = tok % ep if share == 0 else 1 + tok % (ep - 1)
+        chip = np.where(tok < share * b * s, 0, rest)
+        first = chip * (e // ep)
+        feat = np.zeros((b * s, e), np.float32)
+        feat[tok, first], feat[tok, first + 1] = 3.0, 2.75
+        x = jnp.concatenate([jnp.asarray(feat).reshape(b, s, e), x[..., e:]], -1)
+        w = jnp.zeros((h, e)).at[:e].set(2.0 * jnp.eye(e))
+        return {**params, "router": {"w": w}}, x
+
+    @staticmethod
+    def _received(idx, cfg, dp, ep, fair):
+        """The largest count of expert rows a chip would receive, over the
+        fair share, from the global ``expert_idx`` [b*s, k]: the batch is
+        split data-major, and the ``ep`` chips of one data group exchange
+        that group's rows."""
+        chip_of = np.asarray(idx).reshape(dp, -1) // (cfg.num_experts // ep)
+        return max(np.bincount(g, minlength=ep).max() for g in chip_of) / fair
+
+    @pytest.mark.parametrize("name,router,skew", [
+        *itertools.product(TOKEN_MESHES, ROUTERS, [None]),
+        *itertools.product(("ep4", "dp2_ep2", "ep2_tp2", "ep4_tp2"), ["top_k"], SKEWS),
+    ], ids=lambda v: str(v) if v else "")
+    def test_token_sharded_dropless_matches(self, devices8, name, router, skew,
+                                            monkeypatch):
+        """The dropless block partitioned by tokens against the unsharded one:
+        forward, router outputs and every gradient (router, both expert
+        weights, input), at tight tolerance, on each mesh shape that shards
+        tokens (and on none), under both routers; then with 8 experts and the
+        routing forced to each of ``SKEWS``: balanced, a chip that receives
+        exactly the bound, and past it, where at ep 4 the weights travel
+        instead (at ep 2 the bound is the worst case: every token on one
+        chip's experts fills it), with ``ragged_dot`` leaving the rows past
+        its groups unwritten as on the TPU (``_unwritten_tail``).
+
+        Where ``expert`` shards the tokens the rows travel to the resident
+        experts (``moe_expert_exchange``), and the block says how many a chip
+        would receive and which way it went.  Also the regression pin of the
+        ragged_dot EP hazard: XLA's SPMD partitioner has no rule for
+        ragged_dot's group dim; with the expert dim sharded on a strided axis
+        (ep2_tp2) it silently computed local expert slices against global
+        group offsets — full-signal corruption (forward off by the magnitude
+        of y) with no error.  The kernel sees a chip's resident experts only,
+        inside the manual region."""
+        cfg = moe.MoEConfig(num_experts=8 if skew else 4, dropless=True,
+                            **self.ROUTERS[router])
         params, x = params_and_x(jax.random.PRNGKey(9), cfg=cfg)
         x = x.reshape(4, 8, -1)
+        fields, n, cp = self.TOKEN_MESHES[name] or ({}, 1, False)
+        ep = fields.get("expert_model_parallel_size", 1)
+        shards = n // fields.get("tensor_model_parallel_size", 1)
+        if skew:
+            params, x = self._forced(params, x, self.SKEWS[skew], ep, cfg)
 
         def run(p, xx, act_spec=None):
             def loss(p, xx):
@@ -171,12 +248,14 @@ class TestEP:
             return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, xx)
 
         (_, (y_ref, aux_ref)), g_ref = run(params, x)
+        assert aux_ref["stats"] == {}
+        if skew:
+            monkeypatch.setattr(jax.lax, "ragged_dot",
+                                _unwritten_tail(jax.lax.ragged_dot))
         if self.TOKEN_MESHES[name] is None:
             with shd.collect_trace_facts() as traced:
                 (_, (y, aux)), g = jax.jit(run)(params, x)
-            shards = 1
         else:
-            fields, n, cp = self.TOKEN_MESHES[name]
             mesh = build_mesh(MeshConfig(**fields), devices=devices8[:n])
             act_spec = shd.act_spec(False, cp)
             ns = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
@@ -186,9 +265,27 @@ class TestEP:
             with mesh, shd.use_mesh(mesh), shd.collect_trace_facts() as traced:
                 (_, (y, aux)), g = jax.jit(
                     lambda p, xx: run(p, xx, act_spec))(sh_params, sh_x)
-            shards = n // fields.get("tensor_model_parallel_size", 1)
-        # the mechanism engaged: manual over every axis that shards tokens
-        assert traced == {"moe_token_shards": shards}
+        # the mechanism engaged: manual over every axis that shards tokens,
+        # and over ``expert`` the rows travel while no chip receives more
+        # than the bound (where a chip could: else the weights travel)
+        fair = x.shape[0] * x.shape[1] // shards * cfg.top_k
+        worst = ep * fair // cfg.top_k * min(cfg.top_k, cfg.num_experts // ep)
+        bound = min(2 * fair, worst)
+        assert traced == {"moe_token_shards": shards, **(
+            {"moe_expert_exchange": "tokens", "moe_row_bounds": [bound]}
+            if ep > 1 else {})}
+        assert set(aux["stats"]) == ({"moe/recv_rows_share_max"} | (
+            {"moe/row_bound"} if bound < worst else set()) if ep > 1 else set())
+        if skew:
+            share = self._received(aux_ref["expert_idx"], cfg, shards // ep, ep, fair)
+            assert float(aux["stats"]["moe/recv_rows_share_max"]) == share
+            # the forcing reached what it was made for: a balanced exchange,
+            # one that fills the bound, and past it (ep4) the weights' way
+            assert share == {"even": 1.0, "half": 2.0 if ep == 4 else share,
+                             "one_chip": ep}.get(skew, share)
+            if bound < worst:
+                assert int(aux["stats"]["moe/row_bound"]) == (share * fair > bound) \
+                    == (skew in ("3to1", "one_chip"))
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=1e-5, atol=1e-6)
         np.testing.assert_array_equal(np.asarray(aux["expert_idx"]),

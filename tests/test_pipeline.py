@@ -221,10 +221,12 @@ class TestVirtualPipeline:
                 err_msg=f"grad mismatch at {path}",
             )
 
-    def test_mixtral_pp2_matches_per_microbatch_reference(self, devices8):
+    @pytest.mark.parametrize("ep", [1, 2])
+    def test_mixtral_pp2_matches_per_microbatch_reference(self, devices8, ep):
         """Mixtral under pp=2: lm loss + psum'd router aux must equal the mean
         of per-microbatch unpipelined forwards (routing is per-microbatch, so
-        that — not the flat-batch forward — is the exact reference)."""
+        that — not the flat-batch forward — is the exact reference).  With
+        ep 2 the stage body's expert block sends its rows over ``expert``."""
         import dataclasses
 
         from neuronx_distributed_training_tpu.models import mixtral
@@ -249,7 +251,8 @@ class TestVirtualPipeline:
 
         ref_l, ref_g = jax.value_and_grad(ref)(params, mbs)
 
-        mesh = build_mesh(MeshConfig(pipeline_model_parallel_size=2))
+        mesh = build_mesh(MeshConfig(pipeline_model_parallel_size=2,
+                                     expert_model_parallel_size=ep))
         embed_fn, stage_fn, loss_fn = mixtral.pipeline_hooks(cfg, FP32)
 
         def pl(p, m):
@@ -268,12 +271,16 @@ class TestVirtualPipeline:
         with mesh, shd.use_mesh(mesh), shd.collect_trace_facts() as traced:
             loss, grads = jax.jit(jax.value_and_grad(pl, argnums=0))(sh_params, mbs)
         # inside the pipe-manual stage body the dropless expert block nests
-        # its own region over the axes that shard the micro-batch (data 4)
-        assert traced == {"moe_token_shards": 4}
+        # its own region over the axes that shard the micro-batch (data 4, or
+        # data 2 x expert 2: one row a chip, 16 x 2 expert rows its fair share,
+        # twice that all it can receive)
+        assert traced == {"moe_token_shards": 4, **({} if ep == 1 else {
+            "moe_expert_exchange": "tokens", "moe_row_bounds": [64]})}
         np.testing.assert_allclose(float(loss), float(ref_l), rtol=2e-5)
         for path in (
             ("layers", "mlp", "router", "w"),
             ("layers", "mlp", "experts", "gate_up"),
+            ("layers", "mlp", "experts", "down"),
             ("embed", "embedding"),
         ):
             g, rg = grads, ref_g
