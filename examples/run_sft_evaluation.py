@@ -52,12 +52,12 @@ def main() -> None:
         render_prompt,
         score,
     )
-    from neuronx_distributed_training_tpu.trainer.loop import build_model
+    from neuronx_distributed_training_tpu.models.family import resolve
     from neuronx_distributed_training_tpu.utils.dtypes import DtypePolicy
 
     cfg = load_config(args.config)
     policy = DtypePolicy.from_precision_config(cfg.get("precision", {}))
-    model_cfg, _, _, _ = build_model(cfg, policy)
+    _, model_cfg = resolve(cfg)
     tok = AutoTokenizer.from_pretrained(args.tokenizer)
     eos = tok.eos_token_id or 0
 

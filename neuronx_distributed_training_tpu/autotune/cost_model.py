@@ -5,7 +5,7 @@ Three independent terms per plan, each a closed-form function of the
 :class:`~.topology.ChipTopology` — no lowering anywhere:
 
 - **compute**: the per-component FLOPs breakdown
-  (``utils.perf.flops_breakdown_for_model`` — the same accounting MFU uses)
+  (``models.family.flops_breakdown_for_model`` — the same accounting MFU uses)
   x the fwd+2xbwd convention x a remat recompute multiplier, over
   ``chips x peak x efficiency``.
 - **comms**: per-collective byte volumes (tp/SP layer collectives, dp
@@ -627,7 +627,7 @@ def estimate_plan(facts: ModelFacts, plan: Plan, topo: ChipTopology,
     (:func:`comms_calibration_from_summary`) — reprices each comms axis at
     the bandwidth a ``tools/comms_bench.py`` sweep actually measured on the
     wire instead of the topology table's peak."""
-    from neuronx_distributed_training_tpu.utils.perf import (
+    from neuronx_distributed_training_tpu.models.family import (
         flops_breakdown_for_model,
     )
 

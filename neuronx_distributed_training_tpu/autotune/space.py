@@ -120,7 +120,7 @@ class ModelFacts:
     """Everything the lattice + cost model need, extracted once from a
     loaded config mapping — no arrays, no lowering."""
 
-    family: str                      # llama | mistral | mixtral | gpt | ouro
+    family: str                      # models.family.Family.name
     model_cfg: Any                   # the family's config dataclass
     num_layers: int
     num_heads: int
@@ -160,59 +160,13 @@ class ModelFacts:
         from neuronx_distributed_training_tpu.data.build import (
             alignment_strategy,
         )
+        from neuronx_distributed_training_tpu.models.family import resolve
 
         model = dict(cfg.get("model", {}) or {})
         ds = dict(cfg.get("distributed_strategy", {}) or {})
         data = dict(cfg.get("data", {}) or {})
         fusions = dict(model.get("fusions", {}) or {})
-        source = str(cfg.get("model_source", "hf")).lower()
-        arch = str(model.get("architecture",
-                             model.get("model_type", "llama"))).lower()
-
-        if arch == "mixtral":
-            from neuronx_distributed_training_tpu.models import mixtral
-
-            mc: Any = mixtral.MixtralConfig.from_config(model, ds)
-            lc = mc.llama
-            family = "mixtral"
-            experts = int(mc.moe.num_experts)
-            top_k = int(mc.moe.top_k)
-            moe_freq = int(mc.moe_frequency or 1)
-            heads, kv = lc.num_attention_heads, lc.kv_heads
-            head_dim, hidden = lc.head_size, lc.hidden_size
-            ffn, vocab = lc.intermediate_size, lc.vocab_size
-            layers, tied = lc.num_layers, lc.tie_word_embeddings
-        elif arch == "gpt" or source == "megatron":
-            from neuronx_distributed_training_tpu.models import gpt
-
-            mc = gpt.GPTConfig.from_config(model, ds)
-            family = "gpt"
-            experts = int(mc.moe.num_experts) if mc.moe is not None else 0
-            top_k = int(mc.moe.top_k) if mc.moe is not None else 0
-            moe_freq = int(getattr(mc, "moe_frequency", 1) or 1)
-            heads, kv = mc.num_attention_heads, mc.kv_heads
-            head_dim, hidden = mc.head_size, mc.hidden_size
-            ffn, vocab = mc.ffn_size, mc.vocab_size
-            layers = mc.num_layers
-            tied = bool(getattr(mc, "share_embeddings_and_output_weights",
-                                True))
-        else:
-            from neuronx_distributed_training_tpu.models import llama, ouro
-
-            if arch == "ouro":
-                # the looped stack: llama's layout; its passes reach the cost
-                # model through utils.perf.flops_breakdown_for_model only
-                mc = ouro.OuroConfig.from_config(model, ds)
-                lc, family = mc.llama, "ouro"
-            else:
-                mc = lc = llama.LlamaConfig.from_config(model, ds)
-                family = "mistral" if arch == "mistral" else "llama"
-            experts = top_k = 0
-            moe_freq = 1
-            heads, kv = lc.num_attention_heads, lc.kv_heads
-            head_dim, hidden = lc.head_size, lc.hidden_size
-            ffn, vocab = lc.intermediate_size, lc.vocab_size
-            layers, tied = lc.num_layers, lc.tie_word_embeddings
+        family, mc = resolve(cfg)
 
         if fusions.get("ulysses_attention"):
             cp_fusion: Optional[str] = "ulysses"
@@ -235,12 +189,8 @@ class ModelFacts:
         gbs = int(data.get("global_batch_size", 1))
 
         facts = cls(
-            family=family, model_cfg=mc, num_layers=int(layers),
-            num_heads=int(heads), num_kv_heads=int(kv), head_dim=int(head_dim),
-            hidden=int(hidden), ffn=int(ffn), vocab=int(vocab), seq=seq,
-            global_batch_size=gbs, tied_embeddings=bool(tied),
-            num_experts=experts, top_k=top_k, moe_frequency=moe_freq,
-            cp_fusion=cp_fusion,
+            family=family.name, model_cfg=mc, seq=seq, global_batch_size=gbs,
+            **family.plan_shape(mc), cp_fusion=cp_fusion,
             flash_block_kv=(int(fusions["flash_block_kv"])
                             if fusions.get("flash_block_kv") else None),
             sequence_parallel=bool(ds.get("sequence_parallel", False)),
@@ -296,8 +246,9 @@ class ModelFacts:
                 resolve_schedule,
             )
 
-            sched = resolve_schedule("auto", self.model_cfg,
-                                     self._parallel_cfg(d))
+            sched = resolve_schedule(
+                "auto", self.model_cfg.family.manual_vjp_refusal(self.model_cfg),
+                self._parallel_cfg(d))
         return dataclasses.replace(
             d, dp=dp, num_microbatches=nm,
             schedule=(sched if d.pp > 1 else "none"))
@@ -410,6 +361,7 @@ def enumerate_plans(
         supports_1f1b,
     )
 
+    family_refusal = facts.model_cfg.family.manual_vjp_refusal(facts.model_cfg)
     plans: list[Plan] = []
     for tp in _tp_candidates(facts, chips):
         for pp in _pp_candidates(facts, chips // tp):
@@ -436,7 +388,7 @@ def enumerate_plans(
                         else:
                             base = Plan(tp=tp, pp=pp, cp=cp, ep=ep, dp=dp)
                             ok, _ = supports_1f1b(
-                                facts.model_cfg, facts._parallel_cfg(base))
+                                family_refusal, facts._parallel_cfg(base))
                             scheds = [("wavefront", 1)]
                             if ok:
                                 scheds += [("1f1b", 1), ("1f1b-zb", 1)]

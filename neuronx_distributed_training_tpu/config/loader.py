@@ -301,11 +301,10 @@ def validate_config(cfg: ConfigDict) -> None:
                 f"pipeline slices whole groups per stage chunk"
             )
 
-    # ---- looped stack (model.architecture: ouro) ---------------------------
-    if str(model.get("architecture", model.get("model_type", ""))).lower() == "ouro":
-        from neuronx_distributed_training_tpu.models.ouro import OuroConfig
+    # ---- the model family's own refusals -----------------------------------
+    from neuronx_distributed_training_tpu.models.family import resolve
 
-        OuroConfig.from_config(model, ds)  # refuses what the loop is not wired for
+    resolve(cfg)  # its config_from refuses, by key, what it is not wired for
 
     # ---- context parallelism & attention kernels --------------------------
     seq = data.get("seq_length")

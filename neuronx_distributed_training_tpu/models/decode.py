@@ -467,27 +467,6 @@ def decode_step_gpt(params, cache, tokens, pos, cfg, policy):
     return logits[:, 0], {"k": ck, "v": cv}
 
 
-def _family(cfg):
-    """(prefill_fn, decode_fn, logits_cfg_for_head) by config type."""
-    from neuronx_distributed_training_tpu.models import gpt, mixtral
-
-    if getattr(cfg, "total_ut_steps", None) is not None:
-        raise NotImplementedError(
-            "model.architecture: ouro (total_ut_steps passes over one stack) "
-            "has no cached decode: every pass needs a KV cache of its own "
-            "(models/decode.py holds one per layer)")
-    if isinstance(cfg, mixtral.MixtralConfig):
-        return (prefill_mixtral, decode_step_mixtral,
-                lambda params, h, policy: llama.logits_fn(
-                    params, h, cfg.llama, policy))
-    if isinstance(cfg, gpt.GPTConfig):
-        return (prefill_gpt, decode_step_gpt,
-                lambda params, h, policy: gpt._logits_from_hidden(
-                    params, h, cfg, policy))
-    return (prefill, decode_step,
-            lambda params, h, policy: llama.logits_fn(params, h, cfg, policy))
-
-
 def generate_cached(
     params: Any,
     cfg: llama.LlamaConfig,
@@ -515,10 +494,12 @@ def generate_cached(
     buf = buf.at[:, :plen].set(prompt_ids)
     if max_new_tokens <= 0:  # same no-op contract as generate()
         return buf
-    prefill_fn, decode_fn, head_fn = _family(cfg)
+    prefill_fn, decode_fn = cfg.family.decode()
     h, cache = prefill_fn(params, prompt_ids, cfg, policy, max_len=total)
-    # logits ONLY at each row's last prompt position ([b, 1, h] -> [b, vocab])
-    logits = head_fn(params, h[rows, lens - 1][:, None], policy)[:, 0]
+    # logits ONLY at each row's last prompt position ([b, 1, h] -> [b, vocab]);
+    # prefill's hidden states have the final norm already
+    head_fn = cfg.family.head(cfg, policy, norm=False)
+    logits = head_fn(params, h[rows, lens - 1][:, None])[:, 0]
     key = key if key is not None else jax.random.PRNGKey(0)
 
     def pick(next_logits, key):

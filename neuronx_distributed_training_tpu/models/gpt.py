@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from neuronx_distributed_training_tpu.models.family import Family, Refused
 from neuronx_distributed_training_tpu.ops import cross_entropy as ce_ops
 from neuronx_distributed_training_tpu.ops import attention as attn_ops
 from neuronx_distributed_training_tpu.ops import linear as linear_ops
@@ -46,6 +47,7 @@ from neuronx_distributed_training_tpu.ops import norm as norm_ops
 from neuronx_distributed_training_tpu.ops import rope as rope_ops
 from neuronx_distributed_training_tpu.parallel import sharding as shd
 from neuronx_distributed_training_tpu.utils.dtypes import DtypePolicy
+from neuronx_distributed_training_tpu.utils.perf import _attention_flops_per_token
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,6 +104,10 @@ class GPTConfig:
     @property
     def is_glu(self) -> bool:
         return self.activation in ("swiglu", "geglu", "reglu")
+
+    @property
+    def family(self) -> Family:
+        return FAMILY
 
     @classmethod
     def from_config(cls, model_cfg: dict[str, Any], ds_cfg: dict[str, Any] | None = None):
@@ -793,3 +799,92 @@ def forward(
     if cfg.moe is not None:
         loss = loss + aux["router_aux_loss"]
     return loss, aux
+
+
+# ---------------------------------------------------------------------------
+# the family's record (models/family.py)
+# ---------------------------------------------------------------------------
+
+
+def _logits(cfg: GPTConfig, policy: DtypePolicy):
+    def fwd(p, b, rng=None):  # rng: dropout (None in the frozen reference pass)
+        logits, aux = forward(p, {"input_ids": b["input_ids"]}, cfg, policy, rng=rng)
+        return logits, aux.get("router_aux_loss", 0.0)
+
+    return fwd
+
+
+def _head(cfg: GPTConfig, policy: DtypePolicy, *, norm: bool = True):
+    def head_fn(p, hidden):
+        # post_ln layers end normalized; no final LN (init_params omits it)
+        if norm and cfg.transformer_block_type != "post_ln":
+            hidden = _apply_norm(cfg, p["final_norm"], hidden)
+        return _logits_from_hidden(p, hidden, cfg, policy)
+
+    return head_fn
+
+
+def _pipeline(cfg: GPTConfig, policy: DtypePolicy, *, shift_labels: bool = True):
+    return pipeline_hooks(cfg, policy, shift_labels=shift_labels), {
+        "stage_aux": True,
+        # normalized over the layers that HAVE routers (moe_frequency)
+        "aux_inv_layers": 1.0 / num_moe_layers(cfg) if cfg.moe is not None else 0.0,
+        "needs_rng": cfg.hidden_dropout > 0.0 or cfg.embedding_dropout > 0.0,
+    }
+
+
+def _decode():
+    from neuronx_distributed_training_tpu.models import decode
+
+    return decode.prefill_gpt, decode.decode_step_gpt
+
+
+def _flops_breakdown(cfg: GPTConfig, seq_len: int) -> dict[str, float]:
+    attn = cfg.num_layers * _attention_flops_per_token(
+        hidden_size=cfg.hidden_size,
+        num_attention_heads=cfg.num_attention_heads,
+        num_kv_heads=cfg.kv_heads,
+        seq_len=seq_len,
+        head_dim=cfg.head_size,
+    )
+    matmuls = 3 if cfg.is_glu else 2  # (gate,) up, down
+    mlp = 2 * cfg.hidden_size * matmuls * cfg.ffn_size
+    n_moe, top_k, experts = (
+        (num_moe_layers(cfg), cfg.moe.top_k, cfg.moe.num_experts)
+        if cfg.moe is not None else (0, 0, 0))
+    return {
+        "attention": attn,
+        "mlp": float((cfg.num_layers - n_moe) * mlp + n_moe * top_k * mlp),
+        "router": float(n_moe * 2 * cfg.hidden_size * experts),
+        "head": 2.0 * cfg.hidden_size * cfg.vocab_size,
+    }
+
+
+FAMILY = Family(
+    name="gpt",
+    config_from=GPTConfig.from_config,
+    loss=lambda cfg, policy, *, shift_labels=True: (
+        lambda p, batch, key: forward(p, batch, cfg, policy, rng=key,
+                                      shift_labels=shift_labels)),
+    init_params=init_params,
+    param_specs=param_specs,
+    flops_breakdown=_flops_breakdown,
+    plan_shape=lambda cfg: {
+        "num_layers": cfg.num_layers, "num_heads": cfg.num_attention_heads,
+        "num_kv_heads": cfg.kv_heads, "head_dim": cfg.head_size,
+        "hidden": cfg.hidden_size, "ffn": cfg.ffn_size, "vocab": cfg.vocab_size,
+        "tied_embeddings": cfg.share_embeddings_and_output_weights,
+        "num_experts": int(cfg.moe.num_experts) if cfg.moe is not None else 0,
+        "top_k": int(cfg.moe.top_k) if cfg.moe is not None else 0,
+        "moe_frequency": int(cfg.moe_frequency or 1)},
+    logits=_logits,
+    head=_head,
+    pipeline=_pipeline,
+    # learned positions, dropout threading and the post_ln/normformer/gpt_j
+    # head variants keep the autodiff wavefront until a head is wired
+    onef1b_head=Refused(
+        "GPTConfig: head not wired for the manual-vjp schedules (supported "
+        "families: llama/mistral)"),
+    decode=_decode,
+    moe_groups=lambda cfg: num_moe_layers(cfg) if cfg.moe_frequency != 1 else None,
+)

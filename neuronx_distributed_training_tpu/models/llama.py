@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from neuronx_distributed_training_tpu.models.family import Family
 from neuronx_distributed_training_tpu.ops import attention as attn_ops
 from neuronx_distributed_training_tpu.ops import cross_entropy as ce_ops
 from neuronx_distributed_training_tpu.ops import linear as linear_ops
@@ -39,6 +40,7 @@ from neuronx_distributed_training_tpu.ops import norm as norm_ops
 from neuronx_distributed_training_tpu.ops import rope as rope_ops
 from neuronx_distributed_training_tpu.parallel import sharding as shd
 from neuronx_distributed_training_tpu.utils.dtypes import DtypePolicy
+from neuronx_distributed_training_tpu.utils.perf import _attention_flops_per_token
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,6 +83,10 @@ class LlamaConfig:
     @property
     def head_size(self) -> int:
         return self.head_dim or self.hidden_size // self.num_attention_heads
+
+    @property
+    def family(self) -> Family:
+        return FAMILY
 
     @classmethod
     def from_config(cls, model_cfg: dict[str, Any], ds_cfg: dict[str, Any] | None = None) -> "LlamaConfig":
@@ -647,3 +653,131 @@ def _head_loss(params, hidden, batch, cfg: LlamaConfig, policy: DtypePolicy, *,
         logits, labels, loss_mask = ce_ops.shift_for_next_token(logits, labels, loss_mask)
     loss = ce_ops.cross_entropy_loss(logits, labels, loss_mask=loss_mask)
     return loss, aux
+
+
+# ---------------------------------------------------------------------------
+# the family's record (models/family.py)
+# ---------------------------------------------------------------------------
+
+
+def _loss(cfg: LlamaConfig, policy: DtypePolicy, *, shift_labels: bool = True):
+    if cfg.attention_impl != "zigzag_ring":
+        return lambda p, batch, key: forward(p, batch, cfg, policy, shift_labels=shift_labels)
+    # zig-zag CP layout: the loss permutes the batch (labels pre-shifted in
+    # ORIGINAL order — the in-model shift is order-dependent) and feeds
+    # matching RoPE positions; cp is the mesh's, as the op reads it, and
+    # cp == 1 makes both transforms the identity
+    from neuronx_distributed_training_tpu.parallel.ring_attention import (
+        zigzag_positions,
+        zigzag_transform_batch,
+    )
+
+    if not shift_labels:
+        raise NotImplementedError(
+            "zigzag_ring_attention with a pre-shifted data module "
+            "(the zig-zag transform owns the label shift)"
+        )
+
+    def loss_fn(p, batch, key):
+        mesh = shd.active_mesh()
+        cp = int(mesh.shape.get("context", 1)) if mesh is not None else 1
+        zb = zigzag_transform_batch(batch, cp)
+        s = zb["input_ids"].shape[1]
+        pos = jnp.broadcast_to(zigzag_positions(s, cp)[None, :], zb["input_ids"].shape)
+        return forward(p, zb, cfg, policy, positions=pos, shift_labels=False)
+
+    return loss_fn
+
+
+def _logits(cfg: LlamaConfig, policy: DtypePolicy):
+    if cfg.attention_impl == "zigzag_ring":
+        # preference batches are chosen/rejected sequences, not the
+        # zig-zag-permuted LM batches the layout expects
+        raise NotImplementedError(
+            "zigzag_ring_attention with preference alignment; use "
+            "fusions.ring_attention"
+        )
+
+    def fwd(p, b, rng=None):
+        logits, _ = forward(p, {"input_ids": b["input_ids"]}, cfg, policy)
+        return logits, 0.0
+
+    return fwd
+
+
+def head(cfg: LlamaConfig, policy: DtypePolicy, *, norm: bool = True):
+    """``Family.head``; the families built on this block pass ``cfg.llama``."""
+
+    def head_fn(p, hidden):
+        if norm:
+            hidden = norm_ops.apply_rms_norm(p["final_norm"], hidden, eps=cfg.rms_norm_eps)
+        return logits_fn(p, hidden, cfg, policy)
+
+    return head_fn
+
+
+def _under_pp(hooks):
+    """``hooks(cfg, policy, ...)`` for the ``pipe`` axis: refuses the zig-zag
+    layout, whose batch/position transform lives in the non-PP loss (stage
+    hooks thread no positions)."""
+
+    def build(cfg: LlamaConfig, policy: DtypePolicy, **kw):
+        if cfg.attention_impl == "zigzag_ring":
+            raise NotImplementedError(
+                "zigzag_ring_attention under pipeline parallelism; use "
+                "fusions.ring_attention for pp + cp configs"
+            )
+        return hooks(cfg, policy, **kw)
+
+    return build
+
+
+def _decode():
+    from neuronx_distributed_training_tpu.models import decode
+
+    return decode.prefill, decode.decode_step
+
+
+def flops_breakdown(cfg: LlamaConfig, seq_len: int, passes: int = 1) -> dict[str, float]:
+    """``Family.flops_breakdown``; a stack applied several times
+    (models/ouro.py) multiplies its work, heads included."""
+    attn = passes * cfg.num_layers * _attention_flops_per_token(
+        hidden_size=cfg.hidden_size,
+        num_attention_heads=cfg.num_attention_heads,
+        num_kv_heads=cfg.num_kv_heads,
+        seq_len=seq_len,
+        head_dim=cfg.head_dim,
+    )
+    mlp = 2 * cfg.hidden_size * 3 * cfg.intermediate_size
+    return {
+        "attention": attn,
+        "mlp": float(passes * cfg.num_layers * mlp),
+        "router": 0.0,
+        "head": 2.0 * passes * cfg.hidden_size * cfg.vocab_size,
+    }
+
+
+def plan_shape(cfg: LlamaConfig) -> dict[str, Any]:
+    """``Family.plan_shape``: a dense stack's."""
+    return {
+        "num_layers": cfg.num_layers, "num_heads": cfg.num_attention_heads,
+        "num_kv_heads": cfg.kv_heads, "head_dim": cfg.head_size,
+        "hidden": cfg.hidden_size, "ffn": cfg.intermediate_size,
+        "vocab": cfg.vocab_size, "tied_embeddings": cfg.tie_word_embeddings,
+    }
+
+
+FAMILY = Family(
+    name="llama",
+    config_from=LlamaConfig.from_config,
+    loss=_loss,
+    init_params=init_params,
+    param_specs=param_specs,
+    flops_breakdown=flops_breakdown,
+    plan_shape=plan_shape,
+    logits=_logits,
+    head=head,
+    pipeline=_under_pp(lambda cfg, policy, **kw: (pipeline_hooks(cfg, policy, **kw), {})),
+    onef1b_head=_under_pp(onef1b_head_hooks),
+    decode=_decode,
+)

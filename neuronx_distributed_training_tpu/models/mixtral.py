@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from neuronx_distributed_training_tpu.models import llama
+from neuronx_distributed_training_tpu.models.family import Family, Refused
 from neuronx_distributed_training_tpu.ops import cross_entropy as ce_ops
 from neuronx_distributed_training_tpu.ops import linear as linear_ops
 from neuronx_distributed_training_tpu.ops import moe as moe_ops
@@ -64,6 +65,10 @@ class MixtralConfig:
     @property
     def num_kv_heads(self):
         return self.llama.num_kv_heads
+
+    @property
+    def family(self) -> Family:
+        return FAMILY
 
     @classmethod
     def from_config(cls, model_cfg: dict[str, Any], ds_cfg: dict[str, Any] | None = None):
@@ -288,12 +293,6 @@ def pipeline_hooks(cfg: MixtralConfig, policy: DtypePolicy, *,
     return embed_fn, stage_fn, loss_fn
 
 
-def onef1b_head_hooks(cfg: MixtralConfig, policy: DtypePolicy):
-    """1F1B head wiring — identical top-level param layout to llama
-    (embed / final_norm / optional lm_head), so delegate."""
-    return llama.onef1b_head_hooks(cfg.llama, policy)
-
-
 def forward(
     params,
     batch: dict[str, jax.Array],
@@ -364,3 +363,77 @@ def forward(
     loss = lm_loss + aux["router_aux_loss"]
     aux["lm_loss"] = lm_loss
     return loss, aux
+
+
+# ---------------------------------------------------------------------------
+# the family's record (models/family.py)
+# ---------------------------------------------------------------------------
+
+
+def _loss(cfg: MixtralConfig, policy: DtypePolicy, *, shift_labels: bool = True):
+    if cfg.llama.attention_impl == "zigzag_ring":
+        # the zig-zag batch/position transform is llama's loss's; running the
+        # op on an unpermuted batch would silently corrupt the causal structure
+        raise NotImplementedError(
+            "zigzag_ring_attention is llama/mistral-only; use "
+            "fusions.ring_attention for mixtral"
+        )
+    return lambda p, batch, key: forward(p, batch, cfg, policy, shift_labels=shift_labels)
+
+
+def _logits(cfg: MixtralConfig, policy: DtypePolicy):
+    def fwd(p, b, rng=None):
+        logits, aux = forward(p, {"input_ids": b["input_ids"]}, cfg, policy)
+        return logits, aux["router_aux_loss"]
+
+    return fwd
+
+
+def _pipeline(cfg: MixtralConfig, policy: DtypePolicy, *, shift_labels: bool = True):
+    # the router loss is normalized over the layers that HAVE routers
+    return (pipeline_hooks(cfg, policy, shift_labels=shift_labels),
+            {"stage_aux": True, "aux_inv_layers": 1.0 / num_moe_layers(cfg)})
+
+
+def _decode():
+    from neuronx_distributed_training_tpu.models import decode
+
+    return decode.prefill_mixtral, decode.decode_step_mixtral
+
+
+def _flops_breakdown(cfg: MixtralConfig, seq_len: int) -> dict[str, float]:
+    lc = cfg.llama
+    n_moe = num_moe_layers(cfg)
+    swiglu = 2 * lc.hidden_size * 3 * lc.intermediate_size
+    return {
+        **llama.flops_breakdown(lc, seq_len),
+        "mlp": (lc.num_layers - n_moe) * swiglu + n_moe * cfg.moe.top_k * swiglu,
+        "router": float(n_moe * 2 * lc.hidden_size * cfg.moe.num_experts),
+    }
+
+
+FAMILY = Family(
+    name="mixtral",
+    config_from=MixtralConfig.from_config,
+    loss=_loss,
+    init_params=init_params,
+    param_specs=param_specs,
+    flops_breakdown=_flops_breakdown,
+    plan_shape=lambda cfg: {
+        **llama.plan_shape(cfg.llama), "num_experts": int(cfg.moe.num_experts),
+        "top_k": int(cfg.moe.top_k), "moe_frequency": int(cfg.moe_frequency or 1)},
+    logits=_logits,
+    head=lambda cfg, policy, **kw: llama.head(cfg.llama, policy, **kw),
+    pipeline=_pipeline,
+    # The head wiring would be llama's (the same top-level layout:
+    # ``lambda cfg, policy: llama.onef1b_head_hooks(cfg.llama, policy)``), but
+    # the sort-based dropless-MoE stage vjp read a few percent off inside the
+    # manual tick loop when last bisected (loss exact; dense stages exact under
+    # the same schedule), so mixtral keeps the autodiff wavefront until that
+    # is retested (ROADMAP M7)
+    onef1b_head=Refused(
+        "mixtral: dropless-MoE stage vjp has backend-dependent numerics "
+        "under the 1f1b tick loop (dense families only for now)"),
+    decode=_decode,
+    moe_groups=lambda cfg: num_moe_layers(cfg) if cfg.moe_frequency != 1 else None,
+)

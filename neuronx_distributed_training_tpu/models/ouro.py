@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from neuronx_distributed_training_tpu.models import llama
+from neuronx_distributed_training_tpu.models.family import Family, Refused
 from neuronx_distributed_training_tpu.ops import cross_entropy as ce_ops
 from neuronx_distributed_training_tpu.ops import linear as linear_ops
 from neuronx_distributed_training_tpu.ops import norm as norm_ops
@@ -85,10 +86,15 @@ class OuroConfig:
     def head_dim(self):
         return self.llama.head_dim
 
+    @property
+    def family(self) -> Family:
+        return FAMILY
+
     @classmethod
     def from_config(cls, model_cfg: dict[str, Any], ds_cfg: dict[str, Any] | None = None):
         # the one place that refuses what the loop is not wired for, each by
-        # its key's name (``config.loader.validate_config`` calls this)
+        # its key's name (``config.loader.validate_config`` calls every
+        # family's ``config_from``)
         m = dict(model_cfg or {})
         passes = int(m.get("total_ut_steps", 4))
         if passes < 1:
@@ -232,3 +238,36 @@ def forward(
             aux[f"loss/ce_pass{t + 1}"] = jnp.sum(ce[t]) / denom
             aux[f"exit/p_pass{t + 1}"] = jnp.sum(p[t] * mask) / denom
     return loss, aux
+
+
+# the family's record (models/family.py)
+FAMILY = Family(
+    name="ouro",
+    config_from=OuroConfig.from_config,
+    loss=lambda cfg, policy, *, shift_labels=True: (
+        lambda p, batch, key: forward(p, batch, cfg, policy, shift_labels=shift_labels)),
+    init_params=init_params,
+    param_specs=param_specs,
+    flops_breakdown=lambda cfg, seq_len: llama.flops_breakdown(
+        cfg.llama, seq_len, passes=cfg.total_ut_steps),
+    # llama's layout: the planner prices the stack's memory as applied once
+    # and sees the passes through ``flops_breakdown`` only
+    plan_shape=lambda cfg: llama.plan_shape(cfg.llama),
+    logits=Refused("preference alignment not wired for OuroConfig"),
+    head=Refused("a single head not wired for OuroConfig: there is one at the end of every pass"),
+    pipeline=Refused(
+        "pipeline parallelism not wired for OuroConfig yet: every pass would "
+        "have to circle the stages"),
+    onef1b_head=Refused(
+        "OuroConfig: head not wired for the manual-vjp schedules (supported "
+        "families: llama/mistral)"),
+    decode=Refused(
+        "model.architecture: ouro (total_ut_steps passes over one stack) "
+        "has no cached decode: every pass needs a KV cache of its own "
+        "(models/decode.py holds one per layer)"),
+    run_facts=lambda cfg, sched: {
+        "loop_passes": int(cfg.total_ut_steps),
+        "layer_applications_per_step": (
+            int(cfg.total_ut_steps) * int(cfg.num_layers)
+            * int(sched["num_microbatches"]))},
+)

@@ -505,58 +505,27 @@ def blocked_1f1b_reason(parallel_cfg: dict,
     return None
 
 
-def supports_1f1b(model_cfg: Any, parallel_cfg: dict,
+def supports_1f1b(family_refusal: Optional[str], parallel_cfg: dict,
                   schedule: str = "1f1b") -> tuple[bool, str]:
     """Can the manual-vjp ``schedule`` run this model/parallelism combo?
+    ``(ok, reason)``; ``reason`` is the first blocking constraint (and what
+    ``resolve_schedule`` raises when the config FORCES the schedule).
 
-    Returns ``(ok, reason)``; ``reason`` explains the first blocking
-    constraint when ``ok`` is False (and is the message ``resolve_schedule``
-    raises when the config FORCES a manual-vjp schedule).
-
-    ``parallel_cfg`` mirrors the ``distributed_strategy`` block plus trainer
-    context: ``pipeline_model_parallel_size``,
-    ``virtual_pipeline_model_parallel_size``, ``context_parallel_size``,
-    ``alignment`` (None/"sft" or a preference strategy), ``lora`` (bool).
-    ``schedule`` picks the variant: ``1f1b`` (vp == 1), ``1f1b-interleaved``
-    (the circular interleave, vp > 1), or ``1f1b-zb`` (the zero-bubble
-    dgrad/wgrad split, vp == 1).  The model side requires the
-    plain-matmul-head token-CE structure the in-loop vocab-sharded head
-    implements: llama/mistral qualifies today.  Mixtral's head/aux wiring
-    exists but its dropless-MoE stage vjp is gated out (backend-dependent
-    numerics — see the branch below), and megatron-GPT (learned positions,
-    dropout threading, post_ln/normformer/gpt_j head variants) keeps the
-    autodiff wavefront until its head is wired.
+    ``family_refusal`` is the model's half, asked of its family by the caller
+    (``models.family.Family.manual_vjp_refusal``): None where the plain-matmul
+    head + token CE the in-loop vocab-sharded head implements is wired, else
+    the family's sentence.  ``parallel_cfg`` and ``schedule`` are
+    ``blocked_1f1b_reason``'s: the ``distributed_strategy`` sizes plus
+    ``alignment`` (None/"sft" or a preference strategy) and ``lora`` (bool).
     """
-    blocked = blocked_1f1b_reason(parallel_cfg, schedule)
+    blocked = blocked_1f1b_reason(parallel_cfg, schedule) or family_refusal
     if blocked is not None:
         return False, blocked
-    if getattr(model_cfg, "attention_impl", "") == "zigzag_ring":
-        return False, "zigzag_ring attention is not supported under pp at all"
-    from neuronx_distributed_training_tpu.models import llama as _llama
-
-    if isinstance(model_cfg, _llama.LlamaConfig):
-        return True, f"llama/mistral: plain matmul head + token CE ({schedule})"
-    from neuronx_distributed_training_tpu.models import mixtral as _mixtral
-
-    if isinstance(model_cfg, _mixtral.MixtralConfig):
-        # The head/aux wiring exists (mixtral.onef1b_head_hooks), but the
-        # sort-based dropless-MoE stage vjp is numerically corrupted when
-        # linearized at a scan-carry-derived activation inside the legacy
-        # fully-manual shard_map fallback (loss exact, stage grads off by a
-        # few percent; bisected tick-by-tick — dense llama stages are exact
-        # under the identical schedule).  Until the toolchain's shard_map
-        # supports partial-auto natively, mixtral keeps the wavefront.
-        return False, (
-            "mixtral: dropless-MoE stage vjp has backend-dependent numerics "
-            "under the 1f1b tick loop (dense families only for now)"
-        )
-    return False, (
-        f"{type(model_cfg).__name__}: head not wired for the manual-vjp "
-        f"{schedule} schedule (supported families: llama/mistral)"
-    )
+    return True, f"plain matmul head + token CE ({schedule})"
 
 
-def resolve_schedule(schedule: str, model_cfg: Any, parallel_cfg: dict) -> str:
+def resolve_schedule(schedule: str, family_refusal: Optional[str],
+                     parallel_cfg: dict) -> str:
     """``pipeline.schedule`` knob -> concrete schedule name.
 
     ``auto`` picks the memory-bounded manual-vjp family whenever
@@ -583,9 +552,9 @@ def resolve_schedule(schedule: str, model_cfg: Any, parallel_cfg: dict) -> str:
         "virtual_pipeline_model_parallel_size", 1) or 1)
     if schedule == "auto":
         preferred = "1f1b-interleaved" if vp > 1 else "1f1b"
-        ok, _ = supports_1f1b(model_cfg, parallel_cfg, preferred)
+        ok, _ = supports_1f1b(family_refusal, parallel_cfg, preferred)
         return preferred if ok else "wavefront"
-    ok, reason = supports_1f1b(model_cfg, parallel_cfg, schedule)
+    ok, reason = supports_1f1b(family_refusal, parallel_cfg, schedule)
     if not ok:
         raise ValueError(
             f"pipeline.schedule: {schedule} is unsupported here: {reason}")

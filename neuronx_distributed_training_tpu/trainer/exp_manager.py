@@ -384,7 +384,7 @@ class ExpManager:
     ) -> None:
         """Arm MFU/tokens-per-sec-per-chip logging.  The trainer calls this
         once with the analytic per-family FLOPs estimate
-        (``utils.perf.flops_for_model`` x3 for fwd+2xbwd); from then on every
+        (``models.family.flops_for_model`` x3 for fwd+2xbwd); from then on every
         ``log_metrics`` derives ``mfu`` from the throughput window's
         ``tokens_per_sec`` — one source of truth, no second timer.  A peak of
         0 (off the TPU) logs tokens/s/chip and no ``mfu``."""

@@ -35,7 +35,7 @@ from neuronx_distributed_training_tpu.data import (
     PrefetchIterator,
     SyntheticDataModule,
 )
-from neuronx_distributed_training_tpu.models import llama
+from neuronx_distributed_training_tpu.models.family import flops_for_model, resolve
 from neuronx_distributed_training_tpu.optim.adamw import (
     AdamWConfig,
     EMAConfig,
@@ -377,9 +377,9 @@ class Trainer:
                 and (cfg.get("data", {}) or {}).get("data_prefix")
             )
 
-        model_cfg, loss_fn, init_fn, specs_fn = build_model(
-            cfg, policy, shift_labels=shift_labels
-        )
+        family, model_cfg = resolve(cfg)
+        loss_fn = family.loss(model_cfg, policy, shift_labels=shift_labels)
+        specs_fn = lambda **kw: family.param_specs(model_cfg, **kw)
         # params are NOT materialized here: param_builder composes init +
         # LoRA + pipeline-interleave as one pure function, jitted later with
         # out_shardings so every leaf is born sharded on its own devices —
@@ -387,7 +387,7 @@ class Trainer:
         # sequential_move_factor staged moves (base.py:147-152, 693-712);
         # a 405B-class config never materializes unsharded params anywhere
         init_key = jax.random.PRNGKey(seed)
-        param_builder = init_fn
+        param_builder = lambda key: family.init_params(key, model_cfg, policy)
 
         # DPO/ORPO swap the loss for the preference objective; DPO's pre-fit
         # reference-logprob pass runs in fit() (reference base_dpo.py:23-66),
@@ -395,7 +395,7 @@ class Trainer:
         forward_logits = None
         if alignment in ("dpo", "orpo", "kto"):
             dpo_cfg = dict((cfg.get("model", {}) or {}).get(alignment, {}) or {})
-            forward_logits = _forward_logits_for(model_cfg, policy)
+            forward_logits = family.logits(model_cfg, policy)
 
             # reference spells it kl_beta in the strategy block
             beta = float(align_params.get("kl_beta", dpo_cfg.get("beta", 0.1)))
@@ -463,38 +463,25 @@ class Trainer:
             from neuronx_distributed_training_tpu.trainer.step import microbatch_split
 
             vp = int(mesh_cfg.virtual_pipeline_model_parallel_size or 1)
-            if getattr(model_cfg, "attention_impl", "") == "zigzag_ring":
-                # the zig-zag batch/position transform lives in the non-PP
-                # loss hook; pipeline stage hooks don't thread positions
-                raise NotImplementedError(
-                    "zigzag_ring_attention under pipeline parallelism; use "
-                    "fusions.ring_attention for pp + cp configs"
-                )
             # fail early with a clear message instead of an opaque GSPMD error
-            moe_freq = int(getattr(model_cfg, "moe_frequency", 1) or 1)
-            if moe_freq != 1:
+            groups = family.moe_groups(model_cfg)
+            if groups is None:
+                stage_layer_slice(
+                    int(getattr(model_cfg, "num_layers", 0) or 0), pp, vp)
+            elif groups % (pp * vp) != 0:
                 # pipe slices whole (MoE + dense) groups — with vp, every
                 # chunk holds whole groups too (chunk layers = Gc*f, and
                 # to_interleaved reshapes the [G]-leading moe/dense leaves
-                # consistently with the flat [L] attn/norm leaves);
-                # num_moe_layers is family-specific (mixtral wraps a llama
-                # config, gpt is flat)
-                from neuronx_distributed_training_tpu.models import gpt as _gpt
-                from neuronx_distributed_training_tpu.models import mixtral as _mx
-
-                if isinstance(model_cfg, _gpt.GPTConfig):
-                    groups = _gpt.num_moe_layers(model_cfg)
-                else:
-                    groups = _mx.num_moe_layers(model_cfg)
-                if groups % (pp * vp) != 0:
-                    raise ValueError(
-                        f"num_layers {model_cfg.num_layers} / moe frequency "
-                        f"{moe_freq} = {groups} groups, not divisible by "
-                        f"pp*vp = {pp}*{vp}"
-                    )
-            else:
-                stage_layer_slice(
-                    int(getattr(model_cfg, "num_layers", 0) or 0), pp, vp)
+                # consistently with the flat [L] attn/norm leaves)
+                raise ValueError(
+                    f"num_layers {model_cfg.num_layers} / moe frequency "
+                    f"{model_cfg.num_layers // groups} = {groups} groups, not "
+                    f"divisible by pp*vp = {pp}*{vp}"
+                )
+            # the family's hooks (it refuses here what it cannot pipeline)
+            (embed_fn, stage_fn, stage_loss_fn), hook_opts = family.pipeline(
+                model_cfg, policy, shift_labels=shift_labels
+            )
             nm = sched["num_microbatches"]
             if alignment in ("dpo", "orpo", "kto"):
                 # preference losses pipeline via the concatenated forward
@@ -504,56 +491,13 @@ class Trainer:
                 from neuronx_distributed_training_tpu.alignment.dpo import (
                     preference_pipeline_hooks,
                 )
-                from neuronx_distributed_training_tpu.ops import norm as norm_ops
 
-                if isinstance(model_cfg, llama.LlamaConfig):
-                    base_embed, base_stage, _ = llama.pipeline_hooks(
-                        model_cfg, policy
-                    )
-                    hook_opts: dict = {}
-
-                    def head_fn(p, y):
-                        h = norm_ops.apply_rms_norm(
-                            p["final_norm"], y, eps=model_cfg.rms_norm_eps
-                        )
-                        return llama.logits_fn(p, h, model_cfg, policy)
-
-                else:
-                    (base_embed, base_stage, _), hook_opts = pipeline_hooks_for(
-                        cfg, model_cfg, policy, shift_labels=shift_labels
-                    )
-                    from neuronx_distributed_training_tpu.models import (
-                        gpt as _gptm,
-                        mixtral as _mxm,
-                    )
-
-                    if isinstance(model_cfg, _mxm.MixtralConfig):
-                        _lc = model_cfg.llama
-
-                        def head_fn(p, y):
-                            h = norm_ops.apply_rms_norm(
-                                p["final_norm"], y, eps=_lc.rms_norm_eps
-                            )
-                            return llama.logits_fn(p, h, _lc, policy)
-
-                    else:
-
-                        def head_fn(p, y):
-                            # post_ln layers end normalized; no final LN
-                            # (gpt.py init_params omits the param)
-                            h = (y if model_cfg.transformer_block_type
-                                 == "post_ln"
-                                 else _gptm._apply_norm(
-                                     model_cfg, p["final_norm"], y))
-                            return _gptm._logits_from_hidden(
-                                p, h, model_cfg, policy
-                            )
-
-                    # reference parity: the HF models add the router aux loss
-                    # only when ``labels`` is passed; the DPO/ORPO path
-                    # computes logits without labels, so no aux term here
-                    # (stage_aux stays — MoE stages return (x, aux) tuples)
-                    hook_opts = dict(hook_opts, aux_inv_layers=0.0)
+                head_fn = family.head(model_cfg, policy)
+                # reference parity: the HF models add the router aux loss
+                # only when ``labels`` is passed; the DPO/ORPO path
+                # computes logits without labels, so no aux term here
+                # (stage_aux stays — MoE stages return (x, aux) tuples)
+                hook_opts = dict(hook_opts, aux_inv_layers=0.0)
                 if alignment == "kto":
                     # single-sequence batches: embed/stage pass through, only
                     # the loss hook changes (no chosen/rejected concat)
@@ -562,7 +506,7 @@ class Trainer:
                     )
 
                     embed_fn, stage_fn, stage_loss_fn = kto_pipeline_hooks(
-                        base_embed, base_stage, head_fn, beta=beta,
+                        embed_fn, stage_fn, head_fn, beta=beta,
                         desirable_weight=float(
                             align_params.get("desirable_weight", 1.0)),
                         undesirable_weight=float(
@@ -570,12 +514,8 @@ class Trainer:
                     )
                 else:
                     embed_fn, stage_fn, stage_loss_fn = preference_pipeline_hooks(
-                        base_embed, base_stage, head_fn, mode=alignment, beta=beta
+                        embed_fn, stage_fn, head_fn, mode=alignment, beta=beta
                     )
-            else:
-                (embed_fn, stage_fn, stage_loss_fn), hook_opts = pipeline_hooks_for(
-                    cfg, model_cfg, policy, shift_labels=shift_labels
-                )
             stage_aux = bool(hook_opts.get("stage_aux"))
             aux_scale = float(hook_opts.get("aux_inv_layers", 0.0)) / nm
             needs_rng = bool(hook_opts.get("needs_rng"))
@@ -591,7 +531,8 @@ class Trainer:
                 or {}
             )
             pp_schedule = resolve_schedule(
-                pipe_knobs.get("schedule", "auto"), model_cfg,
+                pipe_knobs.get("schedule", "auto"),
+                family.manual_vjp_refusal(model_cfg),
                 {
                     "pipeline_model_parallel_size": pp,
                     "virtual_pipeline_model_parallel_size": vp,
@@ -638,21 +579,9 @@ class Trainer:
                 # train-step grads come from the manual-vjp tick loop (plain
                 # 1F1B, the circular interleave when vp > 1, or the ZB-H1
                 # dgrad/wgrad split); eval keeps the autodiff wavefront loss
-                # above (it only needs the forward value).  Family head
-                # dispatch: the gate currently admits llama/mistral only, but
-                # route by config type so re-admitting mixtral (its
-                # onef1b_head_hooks are already wired) needs nothing beyond
-                # flipping supports_1f1b.
-                from neuronx_distributed_training_tpu.models import (
-                    mixtral as _mixtral_m,
-                )
-
-                if isinstance(model_cfg, _mixtral_m.MixtralConfig):
-                    head_hooks = _mixtral_m.onef1b_head_hooks(model_cfg, policy)
-                else:
-                    head_hooks = llama.onef1b_head_hooks(model_cfg, policy)
+                # above (it only needs the forward value)
                 (head_hidden_fn, head_params_of, head_weight_of,
-                 fold_head_grads) = head_hooks
+                 fold_head_grads) = family.onef1b_head(model_cfg, policy)
 
                 def pp_loss_and_grad(p, batch, key):
                     mbs = microbatch_split(batch, nm)
@@ -945,13 +874,7 @@ class Trainer:
             # null = not known (the census's trace did not run); the census
             # fills in how the block was partitioned.  Absent = no such block
             run_facts["moe_token_shards"] = None
-        passes = getattr(model_cfg, "total_ut_steps", None)
-        if passes is not None:
-            # a stack applied several times with shared weights (models/ouro.py)
-            run_facts["loop_passes"] = int(passes)
-            run_facts["layer_applications_per_step"] = (
-                int(passes) * int(model_cfg.num_layers)
-                * int(sched["num_microbatches"]))
+        run_facts.update(model_cfg.family.run_facts(model_cfg, sched))
         # the manual-vjp schedules run the WORK-COMPACTED executor: record
         # its per-step tick counts (compacted span + per-kind active ticks
         # vs the old lockstep trip count) so the measured timelines are
@@ -1013,7 +936,7 @@ class Trainer:
         except Exception as e:  # noqa: BLE001 — observability, not load-bearing
             logger.warning("comms telemetry arming unavailable: %s", e)
         try:
-            fwd_flops = _perf.flops_for_model(model_cfg, seq_len)
+            fwd_flops = flops_for_model(model_cfg, seq_len)
             run_facts["fwd_flops_per_token"] = fwd_flops
             run_facts["peak_tflops_per_chip"] = peak_tflops
             if exp.telemetry.mfu:
@@ -2362,198 +2285,6 @@ class Trainer:
             if self.fault_injector.maybe_fire("save", self.step):
                 self.preemption_notice = (
                     "injected preemption notice (mid-save)")
-
-
-def build_model(cfg: ConfigDict, policy: DtypePolicy, *, shift_labels: bool = True):
-    """Model dispatch by ``model_source`` + architecture (reference
-    ``training.py:71-91`` selects Megatron vs HF modules the same way).
-
-    ``shift_labels=False`` when the data path pre-shifts on host (the Megatron
-    mmap convention).  Returns ``(model_cfg, loss_fn, init_fn, specs_fn)``.
-    """
-    source = str(cfg.get("model_source", "hf")).lower()
-    if source not in ("hf", "megatron"):
-        raise ValueError(f"unsupported model_source {source!r} (want 'hf' or 'megatron')")
-    model_block = dict(cfg.get("model", {}) or {})
-    ds_block = dict(cfg.get("distributed_strategy", {}) or {})
-    arch = str(model_block.get("architecture", model_block.get("model_type", "llama"))).lower()
-
-    if arch in ("llama", "mistral"):
-        mc = llama.LlamaConfig.from_config(model_block, ds_block)
-
-        if mc.attention_impl == "zigzag_ring":
-            # zig-zag CP layout: the loss hook permutes the batch (labels
-            # pre-shifted in ORIGINAL order — the in-model shift is
-            # order-dependent) and feeds matching RoPE positions; cp == 1
-            # makes both transforms the identity
-            from neuronx_distributed_training_tpu.parallel.ring_attention import (
-                zigzag_positions,
-                zigzag_transform_batch,
-            )
-
-            zz_cp = int(ds_block.get("context_parallel_size", 1) or 1)
-            if not shift_labels:
-                raise NotImplementedError(
-                    "zigzag_ring_attention with a pre-shifted data module "
-                    "(the zig-zag transform owns the label shift)"
-                )
-
-            def loss_fn(p, batch, key):
-                zb = zigzag_transform_batch(batch, zz_cp)
-                s = zb["input_ids"].shape[1]
-                pos = jnp.broadcast_to(
-                    zigzag_positions(s, zz_cp)[None, :], zb["input_ids"].shape
-                )
-                return llama.forward(
-                    p, zb, mc, policy, positions=pos, shift_labels=False
-                )
-
-        else:
-
-            def loss_fn(p, batch, key):
-                return llama.forward(p, batch, mc, policy, shift_labels=shift_labels)
-
-        return (
-            mc,
-            loss_fn,
-            lambda key: llama.init_params(key, mc, policy),
-            lambda **kw: llama.param_specs(mc, **kw),
-        )
-    if arch == "mixtral":
-        from neuronx_distributed_training_tpu.models import mixtral
-
-        xc = mixtral.MixtralConfig.from_config(model_block, ds_block)
-        if xc.llama.attention_impl == "zigzag_ring":
-            # the zig-zag batch/position transform is wired for the llama
-            # loss hook only; running the op on an unpermuted batch would
-            # silently corrupt the causal structure
-            raise NotImplementedError(
-                "zigzag_ring_attention is llama/mistral-only; use "
-                "fusions.ring_attention for mixtral"
-            )
-
-        def loss_fn(p, batch, key):
-            return mixtral.forward(p, batch, xc, policy, shift_labels=shift_labels)
-
-        return (
-            xc,
-            loss_fn,
-            lambda key: mixtral.init_params(key, xc, policy),
-            lambda **kw: mixtral.param_specs(xc, **kw),
-        )
-    if arch == "ouro":
-        from neuronx_distributed_training_tpu.models import ouro
-
-        oc = ouro.OuroConfig.from_config(model_block, ds_block)
-
-        def loss_fn(p, batch, key):
-            return ouro.forward(p, batch, oc, policy, shift_labels=shift_labels)
-
-        return (
-            oc,
-            loss_fn,
-            lambda key: ouro.init_params(key, oc, policy),
-            lambda **kw: ouro.param_specs(oc, **kw),
-        )
-    if arch == "gpt" or source == "megatron":
-        from neuronx_distributed_training_tpu.models import gpt
-
-        gc = gpt.GPTConfig.from_config(model_block, ds_block)
-
-        def loss_fn(p, batch, key):
-            return gpt.forward(p, batch, gc, policy, rng=key, shift_labels=shift_labels)
-
-        return (
-            gc,
-            loss_fn,
-            lambda key: gpt.init_params(key, gc, policy),
-            lambda **kw: gpt.param_specs(gc, **kw),
-        )
-    raise ValueError(f"unsupported model_source/architecture: {source}/{arch}")
-
-
-def _forward_logits_for(model_cfg: Any, policy: DtypePolicy):
-    """``(params, batch, rng=None) -> (logits, reg_loss)`` for any family —
-    the preference losses' policy forward.
-
-    ``reg_loss`` is the model's auxiliary regularizer (Mixtral/GPT-MoE router
-    load-balancing term; 0.0 for dense models) so preference training keeps
-    the same expert-balance pressure as the LM loss path.  ``rng`` threads
-    dropout for GPT policy forwards (None during the frozen reference pass).
-    """
-    if isinstance(model_cfg, llama.LlamaConfig):
-        if model_cfg.attention_impl == "zigzag_ring":
-            # preference batches are chosen/rejected sequences, not the
-            # zig-zag-permuted LM batches the layout expects
-            raise NotImplementedError(
-                "zigzag_ring_attention with preference alignment; use "
-                "fusions.ring_attention"
-            )
-
-        def fwd(p, b, rng=None):
-            logits, _ = llama.forward(
-                p, {"input_ids": b["input_ids"]}, model_cfg, policy)
-            return logits, 0.0
-
-        return fwd
-    from neuronx_distributed_training_tpu.models import gpt, mixtral
-
-    if isinstance(model_cfg, mixtral.MixtralConfig):
-        def fwd(p, b, rng=None):
-            logits, aux = mixtral.forward(
-                p, {"input_ids": b["input_ids"]}, model_cfg, policy)
-            return logits, aux["router_aux_loss"]
-
-        return fwd
-    if isinstance(model_cfg, gpt.GPTConfig):
-        def fwd(p, b, rng=None):
-            logits, aux = gpt.forward(
-                p, {"input_ids": b["input_ids"]}, model_cfg, policy, rng=rng)
-            return logits, aux.get("router_aux_loss", 0.0)
-
-        return fwd
-    raise NotImplementedError(
-        f"preference alignment not wired for {type(model_cfg).__name__}"
-    )
-
-
-def pipeline_hooks_for(cfg: ConfigDict, model_cfg: Any, policy: DtypePolicy,
-                       *, shift_labels: bool = True):
-    """Pipeline hooks dispatch -> ``((embed, stage, loss), opts)``.
-
-    ``opts``: ``stage_aux`` (stage returns ``(x, aux)``), ``aux_inv_layers``
-    (1/num_layers scale for the psum'd MoE router loss; the caller divides by
-    num_microbatches), ``needs_rng`` (thread per-microbatch dropout keys).
-    The reference pipelines every model source the same way
-    (``megatron_gpt_model.py:67-77`` sets ``transformer_layer_cls``).
-    """
-    if isinstance(model_cfg, llama.LlamaConfig):
-        return llama.pipeline_hooks(model_cfg, policy, shift_labels=shift_labels), {}
-    from neuronx_distributed_training_tpu.models import gpt, mixtral
-
-    if isinstance(model_cfg, mixtral.MixtralConfig):
-        return (
-            mixtral.pipeline_hooks(model_cfg, policy, shift_labels=shift_labels),
-            # normalized over the layers that HAVE routers (moe_frequency)
-            {"stage_aux": True,
-             "aux_inv_layers": 1.0 / mixtral.num_moe_layers(model_cfg)},
-        )
-    if isinstance(model_cfg, gpt.GPTConfig):
-        opts = {
-            "stage_aux": True,
-            # normalized over the layers that HAVE routers (moe_frequency)
-            "aux_inv_layers": (
-                1.0 / gpt.num_moe_layers(model_cfg)
-                if model_cfg.moe is not None else 0.0
-            ),
-            "needs_rng": (
-                model_cfg.hidden_dropout > 0.0 or model_cfg.embedding_dropout > 0.0
-            ),
-        }
-        return gpt.pipeline_hooks(model_cfg, policy, shift_labels=shift_labels), opts
-    raise NotImplementedError(
-        f"pipeline parallelism not wired for {type(model_cfg).__name__} yet"
-    )
 
 
 def assemble_step_program(cfg: ConfigDict, **kw: Any) -> StepProgram:

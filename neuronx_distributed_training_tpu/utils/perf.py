@@ -50,6 +50,32 @@ def detect_peak_tflops(device: jax.Device | None = None) -> float | None:
     )
 
 
+#: component keys of the per-token FLOPs breakdown, in reporting order
+FLOPS_COMPONENTS = ("attention", "mlp", "router", "head")
+
+
+def _attention_flops_per_token(
+    *, hidden_size: int, num_attention_heads: int, num_kv_heads: int | None,
+    seq_len: int, head_dim: int | None = None,
+    include_causal_half: bool = True,
+) -> float:
+    """Per-layer attention FLOPs/token: qkv + o projections + the causal
+    score/context matmuls — the Llama accounting with the MLP term removed.
+    Shared by every family's ``flops_breakdown`` (``models/family.py``)."""
+    h = hidden_size
+    d = head_dim or h // num_attention_heads
+    nh = num_attention_heads
+    nkv = num_kv_heads or nh
+    qkv = 2 * h * (nh + 2 * nkv) * d
+    o = 2 * nh * d * h
+    attn_scores = 2 * seq_len * nh * d
+    attn_context = 2 * seq_len * nh * d
+    if include_causal_half:
+        attn_scores /= 2
+        attn_context /= 2
+    return qkv + o + attn_scores + attn_context
+
+
 def llama_flops_per_token(
     *,
     num_layers: int,
@@ -70,23 +96,12 @@ def llama_flops_per_token(
     score/context term (causal masking skips half the work — flash kernels
     exploit this; the reference's estimate does the same).
     """
-    h = hidden_size
-    d = head_dim or h // num_attention_heads
-    nh = num_attention_heads
-    nkv = num_kv_heads or nh
-    s = seq_len
-
-    qkv = 2 * h * (nh + 2 * nkv) * d  # fused qkv proj
-    o = 2 * nh * d * h
-    attn_scores = 2 * s * nh * d  # q@k^T per token
-    attn_context = 2 * s * nh * d  # softmax@v per token
-    if include_causal_half:
-        attn_scores /= 2
-        attn_context /= 2
-    mlp = 2 * h * (3 * intermediate_size)  # gate, up, down
-    per_layer = qkv + o + attn_scores + attn_context + mlp
-    logits = 2 * h * vocab_size
-    return num_layers * per_layer + logits
+    attn = _attention_flops_per_token(
+        hidden_size=hidden_size, num_attention_heads=num_attention_heads,
+        num_kv_heads=num_kv_heads, seq_len=seq_len, head_dim=head_dim,
+        include_causal_half=include_causal_half)
+    mlp = 2 * hidden_size * (3 * intermediate_size)  # gate, up, down
+    return num_layers * (attn + mlp) + 2 * hidden_size * vocab_size
 
 
 def train_step_flops_per_token(fwd_flops_per_token: float) -> float:
@@ -155,122 +170,3 @@ def flops_for_config(model_cfg: Any, seq_len: int) -> float:
         seq_len=seq_len,
         head_dim=getattr(model_cfg, "head_dim", None),
     )
-
-
-#: component keys of the per-token FLOPs breakdown, in reporting order
-FLOPS_COMPONENTS = ("attention", "mlp", "router", "head")
-
-
-def _attention_flops_per_token(
-    *, hidden_size: int, num_attention_heads: int, num_kv_heads: int | None,
-    seq_len: int, head_dim: int | None = None,
-    include_causal_half: bool = True,
-) -> float:
-    """Per-layer attention FLOPs/token: qkv + o projections + the causal
-    score/context matmuls — the Llama accounting with the MLP term removed."""
-    h = hidden_size
-    d = head_dim or h // num_attention_heads
-    nh = num_attention_heads
-    nkv = num_kv_heads or nh
-    qkv = 2 * h * (nh + 2 * nkv) * d
-    o = 2 * nh * d * h
-    attn_scores = 2 * seq_len * nh * d
-    attn_context = 2 * seq_len * nh * d
-    if include_causal_half:
-        attn_scores /= 2
-        attn_context /= 2
-    return qkv + o + attn_scores + attn_context
-
-
-def flops_breakdown_for_model(model_cfg: Any, seq_len: int) -> dict[str, float]:
-    """Per-component fwd FLOPs/token for ANY supported family:
-    ``{attention, mlp, router, head}`` (``FLOPS_COMPONENTS``).
-
-    The autotune cost model consumes this shape directly (each component
-    scales differently under tp/cp/remat); ``flops_for_model`` is exactly its
-    sum, so the scalar and the breakdown cannot drift apart.  Conventions are
-    the MFU ones: mixtral/GPT-MoE count only ACTIVATED expert FLOPs + the
-    router matmul; GPT honors its GLU-vs-plain activation; causal masking
-    halves the score/context term.
-    """
-    from neuronx_distributed_training_tpu.models import gpt as _gpt
-    from neuronx_distributed_training_tpu.models import mixtral as _mx
-
-    if isinstance(model_cfg, _mx.MixtralConfig):
-        lc = model_cfg.llama
-        attn = lc.num_layers * _attention_flops_per_token(
-            hidden_size=lc.hidden_size,
-            num_attention_heads=lc.num_attention_heads,
-            num_kv_heads=lc.num_kv_heads,
-            seq_len=seq_len,
-            head_dim=getattr(lc, "head_dim", None),
-        )
-        n_moe = _mx.num_moe_layers(model_cfg)
-        n_dense = lc.num_layers - n_moe
-        swiglu = 2 * lc.hidden_size * 3 * lc.intermediate_size
-        router = 2 * lc.hidden_size * model_cfg.moe.num_experts
-        return {
-            "attention": attn,
-            "mlp": n_dense * swiglu + n_moe * model_cfg.moe.top_k * swiglu,
-            "router": float(n_moe * router),
-            "head": 2.0 * lc.hidden_size * lc.vocab_size,
-        }
-    if isinstance(model_cfg, _gpt.GPTConfig):
-        attn = model_cfg.num_layers * _attention_flops_per_token(
-            hidden_size=model_cfg.hidden_size,
-            num_attention_heads=model_cfg.num_attention_heads,
-            num_kv_heads=model_cfg.kv_heads,
-            seq_len=seq_len,
-            head_dim=model_cfg.head_size,
-        )
-        matmuls = 3 if model_cfg.is_glu else 2  # (gate,) up, down
-        mlp = 2 * model_cfg.hidden_size * matmuls * model_cfg.ffn_size
-        head = 2.0 * model_cfg.hidden_size * model_cfg.vocab_size
-        if model_cfg.moe is not None:
-            n_moe = _gpt.num_moe_layers(model_cfg)
-            n_dense = model_cfg.num_layers - n_moe
-            router = 2 * model_cfg.hidden_size * model_cfg.moe.num_experts
-            return {
-                "attention": attn,
-                "mlp": n_dense * mlp + n_moe * model_cfg.moe.top_k * mlp,
-                "router": float(n_moe * router),
-                "head": head,
-            }
-        return {
-            "attention": attn,
-            "mlp": float(model_cfg.num_layers * mlp),
-            "router": 0.0,
-            "head": head,
-        }
-    # llama/mistral (and anything exposing the same shape attributes); a stack
-    # applied several times (models/ouro.py) multiplies its work, heads included
-    passes = int(getattr(model_cfg, "total_ut_steps", 1) or 1)
-    attn = passes * model_cfg.num_layers * _attention_flops_per_token(
-        hidden_size=model_cfg.hidden_size,
-        num_attention_heads=model_cfg.num_attention_heads,
-        num_kv_heads=getattr(model_cfg, "num_kv_heads", None),
-        seq_len=seq_len,
-        head_dim=getattr(model_cfg, "head_dim", None),
-    )
-    mlp = 2 * model_cfg.hidden_size * 3 * model_cfg.intermediate_size
-    return {
-        "attention": attn,
-        "mlp": float(passes * model_cfg.num_layers * mlp),
-        "router": 0.0,
-        "head": 2.0 * passes * model_cfg.hidden_size * model_cfg.vocab_size,
-    }
-
-
-def flops_for_model(model_cfg: Any, seq_len: int) -> float:
-    """fwd FLOPs/token for ANY supported model family — the MFU dispatch.
-
-    llama/mistral use the Llama accounting directly; mixtral swaps the dense
-    MLP term for top-k routed experts + the router matmul on its MoE layers;
-    megatron GPT swaps SwiGLU for its configured activation (GLU: 3 matmuls,
-    plain: 2) and honors optional MoE.  Only ACTIVATED expert FLOPs count —
-    MFU measures useful work per token, and an unrouted expert does none.
-
-    The scalar IS the sum of ``flops_breakdown_for_model`` — one accounting,
-    two granularities.
-    """
-    return float(sum(flops_breakdown_for_model(model_cfg, seq_len).values()))

@@ -146,7 +146,8 @@ class TestSpaceLegality:
 
 
 class TestScheduleGate:
-    """supports_1f1b is the one source of truth the lattice honors."""
+    """supports_1f1b, asked with the family's answer, is the one source of
+    truth the lattice honors."""
 
     def test_llama_gets_the_manual_vjp_family(self):
         facts = ModelFacts.from_config(load_config(tiny_raw()))
@@ -340,7 +341,7 @@ class TestCostModel:
 
 class TestFlopsBreakdown:
     def test_gpt_with_moe_breakdown_sums_to_total(self):
-        from neuronx_distributed_training_tpu.models import gpt
+        from neuronx_distributed_training_tpu.models import family, gpt
         from neuronx_distributed_training_tpu.utils import perf
 
         gc = gpt.GPTConfig.from_config({
@@ -349,31 +350,30 @@ class TestFlopsBreakdown:
             "vocab_size": 512, "activation": "swiglu",
             "moe": {"num_experts": 4, "top_k": 2},
         }, {})
-        bd = perf.flops_breakdown_for_model(gc, 128)
+        bd = family.flops_breakdown_for_model(gc, 128)
         assert set(bd) == set(perf.FLOPS_COMPONENTS)
         assert bd["router"] > 0, "MoE GPT must have a router term"
         assert sum(bd.values()) == pytest.approx(
-            perf.flops_for_model(gc, 128), rel=1e-12)
+            family.flops_for_model(gc, 128), rel=1e-12)
 
     def test_llama_breakdown_matches_legacy_scalar(self):
-        from neuronx_distributed_training_tpu.models import llama
+        from neuronx_distributed_training_tpu.models import family, llama
         from neuronx_distributed_training_tpu.utils import perf
 
         lc = llama.LlamaConfig(
             vocab_size=128256, hidden_size=4096, intermediate_size=14336,
             num_layers=32, num_attention_heads=32, num_kv_heads=8)
-        bd = perf.flops_breakdown_for_model(lc, 8192)
+        bd = family.flops_breakdown_for_model(lc, 8192)
         legacy = perf.llama_flops_per_token(
             num_layers=32, hidden_size=4096, intermediate_size=14336,
             num_attention_heads=32, num_kv_heads=8, vocab_size=128256,
             seq_len=8192)
         assert sum(bd.values()) == pytest.approx(legacy, rel=1e-12)
-        assert perf.flops_for_model(lc, 8192) == pytest.approx(legacy,
+        assert family.flops_for_model(lc, 8192) == pytest.approx(legacy,
                                                               rel=1e-12)
 
     def test_mixtral_counts_activated_experts_only(self):
-        from neuronx_distributed_training_tpu.models import mixtral
-        from neuronx_distributed_training_tpu.utils import perf
+        from neuronx_distributed_training_tpu.models import family, mixtral
 
         mc = mixtral.MixtralConfig.from_config({
             "vocab_size": 512, "hidden_size": 64, "intermediate_size": 176,
@@ -381,12 +381,12 @@ class TestFlopsBreakdown:
             "num_key_value_heads": 4,
             "moe": {"num_experts": 8, "top_k": 2},
         }, {})
-        bd = perf.flops_breakdown_for_model(mc, 128)
+        bd = family.flops_breakdown_for_model(mc, 128)
         # 2 activated of 8 experts: the mlp term prices top_k, not E
         swiglu = 2 * 64 * 3 * 176
         assert bd["mlp"] == pytest.approx(4 * 2 * swiglu)
         assert sum(bd.values()) == pytest.approx(
-            perf.flops_for_model(mc, 128), rel=1e-12)
+            family.flops_for_model(mc, 128), rel=1e-12)
 
 
 # ---------------------------------------------------------------------------

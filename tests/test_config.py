@@ -125,14 +125,13 @@ def test_all_shipped_configs_load_and_build():
         batch_schedule,
         load_config,
     )
-    from neuronx_distributed_training_tpu.trainer.loop import build_model
-    from neuronx_distributed_training_tpu.utils.dtypes import DtypePolicy
+    from neuronx_distributed_training_tpu.models.family import resolve
 
     configs = sorted(glob.glob("examples/conf/*.yaml"))
     assert len(configs) >= 20  # parity-class config pack
     for path in configs:
         cfg = load_config(path)
-        model_cfg, loss_fn, init_fn, specs_fn = build_model(cfg, DtypePolicy())
+        family, model_cfg = resolve(cfg)
         assert model_cfg.num_layers > 0, path
         ds = dict(cfg.get("distributed_strategy", {}) or {})
         n_needed = (int(ds.get("tensor_model_parallel_size", 1))
@@ -141,7 +140,7 @@ def test_all_shipped_configs_load_and_build():
         sched = batch_schedule(cfg, n_needed)
         assert sched["num_microbatches"] >= 1, path
         # specs build without touching devices
-        specs = specs_fn()
+        specs = family.param_specs(model_cfg)
         assert "layers" in specs, path
 
 

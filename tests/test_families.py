@@ -1,0 +1,358 @@
+"""The family records (``models/family.py``): every place that used to ask
+"which model family is this, and what can it do?" now asks one record, so the
+records are held here, family by family and capability by capability:
+
+1. every example config names a family whose ``config_from`` takes its blocks;
+2. each capability works at toy size or refuses in a sentence that names the
+   family and the capability;
+3. the FLOPs scalar is the sum of the family's breakdown;
+4. the arrows point one way: nothing under ``parallel/``, ``ops/``, ``optim/``,
+   ``utils/`` imports ``models``, and ``trainer/``, ``autotune/``, ``config/``
+   name no family's config class;
+5. a fifth family is its file and one entry: a toy one defined here trains
+   through ``Trainer.from_config(...).fit()`` with nothing else patched.
+"""
+
+import ast
+import dataclasses
+import functools
+import glob
+import json
+import re
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from neuronx_distributed_training_tpu.config.loader import load_config
+from neuronx_distributed_training_tpu.models import family as fam_mod
+from neuronx_distributed_training_tpu.models.family import (
+    FAMILIES,
+    Family,
+    Refused,
+    flops_breakdown_for_model,
+    flops_for_model,
+    resolve,
+)
+from neuronx_distributed_training_tpu.utils import perf
+from neuronx_distributed_training_tpu.utils.dtypes import DtypePolicy
+
+PKG = Path(fam_mod.__file__).resolve().parents[1]
+FP32 = DtypePolicy(param_dtype=jnp.float32, compute_dtype=jnp.float32,
+                   softmax_dtype=jnp.float32)
+FAMILY_NAMES = sorted(set(FAMILIES.values()))  # the modules under models/
+
+TOY_MODEL = {
+    "vocab_size": 96, "hidden_size": 32, "intermediate_size": 64,
+    "ffn_hidden_size": 64, "num_layers": 2, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "max_position_embeddings": 16,
+    "activations_checkpoint_granularity": None,
+}
+
+
+@functools.lru_cache(maxsize=None)
+def toy(name):
+    """``(family, cfg, params, batch)`` at toy size; the MoE family gets
+    experts, the looped one two passes."""
+    extra = {"mixtral": {"moe": {"num_experts": 4, "top_k": 2, "dropless": True}},
+             "ouro": {"total_ut_steps": 2}}.get(name, {})
+    family, cfg = resolve({"model": {**TOY_MODEL, "architecture": name, **extra}})
+    params = family.init_params(jax.random.PRNGKey(0), cfg, FP32)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 96)
+    return family, cfg, params, {"input_ids": ids, "labels": ids}
+
+
+# -- 1. the example configs ---------------------------------------------------
+
+
+@pytest.mark.parametrize("path", sorted(glob.glob("examples/conf/*.yaml")),
+                         ids=lambda p: Path(p).stem)
+def test_example_config_names_a_family_that_takes_its_blocks(path):
+    cfg = load_config(path)
+    family, model_cfg = resolve(cfg)
+    assert model_cfg.family is family and family.name in FAMILY_NAMES
+    assert model_cfg.num_layers > 0 and model_cfg.vocab_size > 0
+    shape = family.plan_shape(model_cfg)
+    assert shape["num_layers"] == model_cfg.num_layers and shape["hidden"] > 0
+
+
+def test_nobody_answers_to_an_unregistered_architecture():
+    with pytest.raises(ValueError, match="unsupported model_source/architecture: hf/rwkv"):
+        resolve({"model": {"architecture": "rwkv"}})
+    with pytest.raises(ValueError, match="unsupported model_source 'onnx'"):
+        resolve({"model_source": "onnx"})
+    # what ``model_source: megatron`` leaves unnamed is Megatron's GPT
+    assert resolve({"model_source": "megatron", "model": {"architecture": "bert"}})[0].name == "gpt"
+
+
+# -- 2. family x capability: works at toy size, or says why not ----------------
+
+
+def _lm_loss(family, cfg, params, batch):
+    """The plain loss without the router's term: what the pipeline's hooks sum."""
+    loss, aux = family.loss(cfg, FP32)(params, batch, None)
+    return loss - aux.get("router_aux_loss", 0.0)
+
+
+def _works_logits(family, cfg, params, batch):
+    logits, reg = family.logits(cfg, FP32)(params, batch)
+    assert logits.shape == (2, 16, 96) and np.isfinite(float(jnp.sum(logits)))
+    assert float(reg) >= 0.0
+
+
+def _works_pipeline(family, cfg, params, batch):
+    # one stage holding every layer is the whole model: embed -> stage -> loss
+    # gives the plain loss, with the options saying what the stage returns
+    (embed, stage, loss), opts = family.pipeline(cfg, FP32)
+    y = stage(params["layers"], embed(params, batch), batch)
+    if opts.get("stage_aux"):
+        y, aux = y
+        assert aux.shape == () and opts["aux_inv_layers"] >= 0.0
+    loss_sum, valid = loss(params, y, batch)
+    np.testing.assert_allclose(float(loss_sum / valid),
+                               float(_lm_loss(family, cfg, params, batch)), rtol=1e-5)
+
+
+def _works_onef1b_head(family, cfg, params, batch):
+    hidden_fn, params_of, weight_of, fold = family.onef1b_head(cfg, FP32)
+    y = jax.random.normal(jax.random.PRNGKey(2), (2, 16, 32))
+    logits = hidden_fn(params_of(params), y) @ weight_of(params).T
+    np.testing.assert_allclose(np.asarray(logits),
+                               np.asarray(family.head(cfg, FP32)(params, y)), rtol=1e-5)
+    assert family.manual_vjp_refusal(cfg) is None
+
+
+def _works_decode(family, cfg, params, batch):
+    # prefill's hidden states (final norm applied) under the family's head are
+    # the training forward's logits
+    prefill, decode_step = family.decode()
+    h, cache = jax.jit(lambda p, ids: prefill(p, ids, cfg, FP32, max_len=20))(
+        params, batch["input_ids"])
+    logits = family.head(cfg, FP32, norm=False)(params, h)
+    want, _ = family.logits(cfg, FP32)(params, batch)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want), atol=2e-4)
+    assert callable(decode_step) and cache["k"].shape[-3] == 20
+
+
+#: capability -> (what a refusal has to name besides the family, the check at toy size)
+CAPABILITIES = {
+    "logits": ("preference alignment", _works_logits),
+    "pipeline": ("pipeline parallelism", _works_pipeline),
+    "onef1b_head": ("1f1b|manual-vjp", _works_onef1b_head),
+    "decode": ("cached decode", _works_decode),
+}
+
+
+@pytest.mark.parametrize("capability", sorted(CAPABILITIES))
+@pytest.mark.parametrize("name", FAMILY_NAMES)
+def test_capability_works_or_refuses_by_name(name, capability):
+    family, cfg, params, batch = toy(name)
+    entry = getattr(family, capability)
+    words, works = CAPABILITIES[capability]
+    if not isinstance(entry, Refused):
+        works(family, cfg, params, batch)
+        return
+    args = () if capability == "decode" else (cfg, FP32)
+    with pytest.raises(NotImplementedError) as refusal:
+        entry(*args)
+    sentence = str(refusal.value)
+    assert sentence == entry.sentence
+    assert family.name in sentence.lower(), sentence
+    assert re.search(words, sentence), sentence
+    if capability == "onef1b_head":
+        assert family.manual_vjp_refusal(cfg) == sentence
+
+
+def test_what_each_family_cannot_do():
+    """The table of ``docs/parallelism.md`` ("Model families"), as the records
+    have it."""
+    refused = {name: sorted(c for c in CAPABILITIES
+                            if isinstance(getattr(toy(name)[0], c), Refused))
+               for name in FAMILY_NAMES}
+    assert refused == {
+        "llama": [], "gpt": ["onef1b_head"], "mixtral": ["onef1b_head"],
+        "ouro": ["decode", "logits", "onef1b_head", "pipeline"],
+    }
+
+
+def test_llama_refuses_the_zigzag_layout_where_it_is_not_wired():
+    family, cfg, _, _ = toy("llama")
+    zz = dataclasses.replace(cfg, attention_impl="zigzag_ring")
+    for capability in ("logits", "pipeline", "onef1b_head"):
+        with pytest.raises(NotImplementedError, match="zigzag_ring_attention"):
+            getattr(family, capability)(zz, FP32)
+    assert "zigzag" in family.manual_vjp_refusal(zz)
+    with pytest.raises(NotImplementedError, match="pre-shifted"):
+        family.loss(zz, FP32, shift_labels=False)
+    xfam, xcfg, _, _ = toy("mixtral")
+    with pytest.raises(NotImplementedError, match="llama/mistral-only"):
+        xfam.loss(dataclasses.replace(xcfg, llama=zz), FP32)
+
+
+def test_the_pipeline_slices_whole_moe_groups():
+    for name, model in [("mixtral", {}), ("gpt", {"activation": "swiglu"})]:
+        moe = {"num_experts": 4, "top_k": 2, "frequency": 2}
+        family, cfg = resolve({"model": {**TOY_MODEL, "architecture": name,
+                                         "num_layers": 4, "moe": moe, **model}})
+        assert family.moe_groups(cfg) == 2
+    assert all(toy(name)[0].moe_groups(toy(name)[1]) is None for name in FAMILY_NAMES)
+
+
+# -- 3. one FLOPs accounting, two granularities --------------------------------
+
+
+@pytest.mark.parametrize("name", FAMILY_NAMES)
+def test_flops_scalar_is_the_sum_of_the_breakdown(name):
+    family, cfg, _, _ = toy(name)
+    bd = flops_breakdown_for_model(cfg, 128)
+    assert tuple(bd) == perf.FLOPS_COMPONENTS and bd == family.flops_breakdown(cfg, 128)
+    assert all(v >= 0 for v in bd.values()) and bd["attention"] > 0 and bd["head"] > 0
+    assert flops_for_model(cfg, 128) == pytest.approx(sum(bd.values()), rel=1e-12)
+    assert (bd["router"] > 0) == (name == "mixtral")
+
+
+def test_the_looped_stack_multiplies_its_work():
+    _, ocfg, _, _ = toy("ouro")
+    assert flops_for_model(ocfg, 128) == 2 * flops_for_model(ocfg.llama, 128)
+    assert ocfg.family.run_facts(ocfg, {"num_microbatches": 3}) == {
+        "loop_passes": 2, "layer_applications_per_step": 12}
+
+
+# -- 4. the arrows point one way ----------------------------------------------
+
+
+def _imports(path):
+    """Every module name a file imports, absolute."""
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            yield from (a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            yield node.module
+            yield from (f"{node.module}.{a.name}" for a in node.names)
+
+
+@pytest.mark.parametrize("package", ["parallel", "ops", "optim", "utils"])
+def test_lower_layers_import_nothing_from_models(package):
+    files = sorted((PKG / package).rglob("*.py"))
+    assert files
+    for path in files:
+        up = [m for m in _imports(path) if re.search(r"(^|\.)models(\.|$)", m)]
+        assert not up, f"{path.relative_to(PKG)} imports {up}"
+
+
+@pytest.mark.parametrize("package", ["trainer", "autotune", "config"])
+def test_callers_name_no_familys_config_class(package):
+    classes = {type(toy(name)[1]).__name__ for name in FAMILY_NAMES}
+    assert classes == {"LlamaConfig", "MixtralConfig", "GPTConfig", "OuroConfig"}
+    for path in sorted((PKG / package).rglob("*.py")):
+        names = {getattr(n, "id", None) or getattr(n, "attr", None)
+                 for n in ast.walk(ast.parse(path.read_text()))}
+        assert not names & classes, f"{path.relative_to(PKG)} names {names & classes}"
+        family_modules = [m for m in _imports(path)
+                          if re.search(r"\.models\.(llama|mixtral|gpt|ouro)$", m)]
+        assert not family_modules, f"{path.relative_to(PKG)} imports {family_modules}"
+
+
+def test_a_llama_run_imports_no_other_family():
+    code = ("import sys; from neuronx_distributed_training_tpu.models.family import resolve; "
+            "resolve({'model': {'architecture': 'mistral'}}); "
+            "print(sorted(m.rsplit('.', 1)[1] for m in sys.modules if '.models.' in m))")
+    import subprocess
+    import sys
+
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         check=True, env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
+                                          "PYTHONPATH": str(PKG.parent)})
+    assert json.loads(out.stdout.strip().replace("'", '"')) == ["family", "llama"]
+
+
+# -- 5. a fifth family is its file and one entry --------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class BagConfig:
+    """A bag-of-one-token "language model": embedding, then a head."""
+
+    vocab_size: int = 64
+    hidden_size: int = 16
+    num_layers: int = 1
+
+    @property
+    def family(self):
+        return BAG
+
+
+def _bag_loss(cfg, policy, *, shift_labels=True):
+    def loss_fn(params, batch, key):
+        x = params["embed"]["embedding"][batch["input_ids"]].astype(policy.compute_dtype)
+        logits = (x @ params["lm_head"]["w"].astype(policy.compute_dtype)).astype(jnp.float32)
+        labels = batch["labels"]
+        if shift_labels:
+            logits, labels = logits[:, :-1], labels[:, 1:]
+        nll = -jnp.take_along_axis(jax.nn.log_softmax(logits), labels[..., None], axis=-1)
+        return jnp.mean(nll), {}
+
+    return loss_fn
+
+
+def _bag_init(key, cfg, policy):
+    ke, kh = jax.random.split(key)
+    shape = (cfg.vocab_size, cfg.hidden_size)
+    return {"embed": {"embedding": 0.02 * jax.random.normal(ke, shape, policy.param_dtype)},
+            "lm_head": {"w": 0.02 * jax.random.normal(kh, shape[::-1], policy.param_dtype)}}
+
+
+def _not_wired(what):
+    return Refused(f"{what} not wired for BagConfig")
+
+
+BAG = Family(
+    name="bag",
+    config_from=lambda model, ds: BagConfig(vocab_size=int(model["vocab_size"]),
+                                            hidden_size=int(model["hidden_size"])),
+    loss=_bag_loss,
+    init_params=_bag_init,
+    param_specs=lambda cfg, *, pipeline=False: {
+        "embed": {"embedding": P(None, None)}, "lm_head": {"w": P(None, None)}},
+    flops_breakdown=lambda cfg, seq_len: {
+        "attention": 0.0, "mlp": 0.0, "router": 0.0,
+        "head": 2.0 * cfg.hidden_size * cfg.vocab_size},
+    plan_shape=lambda cfg: {
+        "num_layers": 1, "num_heads": 1, "num_kv_heads": 1, "head_dim": cfg.hidden_size,
+        "hidden": cfg.hidden_size, "ffn": cfg.hidden_size, "vocab": cfg.vocab_size,
+        "tied_embeddings": False},
+    logits=_not_wired("preference alignment"), head=_not_wired("a head"),
+    pipeline=_not_wired("pipeline parallelism"),
+    onef1b_head=_not_wired("the manual-vjp schedules' head"),
+    decode=_not_wired("cached decode"),
+)
+
+
+def test_a_fifth_family_trains_through_the_trainer(tmp_path, monkeypatch, devices8):
+    from neuronx_distributed_training_tpu.trainer.loop import Trainer
+
+    monkeypatch.setitem(FAMILIES, "bag", BAG)
+    cfg = load_config({
+        "name": "bag", "seed": 3,
+        "trainer": {"max_steps": 2, "log_every_n_steps": 1},
+        "exp_manager": {"exp_dir": str(tmp_path / "exp"), "create_checkpoint_callback": False,
+                        "create_tensorboard_logger": False},
+        "data": {"global_batch_size": 8, "micro_batch_size": 1, "seq_length": 16,
+                 "synthetic": True},
+        "model": {"architecture": "bag", "vocab_size": 64, "hidden_size": 16,
+                  "optim": {"name": "adamw", "lr": 1e-2}},
+        "precision": {"type": "mixed_precision"},
+    })
+    trainer = Trainer.from_config(cfg, enable_checkpointing=False)
+    assert isinstance(trainer.model_cfg, BagConfig)
+    metrics = trainer.fit()
+    assert metrics["consumed_samples"] == 16
+    assert np.isfinite(metrics["loss"]) and abs(metrics["loss"] - np.log(64)) < 0.5
+    summary = json.loads(next((tmp_path / "exp").rglob("run_summary.json")).read_text())
+    assert summary["model_family"] == "BagConfig"
+    assert summary["fwd_flops_per_token"] == 2.0 * 16 * 64
+    with pytest.raises(NotImplementedError, match="pipeline parallelism not wired for BagConfig"):
+        BAG.pipeline(trainer.model_cfg, FP32)

@@ -312,13 +312,10 @@ def test_the_model_refuses_with_the_loaders_words():
 
 
 def test_cached_decode_and_preference_alignment_refuse_the_loop():
-    from neuronx_distributed_training_tpu.models import decode
-    from neuronx_distributed_training_tpu.trainer.loop import _forward_logits_for
-
     with pytest.raises(NotImplementedError, match="ouro.*KV cache"):
-        decode._family(config())
+        ouro.FAMILY.decode()
     with pytest.raises(NotImplementedError, match="OuroConfig"):
-        _forward_logits_for(config(), MIXED)
+        ouro.FAMILY.logits(config(), MIXED)
 
 
 # -- through nxdt-train -------------------------------------------------------

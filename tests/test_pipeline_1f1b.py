@@ -61,6 +61,11 @@ GRAD_PATHS = (
 )
 
 
+def _says(model_cfg):
+    """The model's half of the gate: its family's answer (None: it can)."""
+    return model_cfg.family.manual_vjp_refusal(model_cfg)
+
+
 def _pcfg(pp=2, vp=1, alignment=None, lora=False):
     return {
         "pipeline_model_parallel_size": pp,
@@ -135,63 +140,63 @@ class TestSupports1F1B:
     """The schedule gate, combination by combination."""
 
     def test_llama_pp2_supported(self):
-        ok, reason = supports_1f1b(CFG, _pcfg(pp=2))
+        ok, reason = supports_1f1b(_says(CFG), _pcfg(pp=2))
         assert ok, reason
 
     def test_pp1_unsupported(self):
-        ok, reason = supports_1f1b(CFG, _pcfg(pp=1))
+        ok, reason = supports_1f1b(_says(CFG), _pcfg(pp=1))
         assert not ok and "pipeline_model_parallel_size" in reason
 
     def test_plain_1f1b_rejects_vp_naming_interleaved(self):
         """The vp>1 message points at the interleaved schedule now — not at
         the autodiff wavefront (satellite: stale-message fix)."""
-        ok, reason = supports_1f1b(CFG, _pcfg(pp=2, vp=2))
+        ok, reason = supports_1f1b(_says(CFG), _pcfg(pp=2, vp=2))
         assert not ok and "1f1b-interleaved" in reason
         assert "wavefront" not in reason
 
     def test_interleaved_supported_with_vp(self):
-        ok, reason = supports_1f1b(CFG, _pcfg(pp=2, vp=2),
+        ok, reason = supports_1f1b(_says(CFG), _pcfg(pp=2, vp=2),
                                    "1f1b-interleaved")
         assert ok, reason
 
     def test_interleaved_needs_vp(self):
-        ok, reason = supports_1f1b(CFG, _pcfg(pp=2), "1f1b-interleaved")
+        ok, reason = supports_1f1b(_says(CFG), _pcfg(pp=2), "1f1b-interleaved")
         assert not ok and "nothing to interleave" in reason
 
     def test_zb_supported_at_vp1_only(self):
-        ok, reason = supports_1f1b(CFG, _pcfg(pp=2), "1f1b-zb")
+        ok, reason = supports_1f1b(_says(CFG), _pcfg(pp=2), "1f1b-zb")
         assert ok, reason
-        ok, reason = supports_1f1b(CFG, _pcfg(pp=2, vp=2), "1f1b-zb")
+        ok, reason = supports_1f1b(_says(CFG), _pcfg(pp=2, vp=2), "1f1b-zb")
         assert not ok and "1f1b-interleaved" in reason
 
     @pytest.mark.parametrize("sched", MANUAL_VJP_SCHEDULES)
     def test_cp_blocks_every_manual_vjp_schedule(self, sched):
         pcfg = dict(_pcfg(pp=2, vp=2 if sched == "1f1b-interleaved" else 1),
                     context_parallel_size=2)
-        ok, reason = supports_1f1b(CFG, pcfg, sched)
+        ok, reason = supports_1f1b(_says(CFG), pcfg, sched)
         assert not ok and "context" in reason
 
     def test_non_manual_schedule_rejected_by_gate(self):
         with pytest.raises(ValueError, match="manual-vjp"):
-            supports_1f1b(CFG, _pcfg(pp=2), "wavefront")
+            supports_1f1b(_says(CFG), _pcfg(pp=2), "wavefront")
 
     def test_cp_unsupported(self):
         pcfg = dict(_pcfg(pp=2), context_parallel_size=2)
-        ok, reason = supports_1f1b(CFG, pcfg)
+        ok, reason = supports_1f1b(_says(CFG), pcfg)
         assert not ok and "context" in reason
-        assert resolve_schedule("auto", CFG, pcfg) == "wavefront"
+        assert resolve_schedule("auto", _says(CFG), pcfg) == "wavefront"
 
     @pytest.mark.parametrize("alignment", ["dpo", "orpo", "kto"])
     def test_preference_alignment_unsupported(self, alignment):
-        ok, reason = supports_1f1b(CFG, _pcfg(pp=2, alignment=alignment))
+        ok, reason = supports_1f1b(_says(CFG), _pcfg(pp=2, alignment=alignment))
         assert not ok and alignment in reason
 
     def test_sft_alignment_supported(self):
-        ok, _ = supports_1f1b(CFG, _pcfg(pp=2, alignment="sft"))
+        ok, _ = supports_1f1b(_says(CFG), _pcfg(pp=2, alignment="sft"))
         assert ok
 
     def test_lora_unsupported(self):
-        ok, reason = supports_1f1b(CFG, _pcfg(pp=2, lora=True))
+        ok, reason = supports_1f1b(_says(CFG), _pcfg(pp=2, lora=True))
         assert not ok and "LoRA" in reason
 
     def test_gpt_unsupported(self):
@@ -199,7 +204,7 @@ class TestSupports1F1B:
 
         gc = gpt.GPTConfig(vocab_size=128, hidden_size=32, num_layers=4,
                            num_attention_heads=4, max_position_embeddings=32)
-        ok, reason = supports_1f1b(gc, _pcfg(pp=2))
+        ok, reason = supports_1f1b(_says(gc), _pcfg(pp=2))
         assert not ok and "GPTConfig" in reason
 
     def test_mixtral_unsupported_keeps_wavefront(self):
@@ -217,67 +222,67 @@ class TestSupports1F1B:
             llama=dataclasses.replace(CFG),
             moe=moe_ops.MoEConfig(num_experts=4, top_k=2, dropless=True),
         )
-        ok, reason = supports_1f1b(xc, _pcfg(pp=2))
+        ok, reason = supports_1f1b(_says(xc), _pcfg(pp=2))
         assert not ok and "mixtral" in reason
-        assert resolve_schedule("auto", xc, _pcfg(pp=2)) == "wavefront"
+        assert resolve_schedule("auto", _says(xc), _pcfg(pp=2)) == "wavefront"
         with pytest.raises(ValueError, match="mixtral"):
-            resolve_schedule("1f1b", xc, _pcfg(pp=2))
+            resolve_schedule("1f1b", _says(xc), _pcfg(pp=2))
 
     def test_zigzag_unsupported(self):
         import dataclasses
 
         zz = dataclasses.replace(CFG, attention_impl="zigzag_ring")
-        ok, reason = supports_1f1b(zz, _pcfg(pp=2))
+        ok, reason = supports_1f1b(_says(zz), _pcfg(pp=2))
         assert not ok and "zigzag" in reason
 
 
 class TestResolveSchedule:
     def test_auto_picks_1f1b_when_supported(self):
-        assert resolve_schedule("auto", CFG, _pcfg(pp=2)) == "1f1b"
+        assert resolve_schedule("auto", _says(CFG), _pcfg(pp=2)) == "1f1b"
 
     def test_auto_picks_interleaved_under_vp(self):
-        assert resolve_schedule("auto", CFG, _pcfg(pp=2, vp=2)) \
+        assert resolve_schedule("auto", _says(CFG), _pcfg(pp=2, vp=2)) \
             == "1f1b-interleaved"
 
     def test_auto_falls_back_to_wavefront(self):
         pcfg = dict(_pcfg(pp=2, vp=2), context_parallel_size=2)
-        assert resolve_schedule("auto", CFG, pcfg) == "wavefront"
+        assert resolve_schedule("auto", _says(CFG), pcfg) == "wavefront"
 
     def test_auto_never_picks_zb(self):
         """zb trades recompute for bubble — a per-plan call the autotune
         cost model prices; auto stays on the no-extra-compute default."""
-        assert resolve_schedule("auto", CFG, _pcfg(pp=2)) == "1f1b"
+        assert resolve_schedule("auto", _says(CFG), _pcfg(pp=2)) == "1f1b"
 
     def test_forced_interleaved_and_zb(self):
-        assert resolve_schedule("1f1b-interleaved", CFG, _pcfg(pp=2, vp=2)) \
+        assert resolve_schedule("1f1b-interleaved", _says(CFG), _pcfg(pp=2, vp=2)) \
             == "1f1b-interleaved"
-        assert resolve_schedule("1f1b-zb", CFG, _pcfg(pp=2)) == "1f1b-zb"
+        assert resolve_schedule("1f1b-zb", _says(CFG), _pcfg(pp=2)) == "1f1b-zb"
         with pytest.raises(ValueError, match="nothing to interleave"):
-            resolve_schedule("1f1b-interleaved", CFG, _pcfg(pp=2))
+            resolve_schedule("1f1b-interleaved", _says(CFG), _pcfg(pp=2))
         with pytest.raises(ValueError, match="1f1b-interleaved"):
-            resolve_schedule("1f1b-zb", CFG, _pcfg(pp=2, vp=2))
+            resolve_schedule("1f1b-zb", _says(CFG), _pcfg(pp=2, vp=2))
 
     def test_forced_wavefront_always_wins(self):
-        assert resolve_schedule("wavefront", CFG, _pcfg(pp=2)) == "wavefront"
+        assert resolve_schedule("wavefront", _says(CFG), _pcfg(pp=2)) == "wavefront"
 
     def test_forced_1f1b_on_supported(self):
-        assert resolve_schedule("1f1b", CFG, _pcfg(pp=2)) == "1f1b"
+        assert resolve_schedule("1f1b", _says(CFG), _pcfg(pp=2)) == "1f1b"
 
     def test_forced_1f1b_on_unsupported_raises_with_reason(self):
         with pytest.raises(ValueError, match="virtual"):
-            resolve_schedule("1f1b", CFG, _pcfg(pp=2, vp=2))
+            resolve_schedule("1f1b", _says(CFG), _pcfg(pp=2, vp=2))
         with pytest.raises(ValueError, match="dpo"):
-            resolve_schedule("1f1b", CFG, _pcfg(pp=2, alignment="dpo"))
+            resolve_schedule("1f1b", _says(CFG), _pcfg(pp=2, alignment="dpo"))
 
     def test_unknown_schedule_rejected(self):
         with pytest.raises(ValueError, match="pipeline.schedule"):
-            resolve_schedule("gpipe", CFG, _pcfg(pp=2))
+            resolve_schedule("gpipe", _says(CFG), _pcfg(pp=2))
         assert PIPELINE_SCHEDULES == ("auto", "1f1b", "1f1b-interleaved",
                                       "1f1b-zb", "wavefront")
         assert MANUAL_VJP_SCHEDULES == ("1f1b", "1f1b-interleaved", "1f1b-zb")
 
     def test_default_none_means_auto(self):
-        assert resolve_schedule(None, CFG, _pcfg(pp=2)) == "1f1b"
+        assert resolve_schedule(None, _says(CFG), _pcfg(pp=2)) == "1f1b"
 
 
 class TestParity:

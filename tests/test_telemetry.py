@@ -17,6 +17,7 @@ from neuronx_distributed_training_tpu.telemetry import (
     SpanTimer,
     TelemetryConfig,
 )
+from neuronx_distributed_training_tpu.models.family import flops_for_model
 from neuronx_distributed_training_tpu.utils import perf
 
 
@@ -169,8 +170,8 @@ class TestFlopsForModel:
 
     def test_llama_matches_flops_for_config(self):
         cfg = self._llama()
-        assert perf.flops_for_model(cfg, 64) == perf.flops_for_config(cfg, 64)
-        assert perf.flops_for_model(cfg, 64) > 0
+        assert flops_for_model(cfg, 64) == perf.flops_for_config(cfg, 64)
+        assert flops_for_model(cfg, 64) > 0
 
     def test_mixtral_counts_activated_experts_only(self):
         from neuronx_distributed_training_tpu.models import mixtral
@@ -178,13 +179,13 @@ class TestFlopsForModel:
 
         mk = lambda k: mixtral.MixtralConfig(
             llama=self._llama(), moe=MoEConfig(num_experts=8, top_k=k))
-        f1, f2 = perf.flops_for_model(mk(1), 64), perf.flops_for_model(mk(2), 64)
+        f1, f2 = flops_for_model(mk(1), 64), flops_for_model(mk(2), 64)
         assert f2 > f1 > 0
         # top_k=2 adds exactly one more expert's SwiGLU per MoE layer
         swiglu = 2 * 64 * 3 * 128
         assert f2 - f1 == pytest.approx(4 * swiglu)
         # dense llama vs top_k=1 mixtral differ only by the router matmul
-        dense = perf.flops_for_model(self._llama(), 64)
+        dense = flops_for_model(self._llama(), 64)
         router = 2 * 64 * 8
         assert f1 - dense == pytest.approx(4 * router)
 
@@ -194,8 +195,8 @@ class TestFlopsForModel:
         mk = lambda act: gpt.GPTConfig(
             vocab_size=1024, hidden_size=64, ffn_hidden_size=128,
             num_layers=4, num_attention_heads=4, activation=act)
-        plain, glu = (perf.flops_for_model(mk("gelu"), 64),
-                      perf.flops_for_model(mk("swiglu"), 64))
+        plain, glu = (flops_for_model(mk("gelu"), 64),
+                      flops_for_model(mk("swiglu"), 64))
         # GLU runs 3 MLP matmuls to plain's 2 at equal ffn width
         mlp2 = 4 * 2 * 64 * 2 * 128
         assert glu - plain == pytest.approx(mlp2 / 2)
@@ -210,7 +211,7 @@ class TestFlopsForModel:
         moe = gpt.GPTConfig(vocab_size=1024, hidden_size=64,
                             num_layers=4, num_attention_heads=4,
                             moe=MoEConfig(num_experts=4, top_k=2))
-        assert perf.flops_for_model(moe, 64) > perf.flops_for_model(dense, 64)
+        assert flops_for_model(moe, 64) > flops_for_model(dense, 64)
 
 
 # ---------------------------------------------------------------------------

@@ -121,13 +121,12 @@ class TestFit:
 
 class TestBuildModel:
     def test_unknown_arch_raises(self, tmp_path):
-        from neuronx_distributed_training_tpu.trainer.loop import build_model
-        from neuronx_distributed_training_tpu.utils.dtypes import DtypePolicy
+        from neuronx_distributed_training_tpu.models.family import resolve
 
         cfg = tiny_cfg(tmp_path)
         cfg["model"]["architecture"] = "rwkv"
         with pytest.raises(ValueError, match="unsupported"):
-            build_model(cfg, DtypePolicy())
+            resolve(cfg)
 
 
 def test_pipeline_vpp_trainer(tmp_path, devices8):

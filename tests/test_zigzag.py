@@ -130,7 +130,7 @@ class TestZigzagTrainer:
         """The full trainer loss hook (permute + pre-shift + positions) under
         zig-zag equals the contiguous-ring loss on the same batch."""
         from neuronx_distributed_training_tpu.config.loader import load_config
-        from neuronx_distributed_training_tpu.trainer.loop import build_model
+        from neuronx_distributed_training_tpu.models.family import resolve
         from neuronx_distributed_training_tpu.utils.dtypes import DtypePolicy
 
         fp32 = DtypePolicy(param_dtype=jnp.float32, compute_dtype=jnp.float32,
@@ -154,9 +154,9 @@ class TestZigzagTrainer:
         ids = jax.random.randint(jax.random.PRNGKey(5), (2, 64), 0, 128)
         batch = {"input_ids": ids, "labels": ids}
 
-        mc_z, loss_z, init_z, _ = build_model(cfg_zz, fp32)
-        mc_r, loss_r, init_r, _ = build_model(cfg_ring, fp32)
-        params = init_z(jax.random.PRNGKey(0))
+        (fam, mc_z), (_, mc_r) = resolve(cfg_zz), resolve(cfg_ring)
+        loss_z, loss_r = fam.loss(mc_z, fp32), fam.loss(mc_r, fp32)
+        params = fam.init_params(jax.random.PRNGKey(0), mc_z, fp32)
         with mesh, shd.use_mesh(mesh):
             lz, _ = jax.jit(loss_z)(params, batch, None)
             lr, _ = jax.jit(loss_r)(params, batch, None)
