@@ -9,16 +9,41 @@ recomputing probabilities per block (no saved probs at all — strictly better
 than the reference's "selective recompute of CoreAttention").
 
 Design notes (see /opt/skills/guides/pallas_guide.md):
-- grid = (batch, q_heads, q_blocks, kv_blocks); the kv dimension is innermost
+- grid = (batch, q_heads, q_blocks, kv_band); the kv dimension is innermost
   and sequential ("arbitrary"), carrying the online-softmax state (m, l, acc)
   in VMEM scratch across kv steps.
-- causality is exploited at block granularity: fully-masked kv blocks are
-  predicated off with ``pl.when`` (the MXU never sees them), matching the
-  2x FLOP saving the reference's kernel gets from causal masking.
+- the band: the innermost dimension covers only the blocks that ``causal``,
+  ``window`` and ``q_offset`` can show an outer block (``_band``, at trace
+  time from Python ints): for fwd and dq the key blocks from a query block's
+  first to its last visible one, for dkv the query blocks that see a key
+  block; its extent is the longest such span (3 of 16 key blocks and 12 of 64
+  query blocks at seq 32768, tiles 512 x 2048, window 4096; the whole range
+  without a window or with 2 key blocks).  Index maps and kernel bodies turn
+  the step ``j`` into the absolute block ``first(outer) + j`` by integer
+  arithmetic on the program ids (``_walk``).  A block with fewer partners
+  than the band (the first windows, the sequence's ends, a ring step's chunk
+  that shows a query block nothing) spends its last steps clamped: the index
+  map stays on the last block fetched, so nothing is fetched, and the body is
+  predicated off.  Visible pairs are visited in ascending order with the same
+  tiles and the same body whatever the band, so the outputs are bit for bit
+  those of the walk over the whole range (tests/test_flash_attention.py hands
+  ``_fwd_pallas`` / ``_bwd_pallas`` that walk and compares with array_equal).
+- causality is exploited at block granularity inside the band too: ``_visible``
+  (and the padding-mask / segment predicates, which the band knows nothing
+  of) predicates fully-masked blocks off with ``pl.when`` (the MXU never sees
+  them), matching the 2x FLOP saving the reference's kernel gets from causal
+  masking.
+- operand order: each kernel is one custom call whose FIRST operand is the
+  4-D ``[b, heads, s, d]`` q (fwd returns ``(o, float32 lse)``, dq one array,
+  dkv two), under the names and scopes ``flash_fwd`` / ``flash_dq`` /
+  ``flash_dkv``: the benchmark's finder (benchmark/trace_reduce.py::flash_kind)
+  knows them by that.  So the band's offsets are arithmetic in the index
+  maps, not a scalar-prefetch table passed in ahead of q.
 - GQA: the kv BlockSpec index-maps query-head ``h`` -> kv-head
   ``h // (nh // nkv)`` so K/V are never physically repeated (the reference
   replicates KV via ``kv_shared_group_size`` instead — unnecessary here).
-- backward: two kernels (dq with kv innermost; dkv with q innermost), both
+- backward: two kernels (dq with the kv band innermost; dkv with the q band
+  innermost, grid = (batch, kv_heads, kv_blocks, group, q_band)), both
   recomputing p = exp(s - lse) from the saved logsumexp, FlashAttention-2
   style.  dk/dv are produced per KV-head: the GQA q-head group is a sequential
   grid dim accumulated in fp32 VMEM scratch.
@@ -31,12 +56,14 @@ from __future__ import annotations
 
 import functools
 import logging
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from neuronx_distributed_training_tpu.parallel import sharding as shd
 
 logger = logging.getLogger(__name__)
 
@@ -105,6 +132,89 @@ def _inner_mask(bq, bkv, qi, ki, causal, window, q_offset):
     return jnp.where(ok, 0.0, NEG_INF)
 
 
+class _Band(NamedTuple):
+    """The walk of a call's innermost grid dimension: which blocks a kernel
+    visits for one outer block, taken from ``causal``, ``window`` and
+    ``q_offset`` alone (a superset of what ``_visible`` admits; padding masks
+    and segments predicate inside it).  ``kv`` / ``q`` are the dimension's
+    extent for fwd + dq / dkv: the largest count of partners any outer block
+    has.  The band of ``causal=False, window=None`` is the whole range walked
+    from block 0, i.e. no band at all (what the tests compare against)."""
+
+    causal: bool
+    window: Optional[int]
+    q_offset: int
+    bq: int
+    bkv: int
+    num_q: int
+    num_kv: int
+    kv: int
+    q: int
+
+
+def _kv_span(band, qi, lo=max, hi=min):
+    """First and last key block query block ``qi`` can see: ``_visible``'s two
+    inequalities solved for ``ki``.  Plain integer arithmetic on non-negative
+    operands, so it serves Python ints (``lo=max, hi=min``) and program ids in
+    an index map or a kernel body (``jnp.maximum``, ``jnp.minimum``) alike.
+    ``last < first``: the block sees nothing."""
+    q_lo = qi * band.bq + band.q_offset
+    first, last = 0, band.num_kv - 1
+    if band.window is not None:  # kv_hi > q_lo - window
+        first = lo(q_lo - band.window + 1, 0) // band.bkv
+    if band.causal:  # kv_lo <= q_hi; a q_hi below 0 gives -1
+        last = hi(lo(q_lo + band.bq - 1 + band.bkv, 0) // band.bkv - 1, last)
+    return first, last
+
+
+def _q_span(band, ki, lo=max, hi=min):
+    """First and last query block that can see key block ``ki`` (dkv's walk)."""
+    kv_lo = ki * band.bkv - band.q_offset
+    first, last = 0, band.num_q - 1
+    if band.causal:  # kv_lo <= q_hi
+        first = lo(kv_lo, 0) // band.bq
+    if band.window is not None:  # kv_hi > q_lo - window
+        q_lo_max = kv_lo + band.bkv - 1 + band.window - 1
+        last = hi(lo(q_lo_max + band.bq, 0) // band.bq - 1, last)
+    return first, last
+
+
+def _band(bq, bkv, num_q, num_kv, causal, window, q_offset) -> _Band:
+    """The band of a call, at trace time: every argument is a Python int."""
+    band = _Band(causal, window, q_offset, bq, bkv, num_q, num_kv, num_kv, num_q)
+
+    def longest(span, outer):
+        spans = (span(band, i) for i in range(outer))
+        return max(1, max(last - first + 1 for first, last in spans))
+
+    return band._replace(kv=longest(_kv_span, num_q), q=longest(_q_span, num_kv))
+
+
+def _call_band(bq, bkv, num_q, num_kv, causal, window, q_offset) -> _Band:
+    """``_band`` of a forward call, recorded for ``run_summary.json`` where a
+    trace collects such facts: ``flash_band``, one entry per distinct shape."""
+    band = _band(bq, bkv, num_q, num_kv, causal, window, q_offset)
+    facts = shd.trace_facts()
+    if facts is not None:
+        shape = {"seq": num_q * bq, "kv_blocks": num_kv, "kv_band": band.kv,
+                 "q_blocks": num_q, "q_band": band.q}
+        if shape not in facts.setdefault("flash_band", []):
+            facts["flash_band"].append(shape)
+    return band
+
+
+def _walk(span, band, outer, j):
+    """Step ``j`` of outer block ``outer``'s walk, on program ids -> (absolute
+    inner block index, whether the step is inside the block's span, the block
+    to fetch).  Past the block's last partner (the first windows, the
+    sequence's ends, a block that sees nothing at all) the fetch stays on the
+    last block fetched, so the step moves no data, and the kernel body
+    predicates it off."""
+    first, last = span(band, outer, jnp.maximum, jnp.minimum)
+    i = first + j
+    return i, i <= last, jnp.maximum(jnp.minimum(i, last), 0)
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -112,7 +222,7 @@ def _inner_mask(bq, bkv, qi, ki, causal, window, q_offset):
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, *refs,
-    sm_scale, causal, window, q_offset, bq, bkv, num_kv, masked, segmented,
+    sm_scale, causal, window, q_offset, bq, bkv, band, masked, segmented,
 ):
     refs = list(refs)
     kvm_ref = refs.pop(0) if masked else None
@@ -120,15 +230,17 @@ def _fwd_kernel(
     segk_ref = refs.pop(0) if segmented else None
     o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    j = pl.program_id(3)
+    ki, in_band, _ = _walk(_kv_span, band, qi, j)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    vis = _visible(qi, ki, bq, bkv, causal, window, q_offset)
+    vis = jnp.logical_and(
+        in_band, _visible(qi, ki, bq, bkv, causal, window, q_offset))
     if kvm_ref is not None:
         # skip kv blocks that are entirely padding (long pad tails cost 0 MXU)
         vis = jnp.logical_and(vis, jnp.any(kvm_ref[0] > 0))
@@ -173,7 +285,7 @@ def _fwd_kernel(
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ki == num_kv - 1)
+    @pl.when(j == band.kv - 1)
     def _finish():
         l = l_scr[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -190,36 +302,48 @@ def _fwd_kernel(
 
 
 def _fwd_pallas(q, k, v, kvm, seg, *, sm_scale, causal, window, q_offset, bq, bkv,
-                interpret):
+                interpret, band=None):
     """q [b, nh, sq, d]; k/v [b, nkv, skv, d]; kvm None or [b, 1, skv] int32
     (1 = real key); seg None or [b, 1, s] int32 segment ids (self-attention
-    packed chunks) -> (o [b, nh, sq, d], lse [b, nh, sq, SUBLANES])."""
+    packed chunks) -> (o [b, nh, sq, d], lse [b, nh, sq, SUBLANES]).
+    ``band``: the walk, ``_band`` of the call unless a test hands in another."""
     b, nh, sq, d = q.shape
     nkv, skv = k.shape[1], k.shape[2]
     group = nh // nkv
     num_q, num_kv = sq // bq, skv // bkv
+    if band is None:
+        band = _call_band(bq, bkv, num_q, num_kv, causal, window, q_offset)
 
-    grid = (b, nh, num_q, num_kv)
+    def kv_at(qi, j):  # the key block step j of query block qi fetches
+        return _walk(_kv_span, band, qi, j)[2]
+
+    grid = (b, nh, num_q, band.kv)
     kernel = functools.partial(
         _fwd_kernel,
         sm_scale=sm_scale, causal=causal, window=window, q_offset=q_offset,
-        bq=bq, bkv=bkv, num_kv=num_kv, masked=kvm is not None,
+        bq=bq, bkv=bkv, band=band, masked=kvm is not None,
         segmented=seg is not None,
     )
+    # q stays the first operand (no scalar-prefetch table in front of it): the
+    # benchmark's finder knows the kernels by their operands' shapes
     in_specs = [
-        pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, bkv, d), lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
-        pl.BlockSpec((1, 1, bkv, d), lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
+        pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, j: (bi, hi, qi, 0)),
+        pl.BlockSpec((1, 1, bkv, d),
+                     lambda bi, hi, qi, j: (bi, hi // group, kv_at(qi, j), 0)),
+        pl.BlockSpec((1, 1, bkv, d),
+                     lambda bi, hi, qi, j: (bi, hi // group, kv_at(qi, j), 0)),
     ]
     in_arrays = [q, k, v]
     if kvm is not None:
-        in_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, hi, qi, ki: (bi, 0, ki)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, bkv), lambda bi, hi, qi, j: (bi, 0, kv_at(qi, j))))
         in_arrays.append(kvm)
     if seg is not None:
         # same [b, 1, s] array read twice: query rows and key cols
-        in_specs.append(pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki: (bi, 0, qi)))
+        in_specs.append(pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, j: (bi, 0, qi)))
         in_arrays.append(seg)
-        in_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, hi, qi, ki: (bi, 0, ki)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, bkv), lambda bi, hi, qi, j: (bi, 0, kv_at(qi, j))))
         in_arrays.append(seg)
     # the scope and the kernel's own name (telemetry.spans.DEVICE_SCOPES): a
     # trace reduction finds the three kernels by them, not by HLO numbering
@@ -230,8 +354,8 @@ def _fwd_pallas(q, k, v, kvm, seg, *, sm_scale, causal, window, q_offset, bq, bk
             grid=grid,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-                pl.BlockSpec((1, 1, bq, SUBLANES), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+                pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, j: (bi, hi, qi, 0)),
+                pl.BlockSpec((1, 1, bq, SUBLANES), lambda bi, hi, qi, j: (bi, hi, qi, 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((b, nh, sq, d), q.dtype),
@@ -257,7 +381,7 @@ def _fwd_pallas(q, k, v, kvm, seg, *, sm_scale, causal, window, q_offset, bq, bk
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-    sm_scale, causal, window, q_offset, bq, bkv, num_kv, masked, segmented,
+    sm_scale, causal, window, q_offset, bq, bkv, band, masked, segmented,
 ):
     refs = list(refs)
     kvm_ref = refs.pop(0) if masked else None
@@ -265,13 +389,15 @@ def _dq_kernel(
     segk_ref = refs.pop(0) if segmented else None
     dq_ref, acc_scr = refs
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    j = pl.program_id(3)
+    ki, in_band, _ = _walk(_kv_span, band, qi, j)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    vis = _visible(qi, ki, bq, bkv, causal, window, q_offset)
+    vis = jnp.logical_and(
+        in_band, _visible(qi, ki, bq, bkv, causal, window, q_offset))
     if kvm_ref is not None:
         vis = jnp.logical_and(vis, jnp.any(kvm_ref[0] > 0))
     if segq_ref is not None:
@@ -315,14 +441,14 @@ def _dq_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(ki == num_kv - 1)
+    @pl.when(j == band.kv - 1)
     def _finish():
         dq_ref[0, 0] = acc_scr[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-    sm_scale, causal, window, q_offset, bq, bkv, num_q, group, masked, segmented,
+    sm_scale, causal, window, q_offset, bq, bkv, band, group, masked, segmented,
 ):
     refs = list(refs)
     kvm_ref = refs.pop(0) if masked else None
@@ -331,14 +457,16 @@ def _dkv_kernel(
     dk_ref, dv_ref, dk_scr, dv_scr = refs
     ki = pl.program_id(2)
     g = pl.program_id(3)
-    qi = pl.program_id(4)
+    j = pl.program_id(4)
+    qi, in_band, _ = _walk(_q_span, band, ki, j)
 
-    @pl.when(jnp.logical_and(g == 0, qi == 0))
+    @pl.when(jnp.logical_and(g == 0, j == 0))
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    vis = _visible(qi, ki, bq, bkv, causal, window, q_offset)
+    vis = jnp.logical_and(
+        in_band, _visible(qi, ki, bq, bkv, causal, window, q_offset))
     if kvm_ref is not None:
         vis = jnp.logical_and(vis, jnp.any(kvm_ref[0] > 0))
     if segq_ref is not None:
@@ -381,19 +509,27 @@ def _dkv_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(jnp.logical_and(g == group - 1, qi == num_q - 1))
+    @pl.when(jnp.logical_and(g == group - 1, j == band.q - 1))
     def _finish():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpret,
-                dlse=None):
+                dlse=None, band=None):
     q, k, v, kvm, seg, o, lse = res  # q [b, nh, sq, d]; k/v [b, nkv, skv, d]
     b, nh, sq, d = q.shape
     nkv, skv = k.shape[1], k.shape[2]
     group = nh // nkv
     num_q, num_kv = sq // bq, skv // bkv
+    if band is None:
+        band = _band(bq, bkv, num_q, num_kv, causal, window, q_offset)
+
+    def kv_at(qi, j):  # dq walks a query block's key blocks,
+        return _walk(_kv_span, band, qi, j)[2]
+
+    def q_at(ki, j):  # dkv a key block's query blocks
+        return _walk(_q_span, band, ki, j)[2]
 
     do = g.astype(jnp.float32)
     delta = jnp.sum(do * o.astype(jnp.float32), axis=-1)  # [b, nh, sq]
@@ -404,32 +540,35 @@ def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpre
     delta = jnp.broadcast_to(delta[..., None], (b, nh, sq, SUBLANES))
 
     common = dict(sm_scale=sm_scale, causal=causal, window=window, q_offset=q_offset,
-                  bq=bq, bkv=bkv, masked=kvm is not None,
+                  bq=bq, bkv=bkv, band=band, masked=kvm is not None,
                   segmented=seg is not None)
     in_arrays = (q, k, v, g, lse, delta) + ((kvm,) if kvm is not None else ())
     if seg is not None:
         in_arrays = in_arrays + (seg, seg)
 
-    dq_specs = [
-        pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, bkv, d), lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
-        pl.BlockSpec((1, 1, bkv, d), lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
-        pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, bq, SUBLANES), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, bq, SUBLANES), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-    ]
+    def dq_q(width):  # dq: a [.., bq, width] block of query block qi
+        return pl.BlockSpec((1, 1, bq, width), lambda bi, hi, qi, j: (bi, hi, qi, 0))
+
+    def dq_kv():  # dq: the key block that step j of query block qi visits
+        return pl.BlockSpec(
+            (1, 1, bkv, d), lambda bi, hi, qi, j: (bi, hi // group, kv_at(qi, j), 0))
+
+    dq_specs = [dq_q(d), dq_kv(), dq_kv(), dq_q(d), dq_q(SUBLANES),
+                dq_q(SUBLANES)]
     if kvm is not None:
-        dq_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, hi, qi, ki: (bi, 0, ki)))
+        dq_specs.append(pl.BlockSpec(
+            (1, 1, bkv), lambda bi, hi, qi, j: (bi, 0, kv_at(qi, j))))
     if seg is not None:
-        dq_specs.append(pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki: (bi, 0, qi)))
-        dq_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, hi, qi, ki: (bi, 0, ki)))
+        dq_specs.append(pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, j: (bi, 0, qi)))
+        dq_specs.append(pl.BlockSpec(
+            (1, 1, bkv), lambda bi, hi, qi, j: (bi, 0, kv_at(qi, j))))
     with jax.named_scope("flash_dq"):
         dq = pl.pallas_call(
-            functools.partial(_dq_kernel, num_kv=num_kv, **common),
+            functools.partial(_dq_kernel, **common),
             name="flash_dq",
-            grid=(b, nh, num_q, num_kv),
+            grid=(b, nh, num_q, band.kv),
             in_specs=dq_specs,
-            out_specs=pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            out_specs=dq_q(d),
             out_shape=jax.ShapeDtypeStruct((b, nh, sq, d), q.dtype),
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
@@ -441,29 +580,29 @@ def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpre
     # dk/dv per KV-head: the q-head group is a sequential grid dim, accumulated
     # in the fp32 VMEM scratch — 1x HBM writes and no bf16 intermediate in the
     # GQA group sum.
-    dkv_specs = [
-        pl.BlockSpec((1, 1, bq, d), lambda bi, kh, ki, g, qi: (bi, kh * group + g, qi, 0)),
-        pl.BlockSpec((1, 1, bkv, d), lambda bi, kh, ki, g, qi: (bi, kh, ki, 0)),
-        pl.BlockSpec((1, 1, bkv, d), lambda bi, kh, ki, g, qi: (bi, kh, ki, 0)),
-        pl.BlockSpec((1, 1, bq, d), lambda bi, kh, ki, g, qi: (bi, kh * group + g, qi, 0)),
-        pl.BlockSpec((1, 1, bq, SUBLANES), lambda bi, kh, ki, g, qi: (bi, kh * group + g, qi, 0)),
-        pl.BlockSpec((1, 1, bq, SUBLANES), lambda bi, kh, ki, g, qi: (bi, kh * group + g, qi, 0)),
-    ]
+    def dkv_q(width):  # dkv: the query block that step j of key block ki visits
+        return pl.BlockSpec(
+            (1, 1, bq, width),
+            lambda bi, kh, ki, g, j: (bi, kh * group + g, q_at(ki, j), 0))
+
+    def dkv_kv():  # dkv: key block ki
+        return pl.BlockSpec((1, 1, bkv, d), lambda bi, kh, ki, g, j: (bi, kh, ki, 0))
+
+    dkv_specs = [dkv_q(d), dkv_kv(), dkv_kv(), dkv_q(d), dkv_q(SUBLANES),
+                 dkv_q(SUBLANES)]
     if kvm is not None:
-        dkv_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, kh, ki, g, qi: (bi, 0, ki)))
+        dkv_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, kh, ki, g, j: (bi, 0, ki)))
     if seg is not None:
-        dkv_specs.append(pl.BlockSpec((1, 1, bq), lambda bi, kh, ki, g, qi: (bi, 0, qi)))
-        dkv_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, kh, ki, g, qi: (bi, 0, ki)))
+        dkv_specs.append(pl.BlockSpec(
+            (1, 1, bq), lambda bi, kh, ki, g, j: (bi, 0, q_at(ki, j))))
+        dkv_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, kh, ki, g, j: (bi, 0, ki)))
     with jax.named_scope("flash_dkv"):
         dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel, num_q=num_q, group=group, **common),
+            functools.partial(_dkv_kernel, group=group, **common),
             name="flash_dkv",
-            grid=(b, nkv, num_kv, group, num_q),
+            grid=(b, nkv, num_kv, group, band.q),
             in_specs=dkv_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, bkv, d), lambda bi, kh, ki, g, qi: (bi, kh, ki, 0)),
-                pl.BlockSpec((1, 1, bkv, d), lambda bi, kh, ki, g, qi: (bi, kh, ki, 0)),
-            ],
+            out_specs=[dkv_kv(), dkv_kv()],
             out_shape=[
                 jax.ShapeDtypeStruct((b, nkv, skv, d), k.dtype),
                 jax.ShapeDtypeStruct((b, nkv, skv, d), v.dtype),

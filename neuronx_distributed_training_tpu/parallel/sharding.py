@@ -88,8 +88,9 @@ def trace_facts() -> Optional[dict]:
 @contextlib.contextmanager
 def collect_trace_facts():
     """Collect what code traced inside the block records about how it was
-    partitioned (``ops.moe``: ``moe_token_shards``).  The facts are of the
-    trace: a function whose jaxpr is already cached records nothing."""
+    partitioned (``ops.moe``: ``moe_token_shards``) or how its kernels walk
+    (``ops.flash_attention``: ``flash_band``).  The facts are of the trace: a
+    function whose jaxpr is already cached records nothing."""
     prev = trace_facts()
     _STATE.facts = facts = {}
     try:

@@ -2111,7 +2111,8 @@ class Trainer:
         # window's exclusion both see it through the span
         spans.add("compile", dt)
         # how the trace partitioned what it could (ops/moe.py:
-        # moe_token_shards) joins the run's static facts
+        # moe_token_shards) and how its flash kernels walk their blocks
+        # (ops/flash_attention.py: flash_band) join the run's static facts
         self.run_facts.update(traced)
         for name, value in sorted(traced.items()):
             logger.info("traced: %s %s", name, value)

@@ -2,6 +2,8 @@
 must match core_attention (the reference-numerics implementation) in
 interpreter mode on CPU (SURVEY.md §4 plan item (a))."""
 
+import itertools
+
 import jax
 import numpy as np
 import jax.numpy as jnp
@@ -345,3 +347,149 @@ def test_flash_inside_pipeline_region_matches_core(devices8, tmp_path):
     flash, core = run(True), run(False)
     assert flash["loss"] == pytest.approx(core["loss"], rel=2e-3)
     assert flash["grad_norm"] == pytest.approx(core["grad_norm"], rel=5e-3)
+
+
+# ---------------------------------------------------------------------------
+# the band: the innermost grid dimension covers only the blocks that
+# causal / window / q_offset can show (ops/flash_attention.py::_band)
+# ---------------------------------------------------------------------------
+
+
+def _walks_agree(q, k, v, *, causal, window, q_offset=0, bq=128, bkv=256,
+                 mask=None, seg=None, with_dlse=False, narrower=True):
+    """fwd, dq and dkv over the call's band against the same kernels walking
+    the whole range from block 0 (the walk before the band): same visible
+    pairs in the same order, so every output must be EQUAL, not close."""
+    from neuronx_distributed_training_tpu.ops import flash_attention as fa
+
+    b, sq, nh, d = q.shape
+    skv = k.shape[1]
+    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+    kvm = fa._prep_rows(mask, b, skv, "attention_mask")
+    segr = fa._prep_rows(seg, b, sq, "segment_ids")
+    num_q, num_kv = sq // bq, skv // bkv
+    band = fa._band(bq, bkv, num_q, num_kv, causal, window, q_offset)
+    full = fa._band(bq, bkv, num_q, num_kv, False, None, 0)
+    assert (full.kv, full.q) == (num_kv, num_q)
+    assert band.kv <= num_kv and band.q <= num_q
+    assert (band.kv < num_kv) == narrower, band
+    common = dict(sm_scale=d ** -0.5, causal=causal, window=window,
+                  q_offset=q_offset, bq=bq, bkv=bkv, interpret=True)
+    kg, kl = jax.random.split(jax.random.PRNGKey(31))
+    g = jax.random.normal(kg, qt.shape, q.dtype)
+    dlse = jax.random.normal(kl, qt.shape[:3], jnp.float32) if with_dlse else None
+    outs = []
+    for walk in (band, full):
+        o, lse = fa._fwd_pallas(qt, kt, vt, kvm, segr, band=walk, **common)
+        dq, dk, dv = fa._bwd_pallas((qt, kt, vt, kvm, segr, o, lse), g,
+                                    dlse=dlse, band=walk, **common)
+        outs.append((o, lse, dq, dk, dv))
+    for name, a, e in zip(("o", "lse", "dq", "dk", "dv"), *outs):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(e), err_msg=name)
+    return outs[0]
+
+
+BAND_CASES = {
+    # name: (nh, nkv, bq, bkv, causal, window, narrower than the full range)
+    "window_8_key_blocks": (2, 2, 128, 256, True, 512, True),
+    "window_16_key_blocks": (2, 2, 128, 128, True, 300, True),
+    "window_covers_seq": (2, 2, 128, 256, True, 2048, False),
+    "no_window": (2, 2, 128, 256, True, None, False),
+    "gqa_group_4": (4, 1, 128, 256, True, 512, True),
+}
+
+
+@pytest.mark.parametrize("case", BAND_CASES, ids=list(BAND_CASES))
+def test_band_walk_equals_full_walk(case):
+    nh, nkv, bq, bkv, causal, window, narrower = BAND_CASES[case]
+    q, k, v = _make_qkv(jax.random.PRNGKey(17), 1, 2048, 2048, nh, nkv, 128,
+                        jnp.bfloat16)
+    _walks_agree(q, k, v, causal=causal, window=window, bq=bq, bkv=bkv,
+                 narrower=narrower)
+
+
+@pytest.mark.parametrize("rows", ["attention_mask", "segment_ids"])
+def test_band_walk_equals_full_walk_with_row_predicates(rows):
+    """The padding-mask and segment predicates stay what they were: the band
+    comes from causal / window / q_offset alone and they predicate inside it."""
+    b, s = 2, 2048
+    q, k, v = _make_qkv(jax.random.PRNGKey(19), b, s, s, 2, 1, 128, jnp.bfloat16)
+    pos = jnp.arange(s)[None, :]
+    if rows == "attention_mask":  # padded tails: whole key blocks of padding
+        given = dict(mask=(pos < jnp.array([[1500], [700]])).astype(jnp.int32))
+    else:  # packed records of uneven length
+        given = dict(seg=((pos >= 300).astype(jnp.int32)
+                          + (pos >= jnp.array([[1100], [1900]]))))
+    _walks_agree(q, k, v, causal=True, window=512, **given)
+
+
+def test_band_walk_survives_query_blocks_that_see_nothing():
+    """A ring step's chunk (non-causal, window at a static q_offset): the late
+    query blocks see no key block at all, their steps are all clamped, and they
+    come out as output 0 with lse NEG_INF, as before."""
+    from neuronx_distributed_training_tpu.ops import flash_attention as fa
+
+    q, k, v = _make_qkv(jax.random.PRNGKey(23), 1, 2048, 2048, 2, 2, 128,
+                        jnp.bfloat16)
+    o, lse, dq, _, _ = _walks_agree(
+        q, k, v, causal=False, window=512, q_offset=1536, with_dlse=True)
+    # key kv_pos is seen iff kv_pos > q_pos + 1536 - 512: rows from 1023 on see none
+    assert jnp.all(lse[0, :, 1023:, 0] == fa.NEG_INF)
+    assert jnp.all(o[0, :, 1023:] == 0) and jnp.all(dq[0, :, 1023:] == 0)
+    assert jnp.all(lse[0, :, :1023, 0] > fa.NEG_INF / 2)
+    # and through the public entry, against core attention on the seen rows
+    o_pub, lse_pub = fa.flash_attention_with_lse(
+        q, k, v, causal=False, sliding_window=512, q_offset=1536,
+        block_q=128, block_kv=256, interpret=True)
+    np.testing.assert_array_equal(np.asarray(jnp.swapaxes(o_pub, 1, 2)),
+                                  np.asarray(o))
+    # core_attention has no window without causal: the plain softmax, masked
+    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) * 128 ** -0.5
+    seen = jnp.arange(2048)[None, :] > jnp.arange(2048)[:, None] + 1536 - 512
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf)[:, :, :1023], axis=-1)
+    ref = jnp.einsum("bhqk,bkhd->bqhd", p, vf)
+    assert jnp.max(jnp.abs(o_pub[:, :1023].astype(jnp.float32) - ref)) < 2e-2
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "non_causal"])
+@pytest.mark.parametrize("window", [None, 1, 100, 128, 300, 512, 5000])
+def test_band_spans_are_exactly_what_visible_admits(causal, window):
+    """The helper alone, over a lattice of shapes: a block's span is the set
+    of partners ``_visible`` admits (so every visible pair is walked), the
+    band is the longest span, and never longer than the full range."""
+    from neuronx_distributed_training_tpu.ops import flash_attention as fa
+
+    for sq, skv, bq, bkv, q_offset in itertools.product(
+            (256, 512, 1024), (256, 512, 1024), (128, 256), (128, 256, 512),
+            (-300, 0, 128, 300, 1024, 4096)):
+        if skv % bkv:
+            continue
+        num_q, num_kv = sq // bq, skv // bkv
+        band = fa._band(bq, bkv, num_q, num_kv, causal, window, q_offset)
+        vis = np.asarray(fa._visible(
+            np.arange(num_q)[:, None], np.arange(num_kv)[None, :], bq, bkv,
+            causal, window, q_offset)) & np.ones((num_q, num_kv), bool)
+        at = dict(sq=sq, skv=skv, bq=bq, bkv=bkv, q_offset=q_offset)
+        for qi in range(num_q):
+            first, last = fa._kv_span(band, qi)
+            assert list(np.flatnonzero(vis[qi])) == list(range(first, last + 1)), (at, qi)
+        for ki in range(num_kv):
+            first, last = fa._q_span(band, ki)
+            assert list(np.flatnonzero(vis[:, ki])) == list(range(first, last + 1)), (at, ki)
+        assert band.kv == max(1, vis.sum(1).max()) <= num_kv, at
+        assert band.q == max(1, vis.sum(0).max()) <= num_q, at
+
+
+def test_band_at_the_32k_cells_shape():
+    """mistral7b-pretrain-32k: seq 32768, tiles 512 x 2048, window 4096."""
+    from neuronx_distributed_training_tpu.ops import flash_attention as fa
+
+    bq, bkv = fa._block_sizes(32768, 32768, None, None)
+    assert (bq, bkv) == (512, 2048)
+    band = fa._band(bq, bkv, 64, 16, True, 4096, 0)
+    assert (band.kv, band.q) == (3, 12)
+    # the 4k cells: window = sequence or none, 2 key blocks: the full extent
+    for window in (4096, None):
+        band = fa._band(bq, bkv, 8, 2, True, window, 0)
+        assert (band.kv, band.q) == (2, 8)

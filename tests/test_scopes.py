@@ -328,3 +328,29 @@ def test_moe_token_shards_is_a_static_fact_of_the_run(tmp_path, family, monkeypa
         assert summary["moe_token_shards"] is None
     else:
         assert summary["moe_token_shards"] == 8
+
+
+# -- how the flash kernels walk their blocks ---------------------------------
+
+
+@pytest.mark.parametrize("window", [128, None], ids=["window_128", "no_window"])
+def test_flash_band_is_a_static_fact_of_the_run(tmp_path, window):
+    """``flash_band``: per distinct flash call of the step, the key blocks of
+    the sequence against those a query block's walk covers (and the query
+    blocks against a key block's): narrower than the sequence under a window
+    shorter than it, the whole range without one."""
+    _, summary = fit_tiny(tmp_path, max_steps=1, overrides={
+        "data.seq_length": 512, "data.global_batch_size": 8,
+        "model.hidden_size": 256, "model.num_attention_heads": 2,
+        "model.num_key_value_heads": 1, "model.max_position_embeddings": 512,
+        "model.num_layers": 1, "model.sliding_window": window,
+        "model.fusions": {"flash_attention": True, "flash_block_q": 128,
+                          "flash_block_kv": 128},
+    })
+    (call,) = summary["flash_band"]
+    assert call["seq"] == 512 and call["kv_blocks"] == call["q_blocks"] == 4
+    if window is None:
+        assert (call["kv_band"], call["q_band"]) == (4, 4)
+    else:  # 128 rows and the 127 before them: two blocks either way
+        assert (call["kv_band"], call["q_band"]) == (2, 2)
+        assert call["kv_band"] < call["kv_blocks"]

@@ -64,6 +64,8 @@ FLASH_CASES = [
     ("sliding_window", 1, 8192, 32, 8, False, False, 4096, False),
     ("with_lse", 1, 8192, 32, 8, False, False, None, True),
     ("mha_s4096", 1, 4096, 32, 32, False, False, None, False),  # Llama-2-7B
+    # the 32k cell's own call: a band of 3 of 16 key blocks, 12 of 64 query
+    ("window_4096_s32768", 1, 32768, 32, 8, False, False, 4096, False),
 ]
 
 
