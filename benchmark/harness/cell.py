@@ -61,6 +61,9 @@ CONTRACT = {"reference": ("init_params", "leaf_names", "run"),
 #: what ``reduced`` may never name, and a declared ``widths`` has to cover
 WIDTH = re.compile(
     r"hidden|intermediate|latent|state|proj|_dim$|_rank$|head_|top_k|per_tok")
+#: but a key that counts layers is a depth whatever else its name holds: the
+#: ``hidden`` in a source's ``num_hidden_layers`` is no width
+DEPTH = re.compile(r"(^|[._])layers$")
 #: the only keys of the model block that ``reduced`` may excuse from equalling
 #: their published key: counts of what is held here (heads, experts, rows)
 COUNT = re.compile(r"(^|\.)(num_\w+|vocab_size)$")
@@ -125,6 +128,11 @@ def load_config_file(bench: dict, name: str, root: Path = ROOT) -> dict:
     return cfg
 
 
+def names_a_width(key: str) -> bool:
+    """Whether ``reduced`` may not list ``key``: a width, and no depth."""
+    return bool(WIDTH.search(key)) and not DEPTH.search(key)
+
+
 def _flat(tree: dict, prefix: str = "") -> dict:
     out = {}
     for k, v in tree.items():
@@ -142,9 +150,9 @@ def header_faults(cfg: dict, reduced: list) -> list:
     model's own key (``vocab_size``, ``moe.num_experts``), for which the file
     states the published count and the deployment instead.  A declared
     map covers every numeric key of the model block that ``DEFAULT_WIDTHS``
-    holds or ``WIDTH`` matches, so that a new configuration is held to no less
-    than the accepted ones."""
-    faults = [f"reduced names a width: {k}" for k in reduced if WIDTH.search(k)]
+    holds or ``names_a_width`` takes for one, so that a new configuration is
+    held to no less than the accepted ones."""
+    faults = [f"reduced names a width: {k}" for k in reduced if names_a_width(k)]
     model, published = _flat(cfg["trainer_config"]["model"]), cfg["published"]
     widths = cfg.get("widths")
     if widths is None:
@@ -152,7 +160,7 @@ def header_faults(cfg: dict, reduced: list) -> list:
                   if "moe" in cfg["trainer_config"]["model"] or not k.startswith("moe.")}
     else:
         faults += [f"widths leaves out the model's {k}" for k, v in model.items()
-                   if (k in DEFAULT_WIDTHS or WIDTH.search(k)) and k not in widths
+                   if (k in DEFAULT_WIDTHS or names_a_width(k)) and k not in widths
                    and isinstance(v, (int, float)) and not isinstance(v, bool)]
     for key, pub in widths.items():
         if pub not in published:

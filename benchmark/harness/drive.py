@@ -273,8 +273,11 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         del trainer
         gc.collect()
 
+        # a step may have several rows (a trace that closes logs one of its own)
+        rows: dict[int, dict] = {}
         with open(log_dir / "metrics.jsonl") as f:
-            rows = {int(r["step"]): r for r in map(json.loads, f)}
+            for r in map(json.loads, f):
+                rows.setdefault(int(r["step"]), {}).update(r)
         with open(log_dir / "run_summary.json") as f:
             summary = json.load(f)
         if clock.window_open is None or not clock.window_steps():

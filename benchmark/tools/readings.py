@@ -2,13 +2,16 @@
 at a cell's own size, many seeds in one process:
 
     python3 benchmark/tools/readings.py --workload <cell> --seeds 1,2,3 \\
-        [--control fp8] [--program-control precision.type=bf16SR] [--no-program]
+        [--control fp8] [--fault half_batch] \\
+        [--program-control precision.type=bf16SR] [--no-program]
 
 For every seed: the reference in float32; the control (the reference with
-every matmul operand rounded to ``--control``) held against it; the program
-held against it (a short window); and, with ``--program-control``, the
-program with its own lower-precision regime switched on.  Prints one JSON
-line per reading; PERF.md keeps the table.
+every matmul operand rounded to ``--control``) held against it; with
+``--fault``, the reference put in the program's place with that fault planted
+in it, held against it too; the program held against it (a short window);
+and, with ``--program-control``, the program with its own lower-precision
+regime switched on.  Prints one JSON line per reading; PERF.md keeps the
+table.
 """
 
 import time
@@ -23,11 +26,28 @@ from pathlib import Path  # noqa: E402
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
 
+def half_batch(tokens: list) -> list:
+    """Half of every step's batch left out and the mean taken over the rest:
+    the second half of its rows replaced by the first, at the same shapes
+    (the mean over rows that come twice is the mean over them once)."""
+    out = []
+    for step in tokens:                      # [micro, rows, seq]
+        step, half = step.copy(), step.shape[1] // 2
+        step[:, half:2 * half] = step[:, :half]
+        out.append(step)
+    return out
+
+
+FAULTS = {"half_batch": half_batch}
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--control", default="fp8")
+    ap.add_argument("--fault", default=None, choices=sorted(FAULTS),
+                    help="planted in the reference put in the program's place")
     ap.add_argument("--program-control", default=None,
                     help="dotted override, key=value, of the program's own path")
     ap.add_argument("--no-program", action="store_true")
@@ -50,18 +70,24 @@ def main() -> None:
         model = as_run["model"]
         tokens = drive.check_tokens(cell, model, seed)
         clip = as_run["trainer"].get("gradient_clip_val")
+        routed = checks.limits_for(cell.config_name, cell.root).get("routed_leaves")
+        stands_in = []      # the reference in the program's place: (reading, how it is run)
         if args.control != "none":
-            t1 = time.perf_counter()
+            stands_in.append((f"control-{args.control}", {"quant": args.control}))
+        if args.fault:
+            stands_in.append((f"fault-{args.fault}", {"tokens": FAULTS[args.fault](tokens)}))
+        if stands_in:
             ref = reference.run(model, model["optim"], clip, tokens, seed,
                                 shard=checks.sharder(devices))
-            ctl = reference.run(model, model["optim"], clip, tokens, seed,
-                                quant=args.control, shard=checks.sharder(devices))
-            routed = checks.limits_for(
-                cell.config_name, cell.root).get("routed_leaves")
-            found = {k: v for k, (v, _) in checks.numbers(ctl, ref, routed).items()}
-            found["leaves"] = {w: checks.leaf_gaps(ctl[w], ref[w])
+        for what, how in stands_in:
+            t1 = time.perf_counter()
+            got = reference.run(model, model["optim"], clip, how.get("tokens", tokens),
+                                seed, quant=how.get("quant"),
+                                shard=checks.sharder(devices))
+            found = {k: v for k, (v, _) in checks.numbers(got, ref, routed).items()}
+            found["leaves"] = {w: checks.leaf_gaps(got[w], ref[w])
                                for w in ("grad1", "dparam")}
-            print(json.dumps({"reading": f"control-{args.control}", "seed": seed,
+            print(json.dumps({"reading": what, "seed": seed,
                               "seconds": time.perf_counter() - t1, **found}),
                   flush=True)
         runs = [] if args.no_program else [("program", None)]

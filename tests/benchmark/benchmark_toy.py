@@ -7,11 +7,14 @@ size 64 the same comparison reads wider on both sides, so the tests carry
 limits set by the same rule from toy readings (PR 23, CPU, seeds 1-3 and the
 rehearsal seeds): sound program runs at most 1.2e-3 on the gradient and
 parameter-change norms and 2.2e-4 on the loss; the fp8 control at least
-4.0e-3 on the gradient norms.
+4.0e-3 on the gradient norms.  A configuration whose toy readings are wider
+brings ``toy_limits_<stem>.json`` (``toy_limits`` below).
 """
 
 import copy
 import dataclasses
+import json
+from pathlib import Path
 
 TOY_MODEL = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
                  num_key_value_heads=2, head_dim=16, vocab_size=256,
@@ -31,4 +34,13 @@ def toy(cell, seq=64):
 
 
 def toy_limits(cell):
+    """The limits a cell is held to at toy widths: its configuration's own,
+    ``toy_limits_<stem>.json`` beside this file (``why`` and ``limits``: the
+    readings and what was set from them), where it has one.  The stem is that
+    of the configuration's own reference module, as for its other files
+    (``tests/test_ouro.py`` reads ``toy_limits_ouro.json`` by that name)."""
+    stem = (cell.config.get("modules") or {}).get("reference")
+    own = Path(__file__).parent / f"toy_limits_{stem}.json"
+    if stem and own.exists():
+        return json.loads(own.read_text())["limits"]
     return TOY_LIMITS_ROUTED if "moe" in cell.model else TOY_LIMITS

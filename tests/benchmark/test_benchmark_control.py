@@ -23,13 +23,14 @@ BENCH = cells.load_benchmark()
 CELLS = {w["config"]: w["name"] for w in BENCH["workloads"]}
 
 
-def three_steps(cell, seed, quant=None):
+def three_steps(cell, seed, quant=None, fault=None):
     as_run = drive.merged_config(
         cell, drive.overrides_for(cell, seed, False, drive.WORK / "unused"))
     model = as_run["model"]
+    tokens = drive.check_tokens(cell, model, seed)
     return cell.reference.run(model, model["optim"],
                               as_run["trainer"]["gradient_clip_val"],
-                              drive.check_tokens(cell, model, seed), seed, quant=quant)
+                              fault(tokens) if fault else tokens, seed, quant=quant)
 
 
 @pytest.mark.parametrize("config", sorted(CELLS))
@@ -45,6 +46,26 @@ def test_fp8_control_is_not_correct(config, capsys):
     assert same and compared["grad1_worst_leaf"] == 0.0
     out = capsys.readouterr().out
     assert "FAILED" in out and " limit " in out
+
+
+def test_half_the_batch_left_out_is_not_correct():
+    """The fault ``tools/readings.py --fault half_batch`` plants in the
+    reference put in the program's place (PERF.md: its chip readings are the
+    upper readings of the four-chip cell's ``dparam`` limits): the first
+    gradient and the parameters' change both see it, under the cell's warm-up
+    too, whose first update runs at rate 0."""
+    from benchmark.tools.readings import half_batch
+
+    cell = toy(cells.load_cell("mixtral8x7b-pretrain-4k-ep4"))
+    limits = toy_limits(cell)
+    for seed in (1, 2, 3):
+        ok, compared = checks.compare(three_steps(cell, seed, fault=half_batch),
+                                      three_steps(cell, seed), limits)
+        assert not ok
+        for number in ("grad1_worst_leaf", "dparam_worst_leaf"):
+            assert compared[number] > 10 * limits[number], (number, compared[number])
+    rows = half_batch(drive.check_tokens(cell, cell.model, 1))[0][0]
+    assert (rows[:2] == rows[2:]).all() and (rows[0] != rows[1]).any()
 
 
 def test_traffic_is_a_function_of_the_seed_and_rows_differ():

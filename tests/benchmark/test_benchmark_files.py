@@ -185,6 +185,10 @@ def test_limits_come_from_one_place(tmp_path):
     (tmp_path / "benchmark" / "limits" / "in-table.json").write_text("{}")
     with pytest.raises(ValueError, match="in benchmark/limits.json and in"):
         checks.limits_for("in-table", tmp_path)
-    # the accepted configurations are held to the table, as they were
+    # every configuration has limits in exactly one of the two places, and
+    # the table's own names are held to the table
     with open(HERE / "limits.json") as f:
-        assert {c: checks.limits_for(c) for c in CONFIG_FILES} == json.load(f)
+        table = json.load(f)
+    assert {c: checks.limits_for(c) for c in table} == table
+    assert set(table) | stems("limits") == set(CONFIG_FILES)
+    assert not set(table) & stems("limits")

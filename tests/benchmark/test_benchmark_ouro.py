@@ -2,21 +2,23 @@
 entries (``benchmark/harness/cell.py``'s docstring).  Its operation count
 against a count by hand, the reader of an inner scope against the trace
 recorded on the chip, its file's statements, and its cell through the harness
-at toy widths: the rehearsal, the fp8 control, a step that leaves its state
-unchanged, and float32, where program and reference agree to rounding.
+at toy widths: a step that leaves its state unchanged, and float32, where
+program and reference agree to rounding.  (The rehearsal and the fp8 control
+are cases of ``test_benchmark_rehearsal.py`` and ``test_benchmark_control.py``
+like every cell's, since ``benchmark_toy.toy_limits`` finds a configuration's
+own toy limits.)
 
-The toy runs are held to ``toy_limits_ouro.json`` beside this file, read at
-toy widths through 8 layers x 4 passes: ``benchmark_toy.TOY_LIMITS`` was read
-on one layer (PERF.md section 7)."""
+The toy runs are held to ``toy_limits_ouro.json`` beside this file, read
+at toy widths through 8 layers x 4 passes: ``benchmark_toy.TOY_LIMITS`` was
+read on one layer."""
 
 import dataclasses
 import json
 import shutil
 import time
-from pathlib import Path
 
 import pytest
-from benchmark_toy import toy
+from benchmark_toy import toy, toy_limits
 
 from benchmark import flops, trace_reduce
 from benchmark.harness import cell as cells
@@ -33,9 +35,7 @@ BENCH = cells.load_benchmark()
 JOINS = {"attention_ms_per_step", "mlp_ms_per_step", "ce_head_ms_per_step",
          "optimizer_ms_per_step", "forward_ms_per_step", "backward_ms_per_step",
          "unscoped_device_pct"}
-#: the cell's limits at toy widths (its own are read on the chip at 2048)
-TOY_LIMITS = json.loads(
-    (Path(__file__).parent / "toy_limits_ouro.json").read_text())["limits"]
+FLASH = {"flash_ms_per_step", "flash_roofline_pct"}
 
 
 @pytest.fixture(scope="module")
@@ -117,23 +117,19 @@ def test_inner_scope_reader_returns_nothing_without_raising(tmp_path):
 # -- the files -----------------------------------------------------------------
 
 
-def test_the_entries_come_last_and_the_cell_joins_the_accepted_scope_metrics(cell):
-    assert [c["name"] for c in BENCH["configs"]][-1] == CONFIG
-    assert [(w["name"], w["config"], w["chips"], w["traffic"])
-            for w in BENCH["workloads"]][-1] == (CELL, CONFIG, 1, "pretrain-4k-gbs1")
-    last = BENCH["per_layer"][-1]
-    assert (last["name"], last["workloads"]) == ("exit_gate_ms_per_step", [CELL])
-    # the cell is appended to the lists it joins, and is in no other
+def test_the_cell_joins_the_accepted_scope_metrics(cell):
+    w = next(w for w in BENCH["workloads"] if w["name"] == CELL)
+    assert (w["config"], w["chips"], w["traffic"]) == (CONFIG, 1, "pretrain-4k-gbs1")
+    # the cell is in the lists it joins, in the two flash metrics' (every cell
+    # that runs the kernels) and in its own metric's, and in no other
     listed = {m["name"]: m["workloads"] for m in BENCH["per_layer"] if "workloads" in m}
+    assert listed["exit_gate_ms_per_step"] == [CELL]
     assert {name for name, cells_ in listed.items() if CELL in cells_} \
-        == JOINS | {"exit_gate_ms_per_step"}
-    assert all(listed[name][-1] == CELL for name in JOINS)
-    # it reports the accepted scope metrics, forward and backward with them,
-    # every list-less metric, and the one metric it brings
+        == JOINS | FLASH | {"exit_gate_ms_per_step"}
+    # it reports those and every list-less metric
     listless = {m["name"] for m in BENCH["per_layer"] if "workloads" not in m}
-    assert len(listless) == 8
     assert {m["name"] for m in cell.per_layer} == (
-        listless | JOINS | {"exit_gate_ms_per_step"})
+        listless | JOINS | FLASH | {"exit_gate_ms_per_step"})
     spec = cells.load_layer_metric("exit_gate_ms_per_step")
     assert spec["reader"] == "inner_scope" and spec["args"] == {"component": "exit_gate"}
 
@@ -144,13 +140,13 @@ def test_the_cells_limits_are_its_own_file_and_the_table_is_as_it_was():
     own = checks.limits_for(CONFIG)
     assert own == json.loads((HERE / "limits" / f"{CONFIG}.json").read_text())
     assert own["routed_leaves"] == "exit_gate"
-    # the source's spelling of the depth cannot stand in ``reduced``: the
-    # harness takes the ``hidden`` in it for a width (PERF.md section 7)
+    # the depth is cut under the source's own spelling: the ``hidden`` in it
+    # is no width, and a width beside it is still refused
     entry = next(c for c in BENCH["configs"] if c["name"] == CONFIG)
-    assert "num_layers" in entry["reduced"] and "num_hidden_layers" not in entry["reduced"]
+    assert "num_hidden_layers" in entry["reduced"] and "num_layers" not in entry["reduced"]
     assert cells.header_faults(cells.load_config_file(BENCH, CONFIG),
-                               entry["reduced"] + ["num_hidden_layers"]) \
-        == ["reduced names a width: num_hidden_layers"]
+                               entry["reduced"] + ["hidden_size"]) \
+        == ["reduced names a width: hidden_size", "reduced cuts hidden_size, which is no count"]
 
 
 def test_the_configuration_states_its_cut_and_what_it_assumes(cell):
@@ -165,12 +161,13 @@ def test_the_configuration_states_its_cut_and_what_it_assumes(cell):
         assert cfg["widths"][key] == key
     assert (published["num_hidden_layers"], model["num_layers"]) == (48, 8)
     assert model["tie_word_embeddings"] is published["tie_word_embeddings"] is False
-    assert set(cfg["reduced"]) == {"num_layers", "global_batch_size", "max_steps",
-                                   "warmup_steps"}
-    assert "num_hidden_layers" in cfg["reduced"]["num_layers"]
-    # the source's keys stand at the top level, number for number
+    assert set(cfg["reduced"]) == {"num_hidden_layers", "global_batch_size",
+                                   "max_steps", "warmup_steps"}
+    assert "trainer_config.model.num_layers" in cfg["reduced"]["num_hidden_layers"]
+    # the source's keys stand at the top level, number for number, but the
+    # depth, which stands there as it is run
     for key, value in published.items():
-        assert cfg[key] == value, key
+        assert cfg[key] == (8 if key == "num_hidden_layers" else value), key
     assert {"exit_entropy_beta", "carried_state", "norm_leaf_names", "initializer_range",
             "exit_gate_init", "recomputation", "gate_only_stage"} <= set(cfg["assumed"])
     assert model["exit_entropy_beta"] == 0.1 and model["initializer_range"] == 0.02
@@ -195,38 +192,7 @@ def rehearse(cell, name, **kw):
     return drive.run_cell(
         dataclasses.replace(toy(cell), name=f"{CELL}-{name}"), trace=False,
         t_process=time.perf_counter(), require_tpu=False,
-        **{"seed": 2**31 + 17, "seconds": 1.0, "limits": TOY_LIMITS, **kw})
-
-
-def test_cell_rehearses(cell, capsys):
-    line = json.loads(json.dumps(rehearse(cell, "rehearsal")))
-    lines = capsys.readouterr().out.splitlines()
-    assert line["correct"] is True, "\n".join(l for l in lines if l.startswith("check"))
-    assert list(line)[-1] == "compared" and len(line["compared"]["limits"]) == 5
-    assert line["failed"] == 0 and line["attempted"] >= 2
-    assert set(line["metrics"]) == {"tokens_per_s_per_chip", "step_ms_p95", "setup_s"}
-    assert line["device"]["platform"] == "cpu" and line["device"]["count"] == 1
-    assert any(l.startswith("  cut: num_layers") for l in lines)
-
-
-def test_fp8_control_is_not_correct(cell, capsys):
-    small = toy(cell)
-
-    def three_steps(seed, quant=None):
-        as_run = drive.merged_config(
-            small, drive.overrides_for(small, seed, False, drive.WORK / "unused"))
-        model = as_run["model"]
-        return small.reference.run(
-            model, model["optim"], as_run["trainer"]["gradient_clip_val"],
-            drive.check_tokens(small, model, seed), seed, quant=quant)
-
-    for seed in (1, 2, 3):
-        ref = three_steps(seed)
-        ok, compared = checks.compare(three_steps(seed, "fp8"), ref, TOY_LIMITS)
-        assert not ok, compared
-        assert compared["grad1_worst_leaf"] > TOY_LIMITS["grad1_worst_leaf"]
-    same, compared = checks.compare(ref, ref, TOY_LIMITS)
-    assert same and compared["grad1_worst_leaf"] == 0.0
+        **{"seed": 2**31 + 17, "seconds": 1.0, "limits": toy_limits(cell), **kw})
 
 
 def test_a_step_that_returns_its_state_unchanged_is_not_correct(cell, capsys):
