@@ -824,8 +824,27 @@ def shrink_overrides(cfg: Mapping, *, max_devices: int = 8) -> dict[str, Any]:
     for key in ("intermediate_size", "ffn_hidden_size"):
         if key in model:
             o[f"model.{key}"] = 2 * heads * head_dim
-    if "kv_channels" in model:
-        o["model.kv_channels"] = head_dim
+    for key in ("kv_channels", "head_dim"):
+        if key in model:
+            o[f"model.{key}"] = head_dim
+    # HF-spelled widths of a family whose layers differ (models/laguna.py):
+    # expert and shared-expert widths, expert counts, and per-layer head
+    # counts in the ratios the config states
+    for key in ("moe_intermediate_size", "shared_expert_intermediate_size"):
+        if model.get(key):
+            o[f"model.{key}"] = 2 * heads * head_dim
+    if model.get("num_experts"):
+        o["model.num_experts"] = max(2 * ep, 4)
+        if model.get("num_experts_per_tok"):
+            o["model.num_experts_per_tok"] = min(int(model["num_experts_per_tok"]), 2)
+    per_layer = model.get("num_attention_heads_per_layer")
+    if per_layer:
+        counts = per_layer.values() if isinstance(per_layer, Mapping) else per_layer
+        least = min(int(c) for c in counts)
+        o["model.num_attention_heads_per_layer"] = (
+            {t: heads * int(c) // least for t, c in per_layer.items()}
+            if isinstance(per_layer, Mapping) else
+            [heads * int(c) // least for c in per_layer])
     o["model.vocab_size"] = 128 * tp
     if "sliding_window" in model and model.get("sliding_window"):
         o["model.sliding_window"] = 32

@@ -190,7 +190,10 @@ class DeclaredComms:
         gbs = int(ctx.sched.get("global_batch_size", 1) or 1)
         mbs = int(ctx.sched.get("micro_batch_size", 1) or 1)
         overlap = ctx.ds.get("overlap") or {}
-        moe_block = (ctx.cfg.get("model", {}) or {}).get("moe")
+        model = ctx.cfg.get("model", {}) or {}
+        moe_block = model.get("moe")
+        # the HF spelling of a routed block (models/laguna.py): always dropless
+        hf_experts = bool(model.get("num_experts"))
         return cls(
             tp=ctx.axis("model"), pp=ctx.axis("pipe"),
             cp=ctx.axis("context"), ep=ctx.axis("expert"),
@@ -199,9 +202,9 @@ class DeclaredComms:
             zero1_bucket=(bool(ctx.ds.get("zero1", True))
                           and float(overlap.get("zero1_bucket_mb", 0) or 0) > 0),
             seq_par=bool(ctx.ds.get("sequence_parallel", False)),
-            moe=bool(moe_block),
-            moe_dropless=bool(moe_block) and MoEConfig.from_config(
-                moe_block).dropless,
+            moe=bool(moe_block) or hf_experts,
+            moe_dropless=(MoEConfig.from_config(moe_block).dropless
+                          if moe_block else hf_experts),
             ulysses=bool(fus.get("ulysses_attention")),
             ring=bool(fus.get("ring_attention")
                       or fus.get("zigzag_ring_attention")),

@@ -1,6 +1,6 @@
 """One record per model family: what the rest of the package asks of a model.
 
-``models/llama.py``, ``mixtral.py``, ``gpt.py`` and ``ouro.py`` each end in one
+``models/llama.py``, ``mixtral.py``, ``gpt.py``, ``ouro.py`` and ``laguna.py`` each end in one
 :class:`Family` (``FAMILY``) and their config class answers ``.family`` with
 it.  The trainer, the pipeline gate, the launch planner, the FLOPs count, the
 cached decode and the config validator ask the record; none of them names a
@@ -87,7 +87,7 @@ class Family:
 #: when resolved, so that a llama run imports neither ``gpt`` nor ``mixtral``
 FAMILIES: dict[str, Any] = {
     "llama": "llama", "mistral": "llama", "mixtral": "mixtral",
-    "ouro": "ouro", "gpt": "gpt",
+    "ouro": "ouro", "laguna": "laguna", "gpt": "gpt",
 }
 
 

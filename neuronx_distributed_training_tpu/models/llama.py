@@ -261,10 +261,26 @@ def param_specs(cfg: LlamaConfig, *, pipeline: bool = False):
 # ---------------------------------------------------------------------------
 
 
+#: ``_attention_block``'s ``sliding_window``: the config's, for every layer
+_CONFIG_WINDOW = object()
+
+
 def _attention_block(lp, x, cos, sin, cfg: LlamaConfig, policy: DtypePolicy,
-                     attention_mask=None, segment_ids=None, return_kv=False):
+                     attention_mask=None, segment_ids=None, return_kv=False, *,
+                     num_heads: Optional[int] = None, sliding_window=_CONFIG_WINDOW,
+                     block_kv: Optional[int] = None):
+    """``x`` (already normed) through qkv, rope, the attention op and ``o``.
+
+    A stack whose layers differ (models/laguna.py) says per call what the
+    config says once: ``num_heads`` query heads, the layer's
+    ``sliding_window`` (``None``: none), its own ``cos`` / ``sin`` (which may
+    rotate part of a head: ``ops.rope.apply_rope``) and key tile.  A leaf
+    ``lp["gate"]`` (``[hidden, heads]``) is a per-head sigmoid gate read from
+    ``x`` and applied to the op's output before ``o``."""
     b, s, h = x.shape
-    nh, nkv, d = cfg.num_attention_heads, cfg.kv_heads, cfg.head_size
+    nh, nkv, d = num_heads or cfg.num_attention_heads, cfg.kv_heads, cfg.head_size
+    if sliding_window is _CONFIG_WINDOW:
+        sliding_window = cfg.sliding_window
     if cfg.fuse_qkv:
         qkv = linear_ops.apply_linear(lp["qkv"], x)
         q, k, v = jnp.split(qkv, [nh * d, (nh + nkv) * d], axis=-1)
@@ -282,13 +298,18 @@ def _attention_block(lp, x, cos, sin, cfg: LlamaConfig, policy: DtypePolicy,
         q, k, v,
         impl=cfg.attention_impl,
         causal=True,
-        sliding_window=cfg.sliding_window,
+        sliding_window=sliding_window,
         softmax_dtype=policy.softmax_dtype,
         attention_mask=attention_mask,
         segment_ids=segment_ids,
         block_q=cfg.flash_block_q,
-        block_kv=cfg.flash_block_kv,
+        block_kv=block_kv or cfg.flash_block_kv,
     )
+    if "gate" in lp:
+        with jax.named_scope("head_gate"):
+            gate = jax.nn.sigmoid(
+                linear_ops.apply_linear(lp["gate"], x).astype(policy.softmax_dtype))
+            out = out * gate.astype(out.dtype)[..., None]
     out = out.reshape(b, s, nh * d)
     # RowParallel o_proj; reduce(-scatter under SP) inserted by GSPMD
     # (reference modeling_llama.py:475)

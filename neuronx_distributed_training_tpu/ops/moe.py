@@ -36,6 +36,15 @@ the dropped-vs-dropless validation at ``training_orchestrator.py:60-102``):
   gathered in the compute dtype, their gradients reduce-scattered in
   ``reduce_dtype``.
 
+- **a held range** (``MoEConfig.experts_held``): the program is one chip of
+  an expert-parallel deployment, alone.  It routes over all the experts,
+  holds a range of them and multiplies the rows that chose one of those
+  (``_held_experts``: a static operand of ``_HELD_ROWS`` x the even share,
+  slices of the sorted rows past it, no row dropped); the other chips' rows
+  are left out and nothing stands in for them.  **A shared expert**
+  (``params["shared"]``) is computed once beside the routed sum and added
+  ungated; ``routed_scaling_factor`` scales the routed sum against it.
+
 SwiGLU experts (``glu_mlp`` in the reference): w_gate/w_up fused as one
 ``[E, h, 2*ff]`` tensor, w_down ``[E, ff, h]``.
 """
@@ -71,6 +80,22 @@ class MoEConfig:
     # de-bias capacity drops from sequence position (reference
     # token_shuffle_group_size, transformer.py:410-411); dropped path only
     token_shuffle_group_size: int = 0
+    # the renormalised gate weights times this (a routed sum scaled against a
+    # shared expert's: models/laguna.py)
+    routed_scaling_factor: float = 1.0
+    # ``(lo, hi)``: this program holds experts ``lo .. hi - 1`` of
+    # ``num_experts`` alone (one chip of an expert-parallel deployment, the
+    # others absent): routing runs over all of them, the expert leaves hold
+    # ``hi - lo`` and only the rows that chose one of those are multiplied
+    # (``_held_experts``); dropless only
+    experts_held: Optional[tuple[int, int]] = None
+
+    @property
+    def experts_resident(self) -> int:
+        """The leading dim of the expert leaves."""
+        if self.experts_held is None:
+            return self.num_experts
+        return self.experts_held[1] - self.experts_held[0]
 
     @classmethod
     def from_config(cls, moe_cfg: dict[str, Any]) -> "MoEConfig":
@@ -87,6 +112,9 @@ class MoEConfig:
             router_z_loss_coef=float(m.get("router_z_loss_coef", 0.0)),
             normalize_top_k_affinities=bool(m.get("normalize_top_k_affinities", True)),
             token_shuffle_group_size=int(m.get("token_shuffle_group_size", 0) or 0),
+            routed_scaling_factor=float(m.get("routed_scaling_factor", 1.0)),
+            experts_held=(tuple(int(i) for i in m["experts_held"])
+                          if m.get("experts_held") is not None else None),
         )
 
 
@@ -99,12 +127,12 @@ def init_moe_params(key: jax.Array, hidden: int, ffn: int, cfg: MoEConfig,
                     dtype=jnp.float32, stddev: float = 0.02):
     """Router + fused SwiGLU expert weights, expert-major ``[E, ...]``."""
     kr, kgu, kd = jax.random.split(key, 3)
-    e = cfg.num_experts
+    e, held = cfg.num_experts, cfg.experts_resident
     return {
         "router": {"w": (jax.random.normal(kr, (hidden, e)) * stddev).astype(jnp.float32)},
         "experts": {
-            "gate_up": (jax.random.normal(kgu, (e, hidden, 2 * ffn)) * stddev).astype(dtype),
-            "down": (jax.random.normal(kd, (e, ffn, hidden)) * stddev).astype(dtype),
+            "gate_up": (jax.random.normal(kgu, (held, hidden, 2 * ffn)) * stddev).astype(dtype),
+            "down": (jax.random.normal(kd, (held, ffn, hidden)) * stddev).astype(dtype),
         },
     }
 
@@ -179,6 +207,8 @@ def route(router_params, x: jax.Array, cfg: MoEConfig):
         probs, idx = jax.lax.top_k(probs_full, cfg.top_k)
     if cfg.normalize_top_k_affinities and cfg.top_k > 1:
         probs = probs / jnp.sum(probs, axis=-1, keepdims=True)
+    if cfg.routed_scaling_factor != 1.0:
+        probs = probs * cfg.routed_scaling_factor
     return probs, idx, logits
 
 
@@ -525,6 +555,156 @@ def _exchange_bwd(*args):
 _exchange_experts.defvjp(_exchange_fwd, _exchange_bwd)
 
 
+#: the sorted-rows operand of a block that holds a range of the experts
+#: (``MoEConfig.experts_held``), as a multiple of the rows it receives when
+#: routing is even, ``T * k * held / E``.  What it receives is the data's (one
+#: sequence's tokens lean to the same experts: PERF.md section 7), anything
+#: up to ``T * min(k, held)``, which as an operand with its ``gu`` would cost
+#: the step more memory than the held experts' weights.  So the operand is a
+#: bound, and a step past it runs the same block on successive slices of the
+#: sorted rows, keeping nothing but its inputs and running each slice forward
+#: again in the backward pass: no row dropped, no temporary past the bound.
+_HELD_ROWS = 4.0
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _held_experts(experts, x, probs, chosen, k: int, bound: int, compute_dtype):
+    """The dropless block's routed half where the program holds a range of the
+    experts alone: ``experts`` their weights (any float dtype; their
+    gradients leave in it, float32 straight from the kernel), ``chosen``
+    ``[T * k]`` the held expert of each (token, choice) row, counted from the
+    range's first, or ``held`` for a row whose expert lies elsewhere.  Such a
+    row is sorted last and left out: nothing stands in for the absent chips.
+    Rows held at most ``bound``: one pass, ``(gu, ys)`` and the sort kept;
+    more: slices (``_HELD_ROWS``)."""
+    return _held_pass(0, k, bound, compute_dtype, experts, x, probs, chosen)[0]
+
+
+def _held_sides(k: int, bound: int, compute_dtype, held: int, f2: int, h: int):
+    """``((forward, backward) under the bound, (forward, backward) past
+    it)`` of ``_held_experts``, in ``_exchange_sides``'s shapes."""
+
+    def cast(experts):
+        return (experts["gate_up"].astype(compute_dtype),
+                experts["down"].astype(compute_dtype))
+
+    def grads(x, d_x, d_probs, d_gu, d_down):
+        return {"gate_up": d_gu, "down": d_down}, d_x.astype(x.dtype), d_probs
+
+    def under_forward(experts, x, probs, chosen):
+        order, sizes = _sorted_rows(chosen, held, bound)
+        y, kept = _expert_rows(x.astype(compute_dtype), probs, order, sizes,
+                               *cast(experts), k=k, count=jnp.sum(sizes))
+        return y, (*kept, order, sizes)
+
+    def under_backward(ct, kept, experts, x, probs, chosen):
+        *kept, order, sizes = kept
+        return grads(x, *_expert_rows_back(
+            ct, kept, x.astype(compute_dtype), probs, order, sizes, *cast(experts),
+            k=k, count=jnp.sum(sizes), grad_dtype=experts["gate_up"].dtype))
+
+    def slices(chosen):
+        """``(n, slice_of)``: the sorted held rows as ``n`` slices of
+        ``bound``; ``slice_of(j)`` the rows of slice ``j`` and how many of
+        them each group holds."""
+        order, sizes = _sorted_rows(chosen, held, chosen.shape[0])
+        with jax.named_scope("dispatch"):
+            order = jnp.pad(order, (0, -order.shape[0] % bound))
+            ends = jnp.cumsum(sizes)
+
+            def slice_of(j):
+                lo, hi = j * bound, (j + 1) * bound
+                part = jnp.clip(jnp.minimum(ends, hi) - jnp.maximum(ends - sizes, lo), 0)
+                return jax.lax.dynamic_slice_in_dim(order, lo, bound), part
+
+            return (ends[-1] + bound - 1) // bound, slice_of
+
+    def past_forward(experts, x, probs, chosen):
+        xc, weights = x.astype(compute_dtype), cast(experts)
+        n, slice_of = slices(chosen)
+
+        def one(j, y):
+            order, sizes = slice_of(j)
+            return y + _expert_rows(xc, probs, order, sizes, *weights, k=k,
+                                    count=jnp.sum(sizes))[0]
+
+        # nothing kept: the other side's shapes, empty
+        return (jax.lax.fori_loop(0, n, one, jnp.zeros_like(xc)),
+                (jnp.zeros((bound, f2), compute_dtype), jnp.zeros((bound, h), compute_dtype),
+                 jnp.zeros((bound,), chosen.dtype), jnp.zeros((held,), chosen.dtype)))
+
+    def past_backward(ct, kept, experts, x, probs, chosen):
+        xc, weights = x.astype(compute_dtype), cast(experts)
+        n, slice_of = slices(chosen)
+        grad_dtype = experts["gate_up"].dtype
+
+        def one(j, acc):
+            order, sizes = slice_of(j)
+            count = jnp.sum(sizes)
+            _, kept_j = _expert_rows(xc, probs, order, sizes, *weights, k=k, count=count)
+            return jax.tree_util.tree_map(jnp.add, acc, _expert_rows_back(
+                ct, kept_j, xc, probs, order, sizes, *weights, k=k, count=count,
+                grad_dtype=grad_dtype))
+
+        zero = (jnp.zeros_like(xc), jnp.zeros_like(probs),
+                jnp.zeros(experts["gate_up"].shape, grad_dtype),
+                jnp.zeros(experts["down"].shape, grad_dtype))
+        return grads(x, *jax.lax.fori_loop(0, n, one, zero))
+
+    return (under_forward, under_backward), (past_forward, past_backward)
+
+
+def _held_pass(back: int, k, bound, compute_dtype, *operands):
+    """The forward (0) or backward (1) pass of ``_held_experts``: under the
+    bound or past it, by the count of the rows held."""
+    experts, x, _, chosen = operands[-4:]
+    held = experts["gate_up"].shape[0]
+    under, past = _held_sides(k, bound, compute_dtype, held,
+                              experts["gate_up"].shape[2], x.shape[1])
+    if bound >= chosen.shape[0]:  # the bound holds every case
+        return under[back](*operands)
+    return jax.lax.cond(jnp.sum(chosen < held) <= bound, under[back], past[back],
+                        *operands)
+
+
+def _held_fwd(experts, x, probs, chosen, *static):
+    y, kept = _held_pass(0, *static, experts, x, probs, chosen)
+    return y, (kept, experts, x, probs, chosen)
+
+
+def _held_bwd(*args):
+    *static, (kept, *operands), ct = args
+    return (*_held_pass(1, *static, ct, kept, *operands), None)
+
+
+_held_experts.defvjp(_held_fwd, _held_bwd)
+
+
+def _dropless_held(experts, x, probs, idx, cfg: MoEConfig, *, compute_dtype):
+    """``_dropless_experts`` under ``cfg.experts_held``; ``stats``:
+    ``moe/held_rows``, the rows that chose a held expert,
+    ``moe/held_rows_share``, that count over the even share ``T * k * held /
+    E``, and ``moe/row_bound``, 1 where the count passed the operand's bound
+    (``_HELD_ROWS``) and the step went by slices."""
+    t, k = idx.shape
+    lo, hi = cfg.experts_held
+    held = hi - lo
+    even = t * k * held / cfg.num_experts
+    bound = min(8 * math.ceil(_HELD_ROWS * even / 8), t * k)
+    with jax.named_scope("dispatch"):
+        chosen = idx.reshape(-1) - lo
+        chosen = jnp.where((chosen >= 0) & (chosen < held), chosen, held)
+        rows = jnp.sum(chosen < held)
+    facts = shd.trace_facts()
+    if facts is not None:
+        facts["moe_experts_held"] = [lo, hi, cfg.num_experts]
+        facts["moe_row_bounds"] = [bound]
+    y = _held_experts(experts, x, probs, chosen, k, bound, compute_dtype)
+    stats = {"moe/held_rows": rows, "moe/held_rows_share": rows / even,
+             "moe/row_bound": rows > bound}
+    return y, jax.tree_util.tree_map(lambda v: v.astype(jnp.float32), stats)
+
+
 def _dropless_experts(experts, x: jax.Array, probs: jax.Array, idx: jax.Array,
                       cfg: MoEConfig, *, compute_dtype, reduce_dtype=jnp.float32,
                       expert_axis: Optional[str] = None,
@@ -550,6 +730,12 @@ def _dropless_experts(experts, x: jax.Array, probs: jax.Array, idx: jax.Array,
     """
     t, h = x.shape
     e, k = cfg.num_experts, cfg.top_k
+    if cfg.experts_held is not None:
+        if expert_axis is not None:
+            raise NotImplementedError(
+                "moe.experts_held under the exchange over the expert axis: a "
+                "held range is one chip's share, with no peer to exchange with")
+        return _dropless_held(experts, x, probs, idx, cfg, compute_dtype=compute_dtype)
     if expert_axis is None:
         # XLA's SPMD partitioner has no rule for ragged_dot's GROUP dimension:
         # with the expert dim sharded it computes each shard's local expert
@@ -694,6 +880,14 @@ def _dropless_on_mesh(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype,
     return y.astype(x.dtype), idx, logits, stats
 
 
+def _shared_expert(shared, x: jax.Array, compute_dtype) -> jax.Array:
+    """A SwiGLU every token passes, computed once beside the routed sum and
+    added to it ungated (``shared``: ``gate_up`` / ``down`` linears)."""
+    with jax.named_scope("shared"):
+        gu = x.astype(compute_dtype) @ shared["gate_up"]["w"].astype(compute_dtype)
+        return (_swiglu(gu) @ shared["down"]["w"].astype(compute_dtype)).astype(x.dtype)
+
+
 def _shuffle_permutation(t: int, group: int) -> jnp.ndarray:
     """Deterministic stride (interleave) permutation of ``t`` tokens.
 
@@ -717,6 +911,7 @@ def moe_block(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat1
     """[b, s, h] wrapper dispatching dropped/dropless; returns ``(y, aux)``,
     ``aux`` the ``router_logits``, the ``expert_idx`` and ``stats``, per-step
     scalars of the block under their metric names (``_dropless_experts``).
+    A ``params["shared"]`` is a shared expert, added to the routed sum.
 
     ``act_spec`` is the block-boundary spec ``x`` is laid out by (default
     ``shd.act_spec()``: batch over the data axes); the dropless block is
@@ -731,7 +926,13 @@ def moe_block(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat1
             y, idx, logits, stats = _dropless_on_mesh(
                 params, x, cfg, compute_dtype=compute_dtype,
                 reduce_dtype=reduce_dtype, act_spec=act_spec)
+            if "shared" in params:
+                y = y + _shared_expert(params["shared"], x, compute_dtype)
             return y, {"router_logits": logits, "expert_idx": idx, "stats": stats}
+        if cfg.experts_held is not None or "shared" in params:
+            raise NotImplementedError(
+                "moe.experts_held and a shared expert are wired for the "
+                "dropless block only")
         flat = x.reshape(b * s, h)
         shuffle = (cfg.token_shuffle_group_size or 0) > 1
         if shuffle:

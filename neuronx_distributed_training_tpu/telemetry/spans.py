@@ -49,10 +49,9 @@ NON_PRODUCTIVE_SPANS = ("compile", "validate", "checkpoint", "restart",
 #: the inner scopes it holds (docs/observability.md "Named scopes").  An op's
 #: scope path reaches the profiler as the ``tf_op`` stat of its event metadata,
 #: wrapped as ``jvp(<scope>)`` forward and ``transpose(jvp(<scope>))`` backward.
-#: ``models/ouro.py`` alone opens one more inner scope, ``ce_head/exit_gate``;
-#: a reader that does not know it counts its time under ``ce_head``.  It joins
-#: this table with the benchmark's copy (``benchmark/readers/scope_time.py``,
-#: which a test holds equal to this one): PERF.md section 7.
+#: The benchmark keeps its own copy (``benchmark/readers/scope_time.py``), which
+#: a test holds equal to this table; scopes that single families open besides
+#: are ``FAMILY_SCOPES``.
 DEVICE_SCOPES: dict[str, tuple[str, ...]] = {
     "embed": (),
     "attention": ("flash_fwd", "flash_dq", "flash_dkv"),
@@ -61,6 +60,20 @@ DEVICE_SCOPES: dict[str, tuple[str, ...]] = {
     "ce_head": (),
     "grad_accum": (),
     "optimizer": ("clip", "adamw", "zero1_bucket_ag"),
+}
+
+#: Inner scopes that one family's step opens inside a scope of
+#: ``DEVICE_SCOPES``, top-level scope -> names: ``models/ouro.py``'s
+#: ``ce_head/exit_gate``; ``models/laguna.py``'s ``attention/attn_full`` and
+#: ``attention/attn_window`` (the whole attention block of a layer of that
+#: kind, the flash kernels' scopes inside it), ``attention/.../head_gate`` and
+#: ``moe/shared`` (``ops/moe.py``'s shared expert).  A reader that does not
+#: know one counts its time under the scope that holds it, so nothing becomes
+#: unscoped; ``benchmark/readers/inner_scope.py`` reads one by its name.
+FAMILY_SCOPES: dict[str, tuple[str, ...]] = {
+    "attention": ("attn_full", "attn_window", "head_gate"),
+    "moe": ("shared",),
+    "ce_head": ("exit_gate",),
 }
 
 

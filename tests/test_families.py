@@ -175,6 +175,7 @@ def test_what_each_family_cannot_do():
     assert refused == {
         "llama": [], "gpt": ["onef1b_head"], "mixtral": ["onef1b_head"],
         "ouro": ["decode", "logits", "onef1b_head", "pipeline"],
+        "laguna": ["decode", "onef1b_head", "pipeline"],
     }
 
 
@@ -246,13 +247,14 @@ def test_lower_layers_import_nothing_from_models(package):
 @pytest.mark.parametrize("package", ["trainer", "autotune", "config"])
 def test_callers_name_no_familys_config_class(package):
     classes = {type(toy(name)[1]).__name__ for name in FAMILY_NAMES}
-    assert classes == {"LlamaConfig", "MixtralConfig", "GPTConfig", "OuroConfig"}
+    assert classes == {"LlamaConfig", "MixtralConfig", "GPTConfig", "OuroConfig",
+                       "LagunaConfig"}
     for path in sorted((PKG / package).rglob("*.py")):
         names = {getattr(n, "id", None) or getattr(n, "attr", None)
                  for n in ast.walk(ast.parse(path.read_text()))}
         assert not names & classes, f"{path.relative_to(PKG)} names {names & classes}"
         family_modules = [m for m in _imports(path)
-                          if re.search(r"\.models\.(llama|mixtral|gpt|ouro)$", m)]
+                          if re.search(r"\.models\.(llama|mixtral|gpt|ouro|laguna)$", m)]
         assert not family_modules, f"{path.relative_to(PKG)} imports {family_modules}"
 
 

@@ -359,3 +359,50 @@ def test_dropless_block_is_partitioned_by_tokens_on_v5e(topo):
         f"bf16[{ep},{tokens},{hidden}]", f"f32[{ep},{tokens},{cfg.top_k}]"}, channels
     assert {shape for kind, shape in channels if kind == "reduce-scatter"} == {
         f"f32[{resident},{hidden},{2 * ffn}]", f"f32[{resident},{ffn},{hidden}]"}, channels
+
+
+# --------------------------------------------------------------------------
+# the mixed stack (models/laguna.py) at the benchmark's cut
+# --------------------------------------------------------------------------
+
+#: the cell ``laguna-s2.1-pretrain-ep32`` (benchmark/configs/laguna-s-2.1.json):
+#: published widths, layer 0 and the period that follows it, experts 0-7 of 256
+#: held, 1/8 of the vocabulary, one sequence of 8192
+LAGUNA_CUT = {
+    "model.num_hidden_layers": 5, "model.vocab_size": 12544,
+    "model.num_experts_held": [0, 8],
+    "distributed_strategy.expert_model_parallel_size": 1,
+    "data.global_batch_size": 1,
+}
+
+
+@pytest.mark.parametrize("nh, window, block_kv", [(72, 512, 512), (48, None, None)],
+                         ids=["window-512-group-9", "causal-group-6"])
+def test_flash_compiles_at_the_mixed_stacks_shapes(topo, nh, window, block_kv):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct((1, 8192, n, 128), jnp.bfloat16, sharding=one_chip)
+            for n in (nh, 8, 8)]
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, sliding_window=window, block_kv=block_kv,
+            interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_the_mixed_stacks_cell_fits_one_v5e_under_full_only(topo):
+    """Why the cell runs ``full``: with 9.06 GiB of state (811 M parameters),
+    ``selective`` (five layers' residuals at 8192 tokens) is refused for one
+    v5e at 19.67 GiB; under ``full`` the compiler takes the step.  Its report
+    of temporaries counts both ways through the held experts (under the rows'
+    bound and past it), of which a step runs one."""
+    compiled = _compile_step(topo, "hf_laguna_s_2_1_config.yaml", 1, LAGUNA_CUT)
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes > 0.9 * ma.argument_size_in_bytes
+    assert 8.9 * 2**30 < ma.argument_size_in_bytes < 9.2 * 2**30
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
+        _compile_step(topo, "hf_laguna_s_2_1_config.yaml", 1, {
+            **LAGUNA_CUT, "model.activations_checkpoint_granularity": "selective"})
