@@ -133,9 +133,16 @@ class LagunaConfig:
     def block_kv(self, attention: str) -> Optional[int]:
         """Key tile of a layer's flash kernels: the config's, but in window
         layers no wider than the window (in lanes of 128): a wider tile shows
-        a query block mostly masked keys.  One v5e, window 512 x 72 heads x
-        seq 8192, forward + forward and backward of one layer (PERF.md section
-        4, PR 36): 21.3 ms at key tiles of 512, 31.4 at the default 2048."""
+        a query block mostly masked keys.  At the published window of 512 that
+        is the default query tile, and a call whose window is no wider than
+        its square tile takes the flash kernels' diagonal walk
+        (``ops/flash_attention.py``).  One v5e, window 512 x 72 heads x seq
+        8192, forward + forward and backward of one layer: 31.4 ms at the
+        default key tile of 2048 and 21.3 at 512 the band's way (PERF.md
+        section 4, PR 36), 9.0 the diagonal way (PR 38).  A window under 512
+        gets a key tile under the query tile here and so keeps the band walk
+        (26.5 ms at 256 where 512 x 512 tiles would take 7.4: PERF.md
+        section 7)."""
         from neuronx_distributed_training_tpu.ops.flash_attention import DEFAULT_BLOCK_KV
 
         window = self.window(attention)

@@ -39,6 +39,33 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
   ``flash_dkv``: the benchmark's finder (benchmark/trace_reduce.py::flash_kind)
   knows them by that.  So the band's offsets are arithmetic in the index
   maps, not a scalar-prefetch table passed in ahead of q.
+- the diagonal walk, a second walk with kernel bodies of its own (they share
+  the mask helpers with the band walk and nothing of the walk): a causal
+  self-attention call whose window is no wider than its square tile
+  (``window <= bq == bkv``, ``q_offset == 0``, ``sq == skv``, no
+  ``attention_mask``, no ``segment_ids``: ``_takes_diagonal``, from the
+  call's own arguments at trace time) sees, from query block ``qi``, keys of
+  blocks ``qi - 1`` and ``qi`` alone.  So the grid has no band dimension: one
+  step a query block (fwd, dq: K and V handed in twice, index maps
+  ``max(qi - 1, 0)`` and ``qi``) or a key block and group head (dkv: q, do,
+  lse, delta handed in twice, ``ki`` and ``min(ki + 1, last)``), and the body
+  walks the block in sub-tiles of ``r`` rows by ``r`` keys (``_sub_rows``;
+  a static Python loop whose bounds are ``_kv_span`` / ``_q_span`` of the two
+  blocks cut into sub-tiles, ``_sub_band``), skipping the sub-tiles the
+  window hides and masking only those its edge or the diagonal crosses.
+  Every visible key of a row is in hand at once, so the forward takes a
+  plain softmax: one maximum and one sum a row, no rescaling, no scratch;
+  dq sums in values and stores once; dkv keeps the band walk's scratch for
+  the sum over the group.  The heads of a GQA group are adjacent in the grid,
+  so K and V are fetched once a group.  Same operand dtypes as the band walk
+  (bf16 into the score and p @ v matmuls, float32 accumulation and ``ds``),
+  another order of summation: close to the band walk, not equal.  A long
+  walk needs the online softmax and its scratch, and a walk that ends inside
+  two blocks is slowed by exactly that (one v5e, window 512 x 72 heads x seq
+  8192: 5.56 ms a forward call the band's way, 1.87 this way), hence two
+  walks and not one adapted.  ``flash_band`` of ``run_summary.json`` says
+  which a call took (``walk``) and, for this one, ``sub_tiles``
+  ``[computed, of]`` those the band of a query block holds.
 - GQA: the kv BlockSpec index-maps query-head ``h`` -> kv-head
   ``h // (nh // nkv)`` so K/V are never physically repeated (the reference
   replicates KV via ``kv_shared_group_size`` instead — unnecessary here).
@@ -190,14 +217,23 @@ def _band(bq, bkv, num_q, num_kv, causal, window, q_offset) -> _Band:
     return band._replace(kv=longest(_kv_span, num_q), q=longest(_q_span, num_kv))
 
 
-def _call_band(bq, bkv, num_q, num_kv, causal, window, q_offset) -> _Band:
+def _call_band(bq, bkv, num_q, num_kv, causal, window, q_offset,
+               sub: Optional[_Band] = None) -> _Band:
     """``_band`` of a forward call, recorded for ``run_summary.json`` where a
-    trace collects such facts: ``flash_band``, one entry per distinct shape."""
+    trace collects such facts: ``flash_band``, one entry per distinct shape.
+    ``sub`` (``_sub_band``): the call takes the diagonal walk, and
+    ``sub_tiles`` says how many ``[computed, of]`` the sub-tiles that the
+    band of a query block holds."""
     band = _band(bq, bkv, num_q, num_kv, causal, window, q_offset)
     facts = shd.trace_facts()
     if facts is not None:
         shape = {"seq": num_q * bq, "kv_blocks": num_kv, "kv_band": band.kv,
-                 "q_blocks": num_q, "q_band": band.q}
+                 "q_blocks": num_q, "q_band": band.q, "walk": "band"}
+        if sub is not None:
+            n = sub.num_q // 2
+            spans = (_kv_span(sub, n + a) for a in range(n))
+            shape.update(walk="diagonal", sub_tiles=[
+                sum(last - first + 1 for first, last in spans), band.kv * n * n])
         if shape not in facts.setdefault("flash_band", []):
             facts["flash_band"].append(shape)
     return band
@@ -515,6 +551,17 @@ def _dkv_kernel(
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+def _delta_rows(g, o, dlse):
+    """The backward kernels' ``delta`` operand [b, nh, sq, SUBLANES]."""
+    do = g.astype(jnp.float32)
+    delta = jnp.sum(do * o.astype(jnp.float32), axis=-1)  # [b, nh, sq]
+    if dlse is not None:
+        # lse exposed as a differentiable output (ring merge): d lse / d s = p,
+        # so ds = p*(dp - delta + dlse) — fold dlse into the delta operand
+        delta = delta - dlse
+    return jnp.broadcast_to(delta[..., None], delta.shape + (SUBLANES,))
+
+
 def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpret,
                 dlse=None, band=None):
     q, k, v, kvm, seg, o, lse = res  # q [b, nh, sq, d]; k/v [b, nkv, skv, d]
@@ -531,13 +578,7 @@ def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpre
     def q_at(ki, j):  # dkv a key block's query blocks
         return _walk(_q_span, band, ki, j)[2]
 
-    do = g.astype(jnp.float32)
-    delta = jnp.sum(do * o.astype(jnp.float32), axis=-1)  # [b, nh, sq]
-    if dlse is not None:
-        # lse exposed as a differentiable output (ring merge): d lse / d s = p,
-        # so ds = p*(dp - delta + dlse) — fold dlse into the delta operand
-        delta = delta - dlse
-    delta = jnp.broadcast_to(delta[..., None], (b, nh, sq, SUBLANES))
+    delta = _delta_rows(g, o, dlse)
 
     common = dict(sm_scale=sm_scale, causal=causal, window=window, q_offset=q_offset,
                   bq=bq, bkv=bkv, band=band, masked=kvm is not None,
@@ -620,27 +661,306 @@ def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpre
 
 
 # ---------------------------------------------------------------------------
+# the diagonal walk: a window no wider than the (square) tile
+# ---------------------------------------------------------------------------
+
+
+def _takes_diagonal(q, k, kvm, seg, causal, window, q_offset, bq, bkv) -> bool:
+    """Whether a call goes the diagonal walk: all from its own arguments."""
+    return bool(
+        causal and window is not None and window <= bq == bkv and q_offset == 0
+        and q.shape[2] == k.shape[2] and kvm is None and seg is None)
+
+
+def _sub_rows(bq: int, window: int) -> int:
+    """Rows (and keys) of a sub-tile of the diagonal walk.  A query block
+    computes ``bq x (window + r)`` scores for the ``bq x window`` it shows, so
+    a smaller ``r`` skips more, and feeds the MXU shorter matmuls.  One v5e, one
+    layer at 72 / 8 heads x 128, seq 8192, tiles 512, forward twice + dq +
+    dkv, ms (PERF.md section 6, PR 38): window 512: r 128 9.98, **256 9.04**,
+    512 10.84 (the band walk 18.62); window 256: 8.29, **7.44**; window 128:
+    **7.20**, 7.45."""
+    r = 2 * LANES
+    return LANES if window <= LANES or bq % r else r
+
+
+def _sub_band(bq: int, window: int) -> _Band:
+    """Two neighbouring blocks as one sequence cut into sub-tiles of ``r`` rows
+    by ``r`` keys (fwd, dq: a query block, second, with the key block before
+    it; dkv: a key block, first, with the query block after it).  Under
+    ``window <= bq`` nothing else is visible, so ``_kv_span`` / ``_q_span`` of
+    this band, on Python ints, are the sub-tiles a row or key sub-block
+    meets, counted from the first block's start."""
+    r = _sub_rows(bq, window)
+    n = 2 * (bq // r)
+    return _Band(True, window, 0, r, r, n, n, n, n)
+
+
+def _sub_masks(sub: _Band, pairs) -> dict:
+    """``_inner_mask`` of those of ``pairs`` (row sub-block, key sub-tile) that
+    the window's edge or the diagonal crosses, keyed by the one thing such a
+    mask depends on: how many sub-tiles the rows lie after the keys.  A pair
+    with no entry is wholly visible."""
+    r = sub.bq
+    crossed = {a - j for a, j in pairs
+               if (a - j) * r - (r - 1) < 0 or (a - j) * r + r - 1 >= sub.window}
+    return {lag: _inner_mask(r, r, lag, 0, True, sub.window, 0) for lag in sorted(crossed)}
+
+
+def _sub_tile(first_ref, second_ref, sub: _Band, i, width=None):
+    """Sub-block ``i`` (counted over both blocks) of a pair of block refs."""
+    r, n = sub.bq, sub.num_q // 2
+    ref = first_ref if i < n else second_ref
+    x = ref[0, 0, pl.ds((i % n) * r, r), :]
+    return x if width is None else x[:, :width]
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _at_edge_or_not(at_edge, walk):
+    """``walk(True)`` for the block at the sequence's edge, which lacks its
+    neighbour (the index map hands it a stand-in), else ``walk(False)``."""
+    pl.when(at_edge)(lambda: walk(True))
+    pl.when(jnp.logical_not(at_edge))(lambda: walk(False))
+
+
+_QK = ((1,), (1,))  # [rows, d] x [keys, d] -> [rows, keys]
+_PV = ((1,), (0,))  # [rows, keys] x [keys, d] -> [rows, d]
+_TN = ((0,), (0,))  # [rows, keys] x [rows, d] -> [keys, d]
+
+
+def _row_spans(sub: _Band):
+    """fwd, dq: the key sub-tiles each row sub-block of the query block (the
+    second of the two blocks) meets, and the masks of those that need one."""
+    n = sub.num_q // 2
+    spans = [_kv_span(sub, n + a) for a in range(n)]
+    return spans, _sub_masks(sub, [(n + a, j) for a, (first, last) in enumerate(spans)
+                                   for j in range(first, last + 1)])
+
+
+def _diag_fwd_kernel(q_ref, kp_ref, kc_ref, vp_ref, vc_ref, o_ref, lse_ref, *,
+                     sm_scale, sub):
+    r, n = sub.bq, sub.num_q // 2
+    spans, masks = _row_spans(sub)
+
+    def walk(first_block):
+        for a, (first, last) in enumerate(spans):
+            rows = pl.ds(a * r, r)
+            q = q_ref[0, 0, rows, :]
+            tiles = range(max(first, n) if first_block else first, last + 1)
+            scores = []
+            for j in tiles:
+                s = _dot(q, _sub_tile(kp_ref, kc_ref, sub, j), _QK) * sm_scale
+                mask = masks.get(n + a - j)
+                scores.append(s if mask is None else s + mask)
+            # every visible key of these rows is in hand (each row sees itself
+            # at least): a plain softmax, one maximum and one sum a row
+            m = jnp.max(functools.reduce(jnp.maximum, scores), axis=-1, keepdims=True)
+            probs = [jnp.exp(s - m) for s in scores]
+            l = jnp.sum(functools.reduce(jnp.add, probs), axis=-1, keepdims=True)
+            acc = functools.reduce(jnp.add, (
+                _dot(p.astype(vc_ref.dtype), _sub_tile(vp_ref, vc_ref, sub, j), _PV)
+                for p, j in zip(probs, tiles)))
+            o_ref[0, 0, rows, :] = (acc * (1.0 / l)).astype(o_ref.dtype)
+            lse_ref[0, 0, rows, :] = jnp.broadcast_to(m + jnp.log(l), (r, SUBLANES))
+
+    _at_edge_or_not(pl.program_id(2) == 0, walk)  # no block before the first
+
+
+def _diag_dq_kernel(q_ref, kp_ref, kc_ref, vp_ref, vc_ref, do_ref, lse_ref, delta_ref,
+                    dq_ref, *, sm_scale, sub):
+    r, n = sub.bq, sub.num_q // 2
+    spans, masks = _row_spans(sub)
+
+    def walk(first_block):
+        for a, (first, last) in enumerate(spans):
+            rows = pl.ds(a * r, r)
+            q = q_ref[0, 0, rows, :]
+            do = do_ref[0, 0, rows, :]
+            lse = lse_ref[0, 0, rows, :][:, :1]
+            delta = delta_ref[0, 0, rows, :][:, :1]
+            dq = None
+            for j in range(max(first, n) if first_block else first, last + 1):
+                k = _sub_tile(kp_ref, kc_ref, sub, j)
+                s = _dot(q, k, _QK) * sm_scale
+                mask = masks.get(n + a - j)
+                p = jnp.exp((s if mask is None else s + mask) - lse)
+                # do and v are what the band walk casts to float32 first: the
+                # same products, summed in float32 either way
+                dp = _dot(do, _sub_tile(vp_ref, vc_ref, sub, j), _QK)
+                ds = p * (dp - delta) * sm_scale
+                # float32 beside ds and p, as in the band walk's kernels
+                part = _dot(ds, k.astype(jnp.float32), _PV)  # jaxlint: disable=JL106
+                dq = part if dq is None else dq + part
+            dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
+
+    _at_edge_or_not(pl.program_id(2) == 0, walk)
+
+
+def _diag_dkv_kernel(qc_ref, qn_ref, k_ref, v_ref, doc_ref, don_ref, lsec_ref, lsen_ref,
+                     deltac_ref, deltan_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
+                     sm_scale, sub, group, num_kv):
+    r, n = sub.bq, sub.num_q // 2
+    spans = [_q_span(sub, j) for j in range(n)]
+    masks = _sub_masks(sub, [(a, j) for j, (first, last) in enumerate(spans)
+                             for a in range(first, last + 1)])
+    g = pl.program_id(3)
+
+    @pl.when(g == 0)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    def walk(last_block):
+        for j, (first, last) in enumerate(spans):
+            keys = pl.ds(j * r, r)
+            k = k_ref[0, 0, keys, :]
+            v = v_ref[0, 0, keys, :]
+            dk = dv = None
+            for a in range(first, min(last, n - 1) + 1 if last_block else last + 1):
+                q = _sub_tile(qc_ref, qn_ref, sub, a)
+                do = _sub_tile(doc_ref, don_ref, sub, a)
+                lse = _sub_tile(lsec_ref, lsen_ref, sub, a, 1)
+                delta = _sub_tile(deltac_ref, deltan_ref, sub, a, 1)
+                s = _dot(q, k, _QK) * sm_scale
+                mask = masks.get(a - j)
+                p = jnp.exp((s if mask is None else s + mask) - lse)
+                dv_part = _dot(p, do.astype(jnp.float32), _TN)  # jaxlint: disable=JL106
+                dp = _dot(do, v, _QK)
+                ds = p * (dp - delta) * sm_scale
+                dk_part = _dot(ds, q.astype(jnp.float32), _TN)  # jaxlint: disable=JL106
+                dk = dk_part if dk is None else dk + dk_part
+                dv = dv_part if dv is None else dv + dv_part
+            dk_scr[keys, :] += dk
+            dv_scr[keys, :] += dv
+
+    # no query block after the last key block
+    _at_edge_or_not(pl.program_id(2) == num_kv - 1, walk)
+
+    @pl.when(g == group - 1)
+    def _finish():
+        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _diag_specs(b, nh, nkv, s, d, bq):
+    """Grid and block specs the diagonal walk's forward and dq share: the
+    heads of a GQA group are adjacent in the grid, so the key blocks' indices
+    stay put over them and are fetched once a group."""
+    group = nh // nkv
+
+    def q_spec(width):
+        return pl.BlockSpec((1, 1, bq, width),
+                            lambda bi, kh, qi, g: (bi, kh * group + g, qi, 0))
+
+    def kv_spec(back):  # key block qi - back; block 0 stands in before the first
+        return pl.BlockSpec((1, 1, bq, d),
+                            lambda bi, kh, qi, g: (bi, kh, jnp.maximum(qi - back, 0), 0))
+
+    params = pltpu.CompilerParams(dimension_semantics=("parallel",) * 4)
+    return (b, nkv, s // bq, group), q_spec, kv_spec, params
+
+
+def _diag_fwd(q, k, v, *, sm_scale, window, bq, interpret):
+    """The diagonal walk's forward: as ``_fwd_pallas``, one grid step a query
+    block, K and V handed in twice (the block before, the block itself)."""
+    b, nh, s, d = q.shape
+    nkv = k.shape[1]
+    sub = _sub_band(bq, window)
+    _call_band(bq, bq, s // bq, s // bq, True, window, 0, sub=sub)
+    grid, q_spec, kv_spec, params = _diag_specs(b, nh, nkv, s, d, bq)
+    with jax.named_scope("flash_fwd"):
+        o, lse = pl.pallas_call(
+            functools.partial(_diag_fwd_kernel, sm_scale=sm_scale, sub=sub),
+            name="flash_fwd",
+            grid=grid,
+            in_specs=[q_spec(d), kv_spec(1), kv_spec(0), kv_spec(1), kv_spec(0)],
+            out_specs=[q_spec(d), q_spec(SUBLANES)],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, nh, s, d), q.dtype),
+                jax.ShapeDtypeStruct((b, nh, s, SUBLANES), jnp.float32),
+            ],
+            compiler_params=params,
+            interpret=interpret,
+        )(q, k, k, v, v)
+    return o, lse
+
+
+def _diag_bwd(res, g, *, sm_scale, window, bq, interpret, dlse=None):
+    """The diagonal walk's backward: as ``_bwd_pallas``.  dq takes the
+    forward's grid and operands; dkv takes one grid step a key block and
+    group head, with q, do, lse and delta handed in twice (the query block of
+    the key block's own index, the one after)."""
+    q, k, v, _, _, o, lse = res
+    b, nh, s, d = q.shape
+    nkv = k.shape[1]
+    group, num_kv = nh // nkv, s // bq
+    sub = _sub_band(bq, window)
+    delta = _delta_rows(g, o, dlse)
+    grid, q_spec, kv_spec, params = _diag_specs(b, nh, nkv, s, d, bq)
+    with jax.named_scope("flash_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_diag_dq_kernel, sm_scale=sm_scale, sub=sub),
+            name="flash_dq",
+            grid=grid,
+            in_specs=[q_spec(d), kv_spec(1), kv_spec(0), kv_spec(1), kv_spec(0),
+                      q_spec(d), q_spec(SUBLANES), q_spec(SUBLANES)],
+            out_specs=q_spec(d),
+            out_shape=jax.ShapeDtypeStruct((b, nh, s, d), q.dtype),
+            compiler_params=params,
+            interpret=interpret,
+        )(q, k, k, v, v, g, lse, delta)
+
+    def rows(width, ahead):  # query block ki + ahead; the last stands in past it
+        return pl.BlockSpec(
+            (1, 1, bq, width), lambda bi, kh, ki, gi: (
+                bi, kh * group + gi, jnp.minimum(ki + ahead, num_kv - 1), 0))
+
+    keys = pl.BlockSpec((1, 1, bq, d), lambda bi, kh, ki, gi: (bi, kh, ki, 0))
+    with jax.named_scope("flash_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_diag_dkv_kernel, sm_scale=sm_scale, sub=sub,
+                              group=group, num_kv=num_kv),
+            name="flash_dkv",
+            grid=(b, nkv, num_kv, group),
+            in_specs=[rows(d, 0), rows(d, 1), keys, keys, rows(d, 0), rows(d, 1),
+                      rows(SUBLANES, 0), rows(SUBLANES, 1),
+                      rows(SUBLANES, 0), rows(SUBLANES, 1)],
+            out_specs=[keys, keys],
+            out_shape=[
+                jax.ShapeDtypeStruct(k.shape, k.dtype),
+                jax.ShapeDtypeStruct(v.shape, v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((bq, d), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            ),
+            interpret=interpret,
+        )(q, q, k, v, g, g, lse, lse, delta, delta)
+    return dq, dk, dv
+
+
+# ---------------------------------------------------------------------------
 # public API (custom_vjp over the [b, s, h, d] layout)
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10)
-)
-def _flash(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret):
-    o, _ = _fwd_pallas(
-        q, k, v, kvm, seg, sm_scale=1.0 / (q.shape[-1] ** 0.5), causal=causal,
-        window=window, q_offset=q_offset, bq=bq, bkv=bkv, interpret=interpret,
+def _forward(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret):
+    """``(o, lse)`` by the walk the call's own arguments choose."""
+    sm_scale = 1.0 / (q.shape[-1] ** 0.5)
+    if _takes_diagonal(q, k, kvm, seg, causal, window, q_offset, bq, bkv):
+        return _diag_fwd(q, k, v, sm_scale=sm_scale, window=window, bq=bq,
+                         interpret=interpret)
+    return _fwd_pallas(
+        q, k, v, kvm, seg, sm_scale=sm_scale, causal=causal, window=window,
+        q_offset=q_offset, bq=bq, bkv=bkv, interpret=interpret,
     )
-    return o
-
-
-def _flash_fwd(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret):
-    o, lse = _fwd_pallas(
-        q, k, v, kvm, seg, sm_scale=1.0 / (q.shape[-1] ** 0.5), causal=causal,
-        window=window, q_offset=q_offset, bq=bq, bkv=bkv, interpret=interpret,
-    )
-    return o, (q, k, v, kvm, seg, o, lse)
 
 
 def _mask_cotangent(kvm):
@@ -653,16 +973,34 @@ def _mask_cotangent(kvm):
     return np.zeros(kvm.shape, dtype=jax.dtypes.float0)
 
 
-def _flash_bwd(causal, window, q_offset, bq, bkv, interpret, res, g):
-    q = res[0]
-    dq, dk, dv = _bwd_pallas(
-        res, g, sm_scale=1.0 / (q.shape[-1] ** 0.5), causal=causal, window=window,
-        q_offset=q_offset, bq=bq, bkv=bkv, interpret=interpret,
-    )
-    return dq, dk, dv, _mask_cotangent(res[3]), _mask_cotangent(res[4])
+def _backward(causal, window, q_offset, bq, bkv, interpret, res, g, dlse=None):
+    """``(dq, dk, dv)`` and the row operands' cotangents, by the forward's walk."""
+    q, k, _, kvm, seg = res[:5]
+    sm_scale = 1.0 / (q.shape[-1] ** 0.5)
+    if _takes_diagonal(q, k, kvm, seg, causal, window, q_offset, bq, bkv):
+        grads = _diag_bwd(res, g, sm_scale=sm_scale, window=window, bq=bq,
+                          interpret=interpret, dlse=dlse)
+    else:
+        grads = _bwd_pallas(
+            res, g, sm_scale=sm_scale, causal=causal, window=window,
+            q_offset=q_offset, bq=bq, bkv=bkv, interpret=interpret, dlse=dlse,
+        )
+    return (*grads, _mask_cotangent(kvm), _mask_cotangent(seg))
 
 
-_flash.defvjp(_flash_fwd, _flash_bwd)
+@functools.partial(
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10)
+)
+def _flash(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret):
+    return _forward(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret)[0]
+
+
+def _flash_fwd(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret):
+    o, lse = _forward(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret)
+    return o, (q, k, v, kvm, seg, o, lse)
+
+
+_flash.defvjp(_flash_fwd, _backward)
 
 
 # -- lse-exposing variant (the ring-attention building block) ----------------
@@ -678,29 +1016,18 @@ def _flash_lse(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret):
     with exact autodiff: the merge is plain JAX, and this op's vjp folds the
     lse cotangent into the kernel's delta operand.
     """
-    o, lse = _fwd_pallas(
-        q, k, v, kvm, seg, sm_scale=1.0 / (q.shape[-1] ** 0.5), causal=causal,
-        window=window, q_offset=q_offset, bq=bq, bkv=bkv, interpret=interpret,
-    )
+    o, lse = _forward(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret)
     return o, lse[..., 0]
 
 
 def _flash_lse_fwd(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret):
-    o, lse = _fwd_pallas(
-        q, k, v, kvm, seg, sm_scale=1.0 / (q.shape[-1] ** 0.5), causal=causal,
-        window=window, q_offset=q_offset, bq=bq, bkv=bkv, interpret=interpret,
-    )
+    o, lse = _forward(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret)
     return (o, lse[..., 0]), (q, k, v, kvm, seg, o, lse)
 
 
 def _flash_lse_bwd(causal, window, q_offset, bq, bkv, interpret, res, g):
     do, dlse = g
-    q = res[0]
-    dq, dk, dv = _bwd_pallas(
-        res, do, sm_scale=1.0 / (q.shape[-1] ** 0.5), causal=causal, window=window,
-        q_offset=q_offset, bq=bq, bkv=bkv, interpret=interpret, dlse=dlse,
-    )
-    return dq, dk, dv, _mask_cotangent(res[3]), _mask_cotangent(res[4])
+    return _backward(causal, window, q_offset, bq, bkv, interpret, res, do, dlse)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -800,6 +1127,10 @@ def flash_attention(
     (tokens attend only within their own record) — a correctness upgrade over
     the reference's ConcatDataset, whose packed records causally attend
     ACROSS record boundaries.
+    A causal call under a window no wider than its (square) tile, without
+    either of them, takes the diagonal walk (module docstring); with
+    ``attention_mask`` or ``segment_ids`` (padded or packed SFT batches) the
+    same window keeps the band walk: correct, not faster.
     Shapes that do not tile the kernel raise on a TPU.  Off the TPU (the CPU
     test mesh, where toy models have head dims far below a lane) they run
     ``core_attention`` with a warning per shape.

@@ -493,3 +493,125 @@ def test_band_at_the_32k_cells_shape():
     for window in (4096, None):
         band = fa._band(bq, bkv, 8, 2, True, window, 0)
         assert (band.kv, band.q) == (2, 8)
+
+
+# ---------------------------------------------------------------------------
+# the diagonal walk: a causal window no wider than the (square) tile, with no
+# row predicates, takes one grid step a query block (ops/flash_attention.py)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("group", [1, 3], ids=["mha", "gqa_group_3"])
+@pytest.mark.parametrize("tile, window", [
+    (512, 512), (512, 256), (512, 128), (128, 128)],
+    ids=lambda x: str(x))
+def test_diagonal_walk_matches_core_and_the_band_walk(tile, window, group):
+    """Forward, lse and the three gradients in float32 under ``interpret``:
+    against ``core_attention`` and against the band walk of the same call
+    (close, not equal: the softmax is taken in one pass and the sums run in
+    another order).  Three query blocks: the first, whose rows see fewer keys
+    than the window, and two that look back into the block before them."""
+    from neuronx_distributed_training_tpu.ops import flash_attention as fa
+
+    s = 3 * tile
+    q, k, v = _make_qkv(jax.random.PRNGKey(41), 1, s, s, group, 1, 128)
+    g = jax.random.normal(jax.random.PRNGKey(42), q.shape, q.dtype)
+    dlse = jax.random.normal(jax.random.PRNGKey(43), (1, group, s), jnp.float32)
+    qt, kt, vt, gt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v, g))
+    args = (qt, kt, None, None, True, window, 0, tile, tile)
+    assert fa._takes_diagonal(*args)
+    common = dict(sm_scale=128 ** -0.5, window=window, bq=tile, interpret=True)
+    o, lse = fa._diag_fwd(qt, kt, vt, **common)
+    grads = fa._diag_bwd((qt, kt, vt, None, None, o, lse), gt, dlse=dlse, **common)
+    band = dict(common, causal=True, q_offset=0, bkv=tile)
+    o_b, lse_b = fa._fwd_pallas(qt, kt, vt, None, None, **band)
+    grads_b = fa._bwd_pallas((qt, kt, vt, None, None, o_b, lse_b), gt, dlse=dlse, **band)
+    for name, a, e in zip(("o", "lse", "dq", "dk", "dv"),
+                          (o, lse, *grads), (o_b, lse_b, *grads_b)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(e), rtol=2e-5,
+                                   atol=2e-5, err_msg=name)
+
+    # the reference: softmax over the window's keys, its logsumexp as a
+    # second output so that dlse is exercised too
+    def core(q, k, v):
+        o = core_attention(q, k, v, causal=True, sliding_window=window)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, group, 2)) * 128 ** -0.5
+        pos = jnp.arange(s)
+        seen = (pos[None, :] <= pos[:, None]) & (pos[None, :] > pos[:, None] - window)
+        return o, jax.nn.logsumexp(jnp.where(seen, scores, -jnp.inf), axis=-1)
+
+    (o_c, lse_c), vjp = jax.vjp(core, q, k, v)
+    np.testing.assert_allclose(np.asarray(jnp.swapaxes(o, 1, 2)), np.asarray(o_c),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse[..., 0]), np.asarray(lse_c),
+                               rtol=2e-5, atol=2e-5)
+    for name, a, e in zip("qkv", grads, vjp((g, dlse))):
+        a = jnp.swapaxes(a, 1, 2)
+        err = jnp.max(jnp.abs(a - e)) / jnp.max(jnp.abs(e))
+        assert err < 1e-5, f"d{name} rel err {err}"
+
+
+#: the Laguna window layers' call at a third of its heads and a quarter of its
+#: sequence, and one departure from it a case: (nh, nkv, sq, skv, window,
+#: block_kv, q_offset, attention_mask, segment_ids) -> the walk
+_LAGUNA = dict(nh=18, nkv=2, sq=2048, skv=2048, window=512, block_kv=512,
+               q_offset=0, mask=False, seg=False)
+WALK_CASES = {
+    "laguna_window_shape": ({}, "diagonal"),  # sub-tiles of 256: 6 of the band's 8
+    "window_narrower_than_tile": ({"window": 200}, "diagonal"),  # 4 of 8
+    "window_wider_than_tile": ({"window": 513}, "band"),
+    "key_tile_not_the_query_tile": ({"block_kv": 1024}, "band"),
+    "attention_mask": ({"mask": True}, "band"),
+    "segment_ids": ({"seg": True}, "band"),
+    "q_offset": ({"q_offset": 512}, "band"),
+    "sq_not_skv": ({"sq": 1024}, "band"),
+    "no_window": ({"window": None}, "band"),
+}
+
+
+@pytest.mark.parametrize("case", WALK_CASES, ids=list(WALK_CASES))
+def test_the_call_chooses_its_walk(case):
+    """The choice is made from the call's own arguments, above the kernels:
+    the band walk's kernels take a grid with the band as its innermost
+    dimension (4 for fwd and dq, 5 for dkv), the diagonal walk's have none
+    (4 each, with K and V handed to fwd and dq twice); ``flash_band`` of the
+    trace's facts says which, and what share of a band's sub-tiles."""
+    from neuronx_distributed_training_tpu.ops import flash_attention as fa
+    from neuronx_distributed_training_tpu.parallel import sharding as shd
+
+    change, walk = WALK_CASES[case]
+    c = dict(_LAGUNA, **change)
+    q = jax.ShapeDtypeStruct((1, c["sq"], c["nh"], 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, c["skv"], c["nkv"], 128), jnp.bfloat16)
+    rows = jnp.ones((1, c["skv"]), jnp.int32)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, sliding_window=c["window"], q_offset=c["q_offset"],
+            attention_mask=rows if c["mask"] else None,
+            segment_ids=rows if c["seg"] else None,
+            block_kv=c["block_kv"], interpret=True).astype(jnp.float32))
+
+    with shd.collect_trace_facts() as facts:
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv)
+    (fact,) = facts["flash_band"]
+    assert fact["walk"] == walk
+    assert fact.get("sub_tiles") == {
+        "laguna_window_shape": [6, 8], "window_narrower_than_tile": [4, 8]}.get(case)
+    calls = {}
+
+    def visit(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls[eqn.params["name"]] = (
+                    len(eqn.params["grid_mapping"].grid), len(eqn.invars))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                visit(sub)
+
+    visit(jaxpr.jaxpr)
+    extra = int(c["mask"]) + 2 * int(c["seg"])
+    assert calls == {
+        "band": {"flash_fwd": (4, 3 + extra), "flash_dq": (4, 6 + extra),
+                 "flash_dkv": (5, 6 + extra)},
+        "diagonal": {"flash_fwd": (4, 5), "flash_dq": (4, 8), "flash_dkv": (4, 10)},
+    }[walk], calls

@@ -338,7 +338,9 @@ def test_flash_band_is_a_static_fact_of_the_run(tmp_path, window):
     """``flash_band``: per distinct flash call of the step, the key blocks of
     the sequence against those a query block's walk covers (and the query
     blocks against a key block's): narrower than the sequence under a window
-    shorter than it, the whole range without one."""
+    shorter than it, the whole range without one; and which walk the call
+    took: under a window no wider than its tiles the diagonal walk, with the
+    sub-tiles it computes of those the band holds."""
     _, summary = fit_tiny(tmp_path, max_steps=1, overrides={
         "data.seq_length": 512, "data.global_batch_size": 8,
         "model.hidden_size": 256, "model.num_attention_heads": 2,
@@ -351,6 +353,9 @@ def test_flash_band_is_a_static_fact_of_the_run(tmp_path, window):
     assert call["seq"] == 512 and call["kv_blocks"] == call["q_blocks"] == 4
     if window is None:
         assert (call["kv_band"], call["q_band"]) == (4, 4)
+        assert call["walk"] == "band" and "sub_tiles" not in call
     else:  # 128 rows and the 127 before them: two blocks either way
         assert (call["kv_band"], call["q_band"]) == (2, 2)
         assert call["kv_band"] < call["kv_blocks"]
+        # tiles of 128 are one sub-tile: both of the band's are computed
+        assert call["walk"] == "diagonal" and call["sub_tiles"] == [2, 2]

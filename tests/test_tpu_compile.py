@@ -376,17 +376,23 @@ LAGUNA_CUT = {
 }
 
 
-@pytest.mark.parametrize("nh, window, block_kv", [(72, 512, 512), (48, None, None)],
-                         ids=["window-512-group-9", "causal-group-6"])
-def test_flash_compiles_at_the_mixed_stacks_shapes(topo, nh, window, block_kv):
+@pytest.mark.parametrize("nh, window, block_kv, masked", [
+    (72, 512, 512, False), (72, 512, 512, True), (48, None, None, False)],
+    ids=["window-512-group-9", "window-512-group-9-attention-mask", "causal-group-6"])
+def test_flash_compiles_at_the_mixed_stacks_shapes(topo, nh, window, block_kv, masked):
+    """Both sides of the flash kernels' choice of walk at the window layers'
+    shape: the diagonal walk's three kernels, and with an ``attention_mask``
+    the band walk's at tiles of 512 x 512; then the full layers' causal call."""
     one_chip = SingleDeviceSharding(topo.devices[0])
     args = [jax.ShapeDtypeStruct((1, 8192, n, 128), jnp.bfloat16, sharding=one_chip)
             for n in (nh, 8, 8)]
+    if masked:
+        args.append(jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip))
 
-    def loss(q, k, v):
+    def loss(q, k, v, mask=None):
         return jnp.sum(fa.flash_attention(
             q, k, v, causal=True, sliding_window=window, block_kv=block_kv,
-            interpret=False).astype(jnp.float32))
+            attention_mask=mask, interpret=False).astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") == 3
