@@ -833,10 +833,16 @@ def shrink_overrides(cfg: Mapping, *, max_devices: int = 8) -> dict[str, Any]:
     for key in ("moe_intermediate_size", "shared_expert_intermediate_size"):
         if model.get(key):
             o[f"model.{key}"] = 2 * heads * head_dim
-    if model.get("num_experts"):
-        o["model.num_experts"] = max(2 * ep, 4)
-        if model.get("num_experts_per_tok"):
-            o["model.num_experts_per_tok"] = min(int(model["num_experts_per_tok"]), 2)
+    for key in ("num_experts", "n_routed_experts"):  # models/laguna.py, kanana.py
+        if model.get(key):
+            o[f"model.{key}"] = max(2 * ep, 4)
+            if model.get("num_experts_per_tok"):
+                o["model.num_experts_per_tok"] = min(int(model["num_experts_per_tok"]), 2)
+    # latent attention's dims (models/kanana.py): rope on half a toy head
+    for key, width in (("qk_nope_head_dim", head_dim), ("v_head_dim", head_dim),
+                       ("qk_rope_head_dim", head_dim // 2), ("kv_lora_rank", 2 * head_dim)):
+        if key in model:
+            o[f"model.{key}"] = width
     per_layer = model.get("num_attention_heads_per_layer")
     if per_layer:
         counts = per_layer.values() if isinstance(per_layer, Mapping) else per_layer

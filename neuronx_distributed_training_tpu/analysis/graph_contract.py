@@ -192,8 +192,8 @@ class DeclaredComms:
         overlap = ctx.ds.get("overlap") or {}
         model = ctx.cfg.get("model", {}) or {}
         moe_block = model.get("moe")
-        # the HF spelling of a routed block (models/laguna.py): always dropless
-        hf_experts = bool(model.get("num_experts"))
+        # the HF spellings of a routed block (models/laguna.py, kanana.py): always dropless
+        hf_experts = bool(model.get("num_experts") or model.get("n_routed_experts"))
         return cls(
             tp=ctx.axis("model"), pp=ctx.axis("pipe"),
             cp=ctx.axis("context"), ep=ctx.axis("expert"),

@@ -1,6 +1,6 @@
 """One record per model family: what the rest of the package asks of a model.
 
-``models/llama.py``, ``mixtral.py``, ``gpt.py``, ``ouro.py`` and ``laguna.py`` each end in one
+``models/llama.py``, ``mixtral.py``, ``gpt.py``, ``ouro.py``, ``laguna.py`` and ``kanana.py`` each end in one
 :class:`Family` (``FAMILY``) and their config class answers ``.family`` with
 it.  The trainer, the pipeline gate, the launch planner, the FLOPs count, the
 cached decode and the config validator ask the record; none of them names a
@@ -29,6 +29,19 @@ class Refused:
 
     def __call__(self, *args: Any, **kwargs: Any):
         raise NotImplementedError(self.sentence)
+
+
+@dataclasses.dataclass(frozen=True)
+class AfterUpdate:
+    """Leaves that move by a rule that is not the optimizer's (a router's
+    selection bias, by the experts' load).  The train step keeps the loss's
+    aux entries ``reads`` whole (summed over micro-batches; non-scalars
+    otherwise stay inside the loss) and, after the optimizer, inside the
+    compiled step, takes ``apply(params, {name: entry}) -> params``.  The
+    leaves stay in ``params`` with a gradient of zero, so the optimizer's
+    state keeps the parameters' structure and AdamW moves them by nothing."""
+    reads: tuple[str, ...]
+    apply: Callable[[Any, Mapping[str, Any]], Any]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +83,8 @@ class Family:
     moe_groups: Callable[[Any], Optional[int]] = lambda cfg: None
     #: ``(cfg, sched) -> dict``: the family's own lines of ``run_summary.json``
     run_facts: Callable[[Any, Mapping], dict] = lambda cfg, sched: {}
+    #: ``(cfg) -> AfterUpdate | None``: what moves after the optimizer's step
+    after_update: Callable[[Any], Optional[AfterUpdate]] = lambda cfg: None
 
     def manual_vjp_refusal(self, cfg: Any) -> Optional[str]:
         """The family's half of ``parallel.pipeline.supports_1f1b``: why the
@@ -88,6 +103,7 @@ class Family:
 FAMILIES: dict[str, Any] = {
     "llama": "llama", "mistral": "llama", "mixtral": "mixtral",
     "ouro": "ouro", "laguna": "laguna", "gpt": "gpt",
+    "kanana": "kanana", "deepseek_v3": "kanana",
 }
 
 

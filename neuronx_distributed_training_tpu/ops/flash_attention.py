@@ -107,12 +107,17 @@ NEG_INF = -1e30
 
 
 def _block_sizes(sq: int, skv: int, bq: Optional[int], bkv: Optional[int],
-                 dtype=jnp.bfloat16):
+                 dtype=jnp.bfloat16, d: int = LANES):
     bq = bq or min(DEFAULT_BLOCK_Q, sq)
     # 4-byte operands get half the kv block: at 2048 the dkv kernel of an
     # MHA layer (s 4096, d 128, float32) needs 17.08 MiB of the TPU's 16 MiB
     # scoped VMEM and the compiler refuses it
     default_kv = DEFAULT_BLOCK_KV // max(jnp.dtype(dtype).itemsize // 2, 1)
+    if d > LANES:
+        # and so do score dims past one lane width: at 2048 the dkv kernel
+        # of 32 heads x 192 (held in VMEM as 256) x seq 8192, bf16, needs
+        # 16.73 MiB; 1024 is taken
+        default_kv //= 2
     bkv = bkv or min(default_kv, skv)
     while sq % bq:
         bq //= 2
@@ -121,13 +126,20 @@ def _block_sizes(sq: int, skv: int, bq: Optional[int], bkv: Optional[int],
     return max(bq, 1), max(bkv, 1)
 
 
-def _tileable(sq: int, skv: int, d: int, bq: int, bkv: int) -> bool:
+def _tileable(sq: int, skv: int, d: int, bq: int, bkv: int,
+              d_v: Optional[int] = None) -> bool:
+    """``d``: the dims q and k are scored over, ``d_v`` (default ``d``) those
+    of v and the output.  Where they differ (latent attention: 192 and 128)
+    the score dims may end on half a lane width, fed as they are: a block
+    whose last dim is the array's."""
+    d_v = d if d_v is None else d_v
     return (
         sq % bq == 0
         and skv % bkv == 0
         and bq % LANES == 0
         and bkv % LANES == 0
-        and d % LANES == 0
+        and d_v % LANES == 0
+        and d % (LANES if d == d_v else LANES // 2) == 0
     )
 
 
@@ -218,17 +230,21 @@ def _band(bq, bkv, num_q, num_kv, causal, window, q_offset) -> _Band:
 
 
 def _call_band(bq, bkv, num_q, num_kv, causal, window, q_offset,
-               sub: Optional[_Band] = None) -> _Band:
+               sub: Optional[_Band] = None, dims: Optional[tuple] = None) -> _Band:
     """``_band`` of a forward call, recorded for ``run_summary.json`` where a
     trace collects such facts: ``flash_band``, one entry per distinct shape.
     ``sub`` (``_sub_band``): the call takes the diagonal walk, and
     ``sub_tiles`` says how many ``[computed, of]`` the sub-tiles that the
-    band of a query block holds."""
+    band of a query block holds.  ``dims``: ``(d_qk, d_v)``, said where they
+    differ, with how the score dims are fed (``feed: whole``: q and k blocks
+    as wide as their arrays, one contraction over all of ``d_qk``)."""
     band = _band(bq, bkv, num_q, num_kv, causal, window, q_offset)
     facts = shd.trace_facts()
     if facts is not None:
         shape = {"seq": num_q * bq, "kv_blocks": num_kv, "kv_band": band.kv,
                  "q_blocks": num_q, "q_band": band.q, "walk": "band"}
+        if dims is not None and dims[0] != dims[1]:
+            shape.update(d_qk=dims[0], d_v=dims[1], feed="whole")
         if sub is not None:
             n = sub.num_q // 2
             spans = (_kv_span(sub, n + a) for a in range(n))
@@ -339,16 +355,18 @@ def _fwd_kernel(
 
 def _fwd_pallas(q, k, v, kvm, seg, *, sm_scale, causal, window, q_offset, bq, bkv,
                 interpret, band=None):
-    """q [b, nh, sq, d]; k/v [b, nkv, skv, d]; kvm None or [b, 1, skv] int32
-    (1 = real key); seg None or [b, 1, s] int32 segment ids (self-attention
-    packed chunks) -> (o [b, nh, sq, d], lse [b, nh, sq, SUBLANES]).
-    ``band``: the walk, ``_band`` of the call unless a test hands in another."""
+    """q [b, nh, sq, d]; k [b, nkv, skv, d]; v [b, nkv, skv, dv]; kvm None or
+    [b, 1, skv] int32 (1 = real key); seg None or [b, 1, s] int32 segment ids
+    (self-attention packed chunks) -> (o [b, nh, sq, dv], lse [b, nh, sq,
+    SUBLANES]).  ``band``: the walk, ``_band`` of the call unless a test hands
+    in another."""
     b, nh, sq, d = q.shape
-    nkv, skv = k.shape[1], k.shape[2]
+    nkv, skv, dv = k.shape[1], k.shape[2], v.shape[3]
     group = nh // nkv
     num_q, num_kv = sq // bq, skv // bkv
     if band is None:
-        band = _call_band(bq, bkv, num_q, num_kv, causal, window, q_offset)
+        band = _call_band(bq, bkv, num_q, num_kv, causal, window, q_offset,
+                          dims=(d, dv))
 
     def kv_at(qi, j):  # the key block step j of query block qi fetches
         return _walk(_kv_span, band, qi, j)[2]
@@ -366,7 +384,7 @@ def _fwd_pallas(q, k, v, kvm, seg, *, sm_scale, causal, window, q_offset, bq, bk
         pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, j: (bi, hi, qi, 0)),
         pl.BlockSpec((1, 1, bkv, d),
                      lambda bi, hi, qi, j: (bi, hi // group, kv_at(qi, j), 0)),
-        pl.BlockSpec((1, 1, bkv, d),
+        pl.BlockSpec((1, 1, bkv, dv),
                      lambda bi, hi, qi, j: (bi, hi // group, kv_at(qi, j), 0)),
     ]
     in_arrays = [q, k, v]
@@ -390,17 +408,17 @@ def _fwd_pallas(q, k, v, kvm, seg, *, sm_scale, causal, window, q_offset, bq, bk
             grid=grid,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, j: (bi, hi, qi, 0)),
+                pl.BlockSpec((1, 1, bq, dv), lambda bi, hi, qi, j: (bi, hi, qi, 0)),
                 pl.BlockSpec((1, 1, bq, SUBLANES), lambda bi, hi, qi, j: (bi, hi, qi, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((b, nh, sq, d), q.dtype),
+                jax.ShapeDtypeStruct((b, nh, sq, dv), q.dtype),
                 jax.ShapeDtypeStruct((b, nh, sq, SUBLANES), jnp.float32),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bq, LANES), jnp.float32),
                 pltpu.VMEM((bq, LANES), jnp.float32),
-                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((bq, dv), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
@@ -564,9 +582,10 @@ def _delta_rows(g, o, dlse):
 
 def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpret,
                 dlse=None, band=None):
-    q, k, v, kvm, seg, o, lse = res  # q [b, nh, sq, d]; k/v [b, nkv, skv, d]
+    # q [b, nh, sq, d]; k [b, nkv, skv, d]; v [b, nkv, skv, dv]; o, g as v
+    q, k, v, kvm, seg, o, lse = res
     b, nh, sq, d = q.shape
-    nkv, skv = k.shape[1], k.shape[2]
+    nkv, skv, dv = k.shape[1], k.shape[2], v.shape[3]
     group = nh // nkv
     num_q, num_kv = sq // bq, skv // bkv
     if band is None:
@@ -590,11 +609,12 @@ def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpre
     def dq_q(width):  # dq: a [.., bq, width] block of query block qi
         return pl.BlockSpec((1, 1, bq, width), lambda bi, hi, qi, j: (bi, hi, qi, 0))
 
-    def dq_kv():  # dq: the key block that step j of query block qi visits
+    def dq_kv(width):  # dq: the key block that step j of query block qi visits
         return pl.BlockSpec(
-            (1, 1, bkv, d), lambda bi, hi, qi, j: (bi, hi // group, kv_at(qi, j), 0))
+            (1, 1, bkv, width),
+            lambda bi, hi, qi, j: (bi, hi // group, kv_at(qi, j), 0))
 
-    dq_specs = [dq_q(d), dq_kv(), dq_kv(), dq_q(d), dq_q(SUBLANES),
+    dq_specs = [dq_q(d), dq_kv(d), dq_kv(dv), dq_q(dv), dq_q(SUBLANES),
                 dq_q(SUBLANES)]
     if kvm is not None:
         dq_specs.append(pl.BlockSpec(
@@ -626,10 +646,10 @@ def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpre
             (1, 1, bq, width),
             lambda bi, kh, ki, g, j: (bi, kh * group + g, q_at(ki, j), 0))
 
-    def dkv_kv():  # dkv: key block ki
-        return pl.BlockSpec((1, 1, bkv, d), lambda bi, kh, ki, g, j: (bi, kh, ki, 0))
+    def dkv_kv(width):  # dkv: key block ki
+        return pl.BlockSpec((1, 1, bkv, width), lambda bi, kh, ki, g, j: (bi, kh, ki, 0))
 
-    dkv_specs = [dkv_q(d), dkv_kv(), dkv_kv(), dkv_q(d), dkv_q(SUBLANES),
+    dkv_specs = [dkv_q(d), dkv_kv(d), dkv_kv(dv), dkv_q(dv), dkv_q(SUBLANES),
                  dkv_q(SUBLANES)]
     if kvm is not None:
         dkv_specs.append(pl.BlockSpec((1, 1, bkv), lambda bi, kh, ki, g, j: (bi, 0, ki)))
@@ -643,14 +663,14 @@ def _bwd_pallas(res, g, *, sm_scale, causal, window, q_offset, bq, bkv, interpre
             name="flash_dkv",
             grid=(b, nkv, num_kv, group, band.q),
             in_specs=dkv_specs,
-            out_specs=[dkv_kv(), dkv_kv()],
+            out_specs=[dkv_kv(d), dkv_kv(dv)],
             out_shape=[
                 jax.ShapeDtypeStruct((b, nkv, skv, d), k.dtype),
-                jax.ShapeDtypeStruct((b, nkv, skv, d), v.dtype),
+                jax.ShapeDtypeStruct((b, nkv, skv, dv), v.dtype),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bkv, d), jnp.float32),
-                pltpu.VMEM((bkv, d), jnp.float32),
+                pltpu.VMEM((bkv, dv), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel", "arbitrary", "arbitrary"),
@@ -951,10 +971,17 @@ def _diag_bwd(res, g, *, sm_scale, window, bq, interpret, dlse=None):
 # ---------------------------------------------------------------------------
 
 
+def _one_head_dim(q, v) -> bool:
+    """The diagonal walk's kernels know one head dim; a call that scores over
+    other dims than it weighs (latent attention) keeps the band walk."""
+    return q.shape[-1] == v.shape[-1]
+
+
 def _forward(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret):
     """``(o, lse)`` by the walk the call's own arguments choose."""
     sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    if _takes_diagonal(q, k, kvm, seg, causal, window, q_offset, bq, bkv):
+    if _one_head_dim(q, v) and _takes_diagonal(
+            q, k, kvm, seg, causal, window, q_offset, bq, bkv):
         return _diag_fwd(q, k, v, sm_scale=sm_scale, window=window, bq=bq,
                          interpret=interpret)
     return _fwd_pallas(
@@ -975,9 +1002,10 @@ def _mask_cotangent(kvm):
 
 def _backward(causal, window, q_offset, bq, bkv, interpret, res, g, dlse=None):
     """``(dq, dk, dv)`` and the row operands' cotangents, by the forward's walk."""
-    q, k, _, kvm, seg = res[:5]
+    q, k, v, kvm, seg = res[:5]
     sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    if _takes_diagonal(q, k, kvm, seg, causal, window, q_offset, bq, bkv):
+    if _one_head_dim(q, v) and _takes_diagonal(
+            q, k, kvm, seg, causal, window, q_offset, bq, bkv):
         grads = _diag_bwd(res, g, sm_scale=sm_scale, window=window, bq=bq,
                           interpret=interpret, dlse=dlse)
     else:
@@ -1043,10 +1071,11 @@ def _warn_core_route(shapes: str) -> None:
 
 def flash_tileable(sq: int, skv: int, d: int, nh: int, nkv: int,
                    block_q: Optional[int] = None,
-                   block_kv: Optional[int] = None) -> bool:
+                   block_kv: Optional[int] = None,
+                   d_v: Optional[int] = None) -> bool:
     """True when these shapes can run the Pallas kernels (no fallback)."""
-    bq, bkv = _block_sizes(sq, skv, block_q, block_kv)
-    return _tileable(sq, skv, d, bq, bkv) and nh % nkv == 0
+    bq, bkv = _block_sizes(sq, skv, block_q, block_kv, d=d)
+    return _tileable(sq, skv, d, bq, bkv, d_v) and nh % nkv == 0
 
 
 def _prep_rows(x, b, s, name):
@@ -1087,11 +1116,11 @@ def flash_attention_with_lse(
     # NOTE: unlike ``flash_attention``, sliding_window is honored even when
     # causal=False — the ring's fully-visible past chunks need exactly that
     # (window mask at a static relative offset, no causal mask)
-    bq, bkv = _block_sizes(sq, skv, block_q, block_kv, q.dtype)
-    if not _tileable(sq, skv, d, bq, bkv) or nh % nkv != 0:
+    bq, bkv = _block_sizes(sq, skv, block_q, block_kv, q.dtype, d)
+    if not _tileable(sq, skv, d, bq, bkv, v.shape[-1]) or nh % nkv != 0:
         raise ValueError(
             f"flash_attention_with_lse: shapes not tileable "
-            f"(sq={sq}, skv={skv}, d={d}, nh={nh}, nkv={nkv})"
+            f"(sq={sq}, skv={skv}, d={d}, d_v={v.shape[-1]}, nh={nh}, nkv={nkv})"
         )
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -1107,7 +1136,7 @@ def flash_attention_with_lse(
 def flash_attention(
     q: jax.Array,  # [b, sq, nh, d]
     k: jax.Array,  # [b, skv, nkv, d]
-    v: jax.Array,  # [b, skv, nkv, d]
+    v: jax.Array,  # [b, skv, nkv, d_v]: d, or its own (latent attention)
     *,
     causal: bool = True,
     sliding_window: Optional[int] = None,
@@ -1140,15 +1169,18 @@ def flash_attention(
     skv, nkv = k.shape[1], k.shape[2]
     if not causal:
         sliding_window = None  # window is causal-only, matching core_attention
-    bq, bkv = _block_sizes(sq, skv, block_q, block_kv, q.dtype)
-    if not _tileable(sq, skv, d, bq, bkv) or nh % nkv != 0:
-        shapes = f"sq={sq}, skv={skv}, d={d}, nh={nh}, nkv={nkv}"
+    bq, bkv = _block_sizes(sq, skv, block_q, block_kv, q.dtype, d)
+    d_v = v.shape[-1]
+    if not _tileable(sq, skv, d, bq, bkv, d_v) or nh % nkv != 0:
+        shapes = f"sq={sq}, skv={skv}, d={d}, nh={nh}, nkv={nkv}" + (
+            f", d_v={d_v}" if d_v != d else "")
         # a host query, not a traced value
         if jax.default_backend() == "tpu":  # jaxlint: disable=JL102
             raise ValueError(
                 f"flash_attention: shapes do not tile the Pallas kernel "
                 f"({shapes}: seq blocks and head_dim must be multiples of "
-                f"{LANES}); set fusions.flash_attention: false for this model"
+                f"{LANES}, score dims that differ from the value dims of "
+                f"{LANES // 2}); set fusions.flash_attention: false for this model"
             )
         _warn_core_route(shapes)
         from neuronx_distributed_training_tpu.ops.attention import (

@@ -7,7 +7,11 @@ the dropped-vs-dropless validation at ``training_orchestrator.py:60-102``):
 
 - **router**: top-k softmax routing (Mixtral) or sinkhorn (Megatron top-1)
   over token logits; router always computed in fp32 (routing decisions must
-  not flip under bf16);
+  not flip under bf16).  ``score_func: sigmoid`` (DeepSeek-V3's ``noaux_tc``):
+  scores ``sigmoid(logits)``, chosen by ``score + bias`` with a
+  ``params["router"]["bias"]`` that takes no gradient, weighed by the scores
+  alone; the bias moves by ``bias_update`` after the optimizer
+  (``trainer/step.py``), from the per-expert counts the block returns;
 - **dropped** (capacity factor): dense dispatch/combine einsums against a
   ``[tokens, experts, capacity]`` one-hot — MXU-friendly, static shapes,
   tokens beyond ``capacity_factor * tokens/experts`` per expert are dropped
@@ -89,6 +93,12 @@ class MoEConfig:
     # ``hi - lo`` and only the rows that chose one of those are multiplied
     # (``_held_experts``); dropless only
     experts_held: Optional[tuple[int, int]] = None
+    # "softmax" | "sigmoid": how router logits become scores.  sigmoid: the
+    # router holds a ``bias`` besides ``w``, selection is by score + bias,
+    # gate weights by the scores alone (over their sum + 1e-20)
+    score_func: str = "softmax"
+    # the selection bias's step a train step (``bias_update``); 0: never moves
+    bias_update_rate: float = 0.0
 
     @property
     def experts_resident(self) -> int:
@@ -115,6 +125,10 @@ class MoEConfig:
             routed_scaling_factor=float(m.get("routed_scaling_factor", 1.0)),
             experts_held=(tuple(int(i) for i in m["experts_held"])
                           if m.get("experts_held") is not None else None),
+            # the source's keys (HF deepseek_v3): scoring_func, and
+            # topk_method noaux_tc = a selection bias and no auxiliary loss
+            score_func=str(m.get("scoring_func", "softmax")),
+            bias_update_rate=float(m.get("router_bias_update_rate", 0.0) or 0.0),
         )
 
 
@@ -128,13 +142,16 @@ def init_moe_params(key: jax.Array, hidden: int, ffn: int, cfg: MoEConfig,
     """Router + fused SwiGLU expert weights, expert-major ``[E, ...]``."""
     kr, kgu, kd = jax.random.split(key, 3)
     e, held = cfg.num_experts, cfg.experts_resident
-    return {
+    params = {
         "router": {"w": (jax.random.normal(kr, (hidden, e)) * stddev).astype(jnp.float32)},
         "experts": {
             "gate_up": (jax.random.normal(kgu, (held, hidden, 2 * ffn)) * stddev).astype(dtype),
             "down": (jax.random.normal(kd, (held, ffn, hidden)) * stddev).astype(dtype),
         },
     }
+    if cfg.score_func == "sigmoid":   # the selection bias: float32 as the router
+        params["router"]["bias"] = jnp.zeros((e,))
+    return params
 
 
 def moe_param_specs(cfg: MoEConfig):
@@ -196,6 +213,16 @@ def route(router_params, x: jax.Array, cfg: MoEConfig):
     router_logits [tokens, E]).  fp32 throughout.
     """
     logits = x.astype(jnp.float32) @ router_params["w"].astype(jnp.float32)
+    if cfg.score_func == "sigmoid":
+        # chosen by score + bias, weighed by the score: the bias steers the
+        # load and reaches neither the output nor a gradient
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(jax.lax.stop_gradient(
+            scores + router_params["bias"]), cfg.top_k)
+        probs = jnp.take_along_axis(scores, idx, axis=-1)
+        if cfg.normalize_top_k_affinities:
+            probs = probs / (jnp.sum(probs, axis=-1, keepdims=True) + 1e-20)
+        return probs * cfg.routed_scaling_factor, idx, logits
     if cfg.router_type == "sinkhorn":
         # balanced assignment for selection; gate values from plain softmax
         norm = _sinkhorn(logits, cfg.sinkhorn_iterations)
@@ -210,6 +237,19 @@ def route(router_params, x: jax.Array, cfg: MoEConfig):
     if cfg.routed_scaling_factor != 1.0:
         probs = probs * cfg.routed_scaling_factor
     return probs, idx, logits
+
+
+def expert_counts(idx: jax.Array, num_experts: int) -> jax.Array:
+    """``[E]`` float32: the (token, choice) slots each expert was chosen for."""
+    return jnp.zeros((num_experts,), jnp.float32).at[idx.reshape(-1)].add(1.0)
+
+
+def bias_update(bias: jax.Array, counts: jax.Array, rate: float) -> jax.Array:
+    """The selection bias after a step (DeepSeek-V3, arXiv:2412.19437,
+    2.1.2): up by ``rate`` where an expert was chosen for fewer slots than
+    the mean, down where for more; ``counts [..., E]`` over the step's tokens."""
+    mean = jnp.mean(counts, axis=-1, keepdims=True)
+    return bias + rate * jnp.sign(mean - counts).astype(bias.dtype)
 
 
 def load_balancing_loss(router_logits: jax.Array, idx: jax.Array, cfg: MoEConfig) -> jax.Array:
@@ -910,7 +950,10 @@ def moe_block(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat1
               reduce_dtype=jnp.float32, act_spec: Optional[P] = None):
     """[b, s, h] wrapper dispatching dropped/dropless; returns ``(y, aux)``,
     ``aux`` the ``router_logits``, the ``expert_idx`` and ``stats``, per-step
-    scalars of the block under their metric names (``_dropless_experts``).
+    scalars of the block under their metric names (``_dropless_experts``);
+    under ``score_func: sigmoid`` also ``expert_counts [E]``, the slots each
+    expert was chosen for, and the stat ``moe/load_max_share``, the largest of
+    them over their mean.
     A ``params["shared"]`` is a shared expert, added to the routed sum.
 
     ``act_spec`` is the block-boundary spec ``x`` is laid out by (default
@@ -928,7 +971,15 @@ def moe_block(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat1
                 reduce_dtype=reduce_dtype, act_spec=act_spec)
             if "shared" in params:
                 y = y + _shared_expert(params["shared"], x, compute_dtype)
-            return y, {"router_logits": logits, "expert_idx": idx, "stats": stats}
+            aux = {"router_logits": logits, "expert_idx": idx, "stats": stats}
+            if cfg.score_func == "sigmoid":
+                # the loads the selection bias answers to (``bias_update``)
+                with jax.named_scope("router"):
+                    counts = expert_counts(idx, cfg.num_experts)
+                aux["expert_counts"] = counts
+                aux["stats"] = {**stats, "moe/load_max_share":
+                                jnp.max(counts) / (b * s * cfg.top_k / cfg.num_experts)}
+            return y, aux
         if cfg.experts_held is not None or "shared" in params:
             raise NotImplementedError(
                 "moe.experts_held and a shared expert are wired for the "

@@ -4,7 +4,8 @@ Covers the reference's two RoPE implementations: the HF-style
 ``LlamaRotaryEmbedding`` with fp64-precision inv-freq override
 (``modeling_llama.py:847-873``) and Megatron's ``rotary_pos_embedding.py`` with
 position-interpolation and ABF base scaling (``rotary_pos_embedding.py:22-81``),
-plus the HF ``yarn`` frequencies and a partial rotary factor (``models/laguna.py``).
+plus the HF ``yarn`` frequencies and a partial rotary factor (``models/laguna.py``)
+and the interleaved pairing (``rope_interleave``, ``models/kanana.py``).
 Frequencies are computed in fp64 on host at trace time (static) then applied in
 fp32 — matching the reference's precision discipline without any global flag.
 
@@ -114,3 +115,11 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     out1 = x1 * cos_b - x2 * sin_b
     out2 = x2 * cos_b + x1 * sin_b
     return jnp.concatenate([out1, out2], axis=-1).astype(orig_dtype)
+
+
+def apply_rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """``apply_rope`` where the source pairs neighbours, ``(x[2i], x[2i + 1])``
+    (HF ``rope_interleave``): the dims are brought into the half layout first
+    (evens, then odds) and stay there.  A score is a sum over the dims of q
+    and k alike, so the order they are left in does not show in it."""
+    return apply_rope(jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1), cos, sin)

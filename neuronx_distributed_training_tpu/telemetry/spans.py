@@ -67,11 +67,14 @@ DEVICE_SCOPES: dict[str, tuple[str, ...]] = {
 #: ``ce_head/exit_gate``; ``models/laguna.py``'s ``attention/attn_full`` and
 #: ``attention/attn_window`` (the whole attention block of a layer of that
 #: kind, the flash kernels' scopes inside it), ``attention/.../head_gate`` and
-#: ``moe/shared`` (``ops/moe.py``'s shared expert).  A reader that does not
+#: ``moe/shared`` (``ops/moe.py``'s shared expert); ``models/kanana.py``'s
+#: ``attention/mla_latent`` (what latent attention adds beside q, the kernels
+#: and o: the down-projection, the latent's norm, the up-projection, the
+#: shared key's rope, the assembly of k).  A reader that does not
 #: know one counts its time under the scope that holds it, so nothing becomes
 #: unscoped; ``benchmark/readers/inner_scope.py`` reads one by its name.
 FAMILY_SCOPES: dict[str, tuple[str, ...]] = {
-    "attention": ("attn_full", "attn_window", "head_gate"),
+    "attention": ("attn_full", "attn_window", "head_gate", "mla_latent"),
     "moe": ("shared",),
     "ce_head": ("exit_gate",),
 }

@@ -715,6 +715,7 @@ class Trainer:
             bucket_plan=bucket_plan,
             prefetch_ag=overlap_cfg.prefetch_ag,
             tensorstats_cfg=tensorstats_cfg,
+            after_update=family.after_update(model_cfg),
         )
         # params and optimizer state are donated, under EMA too: an earlier
         # runtime refused to donate an optimizer state carrying the EMA tree

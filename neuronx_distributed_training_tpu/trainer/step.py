@@ -62,6 +62,7 @@ def make_train_step(
     bucket_plan: Any = None,  # optim.overlap.BucketPlan (engineered overlap)
     prefetch_ag: bool = True,
     tensorstats_cfg: Any = None,  # telemetry.tensorstats.TensorStatsConfig
+    after_update: Any = None,  # models.family.AfterUpdate (a rule beside the optimizer's)
 ) -> Callable:
     """Build the (un-jitted) train step:
     ``(params, opt_state, batch, step_key) -> (params, opt_state, metrics)``.
@@ -88,7 +89,16 @@ def make_train_step(
     surfaced as ``tensorstats/...`` scalars plus ``tensorstats_hist/...``
     packed vectors in the boundary metrics.  Shares the health probes' layer
     grouping and the clipping norm's reduction pass; rides the same one
-    executable."""
+    executable.
+
+    ``after_update``: the family's leaves that move by a rule of their own
+    (``models.family.AfterUpdate``).  The entries of the loss's aux that the
+    rule reads pass the scalar filter whole, are summed over the micro-batches
+    (every other entry is averaged), and after the optimizer's update, inside
+    this step, ``apply`` returns the parameters with those leaves moved; where
+    ``health.policy: skip_update`` suppresses a non-finite step, the rule's
+    move is suppressed with it."""
+    kept = tuple(after_update.reads) if after_update is not None else ()
     health = health_cfg if (health_cfg is not None
                             and getattr(health_cfg, "enabled", False)) else None
     tstats = (tensorstats_cfg
@@ -108,7 +118,7 @@ def make_train_step(
             scalars = {
                 k: jnp.asarray(v, jnp.float32)
                 for k, v in aux.items()
-                if jnp.ndim(v) == 0
+                if jnp.ndim(v) == 0 or k in kept
             }
             return loss.astype(jnp.float32), scalars
 
@@ -162,7 +172,8 @@ def make_train_step(
             loss = loss_sum * inv
             with jax.named_scope("grad_accum"):
                 grads = jax.tree_util.tree_map(lambda g: g * inv, grad_sum)
-            aux = {k: jnp.mean(v) for k, v in aux_stack.items()}
+            aux = {k: jnp.sum(v, axis=0) if k in kept else jnp.mean(v)
+                   for k, v in aux_stack.items()}
 
         if param_specs is not None:
             # Pin gradients to the PARAM sharding at the loss->optimizer
@@ -194,6 +205,15 @@ def make_train_step(
                 bucket_plan=bucket_plan, prefetch_ag=prefetch_ag,
                 tensorstats_cfg=tstats,
             )
+            if after_update is not None:
+                moved = after_update.apply(new_params, {k: aux.pop(k) for k in kept})
+                if health is not None and health.policy == "skip_update":
+                    # a suppressed step moves nothing: the rule's leaves stay
+                    # too (their counts may come off a non-finite forward)
+                    ok = opt_metrics["updates_finite"]
+                    moved = jax.tree_util.tree_map(
+                        lambda a, b: jnp.where(ok, a, b), moved, new_params)
+                new_params = moved
         metrics = {
             "loss": loss,
             "lr": jnp.asarray(lr, jnp.float32),

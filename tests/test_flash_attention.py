@@ -376,7 +376,7 @@ def _walks_agree(q, k, v, *, causal, window, q_offset=0, bq=128, bkv=256,
     common = dict(sm_scale=d ** -0.5, causal=causal, window=window,
                   q_offset=q_offset, bq=bq, bkv=bkv, interpret=True)
     kg, kl = jax.random.split(jax.random.PRNGKey(31))
-    g = jax.random.normal(kg, qt.shape, q.dtype)
+    g = jax.random.normal(kg, qt.shape[:3] + vt.shape[3:], q.dtype)   # o's shape
     dlse = jax.random.normal(kl, qt.shape[:3], jnp.float32) if with_dlse else None
     outs = []
     for walk in (band, full):
@@ -406,6 +406,17 @@ def test_band_walk_equals_full_walk(case):
                         jnp.bfloat16)
     _walks_agree(q, k, v, causal=causal, window=window, bq=bq, bkv=bkv,
                  narrower=narrower)
+
+
+@pytest.mark.parametrize("d_qk, d_v", [(192, 128), (128, 256)])
+def test_band_walk_equals_full_walk_where_score_dims_are_not_value_dims(d_qk, d_v):
+    """Latent attention's call (models/kanana.py): q and k of ``d_qk``, v, o and
+    do of ``d_v``; dq and dk come out ``d_qk`` wide, dv ``d_v``."""
+    q, k, _ = _make_qkv(jax.random.PRNGKey(23), 1, 1024, 1024, 2, 2, d_qk, jnp.bfloat16)
+    *_, v = _make_qkv(jax.random.PRNGKey(24), 1, 1024, 1024, 2, 2, d_v, jnp.bfloat16)
+    o, lse, dq, dk, dv = _walks_agree(q, k, v, causal=True, window=300, bq=128, bkv=128)
+    assert o.shape == dv.shape == (1, 2, 1024, d_v)
+    assert dq.shape == dk.shape == (1, 2, 1024, d_qk) and lse.shape[:3] == (1, 2, 1024)
 
 
 @pytest.mark.parametrize("rows", ["attention_mask", "segment_ids"])

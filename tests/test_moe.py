@@ -58,6 +58,33 @@ class TestRouting:
         # balanced routing: no expert should starve
         assert counts.min() > 0.1 * 256 / 4, counts
 
+    def test_sigmoid_scores_choose_by_bias_and_weigh_by_score(self):
+        """``score_func: sigmoid``: a bias that favours an expert brings it
+        rows and leaves the weights the scores' own; the block's output is
+        the dense sum over the experts so chosen."""
+        cfg = moe.MoEConfig(num_experts=4, top_k=2, score_func="sigmoid",
+                            routed_scaling_factor=1.5)
+        params, x = params_and_x(jax.random.PRNGKey(2), cfg=cfg)
+        assert params["router"]["bias"].shape == (4,)
+        probs, idx, logits = moe.route(params["router"], x, cfg)
+        np.testing.assert_allclose(np.asarray(probs.sum(-1)), 1.5, rtol=1e-5)
+        np.testing.assert_array_equal(
+            np.asarray(jnp.sort(idx, -1)),
+            np.asarray(jnp.sort(jax.lax.top_k(logits, 2)[1], -1)))   # bias 0: the scores'
+        params["router"]["bias"] = jnp.array([0.0, 0.0, 0.0, 5.0])
+        _, idx, _ = moe.route(params["router"], x, cfg)
+        assert bool(jnp.all(jnp.any(idx == 3, axis=-1)))
+        y, aux = moe.moe_block(params, x[None], cfg, compute_dtype=jnp.float32)
+        np.testing.assert_allclose(np.asarray(y[0]), dense_reference(params, x, cfg),
+                                   rtol=2e-4, atol=2e-5)
+        counts = np.asarray(aux["expert_counts"])
+        assert counts.sum() == 64 and counts[3] == 32
+        assert float(aux["stats"]["moe/load_max_share"]) == pytest.approx(32 / 16)
+        # a softmax block returns neither
+        _, plain = moe.moe_block(params_and_x(jax.random.PRNGKey(2))[0], x[None], CFG,
+                                 compute_dtype=jnp.float32)
+        assert "expert_counts" not in plain and plain["stats"] == {}
+
     def test_aux_loss_uniform_is_one(self):
         # perfectly uniform router -> loss == 1.0 (its minimum)
         logits = jnp.zeros((64, 4))

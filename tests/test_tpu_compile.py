@@ -412,3 +412,54 @@ def test_the_mixed_stacks_cell_fits_one_v5e_under_full_only(topo):
     with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
         _compile_step(topo, "hf_laguna_s_2_1_config.yaml", 1, {
             **LAGUNA_CUT, "model.activations_checkpoint_granularity": "selective"})
+
+
+# --------------------------------------------------------------------------
+# latent attention (models/kanana.py) at the benchmark's cut
+# --------------------------------------------------------------------------
+
+#: the cell ``kanana2-30b-pretrain-8k-ep8`` (benchmark/configs/kanana-2-30b-a3b.json):
+#: published widths, layer 0 and five sparse layers, experts 0-15 of 128 held,
+#: 1/8 of the vocabulary, two sequences of 8192 in one micro-batch
+KANANA_CUT = {
+    "model.num_hidden_layers": 6, "model.vocab_size": 16032,
+    "model.num_experts_held": [0, 16],
+    "distributed_strategy.expert_model_parallel_size": 1,
+    "data.global_batch_size": 2,
+}
+
+
+@pytest.mark.parametrize("d_qk, block_kv", [(192, None), (256, None), (192, 2048)],
+                         ids=["192-as-fed", "256-padded", "192-key-tile-2048"])
+def test_flash_compiles_where_score_dims_are_not_value_dims(topo, d_qk, block_kv):
+    """The band walk's three kernels at the cell's shape, q and k of 192 (fed
+    whole: the block's last dim is the array's) or of 256, v of 128; at the
+    default key tile of 2048 the dkv kernel would need 16.73 MiB of the 16 MiB
+    of VMEM, so score dims past one lane width take 1024."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct((2, 8192, 32, d), jnp.bfloat16, sharding=one_chip)
+            for d in (d_qk, d_qk, 128)]
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True, block_kv=block_kv,
+                                          interpret=False).astype(jnp.float32))
+
+    lower = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args)
+    if block_kv == 2048:
+        with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|vmem"):
+            lower.compile()
+        return
+    assert lower.compile().as_text().count("tpu_custom_call") == 3
+
+
+def test_the_latent_attention_cell_fits_one_v5e_under_full(topo):
+    """8.25 GB of state (687.5 M parameters) and two sequences of 8192: under
+    ``full`` the compiler takes the step (``selective`` is refused at 22.05 GiB
+    of 15.75: PERF.md section 4).  Its report of temporaries counts both ways
+    through the held experts (under the rows' bound and past it), of which a
+    step runs one."""
+    compiled = _compile_step(topo, "hf_kanana_2_30b_a3b_config.yaml", 1, KANANA_CUT)
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes > 0.9 * ma.argument_size_in_bytes
+    assert 7.6 * 2**30 < ma.argument_size_in_bytes < 7.8 * 2**30
