@@ -173,16 +173,22 @@ def hbm_breakdown(facts: ModelFacts, plan: Plan,
     layers_local = facts.num_layers / plan.pp
 
     # residual bytes saved per token per layer, by remat policy: "full"
-    # keeps only the scan carry (the layer input); "selective" additionally
-    # keeps the projection/MLP intermediates but recomputes the attention
-    # core; "none" keeps everything the backward reads
+    # keeps the scan carry (the layer input) and, where the attention op is
+    # the flash kernel, its o and float32 lse (models/llama.py::_remat_policy);
+    # "selective" additionally keeps the projection/MLP intermediates but
+    # recomputes the attention core; "none" keeps everything the backward reads
     qkv_width = (nh + 2 * nkv) * d / plan.tp
     is_moe = bool(facts.num_experts)
     mlp_width = (facts.top_k if is_moe and facts.moe_frequency == 1
                  else 1) * ffn / plan.tp
     remat = "selective" if plan.pp > 1 else plan.remat  # pp ignores remat
+    impl = getattr(facts.model_cfg, "attention_impl",
+                   getattr(getattr(facts.model_cfg, "llama", None),
+                           "attention_impl", "core"))
     if remat == "full":
         c_tok = (h / sp_div) * abytes
+        if impl == "flash":
+            c_tok += nh / plan.tp * (d * abytes + 4)
     elif remat == "selective":
         c_tok = (2.0 * h / sp_div + qkv_width + 2.0 * mlp_width) * abytes
     else:
@@ -191,9 +197,6 @@ def hbm_breakdown(facts: ModelFacts, plan: Plan,
     # naive core attention materializes [b, nh/tp, s/cp, s] f32 scores; flash
     # (a real kernel on TPU) tiles them away.  "full" remat frees them
     # between layers; the other policies keep them at the scheduler's peak.
-    impl = getattr(facts.model_cfg, "attention_impl",
-                   getattr(getattr(facts.model_cfg, "llama", None),
-                           "attention_impl", "core"))
     if impl == "core" and remat != "full":
         c_tok += _SCORE_BUFFERS * (nh / plan.tp) * (facts.seq / plan.cp) * 4
     if is_moe:

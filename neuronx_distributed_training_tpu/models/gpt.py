@@ -416,6 +416,7 @@ def _attention_block(cfg, lp, x, cos, sin, policy, attention_mask=None,
         sliding_window=cfg.sliding_window, softmax_dtype=policy.softmax_dtype,
         attention_mask=attention_mask, segment_ids=segment_ids,
         block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
+        keep_flash_outputs=cfg.activations_checkpoint_granularity == "full",
     )
     out = linear_ops.apply_linear(lp["o"], out.reshape(b, s, nh * d))
     if return_kv:
@@ -766,11 +767,9 @@ def forward(
 
         xs = (layer_stack, layer_keys) if layer_keys is not None else layer_stack
 
-    from neuronx_distributed_training_tpu.models.llama import _remat_policy
+    from neuronx_distributed_training_tpu.models.llama import checkpoint_layer
 
-    remat = _remat_policy(cfg.activations_checkpoint_granularity)
-    if remat is not None:
-        body = jax.checkpoint(body, policy=remat, prevent_cse=False)
+    body = checkpoint_layer(body, cfg, stack="layers")
     (x, aux_sum), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
     # post_ln layers already end normalized; the reference has no final LN
     # for that layout (transformer.py:2478, 2569-2570)

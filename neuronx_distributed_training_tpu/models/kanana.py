@@ -335,7 +335,8 @@ def _mla_block(lp, x, cos, sin, cfg: KananaConfig, policy: DtypePolicy,
     out = attn_ops.attention(
         q, k, v, impl=lc.attention_impl, causal=True, sliding_window=None,
         softmax_dtype=policy.softmax_dtype, attention_mask=attention_mask,
-        segment_ids=segment_ids, block_q=lc.flash_block_q, block_kv=lc.flash_block_kv)
+        segment_ids=segment_ids, block_q=lc.flash_block_q, block_kv=lc.flash_block_kv,
+        keep_flash_outputs=llama._keeps_flash_outputs(lc))
     return linear_ops.apply_linear(lp["o"], out.reshape(b, s, nh * dv))
 
 
@@ -384,8 +385,17 @@ def _decoder_layer(lp, x, cos, sin, cfg: KananaConfig, policy: DtypePolicy, kind
 def decoder_stack(layers, x, cos, sin, cfg: KananaConfig, policy: DtypePolicy, *,
                   attention_mask=None, segment_ids=None):
     """The dense layers, then the sparse ones, each kind one scan of
-    checkpointed layers -> ``(x, stats of the sparse scan, stacked by layer)``."""
-    remat = llama._remat_policy(cfg.llama.activations_checkpoint_granularity)
+    checkpointed layers -> ``(x, stats of the sparse scan, stacked by layer)``.
+
+    A run of ONE layer (the family's dense layer 0) is checkpointed with
+    ``prevent_cse``: a scan of length 1 is unrolled, and without the barrier
+    the compiler merges the layer's rerun with its first run, so that nothing
+    of the layer is rematerialized and every activation of it (q and k of
+    ``[b, heads, s, 192]`` among them: 1.17 GiB at the benchmark's cut) lives
+    through the whole step.  With them held, the step that keeps the sparse
+    layers' kernel outputs is refused for one v5e by 148 MiB
+    (tests/test_tpu_compile.py); released, it fits, at the price of that one
+    layer's projections and MLP run forward twice."""
     stats: dict = {}
     for kind in KINDS:
         if kind not in layers:
@@ -394,8 +404,8 @@ def decoder_stack(layers, x, cos, sin, cfg: KananaConfig, policy: DtypePolicy, *
         def body(x, lp, kind=kind):
             return _decoder_layer(_cast_layer(lp, policy), x, cos, sin, cfg, policy, kind,
                                   attention_mask=attention_mask, segment_ids=segment_ids)
-        if remat is not None:
-            body = jax.checkpoint(body, policy=remat, prevent_cse=False)
+        body = llama.checkpoint_layer(body, cfg.llama, stack=kind,
+                                      prevent_cse=cfg.layers_of[kind] == 1)
         x, stats = jax.lax.scan(body, x, layers[kind])
     return x, stats
 

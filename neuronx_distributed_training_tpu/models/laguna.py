@@ -464,8 +464,6 @@ def decoder_stack(layers, x, tables, cfg: LagunaConfig, policy: DtypePolicy, *,
                   attention_mask=None, segment_ids=None):
     """The whole stack by ``stack_plan`` -> ``(x, router loss summed over the
     sparse layers, [stats of each scan])``."""
-    remat = llama._remat_policy(cfg.llama.activations_checkpoint_granularity)
-
     def run_of(kind):
         def body(carry, lp):
             x, aux_acc = carry
@@ -473,8 +471,10 @@ def decoder_stack(layers, x, tables, cfg: LagunaConfig, policy: DtypePolicy, *,
                 _cast_layer(lp, policy), x, tables, cfg, policy, kind,
                 attention_mask=attention_mask, segment_ids=segment_ids)
             return (x, aux_acc + aux), stats
-        if remat is not None:
-            body = jax.checkpoint(body, policy=remat, prevent_cse=False)
+        # a run of one layer stays merged with its rerun (prevent_cse off, as
+        # every stack): the two full layers fit so, and un-merging them would
+        # rerun layer 0's 12288-wide MLP (models/kanana.py takes the other side)
+        body = llama.checkpoint_layer(body, cfg.llama, stack=kind_name(*kind))
         return lambda carry, stack: jax.lax.scan(body, carry, stack)
 
     taken = {name: 0 for name in layers}

@@ -304,6 +304,7 @@ def _attention_block(lp, x, cos, sin, cfg: LlamaConfig, policy: DtypePolicy,
         segment_ids=segment_ids,
         block_q=cfg.flash_block_q,
         block_kv=block_kv or cfg.flash_block_kv,
+        keep_flash_outputs=_keeps_flash_outputs(cfg),
     )
     if "gate" in lp:
         with jax.named_scope("head_gate"):
@@ -355,16 +356,60 @@ def _decoder_layer(layer_params, x, cos, sin, cfg: LlamaConfig, policy: DtypePol
     return x
 
 
+#: ``selective`` recomputes the O(s^2) attention internals only — the
+#: reference's activations_checkpoint_recompute: [CoreAttention]
+_SELECTIVE_RECOMPUTES = ("attn_scores", "attn_probs")
+
+
 def _remat_policy(granularity: Optional[str]):
     if granularity == "full":
-        return jax.checkpoint_policies.nothing_saveable
+        # the layer's input and the flash forward kernel's two outputs: the
+        # rerun rebuilds q, k and v from the input, not the kernel's o and lse
+        # (16 ms of the MXU for 128 MiB at the latent-attention cell's shape)
+        from neuronx_distributed_training_tpu.ops.flash_attention import KEPT_NAMES
+
+        return jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
     if granularity == "selective":
-        # recompute the O(s^2) attention internals only — the reference's
-        # activations_checkpoint_recompute: [CoreAttention]
         return jax.checkpoint_policies.save_anything_except_these_names(
-            "attn_scores", "attn_probs"
-        )
+            *_SELECTIVE_RECOMPUTES)
     return None
+
+
+def _keeps_flash_outputs(cfg) -> bool:
+    """``attn_ops.attention``'s ``keep_flash_outputs`` for a layer of ``cfg``'s
+    stacks: whether ``_remat_policy`` keeps the names the kernel's forward
+    rule would give its outputs."""
+    return cfg.activations_checkpoint_granularity == "full"
+
+
+def checkpoint_layer(body, cfg, *, stack: str, prevent_cse: bool = False):
+    """``body`` (a scanned stack's layer) rematerialized as
+    ``cfg.activations_checkpoint_granularity`` says (``cfg``: a ``LlamaConfig``
+    or what has its two fields read here), and what that keeps recorded among
+    the trace's facts: ``remat`` of ``run_summary.json``, one entry a
+    ``stack``.  ``flash_fwd_per_layer_application``: 1 where the forward
+    kernel's outputs are kept or nothing is rematerialized, 2 where the rerun
+    calls it again (``full`` around a context-parallel body, whose calls are
+    not named); absent where the attention op is not a flash kernel."""
+    granularity = cfg.activations_checkpoint_granularity
+    policy = _remat_policy(granularity)
+    facts = shd.trace_facts()
+    if facts is not None:
+        if granularity == "full":
+            from neuronx_distributed_training_tpu.ops.flash_attention import KEPT_NAMES
+
+            entry = {"granularity": granularity, "kept": list(KEPT_NAMES)}
+        else:
+            entry = {"granularity": granularity, "kept": "all"}
+            if granularity == "selective":
+                entry["recomputed"] = list(_SELECTIVE_RECOMPUTES)
+        if cfg.attention_impl != "core":
+            entry["flash_fwd_per_layer_application"] = (
+                2 if granularity == "full" and cfg.attention_impl != "flash" else 1)
+        facts.setdefault("remat", {})[stack] = entry
+    if policy is None:
+        return body
+    return jax.checkpoint(body, policy=policy, prevent_cse=prevent_cse)
 
 
 def embed_and_rope(
@@ -407,10 +452,7 @@ def decoder_stack(layer_stack, x: jax.Array, cos, sin, cfg: LlamaConfig,
                               attention_mask=attention_mask,
                               segment_ids=segment_ids), None
 
-    remat = _remat_policy(cfg.activations_checkpoint_granularity)
-    if remat is not None:
-        body = jax.checkpoint(body, policy=remat, prevent_cse=False)
-    x, _ = jax.lax.scan(body, x, layer_stack)
+    x, _ = jax.lax.scan(checkpoint_layer(body, cfg, stack="layers"), x, layer_stack)
     return x
 
 

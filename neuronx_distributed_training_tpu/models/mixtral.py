@@ -318,8 +318,6 @@ def forward(
         positions=llama.positions_for(input_ids, attention_mask, segment_ids)
     )
     layer_stack = params["layers"]
-    remat = llama._remat_policy(lc.activations_checkpoint_granularity)
-
     if cfg.moe_frequency == 1:
 
         def body(carry, lp):
@@ -337,8 +335,7 @@ def forward(
                                  attention_mask=attention_mask,
                                  segment_ids=segment_ids)
 
-    if remat is not None:
-        body = jax.checkpoint(body, policy=remat, prevent_cse=False)
+    body = llama.checkpoint_layer(body, lc, stack="layers")
     (x, aux_sum), stats = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
     # router_aux_loss is already coefficient-weighted (weighted_router_loss);
     # averaged over the layers that HAVE routers

@@ -20,8 +20,9 @@ scale with layers, activations with layers x passes: under an
 ``[T, batch, seq]`` floats (``ce_t`` and the gate's logit) and backward holds
 one pass's logits at a time: under ``selective`` the whole pass (stack, final
 norm, head, CE, gate) is rematerialized and its layers keep their residuals
-once; under ``full`` the layers keep only their inputs and the head alone is
-rematerialized.  Either way the stack runs forward twice, not three times.
+once; under ``full`` the layers keep their inputs and the flash kernel's
+outputs (``llama._remat_policy``) and the head alone is rematerialized.
+Either way the stack runs forward twice, not three times.
 ``T = 1`` without the post-sub-layer norms is ``llama.forward``.
 
 Not wired (each refused by name): pipeline parallelism (a pass would circle

@@ -181,6 +181,7 @@ def attention(
     segment_ids: Optional[jax.Array] = None,  # [b, s] packed-record segments
     block_q: Optional[int] = None,   # Pallas flash tile sizes (None = default;
     block_kv: Optional[int] = None,  # a per-chip tuning knob, fusions.flash_block_*)
+    keep_flash_outputs: bool = False,  # the caller's remat policy keeps them
 ) -> jax.Array:
     """Dispatch mirroring the reference's flash/ring/Core selection
     (``modeling_llama.py:482-489``).
@@ -190,7 +191,13 @@ def attention(
     on the O(seq)-memory kernels (the reference runs its NKI flash kernel on
     ``attention_mask`` batches too, ``llama_model.py:94-101``).  Only
     zigzag_ring rejects it: the batch is zig-zag permuted and a key-position
-    mask would be wrong in that layout."""
+    mask would be wrong in that layout.
+
+    ``keep_flash_outputs``: the layer is rematerialized under a policy that
+    keeps the flash forward kernel's outputs (``models.llama._remat_policy``,
+    ``full``), so the ``flash`` path names them (``flash_attention``'s
+    ``keep_outputs``).  The context-parallel bodies take no notice: a ring
+    would keep one partial output a step."""
     if attention_mask is not None and impl == "zigzag_ring":
         # a core fallback would be WRONG here (the batch is zig-zag permuted
         # and core's causal mask assumes contiguous order) — so raise
@@ -209,7 +216,7 @@ def attention(
         return _flash_on_mesh(
             q, k, v, attention_mask, segment_ids, causal=causal,
             sliding_window=sliding_window, q_offset=q_offset,
-            block_q=block_q, block_kv=block_kv,
+            block_q=block_q, block_kv=block_kv, keep_outputs=keep_flash_outputs,
         )
     if impl == "ring":
         from neuronx_distributed_training_tpu.parallel.ring_attention import ring_attention

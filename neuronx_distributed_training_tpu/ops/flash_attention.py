@@ -87,6 +87,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -104,6 +105,8 @@ DEFAULT_BLOCK_Q = 512
 # per-chip knob via fusions.flash_block_kv.
 DEFAULT_BLOCK_KV = 2048
 NEG_INF = -1e30
+#: ``checkpoint_name``s of the plain call's forward outputs, o and lse
+KEPT_NAMES = ("flash_o", "flash_lse")
 
 
 def _block_sizes(sq: int, skv: int, bq: Optional[int], bkv: Optional[int],
@@ -1017,18 +1020,35 @@ def _backward(causal, window, q_offset, bq, bkv, interpret, res, g, dlse=None):
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11)
 )
-def _flash(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret):
+def _flash(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret, keep):
     return _forward(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret)[0]
 
 
-def _flash_fwd(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret):
+def _flash_fwd(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret, keep):
+    """``keep``: name the kernel's two outputs (``KEPT_NAMES``) for a
+    ``jax.checkpoint`` policy that keeps them, so that a rematerialized layer
+    rebuilds q, k and v and does not call the forward kernel again
+    (models/llama.py::_remat_policy, ``full``).  The row statistics then cross
+    as ``[b, heads, s]``: as the kernel writes them, ``[..., SUBLANES]``
+    float32, HBM pads the minor dim to a lane width and holds 16 times their
+    bytes.  Without ``keep`` the rule is what it was, to the lowered text."""
     o, lse = _forward(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret)
+    if keep:
+        o = checkpoint_name(o, KEPT_NAMES[0])
+        lse = checkpoint_name(lse[..., 0], KEPT_NAMES[1])
     return o, (q, k, v, kvm, seg, o, lse)
 
 
-_flash.defvjp(_flash_fwd, _backward)
+def _flash_bwd(causal, window, q_offset, bq, bkv, interpret, keep, res, g):
+    if keep:  # the SUBLANES columns the kernel wrote were all this one
+        lse = jnp.broadcast_to(res[6][..., None], res[6].shape + (SUBLANES,))
+        res = (*res[:6], lse)
+    return _backward(causal, window, q_offset, bq, bkv, interpret, res, g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 # -- lse-exposing variant (the ring-attention building block) ----------------
@@ -1043,6 +1063,10 @@ def _flash_lse(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret):
     lets callers merge partial attention over KV chunks (context-parallel ring)
     with exact autodiff: the merge is plain JAX, and this op's vjp folds the
     lse cotangent into the kernel's delta operand.
+
+    Its outputs carry no ``checkpoint_name`` (``_flash_fwd`` has them): a ring
+    of n steps would keep n partial outputs a layer, and no measured cell runs
+    one, so a layer rematerialized under ``full`` reruns this forward kernel.
     """
     o, lse = _forward(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret)
     return o, lse[..., 0]
@@ -1146,6 +1170,7 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_kv: Optional[int] = None,
     interpret: Optional[bool] = None,
+    keep_outputs: bool = False,
 ) -> jax.Array:
     """Flash attention in the model's [b, s, h, d] layout.
 
@@ -1164,6 +1189,8 @@ def flash_attention(
     test mesh, where toy models have head dims far below a lane) they run
     ``core_attention`` with a warning per shape.
     ``interpret`` defaults to True off-TPU so tests run on CPU.
+    ``keep_outputs``: the caller's layer is rematerialized under a policy that
+    keeps ``KEPT_NAMES`` (``_flash_fwd``); the values are the same either way.
     """
     b, sq, nh, d = q.shape
     skv, nkv = k.shape[1], k.shape[2]
@@ -1212,5 +1239,5 @@ def flash_attention(
         )
     seg = _prep_rows(segment_ids, b, sq, "segment_ids")
     o = _flash(qt, kt, vt, kvm, seg, causal, sliding_window, q_offset, bq, bkv,
-               interpret)
+               interpret, keep_outputs)
     return jnp.swapaxes(o, 1, 2)

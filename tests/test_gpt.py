@@ -131,7 +131,8 @@ class TestShardedGPT:
         # dp2 x ep4, 4 experts, one row of 16 tokens a chip, one choice each:
         # rows travel up to twice the fair 16 (a chip could receive 64)
         assert traced == {"moe_token_shards": 8, "moe_expert_exchange": "tokens",
-                          "moe_row_bounds": [32]}
+                          "moe_row_bounds": [32],
+                          "remat": {"layers": {"granularity": None, "kept": "all"}}}
         np.testing.assert_allclose(float(loss), float(ref_loss), rtol=2e-5)
         for a, b in zip(jax.tree_util.tree_leaves(ref_grads),
                         jax.tree_util.tree_leaves(grads)):

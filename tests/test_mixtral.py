@@ -135,7 +135,8 @@ class TestMixtralSharded:
         with mesh, shd.use_mesh(mesh), shd.collect_trace_facts() as traced:
             loss, grads = jax.jit(jax.value_and_grad(loss_fn))(sh_params, sh_batch)
         assert traced == {"moe_token_shards": shards,
-                          "moe_expert_exchange": "tokens", "moe_row_bounds": bounds}
+                          "moe_expert_exchange": "tokens", "moe_row_bounds": bounds,
+                          "remat": {"layers": {"granularity": None, "kept": "all"}}}
         np.testing.assert_allclose(float(loss), float(ref_loss), rtol=2e-5)
         ref_leaves, treedef = jax.tree_util.tree_flatten_with_path(ref_grads)
         for (path, rg), g in zip(ref_leaves, treedef.flatten_up_to(grads)):

@@ -359,3 +359,26 @@ def test_flash_band_is_a_static_fact_of_the_run(tmp_path, window):
         assert call["kv_band"] < call["kv_blocks"]
         # tiles of 128 are one sub-tile: both of the band's are computed
         assert call["walk"] == "diagonal" and call["sub_tiles"] == [2, 2]
+
+
+@pytest.mark.parametrize("granularity, expected", [
+    ("full", {"granularity": "full", "kept": ["flash_o", "flash_lse"],
+              "flash_fwd_per_layer_application": 1}),
+    ("selective", {"granularity": "selective", "kept": "all",
+                   "recomputed": ["attn_scores", "attn_probs"],
+                   "flash_fwd_per_layer_application": 1}),
+])
+def test_remat_is_a_static_fact_of_the_run(tmp_path, granularity, expected):
+    """``remat``: per scanned stack what a layer keeps for its backward and
+    how often a layer application runs the flash forward kernel in a step
+    (``models/llama.py::checkpoint_layer``), in ``run_summary.json``."""
+    _, summary = fit_tiny(tmp_path, max_steps=1, overrides={
+        "data.seq_length": 256, "data.global_batch_size": 8,
+        "model.hidden_size": 256, "model.num_attention_heads": 2,
+        "model.num_key_value_heads": 1, "model.max_position_embeddings": 256,
+        "model.num_layers": 2,
+        "model.activations_checkpoint_granularity": granularity,
+        "model.fusions": {"flash_attention": True, "flash_block_q": 128,
+                          "flash_block_kv": 128},
+    })
+    assert summary["remat"] == {"layers": expected}
