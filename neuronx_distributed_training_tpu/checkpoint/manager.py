@@ -28,8 +28,13 @@ from pathlib import Path
 from typing import Any, Optional
 
 import jax
-import orbax.checkpoint as ocp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from neuronx_distributed_training_tpu.telemetry.spans import timed_import
+
+# start-up timeline: ``startup.imports_s["orbax.checkpoint"]``
+with timed_import("orbax.checkpoint"):
+    import orbax.checkpoint as ocp
 
 from neuronx_distributed_training_tpu.checkpoint import integrity as ck_integrity
 from neuronx_distributed_training_tpu.checkpoint.integrity import (

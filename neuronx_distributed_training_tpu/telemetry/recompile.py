@@ -14,11 +14,16 @@ symptom (a stalled step) minutes later; when the trainer has swapped in an
 AOT-compiled step, the same check turns XLA's opaque "argument mismatch"
 error into a readable shape diff.
 
-The signature check sees the host's side only.  ``watch_compiles`` counts what
-XLA actually did: every backend compile (or persistent-cache read, which the
-same jax event wraps) with the trainer step it hit and its seconds — the
-``compile_events`` of ``run_summary.json``.  A stall the size of a compile
-inside the steady window shows there with its step.
+The signature check sees the host's side only.  The process's one
+``jax.monitoring`` listener counts what XLA actually did, from the package's
+first import on: every backend compile (or persistent-cache read, which the
+same jax event wraps), the Python tracing and lowering before it (which no
+cache saves), and the persistent cache's hits, misses and retrieval time,
+each under the span open when it fired (``spans.open_phase``) — ``COMPILES``,
+the ``compiles`` and ``compile_cache`` of ``run_summary.json``'s ``startup``
+section.  ``watch_compiles`` additionally lists the backend compiles of one
+``fit()`` with the trainer step they hit — its ``compile_events``.  A stall the
+size of a compile inside the steady window shows there with its step.
 """
 
 from __future__ import annotations
@@ -28,30 +33,95 @@ from typing import Any, Callable, Optional
 
 import jax
 
+from neuronx_distributed_training_tpu.telemetry import spans as _spans
+
 logger = logging.getLogger(__name__)
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-# jax.monitoring has no unregister: ONE listener per process, registered on
-# first use, forwards to whichever detector the running fit() pointed it at.
-# It is called only when jax records an event duration (trace, lower,
-# compile), never on a steady step.
-_compile_sink: Optional[Callable[[float], None]] = None
-_listening = False
+#: jax event -> the whole-run total it feeds (seconds; counts)
+_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    _BACKEND_COMPILE_EVENT: "backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_s",
+}
+_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    # recorded when a compile is WRITTEN to the cache: one that took the
+    # cache's minimum compile time (utils/compile_cache.py: 1 s) or longer
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+
+
+class CompileLog:
+    """What the listener accumulates, apart from any capped list: whole-run
+    totals, and per phase the backend compiles (``n``, ``seconds``) and the
+    tracing and lowering (``trace_lower_s``) that fired under it."""
+
+    def __init__(self) -> None:
+        self.totals: dict[str, float] = {
+            **{k: 0.0 for k in _DURATIONS.values()},
+            **{k: 0 for k in _COUNTS.values()},
+            # every call jax made to the two listeners: what listening costs
+            "listener_calls": 0}
+        self.by_phase: dict[str, dict[str, float]] = {}
+
+    def _phase(self) -> dict[str, float]:
+        return self.by_phase.setdefault(
+            _spans.open_phase() or "unattributed",
+            {"n": 0, "seconds": 0.0, "trace_lower_s": 0.0})
+
+    def duration(self, event: str, seconds: float) -> None:
+        self.totals["listener_calls"] += 1
+        key = _DURATIONS.get(event)
+        if key is None:
+            return
+        self.totals[key] += seconds
+        if key == "backend_s":
+            phase = self._phase()
+            phase["n"] += 1
+            phase["seconds"] += seconds
+        elif key in ("trace_s", "lower_s"):
+            self._phase()["trace_lower_s"] += seconds
+
+    def count(self, event: str) -> None:
+        self.totals["listener_calls"] += 1
+        key = _COUNTS.get(event)
+        if key is not None:
+            self.totals[key] += 1
+
+    def summary(self) -> dict:
+        """``{"totals", "by_phase"}``, rounded, for ``run_summary.json``."""
+        return {
+            "totals": {k: round(v, 6) for k, v in self.totals.items()},
+            "by_phase": {p: {k: round(v, 6) for k, v in d.items()}
+                         for p, d in self.by_phase.items()},
+        }
+
+
+#: the process's log, from the package's first import
+COMPILES = CompileLog()
+# jax.monitoring has no unregister: ONE pair of listeners per process,
+# registered below when the package is first imported.  They feed COMPILES
+# and forward backend compiles to whichever detector the running fit()
+# pointed them at.  They are called only when jax records an event (trace,
+# lower, compile, cache), never on a steady step.
+_compile_sink: Optional[Callable[[float, Optional[str]], None]] = None
 
 
 def _on_event_duration(event: str, duration_secs: float, **_: Any) -> None:
+    COMPILES.duration(event, duration_secs)
     sink = _compile_sink
     if sink is not None and event == _BACKEND_COMPILE_EVENT:
-        sink(duration_secs)
+        sink(duration_secs, _spans.open_phase())
 
 
-def _point_compile_listener(sink: Optional[Callable[[float], None]]) -> None:
-    global _compile_sink, _listening
-    if not _listening and sink is not None:
-        jax.monitoring.register_event_duration_secs_listener(
-            _on_event_duration)
-        _listening = True
-    _compile_sink = sink
+def _on_event(event: str, **_: Any) -> None:
+    COMPILES.count(event)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
+jax.monitoring.register_event_listener(_on_event)
 
 
 def _leaf_sig(x: Any) -> str:
@@ -88,21 +158,26 @@ class RecompileDetector:
         self._seen: dict[str, dict[str, str]] = {}
         self._warned: set[str] = set()
         self.events: list[str] = []
-        self.compile_events: list[dict[str, float]] = []
+        self.compile_events: list[dict[str, Any]] = []
 
     def watch_compiles(self, step_of: Callable[[], int]) -> None:
         """Record every backend compile from now on as ``{"step":
-        step_of(), "seconds": s}`` in ``compile_events`` (takes the
-        process's listener over from any earlier detector)."""
-        def sink(seconds: float) -> None:
+        step_of(), "seconds": s, "phase": <the span open, or None>}`` in
+        ``compile_events`` (takes the process's listener over from any
+        earlier detector)."""
+        global _compile_sink
+
+        def sink(seconds: float, phase: Optional[str]) -> None:
             self.compile_events.append(
-                {"step": int(step_of()), "seconds": round(seconds, 4)})
+                {"step": int(step_of()), "seconds": round(seconds, 4),
+                 "phase": phase})
             del self.compile_events[:-self.MAX_COMPILE_EVENTS]
 
-        _point_compile_listener(sink)
+        _compile_sink = sink
 
     def unwatch_compiles(self) -> None:
-        _point_compile_listener(None)
+        global _compile_sink
+        _compile_sink = None
 
     def check(self, name: str, *args: Any) -> bool:
         """Record ``args``' signature under ``name``; returns True (and

@@ -24,6 +24,7 @@ import argparse
 import logging
 import os
 import sys
+import time
 
 logger = logging.getLogger("nxdt.train")
 
@@ -121,16 +122,28 @@ def main() -> None:
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
 
-    from neuronx_distributed_training_tpu.utils.compile_cache import (
-        configure_compilation_cache,
+    # the module's imports that cost (the loop's bring orbax) and the
+    # backend's first use are phases of the start-up timeline
+    # (docs/observability.md "Start-up timeline"), each bracketed where it
+    # stands; the few milliseconds of the later ones lie between phases
+    from neuronx_distributed_training_tpu.telemetry.spans import (
+        startup_add,
+        startup_phase,
     )
+
+    with startup_phase("startup/imports"):
+        from neuronx_distributed_training_tpu.utils.compile_cache import (
+            configure_compilation_cache,
+        )
 
     configure_compilation_cache(args.compilation_cache)
 
-    maybe_init_distributed(jax)
+    with startup_phase("startup/backend"):
+        maybe_init_distributed(jax)
 
-    from neuronx_distributed_training_tpu.config.loader import load_config
-    from neuronx_distributed_training_tpu.trainer.loop import Trainer
+    with startup_phase("startup/imports"):
+        from neuronx_distributed_training_tpu.config.loader import load_config
+        from neuronx_distributed_training_tpu.trainer.loop import Trainer
 
     overrides = parse_overrides(args.overrides)
     if os.environ.get("TRAIN_ITERS"):  # reference test hook
@@ -213,7 +226,10 @@ def main() -> None:
         )
 
         try:
-            replan = maybe_replan(cfg, len(jax.devices()), elastic=elastic_cfg)
+            with startup_phase("startup/backend"):
+                n_devices = len(jax.devices())
+            t_replan = time.perf_counter()
+            replan = maybe_replan(cfg, n_devices, elastic=elastic_cfg)
         except ElasticResumeError as e:
             # curated operator-facing refusal (the message carries the --set
             # remediation) — a clean one-line exit with the tagged code
@@ -228,6 +244,8 @@ def main() -> None:
             print(f"elastic resume refused: {e}", file=sys.stderr)
             raise SystemExit(EXIT_ALL_CORRUPT) from e
         if replan.replanned:
+            # on the start-up timeline under the name fit() accounts it by
+            startup_add("replan", t_replan)
             cfg = replan.cfg
             logger.warning(
                 "elastic replan imposed for %d chips (was %d): see "
@@ -250,7 +268,8 @@ def main() -> None:
 
         top_k = (args.autotune if (args.autotune or 0) > 0
                  else int(at_block.get("top_k", 5)))
-        chips = len(jax.devices())
+        with startup_phase("startup/backend"):
+            chips = len(jax.devices())
         plan_report = plan_config(
             cfg, chips=chips,
             topology=at_block.get("topology"),

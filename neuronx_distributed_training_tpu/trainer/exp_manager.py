@@ -166,7 +166,13 @@ class ExpManager:
         self._tb = None
         if create_tensorboard_logger:
             try:
-                from torch.utils.tensorboard import SummaryWriter
+                from neuronx_distributed_training_tpu.telemetry.spans import (
+                    timed_import,
+                )
+
+                # startup.imports_s["torch.utils.tensorboard"]
+                with timed_import("torch.utils.tensorboard"):
+                    from torch.utils.tensorboard import SummaryWriter
 
                 self._tb = SummaryWriter(log_dir=str(self.log_dir / "tb"))
             except Exception as e:  # noqa: BLE001 — TB is optional observability

@@ -17,41 +17,55 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import time
 from typing import Any, Callable, Iterator, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from neuronx_distributed_training_tpu.checkpoint import (
-    CheckpointConfig,
-    Checkpointer,
-    TrainState,
+from neuronx_distributed_training_tpu.telemetry import recompile as _recompile
+from neuronx_distributed_training_tpu.telemetry.spans import (
+    claim_startup,
+    compile_sections,
+    named,
+    startup_add,
+    startup_phase,
 )
-from neuronx_distributed_training_tpu.config.loader import ConfigDict, batch_schedule
-from neuronx_distributed_training_tpu.data import (
-    DataModule,
-    DataStallError,
-    PrefetchIterator,
-    SyntheticDataModule,
-)
-from neuronx_distributed_training_tpu.models.family import flops_for_model, resolve
-from neuronx_distributed_training_tpu.optim.adamw import (
-    AdamWConfig,
-    EMAConfig,
-    init_opt_state,
-    opt_state_specs,
-)
-from neuronx_distributed_training_tpu.optim.lr import build_lr_schedule
-from neuronx_distributed_training_tpu.parallel import sharding as shd
-from neuronx_distributed_training_tpu.parallel.mesh import MeshConfig, build_mesh
-from neuronx_distributed_training_tpu.trainer.exp_manager import ExpManager
-from neuronx_distributed_training_tpu.trainer.step import (
-    jit_train_step,
-    make_eval_step,
-    make_train_step,
-)
-from neuronx_distributed_training_tpu.utils.dtypes import DtypePolicy
+
+# the module's imports (orbax's among them, checkpoint/manager.py) are a
+# phase of the start-up timeline; under ``nxdt-train`` the CLI's bracket is
+# open around this one, which then counts there
+with startup_phase("startup/imports"):
+    from neuronx_distributed_training_tpu.checkpoint import (
+        CheckpointConfig,
+        Checkpointer,
+        TrainState,
+    )
+    from neuronx_distributed_training_tpu.config.loader import ConfigDict, batch_schedule
+    from neuronx_distributed_training_tpu.data import (
+        DataModule,
+        DataStallError,
+        PrefetchIterator,
+        SyntheticDataModule,
+    )
+    from neuronx_distributed_training_tpu.models.family import flops_for_model, resolve
+    from neuronx_distributed_training_tpu.optim.adamw import (
+        AdamWConfig,
+        EMAConfig,
+        init_opt_state,
+        opt_state_specs,
+    )
+    from neuronx_distributed_training_tpu.optim.lr import build_lr_schedule
+    from neuronx_distributed_training_tpu.parallel import sharding as shd
+    from neuronx_distributed_training_tpu.parallel.mesh import MeshConfig, build_mesh
+    from neuronx_distributed_training_tpu.trainer.exp_manager import ExpManager
+    from neuronx_distributed_training_tpu.trainer.step import (
+        jit_train_step,
+        make_eval_step,
+        make_train_step,
+    )
+    from neuronx_distributed_training_tpu.utils.dtypes import DtypePolicy
 
 logger = logging.getLogger(__name__)
 
@@ -312,11 +326,16 @@ class Trainer:
         devices: Optional[list] = None,
         enable_checkpointing: bool = True,
     ) -> "Trainer":
-        devices = devices if devices is not None else jax.devices()
-        asm = cls.assemble(
-            cfg, devices=devices, data_module=data_module,
-            val_data_module=val_data_module,
-        )
+        # each stretch below is a phase of the process's start-up timeline
+        # (telemetry/spans.py::STARTUP; host wall time, nothing syncs)
+        if devices is None:
+            with startup_phase("startup/backend"):
+                devices = jax.devices()
+        with startup_phase("startup/assemble"):
+            asm = cls.assemble(
+                cfg, devices=devices, data_module=data_module,
+                val_data_module=val_data_module,
+            )
         return cls._materialize(
             asm, devices=devices, enable_checkpointing=enable_checkpointing
         )
@@ -771,7 +790,7 @@ class Trainer:
         shardings = lambda specs: jax.tree_util.tree_map(
             ns, specs, is_leaf=lambda x: isinstance(x, P)
         )
-        with mesh, shd.use_mesh(mesh):
+        with startup_phase("startup/init_params"), mesh, shd.use_mesh(mesh):
             params = jax.jit(
                 param_builder, out_shardings=shardings(pspecs)
             )(init_key)
@@ -793,7 +812,8 @@ class Trainer:
                 warm_ck.close()
             logger.info("warm start: params restored from %s", warm_path)
 
-        with mesh, shd.use_mesh(mesh):
+        with startup_phase("startup/init_opt_state"), \
+                mesh, shd.use_mesh(mesh):
             opt_state = jax.jit(
                 functools.partial(
                     init_opt_state, policy=policy,
@@ -840,13 +860,17 @@ class Trainer:
             if key in data_block and hasattr(data_module, key):
                 setattr(data_module, key, cast(data_block[key]))
 
-        exp = ExpManager.from_config(cfg, global_batch_size=sched["global_batch_size"])
+        with startup_phase("startup/exp_manager"):
+            exp = ExpManager.from_config(
+                cfg, global_batch_size=sched["global_batch_size"])
 
         # -- telemetry wiring: MFU reference + the static run facts the
         # compile census persists to run_summary.json.  The analytic FLOPs
         # estimate (utils.perf, the reference's llama_perf_estimate role) is
         # per-family; throughput itself stays the one source of truth —
         # mfu derives from its tokens_per_sec at each logging boundary.
+        # (Down to the checkpointer: ``startup/telemetry_arming``.)
+        t_arming = time.perf_counter()
         from neuronx_distributed_training_tpu.utils import perf as _perf
 
         seq_len = int((cfg.get("data", {}) or {}).get("seq_length", 0) or 0) \
@@ -952,11 +976,14 @@ class Trainer:
             logger.warning("MFU estimation unavailable for %s: %s",
                            type(model_cfg).__name__, e)
 
+        startup_add("startup/telemetry_arming", t_arming)
+
         checkpointer = None
         if enable_checkpointing:
-            ck_cfg = CheckpointConfig.from_config(cfg)
-            ck_cfg = dataclasses.replace(ck_cfg, dir=exp.checkpoint_dir)
-            checkpointer = Checkpointer(ck_cfg)
+            with startup_phase("startup/checkpointer"):
+                ck_cfg = CheckpointConfig.from_config(cfg)
+                ck_cfg = dataclasses.replace(ck_cfg, dir=exp.checkpoint_dir)
+                checkpointer = Checkpointer(ck_cfg)
 
         from neuronx_distributed_training_tpu.trainer.elastic import (
             ElasticConfig,
@@ -1239,6 +1266,7 @@ class Trainer:
     # -- the loop -----------------------------------------------------------
 
     def fit(self) -> dict[str, float]:
+        t_fit = time.perf_counter()
         import contextlib
         import signal
         import time as _time
@@ -1256,7 +1284,18 @@ class Trainer:
         tel = self.exp.telemetry
         # spans power both the per-boundary decomposition AND goodput; the
         # timer is pure perf_counter bookkeeping, so either knob arms it
-        spans = SpanTimer(enabled=tel.spans or tel.goodput)
+        # The first fit() of a process continues the clock of the process's
+        # start-up timeline (telemetry/spans.py::STARTUP): goodput's wall
+        # then starts at process start, and the loop's spans up to the end
+        # of the first boundary join the timeline's phases.  A later fit()
+        # in the same process (tests, drills) starts its own clock.
+        startup = claim_startup()
+        if startup is not None and not (tel.spans or tel.goodput):
+            startup.close()
+            startup = None
+        spans = SpanTimer(enabled=tel.spans or tel.goodput,
+                          earlier=startup.timer if startup else None)
+        startup_section: Optional[dict] = None
         detector = RecompileDetector()
         # numerics flight recorder: ring-buffers per-step forensic context
         # (host references only — no device fetch on healthy steps) and
@@ -1494,7 +1533,9 @@ class Trainer:
             # CLI / drill harness BEFORE this trainer existed): account its
             # wall time as the "replan" span so goodput sees the full
             # restart cost
-            if self.replan_record:
+            if self.replan_record and not (
+                    startup and "replan" in startup.timer.snapshot()):
+                # (a replan the CLI bracketed is on the timeline already)
                 spans.add_preexisting(
                     "replan",
                     float(self.replan_record.get("replan_seconds", 0.0) or 0.0))
@@ -1550,6 +1591,12 @@ class Trainer:
                 spans.take_excluded()
                 first_dispatch = True
                 last_fetch = self.step
+                if startup is not None:
+                    # entry of fit() to here; the restart inside it is a
+                    # phase of its own and holds its part of the stretch
+                    startup.timer.add(
+                        "startup/fit_prologue",
+                        time.perf_counter() - t_fit, begin=t_fit)
                 while self.step < self.max_steps:
                     # device-time capture window (telemetry.trace): start/
                     # stop rides the same per-step cadence; steps outside
@@ -1773,6 +1820,12 @@ class Trainer:
                             # SCALARS already rode last_metrics into every
                             # scalar sink above)
                             self.exp.log_tensorstats(self.step, ts_payload)
+                    if startup is not None:
+                        # the first boundary: the timeline ends with its
+                        # host_sync and is written now, so a run killed
+                        # after step 1 has it
+                        startup_section = self._write_startup(startup)
+                        startup = None
                     fleet_metrics: dict[str, float] = {}
                     if fleet is not None:
                         # this host's beacon + (rank 0) the fleet fold; a
@@ -1964,12 +2017,42 @@ class Trainer:
                         self.checkpointer.wait()
                         self.checkpointer.close()
             finally:
+                if startup is not None:
+                    startup.close()  # no first boundary: no section
                 self._write_teardown_summaries(
-                    spans, detector, tel, resumed, stop_requested)
+                    spans, detector, tel, resumed, stop_requested,
+                    startup_section)
         return last_metrics
 
+    def _write_startup(self, startup) -> Optional[dict]:
+        """The first boundary of the process's first ``fit()``: close the
+        start-up timeline, write the ``startup`` section of
+        ``run_summary.json`` and say in one line where the time to step 1
+        went.  Observability: a failure is logged, never raised."""
+        try:
+            section = startup.section(_recompile.COMPILES.summary())
+            if section is None:
+                return None
+            self.exp.write_run_summary({"startup": section})
+            over = ", ".join(
+                f"{k} {v:.1f}" for k, v in section["seconds"].items()
+                if v >= 1.0 and k not in ("init_state", "trace_lower"))
+            logger.info(
+                "start-up: %.1f s from %s to the end of step %d's fetch; "
+                "phases over 1 s: %s; compile cache %d hits, %d misses",
+                section["to_first_step_s"], section["origin"], self.step,
+                over or "none", section["compile_cache"]["cache_hits"],
+                section["compile_cache"]["cache_misses"])
+            return section
+        except Exception as e:  # noqa: BLE001 — observability, not load-bearing
+            logger.warning("start-up timeline write failed: %s", e)
+            return None
+        finally:
+            startup.close()
+
     def _write_teardown_summaries(self, spans, detector, tel, resumed,
-                                  stop_requested) -> None:
+                                  stop_requested,
+                                  startup_section=None) -> None:
         """fit() teardown after the checkpoint drain: persist the goodput and
         elastic sections of ``run_summary.json`` and close the exp manager.
         Runs even when the drain raised."""
@@ -1979,6 +2062,12 @@ class Trainer:
             # cache read) this fit() saw, with the step it hit
             summary: dict[str, Any] = {
                 "compile_events": detector.compile_events}
+            if startup_section is not None:
+                # the section of the first boundary, completed with the
+                # whole run's compiles and cache counters
+                summary["startup"] = {
+                    **startup_section,
+                    **compile_sections(_recompile.COMPILES.summary())}
             if tel.goodput:
                 summary["goodput"] = spans.goodput_summary()
                 if detector.events:
@@ -2091,9 +2180,9 @@ class Trainer:
         try:
             t0 = _time.perf_counter()
             # spans.add below has no annotation of its own: name the compile
-            # on the profiler's clock here, as SpanTimer.span does
-            with jax.profiler.TraceAnnotation("compile"), \
-                    shd.collect_trace_facts() as traced:
+            # on the profiler's clock and as the open phase here, as
+            # SpanTimer.span does
+            with named("compile"), shd.collect_trace_facts() as traced:
                 lowered = self.train_step.lower(
                     self.params, self.opt_state, batch, key
                 )
@@ -2110,7 +2199,7 @@ class Trainer:
         self.train_step = compiled
         # compile is non-productive wall time: goodput + the throughput
         # window's exclusion both see it through the span
-        spans.add("compile", dt)
+        spans.add("compile", dt, begin=t0)
         # how the trace partitioned what it could (ops/moe.py:
         # moe_token_shards) and how its flash kernels walk their blocks
         # (ops/flash_attention.py: flash_band) join the run's static facts

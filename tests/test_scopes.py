@@ -239,7 +239,12 @@ def test_log_metrics_span_is_productive_and_lands_in_the_next_row(
     assert {k: made[k] for k in ("data_wait", "dispatch", "host_sync",
                                  "log_metrics")} == {
         "data_wait": 4, "dispatch": 4, "host_sync": 4, "log_metrics": 4}
-    assert sum(made.values()) <= 4 * 4 + 3   # + restart, compile, teardown
+    # (where this is the worker's first fit(), the start-up timeline's phases
+    # are annotated too, once each: they are no part of a step)
+    once = {k: v for k, v in made.items() if k.startswith("startup/")}
+    assert all(v == 1 for v in once.values()), once
+    # + restart, compile, teardown
+    assert sum(made.values()) - len(once) <= 4 * 4 + 3
 
 
 # -- the compile counter ----------------------------------------------------
@@ -290,12 +295,17 @@ def test_a_forced_second_compile_shows_with_its_step(tmp_path):
 
     _, summary = fit_tiny(tmp_path, max_steps=5, at_step=force)
     events = summary["compile_events"]
-    assert events and all(set(e) == {"step", "seconds"} for e in events)
+    # the two keys the benchmark's reader takes, and since the start-up
+    # timeline the span open when the compile fired
+    assert events and all(
+        set(e) == {"step", "seconds", "phase"} for e in events)
     assert any(e["step"] == 0 for e in events)       # the step's own compile
     late = [e for e in events if e["step"] >= 1]
     # steady steps compile nothing; the forced program (and the constants it
     # is fed) is the only thing after the step's own compile
     assert late and {e["step"] for e in late} == {3}
+    assert {e["phase"] for e in late} == {"log_metrics"}  # the sink's span
+    assert [e["phase"] for e in events if e["step"] == 0] == ["compile"]
     assert all(e["seconds"] > 0 for e in late) and len(events) <= 50
     # fit() detaches the process's listener on its way out
     from neuronx_distributed_training_tpu.telemetry import recompile

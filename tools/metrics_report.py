@@ -113,6 +113,26 @@ def goodput_section(summary: dict) -> str:
     return "\n".join(lines)
 
 
+def startup_section(summary: dict) -> str:
+    """The start-up timeline of the process's first fit()
+    (docs/observability.md "Start-up timeline")."""
+    st = summary.get("startup")
+    if not st:
+        return ""
+    cache = st.get("compile_cache") or {}
+    lines = ["", f"start-up ({st.get('origin')} to the end of the first "
+                 f"boundary's fetch: {_fmt(st.get('to_first_step_s'))} s; "
+                 f"unattributed {_fmt(st.get('unattributed_pct'))} %; compile "
+                 f"cache {cache.get('cache_hits')} hits, "
+                 f"{cache.get('cache_misses')} misses)"]
+    for ph in st.get("phases") or []:
+        lines.append(f"  {_fmt(ph['begin_s']):>10}  {ph['name']:<19} "
+                     f"{_fmt(ph['seconds'])} s")
+    for name, secs in sorted((st.get("imports_s") or {}).items()):
+        lines.append(f"    import {name:<25} {_fmt(secs)} s")
+    return "\n".join(lines)
+
+
 def _plan_str(plan: dict) -> str:
     # deliberate copy of trainer/elastic.py::_plan_str — importing it would
     # pull the package __init__ (and jax) into this stdlib-only tool; keep
@@ -540,6 +560,7 @@ def render(metrics_path: str | None, summary_path: str | None,
             parts.append(f"unreadable {summary_path}: {e}")
     if summary:
         parts.append(goodput_section(summary))
+        parts.append(startup_section(summary))
         parts.append(elastic_section(summary))
         parts.append(integrity_section(summary))
         parts.append(anomalies_section(summary))
