@@ -457,6 +457,12 @@ def _home_sum(a: jax.Array, axis: str) -> jax.Array:
     return jnp.sum(parts, axis=0, dtype=jnp.float32).astype(a.dtype)
 
 
+def _cast_experts(experts, compute_dtype):
+    """``(gate_up, down)`` of ``experts`` in ``compute_dtype``, ffn over ``model``."""
+    return (shd.constrain(experts["gate_up"].astype(compute_dtype), _GATE_UP_SPEC),
+            shd.constrain(experts["down"].astype(compute_dtype), _DOWN_SPEC))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _exchange_experts(experts, x, probs, chosen, rows_travel, cfg: MoEConfig,
                       bound: int, expert_axis: str, compute_dtype, reduce_dtype):
@@ -479,28 +485,28 @@ def _exchange_experts(experts, x, probs, chosen, rows_travel, cfg: MoEConfig,
     ``compute_dtype``, and the partial weight gradients, float32 straight
     from the kernel, are reduce-scattered back in ``reduce_dtype``.
 
-    Both ways keep ``(gu, ys)`` of ``bound`` rows and the weights as
-    multiplied between the passes, in the same shapes, so a step holds room
-    for one of them.  ``experts`` may arrive in any float dtype (the master's:
-    ``models/mixtral.py``); their gradients leave in it."""
-    return _exchange_pass(0, rows_travel, cfg, bound, expert_axis, compute_dtype,
-                          reduce_dtype, experts, x, probs, chosen)[0]
+    Between the passes both ways keep ``(gu, ys)`` of ``bound`` rows and this
+    chip's own weights in ``compute_dtype`` (cast once, ahead of the choice of
+    way: what the rows' way multiplies and the weights' way gathers), nothing
+    of the gathered weights' shape.  So the weights' way gathers them again in
+    its backward pass: one more all-gather of ``E / ep`` experts a chip on a
+    step that goes that way (``moe/row_bound`` 1), and no zero-filled room for
+    ``E`` experts on one that does not.  ``experts`` may arrive in any float
+    dtype (the master's: ``models/mixtral.py``); their gradients leave in it."""
+    return _exchange_fwd(experts, x, probs, chosen, rows_travel, cfg, bound,
+                         expert_axis, compute_dtype, reduce_dtype)[0]
 
 
 def _exchange_sides(cfg: MoEConfig, bound: int, axis: str, compute_dtype,
-                    reduce_dtype, t: int, both: bool):
+                    reduce_dtype, t: int):
     """``((forward, backward) where the rows travel, (forward, backward)
-    where the weights do)`` of ``_exchange_experts``: forward
-    ``(experts, x, probs, chosen) -> (y, kept)``, backward ``(ct, kept,
-    experts, x, probs, chosen) -> (d_experts, d_x, d_probs)``; with ``both``
-    in use, ``kept`` is of one structure and shapes on both sides."""
+    where the weights do)`` of ``_exchange_experts``: forward ``(weights,
+    experts, x, probs, chosen) -> (y, kept)``, backward ``(ct, kept, weights,
+    experts, x, probs, chosen) -> (d_experts, d_x, d_probs)``; ``weights``
+    this chip's own (``_cast_experts``), ``experts`` there for their dtype,
+    ``kept`` the ``(gu, ys)`` of ``bound`` rows on both sides."""
     e, k = cfg.num_experts, cfg.top_k
-    ep = jax.lax.axis_size(axis)
-    e_local = e // ep
-
-    def cast(experts):
-        return (shd.constrain(experts["gate_up"].astype(compute_dtype), _GATE_UP_SPEC),
-                shd.constrain(experts["down"].astype(compute_dtype), _DOWN_SPEC))
+    e_local = e // jax.lax.axis_size(axis)
 
     def gathered(x, probs, chosen):
         """Every peer's tokens, and their rows sorted by this chip's
@@ -512,59 +518,51 @@ def _exchange_sides(cfg: MoEConfig, bound: int, axis: str, compute_dtype,
         order, group_sizes = _sorted_rows(chosen, e_local, bound)
         return x, probs, order, group_sizes, jnp.sum(group_sizes)
 
-    def rows_forward(experts, x, probs, chosen):
-        gu_w, down_w = cast(experts)
+    def rows_forward(weights, experts, x, probs, chosen):
         x, probs, order, group_sizes, count = gathered(x, probs, chosen)
-        y, kept = _expert_rows(x, probs, order, group_sizes, gu_w, down_w,
-                               k=k, count=count)
+        y, kept = _expert_rows(x, probs, order, group_sizes, *weights, k=k, count=count)
         with jax.named_scope("combine"):
-            # the weights as multiplied; with both sides, at the head of the
-            # other's gathered ones
-            rest = ((0, e - e_local if both else 0), (0, 0), (0, 0))
-            return _home_sum(y, axis), kept + tuple(
-                jnp.pad(w, rest) for w in (gu_w, down_w))
+            return _home_sum(y, axis), kept
 
-    def rows_backward(ct, kept, experts, x, probs, chosen):
-        gu_w, down_w = (w[:e_local] for w in kept[2:])
+    def rows_backward(ct, kept, weights, experts, x, probs, chosen):
         x_all, probs_all, order, group_sizes, count = gathered(x, probs, chosen)
         with jax.named_scope("combine"):
             ct = _peers_rows(ct, axis)
         d_x, d_probs, d_gu, d_down = _expert_rows_back(
-            ct, kept[:2], x_all, probs_all, order, group_sizes, gu_w, down_w,
+            ct, kept, x_all, probs_all, order, group_sizes, *weights,
             k=k, count=count, grad_dtype=experts["gate_up"].dtype)
         with jax.named_scope("dispatch"):
             return ({"gate_up": d_gu, "down": d_down},
                     _home_sum(d_x, axis).astype(x.dtype), _home_sum(d_probs, axis))
 
-    def own(x, chosen):
-        """This chip's tokens, and their rows sorted by expert."""
+    def own(weights, x, chosen):
+        """All ``E`` experts' weights gathered beside this chip's, its own
+        tokens, and their rows sorted by expert."""
+        with jax.named_scope("experts"):
+            weights = [_on_auto_axes(functools.partial(_peers_rows, axis=axis), spec)(w)
+                       for w, spec in zip(weights, (_GATE_UP_SPEC, _DOWN_SPEC))]
         with jax.named_scope("dispatch"):
             chosen = jax.lax.dynamic_slice_in_dim(
                 chosen, jax.lax.axis_index(axis) * t * k, t * k)
         order, group_sizes = _sorted_rows(chosen, e, t * k)
-        return x.astype(compute_dtype), order, group_sizes
+        return weights, x.astype(compute_dtype), order, group_sizes
 
-    def weights_forward(experts, x, probs, chosen):
-        with jax.named_scope("experts"):
-            gu_w, down_w = (
-                _on_auto_axes(functools.partial(_peers_rows, axis=axis), spec)(w)
-                for w, spec in zip(cast(experts), (_GATE_UP_SPEC, _DOWN_SPEC)))
-        xc, order, group_sizes = own(x, chosen)
-        y, kept = _expert_rows(xc, probs, order, group_sizes, gu_w, down_w, k=k)
-        return y, (*(jnp.pad(a, ((0, bound - t * k), (0, 0))) for a in kept),
-                   gu_w, down_w)
+    def weights_forward(weights, experts, x, probs, chosen):
+        weights, xc, order, group_sizes = own(weights, x, chosen)
+        y, kept = _expert_rows(xc, probs, order, group_sizes, *weights, k=k)
+        return y, tuple(jnp.pad(a, ((0, bound - t * k), (0, 0))) for a in kept)
 
-    def weights_backward(ct, kept, experts, x, probs, chosen):
-        xc, order, group_sizes = own(x, chosen)
+    def weights_backward(ct, kept, weights, experts, x, probs, chosen):
+        weights, xc, order, group_sizes = own(weights, x, chosen)
         d_x, d_probs, *d_weights = _expert_rows_back(
-            ct, [a[:t * k] for a in kept[:2]], xc, probs, order, group_sizes,
-            *kept[2:], k=k, grad_dtype=reduce_dtype)
+            ct, [a[:t * k] for a in kept], xc, probs, order, group_sizes,
+            *weights, k=k, grad_dtype=reduce_dtype)
         with jax.named_scope("experts"):
             d_gu, d_down = (
                 _on_auto_axes(lambda g: jax.lax.psum_scatter(
-                    g, axis, scatter_dimension=0, tiled=True), spec)(g).astype(w.dtype)
-                for g, spec, w in zip(d_weights, (_GATE_UP_SPEC, _DOWN_SPEC),
-                                      (experts["gate_up"], experts["down"])))
+                    g, axis, scatter_dimension=0, tiled=True), spec)(g).astype(
+                        experts["gate_up"].dtype)
+                for g, spec in zip(d_weights, (_GATE_UP_SPEC, _DOWN_SPEC)))
         return {"gate_up": d_gu, "down": d_down}, d_x.astype(x.dtype), d_probs
 
     return (rows_forward, rows_backward), (weights_forward, weights_backward)
@@ -576,15 +574,18 @@ def _exchange_pass(back: int, rows_travel, cfg, bound, expert_axis, compute_dtyp
     ``rows_travel`` says; ``None``: the bound holds every case."""
     x = operands[-3]  # (..., x, probs, chosen) on either pass
     rows, weights = _exchange_sides(cfg, bound, expert_axis, compute_dtype,
-                                    reduce_dtype, x.shape[0], rows_travel is not None)
+                                    reduce_dtype, x.shape[0])
     if rows_travel is None:
         return rows[back](*operands)
     return jax.lax.cond(rows_travel, rows[back], weights[back], *operands)
 
 
 def _exchange_fwd(experts, x, probs, chosen, rows_travel, *static):
-    y, kept = _exchange_pass(0, rows_travel, *static, experts, x, probs, chosen)
-    return y, (kept, experts, x, probs, chosen, rows_travel)
+    # cast ahead of the ``cond`` and kept from here, a result of neither branch
+    *_, compute_dtype, _ = static
+    operands = (_cast_experts(experts, compute_dtype), experts, x, probs, chosen)
+    y, kept = _exchange_pass(0, rows_travel, *static, *operands)
+    return y, (kept, *operands, rows_travel)
 
 
 def _exchange_bwd(*args):
@@ -789,11 +790,10 @@ def _dropless_experts(experts, x: jax.Array, probs: jax.Array, idx: jax.Array,
         # The ffn dim's 'model' sharding, which ragged_dot partitions
         # correctly, is preserved.
         # Sharded-vs-unsharded parity: tests/test_moe.py, tests/test_mixtral.py.
-        gu_w = shd.constrain(experts["gate_up"].astype(compute_dtype), _GATE_UP_SPEC)
-        down_w = shd.constrain(experts["down"].astype(compute_dtype), _DOWN_SPEC)
+        weights = _cast_experts(experts, compute_dtype)
         order, group_sizes = _sorted_rows(idx.reshape(-1), e, t * k)
         return _expert_rows(x.astype(compute_dtype), probs, order, group_sizes,
-                            gu_w, down_w, k=k)[0], {}
+                            *weights, k=k)[0], {}
 
     ep = jax.lax.axis_size(expert_axis)
     e_local = e // ep

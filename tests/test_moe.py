@@ -1,5 +1,6 @@
 """MoE: routing, dropped vs dropless numerics, aux loss, EP sharding."""
 
+import hashlib
 import itertools
 
 import jax
@@ -324,6 +325,124 @@ class TestEP:
                         jax.tree_util.tree_leaves(g)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-6)
+
+    @staticmethod
+    def _block_on_ep(devices, ep, cfg, params, x):
+        """``(mesh, jitted (params, x) -> ((y, stats), grads), sharded params,
+        sharded x)`` of the dropless block in float32 on ``expert`` = ``ep``
+        (4 devices) under the loss of the parity test above."""
+        mesh = build_mesh(MeshConfig(expert_model_parallel_size=ep), devices=devices[:4])
+        act_spec = shd.act_spec(False, False)
+
+        def run(p, xx, act_spec=act_spec):
+            def loss(p, xx):
+                y, aux = moe.moe_block(p, xx, cfg, act_spec=act_spec, **FP32)
+                return (y ** 2).sum() + moe.weighted_router_loss(
+                    aux["router_logits"], aux["expert_idx"], cfg), (y, aux["stats"])
+
+            (_, out), g = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, xx)
+            return out, g
+
+        ns = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+        sh_params = jax.device_put(params, jax.tree_util.tree_map(
+            ns, moe.moe_param_specs(cfg), is_leaf=lambda s: isinstance(s, P)))
+        return mesh, jax.jit(run), sh_params, jax.device_put(x, ns(act_spec))
+
+    #: skew -> sha256 of the block's output and every gradient, float32, under
+    #: XLA:CPU, from the tree of PR 41 (the exchange's residuals still of the
+    #: gathered weights' shape): (the plain block's, what this PR leaves alone;
+    #: the exchange's on ep 4)
+    PARENT_BITS = {
+        "even": ("617408d69ee87b8ace7c916372d34ad4a0ec611f522def80d22bae9ccb0a17d1",
+                 "bd929de7828b2cfae135281f48b5e1111e95dc2e8058c4b173a2ace6efb6d8fb"),
+        "half": ("77aa6369b5c926ff8715281896b5b3bfa399935324dff9a70a138f9fe68fe169",
+                 "fdbdfbdeb86ae9645425eb64c5a619f26eaccf7515256e29ef45f1ad8c82273d"),
+        "3to1": ("9e1c5c6b4eacfe600de5d669f62d71a0405dd02f9f99facfc30ca87607dc9be2",
+                 "e7ab258a2fcb27a52a224c8718b75e9a127cebe099939394707f46eb2650ce6f"),
+    }
+
+    @pytest.mark.parametrize("skew", list(PARENT_BITS))
+    def test_exchange_gives_the_bits_it_gave(self, devices8, skew):
+        """The exchange does the arithmetic it did before its residuals shrank
+        (PR 42): the rows' way, balanced and with a chip at the bound, and the
+        weights' way past it, bit for bit against values pinned from the
+        parent's tree.  Skipped on a machine whose float32 arithmetic is not
+        the pinning machine's, which the plain block's own bits tell."""
+        def bits(y, g):
+            h = hashlib.sha256()
+            for a in map(np.asarray, jax.tree_util.tree_leaves((y, g))):
+                h.update(str((a.shape, a.dtype)).encode() + a.tobytes())
+            return h.hexdigest()
+
+        cfg = moe.MoEConfig(num_experts=8, top_k=2, dropless=True)
+        params, x = params_and_x(jax.random.PRNGKey(9), cfg=cfg)
+        params, x = self._forced(params, x.reshape(4, 8, -1), self.SKEWS[skew], 4, cfg)
+        mesh, run, sh_params, sh_x = self._block_on_ep(devices8, 4, cfg, params, x)
+        (y, _), g = jax.jit(lambda p, xx: run(p, xx, None))(params, x)
+        plain, exchanged = self.PARENT_BITS[skew]
+        if bits(y, g) != plain:
+            pytest.skip("the plain block's bits are not the pinning machine's")
+        with mesh, shd.use_mesh(mesh):
+            (y, stats), g = run(sh_params, sh_x)
+        assert int(stats["moe/row_bound"]) == (skew == "3to1")
+        assert bits(y, g) == exchanged
+
+    def test_bound_that_holds_every_case_lowers_as_it_did(self, devices8):
+        """At ep 2 no routing passes the bound (``rows_travel`` is ``None``):
+        one way, no ``cond``.  The step's lowered text is the parent's (PR 41:
+        989 lines) less two ``_pad``s of the kept weights by no rows, 18
+        lines which the compiler deleted, and the renumbering that follows:
+        read in a diff of the two trees' texts by PR 42, which pinned this
+        one's digest.  A PR that changes what the block lowers to re-pins it
+        from such a diff."""
+        cfg = moe.MoEConfig(num_experts=4, top_k=2, dropless=True)
+        params, x = params_and_x(jax.random.PRNGKey(9), cfg=cfg)
+        mesh, run, sh_params, sh_x = self._block_on_ep(
+            devices8, 2, cfg, params, x.reshape(4, 8, -1))
+        with mesh, shd.use_mesh(mesh):
+            text = run.lower(sh_params, sh_x).as_text()
+        assert "stablehlo.case" not in text and "stablehlo.pad" not in text
+        assert (len(text.splitlines()), hashlib.sha256(text.encode()).hexdigest()) == (
+            971, "e76222a3696d63ce45d7c623acc2f69f465a7102e12032948df4b4554e2a0da5")
+
+    @pytest.mark.parametrize("side", ["rows", "weights", "cond"])
+    def test_exchange_keeps_nothing_of_the_gathered_weights_shape(self, devices8, side):
+        """What crosses from the forward to the backward pass of the exchange
+        at ep 4, where both ways exist: ``(gu, ys)`` of ``bound`` rows from
+        either way alone, and with them from ``_exchange_fwd`` (the ``cond``
+        over both) this chip's own cast weights and the operands (``experts``
+        a dict, so ``down`` first).  No array leads with all ``E`` experts,
+        so no step pads to that shape."""
+        cfg = moe.MoEConfig(num_experts=8, top_k=2, dropless=True)
+        t, h, ffn, ep = 16, 16, 32, 4
+        bound = 2 * t * cfg.top_k
+        static = (cfg, bound, "expert", jnp.bfloat16, jnp.float32)
+        mesh = build_mesh(MeshConfig(expert_model_parallel_size=ep), devices=devices8[:4])
+        kept = []
+
+        def body(experts, x, probs, chosen):
+            if side == "cond":
+                kept.append(moe._exchange_fwd(
+                    experts, x, probs, chosen, jnp.max(chosen) < 0, *static)[1])
+            else:
+                forward = moe._exchange_sides(*static, t)[side == "weights"][0]
+                kept.append(forward(moe._cast_experts(experts, jnp.bfloat16),
+                                    experts, x, probs, chosen)[1])
+            return x
+
+        experts = moe.init_moe_params(jax.random.PRNGKey(0), h, ffn, cfg)["experts"]
+        with mesh, shd.use_mesh(mesh):
+            jax.eval_shape(shd.shard_map(
+                body, mesh=mesh, in_specs=(P("expert"), P("expert"), P("expert"), P()),
+                out_specs=P("expert"), axis_names=frozenset({"expert"}), check_vma=False,
+            ), experts, jnp.zeros((ep * t, h)), jnp.zeros((ep * t, cfg.top_k)),
+                jnp.zeros((ep * t * cfg.top_k,), jnp.int32))
+        shapes = [a.shape for a in jax.tree_util.tree_leaves(kept)]
+        rows = [(bound, 2 * ffn), (bound, h)]
+        local = [(cfg.num_experts // ep, h, 2 * ffn), (cfg.num_experts // ep, ffn, h)]
+        assert shapes == (rows if side != "cond" else rows + local + local[::-1] + [
+            (t, h), (t, cfg.top_k), (ep * t * cfg.top_k,), ()])
+        assert not any(s[:1] == (cfg.num_experts,) for s in shapes)
 
     def test_ep_tp_sharded_dropless_matches(self, devices8):
         """Regression: ``moe_dropless`` left to GSPMD on an EP x TP mesh

@@ -15,6 +15,7 @@ cannot be read back without one).
 """
 
 import os
+import re
 from unittest import mock
 
 import jax
@@ -168,10 +169,11 @@ STEP_CASES = {
     # per-pass head is rematerialized
     # the benchmark's four-chip cell (benchmark/configs/mixtral-8x7b.json):
     # published widths, 1 of 32 layers, ep 4 x ZeRO-1, one sequence a chip.
-    # The compiler takes it; its report reads 5.64 GiB of state and 13.20 GiB
-    # of temporaries (PR 28; 5.64 + 6.66 when the weights always travelled,
-    # PR 25), which counts both ways through the experts, of which a step
-    # runs one
+    # The compiler takes it; its report reads 5.64 GiB of state and 8.54 GiB
+    # of temporaries, which counts both ways through the experts, of which a
+    # step runs one (13.17 GiB while both ways kept weights of all 8 experts'
+    # shape between the passes, PRs 28-41; 6.66 when the weights always
+    # travelled, PR 25)
     "mixtral_1_layer_ep4": ("hf_mixtral_8x7b_config.yaml", 4, {
         "model.num_layers": 1,
         "distributed_strategy.tensor_model_parallel_size": 1,
@@ -207,6 +209,12 @@ def test_train_step_compiles_for_v5e(topo, name):
         # kernel's outputs were kept; the report read 6.85 + 6.70 GiB then)
         assert _flash_forward_calls(compiled) == 1
         assert ma.temp_size_in_bytes < 7.6 * 2**30
+    if name == "mixtral_1_layer_ep4":
+        # between the passes the exchange keeps a chip's own 2 experts' cast
+        # weights on both ways: no step pads them to the gathered 8's shape
+        assert ma.temp_size_in_bytes < 9.0 * 2**30
+        assert not re.search(r"bf16\[8,(4096,28672|14336,4096)\]\S* pad\(",
+                             compiled.as_text())
 
 
 def test_two_micro_batches_do_not_fit_one_chip(topo):
@@ -243,7 +251,6 @@ def test_named_scopes_leave_the_v5e_program_the_same(topo, monkeypatch):
     every scope is in the compiled text's ``op_name``s and the three flash
     kernels carry their own names."""
     import contextlib
-    import re
 
     from test_scopes import memory_totals, opcode_census
 
@@ -292,7 +299,6 @@ def test_dropless_block_is_partitioned_by_tokens_on_v5e(topo):
     back.  Master weights are float32 and cast per layer inside the
     differentiated function, as the step does."""
     import collections
-    import re
 
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -358,8 +364,8 @@ def test_dropless_block_is_partitioned_by_tokens_on_v5e(topo):
     assert by_kind["collective-permute"] == 0
     # the rows' way: the token shards gathered (rows, gate weights, choices),
     # outputs and the rows' and gate weights' cotangents returned.  The
-    # weights' way: each expert weight gathered once in bf16, its gradient
-    # reduce-scattered once in float32.  And no other gather: routing runs
+    # weights' way: each expert weight gathered in bf16, once a pass, its
+    # gradient reduce-scattered once in float32.  And no other gather: routing runs
     # outside the region on the global tokens and stays partitioned by them
     gathered = ep * tokens
     # (the compiler spells the cotangent's gather with a leading 1 or not)
