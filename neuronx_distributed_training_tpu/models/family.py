@@ -1,6 +1,7 @@
 """One record per model family: what the rest of the package asks of a model.
 
-``models/llama.py``, ``mixtral.py``, ``gpt.py``, ``ouro.py``, ``laguna.py`` and ``kanana.py`` each end in one
+``models/llama.py``, ``mixtral.py``, ``gpt.py``, ``ouro.py``, ``laguna.py``, ``kanana.py`` and
+``lfm2.py`` each end in one
 :class:`Family` (``FAMILY``) and their config class answers ``.family`` with
 it.  The trainer, the pipeline gate, the launch planner, the FLOPs count, the
 cached decode and the config validator ask the record; none of them names a
@@ -104,6 +105,7 @@ FAMILIES: dict[str, Any] = {
     "llama": "llama", "mistral": "llama", "mixtral": "mixtral",
     "ouro": "ouro", "laguna": "laguna", "gpt": "gpt",
     "kanana": "kanana", "deepseek_v3": "kanana",
+    "lfm2": "lfm2", "lfm2_moe": "lfm2",
 }
 
 

@@ -477,6 +477,17 @@ def decoder_stack(layers, x, tables, cfg: LagunaConfig, policy: DtypePolicy, *,
         body = llama.checkpoint_layer(body, cfg.llama, stack=kind_name(*kind))
         return lambda carry, stack: jax.lax.scan(body, carry, stack)
 
+    (x, aux_sum), all_stats = run_stacks(
+        layers, (x, jnp.zeros((), jnp.float32)), cfg.kinds, run_of)
+    return x, aux_sum, all_stats
+
+
+def run_stacks(layers, carry, kinds, run_of):
+    """``carry`` through the stacks ``layers`` (``kind_name`` -> a kind's
+    layers, in layer order) by ``stack_plan(kinds)`` -> ``(carry, [what each
+    scan stacked, in the plan's order])``.  ``run_of(kind)`` gives ``(carry,
+    a stack of that kind's layers) -> (carry, stacked)``, one scan (shared
+    with models/lfm2.py, whose layers differ in other ways)."""
     taken = {name: 0 for name in layers}
 
     def take(kind, n, lead=()):
@@ -490,8 +501,8 @@ def decoder_stack(layers, x, tables, cfg: LagunaConfig, policy: DtypePolicy, *,
         return jax.tree_util.tree_map(
             lambda a: a[lo:lo + n].reshape(lead + (per,) + a.shape[1:]), layers[name])
 
-    carry, all_stats = (x, jnp.zeros((), jnp.float32)), []
-    for segment in stack_plan(cfg.kinds):
+    all_stats = []
+    for segment in stack_plan(kinds):
         if segment[0] == "run":
             _, kind, n = segment
             carry, stats = run_of(kind)(carry, take(kind, n))
@@ -510,7 +521,7 @@ def decoder_stack(layers, x, tables, cfg: LagunaConfig, policy: DtypePolicy, *,
 
         carry, stats = jax.lax.scan(one_period, carry, xs)
         all_stats += stats
-    return carry[0], carry[1], all_stats
+    return carry, all_stats
 
 
 def _period_takes(period):

@@ -276,7 +276,10 @@ def _attention_block(lp, x, cos, sin, cfg: LlamaConfig, policy: DtypePolicy,
     ``sliding_window`` (``None``: none), its own ``cos`` / ``sin`` (which may
     rotate part of a head: ``ops.rope.apply_rope``) and key tile.  A leaf
     ``lp["gate"]`` (``[hidden, heads]``) is a per-head sigmoid gate read from
-    ``x`` and applied to the op's output before ``o``."""
+    ``x`` and applied to the op's output before ``o``.  Leaves
+    ``lp["q_norm"]`` / ``lp["k_norm"]`` (``[head_dim]``, models/lfm2.py) are
+    an RMS norm of every query / key head before the rope, one learned scale
+    for all the heads."""
     b, s, h = x.shape
     nh, nkv, d = num_heads or cfg.num_attention_heads, cfg.kv_heads, cfg.head_size
     if sliding_window is _CONFIG_WINDOW:
@@ -292,6 +295,10 @@ def _attention_block(lp, x, cos, sin, cfg: LlamaConfig, policy: DtypePolicy,
     k = k.reshape(b, s, nkv, d)
     v = v.reshape(b, s, nkv, d)
     q = shd.constrain(q, shd.heads_spec(cfg.context_parallel))
+    if "q_norm" in lp:
+        with jax.named_scope("qk_norm"):
+            q = norm_ops.apply_rms_norm(lp["q_norm"], q, eps=cfg.rms_norm_eps)
+            k = norm_ops.apply_rms_norm(lp["k_norm"], k, eps=cfg.rms_norm_eps)
     q = rope_ops.apply_rope(q, cos, sin)
     k = rope_ops.apply_rope(k, cos, sin)
     out = attn_ops.attention(

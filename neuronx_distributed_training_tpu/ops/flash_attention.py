@@ -107,20 +107,39 @@ DEFAULT_BLOCK_KV = 2048
 NEG_INF = -1e30
 #: ``checkpoint_name``s of the plain call's forward outputs, o and lse
 KEPT_NAMES = ("flash_o", "flash_lse")
+#: how heads of 64 dims reach the kernels (``flash_band``'s ``feed``): blocks
+#: whose last dim is the array's 64, nothing padded, no heads folded into rows.
+#: One v5e, one layer at 32 / 8 heads x 64, two sequences of 8192, forward +
+#: backward at tiles of 512 x 2048 (PERF.md section 6, PR 43): 41.14 ms as
+#: fed; 42.01 with q, k and v zero-padded to 128 (twice their bytes and the
+#: score FLOPs for nothing: the exponentials bound the kernels either way);
+#: the 4 query heads of a key/value group folded into the query block's rows,
+#: forward alone, 12.13 against 12.15: K and V fetched once a group buy nothing
+HALF_LANE_FEED = "whole"
 
 
 def _block_sizes(sq: int, skv: int, bq: Optional[int], bkv: Optional[int],
                  dtype=jnp.bfloat16, d: int = LANES):
-    bq = bq or min(DEFAULT_BLOCK_Q, sq)
     # 4-byte operands get half the kv block: at 2048 the dkv kernel of an
     # MHA layer (s 4096, d 128, float32) needs 17.08 MiB of the TPU's 16 MiB
     # scoped VMEM and the compiler refuses it
+    default_q = DEFAULT_BLOCK_Q
     default_kv = DEFAULT_BLOCK_KV // max(jnp.dtype(dtype).itemsize // 2, 1)
     if d > LANES:
         # and so do score dims past one lane width: at 2048 the dkv kernel
         # of 32 heads x 192 (held in VMEM as 256) x seq 8192, bf16, needs
         # 16.73 MiB; 1024 is taken
         default_kv //= 2
+    elif d < LANES:
+        # heads of half a lane width: the kernels are bound by the scores'
+        # exponentials, not by the 64-deep contraction, and a square tile of
+        # 1024 x 1024 walks them fastest.  One v5e, one layer at 32 / 8 heads
+        # x 64, two sequences of 8192, forward / forward + backward, ms
+        # (PERF.md section 6, PR 43): 512 x 2048 12.15 / 41.14, 512 x 1024
+        # 12.76 / 41.43, **1024 x 1024 10.72 / 39.28**, 256 x 2048 13.51 /
+        # 44.92; 1024 x 2048 and 512 x 4096 need more VMEM than a kernel gets
+        default_q, default_kv = 2 * default_q, default_kv // 2
+    bq = bq or min(default_q, sq)
     bkv = bkv or min(default_kv, skv)
     while sq % bq:
         bq //= 2
@@ -134,15 +153,19 @@ def _tileable(sq: int, skv: int, d: int, bq: int, bkv: int,
     """``d``: the dims q and k are scored over, ``d_v`` (default ``d``) those
     of v and the output.  Where they differ (latent attention: 192 and 128)
     the score dims may end on half a lane width, fed as they are: a block
-    whose last dim is the array's."""
+    whose last dim is the array's.  Heads of half a lane width (``d == d_v ==
+    64``) are fed the same way: every block of q, k, v, o and their cotangents
+    64 wide as its array is, the contractions 64 deep, the accumulators 64
+    lanes of a register's 128 (``HALF_LANE_FEED``)."""
     d_v = d if d_v is None else d_v
+    dims = (d == d_v == LANES // 2
+            or (d_v % LANES == 0 and d % (LANES if d == d_v else LANES // 2) == 0))
     return (
         sq % bq == 0
         and skv % bkv == 0
         and bq % LANES == 0
         and bkv % LANES == 0
-        and d_v % LANES == 0
-        and d % (LANES if d == d_v else LANES // 2) == 0
+        and dims
     )
 
 
@@ -248,6 +271,8 @@ def _call_band(bq, bkv, num_q, num_kv, causal, window, q_offset,
                  "q_blocks": num_q, "q_band": band.q, "walk": "band"}
         if dims is not None and dims[0] != dims[1]:
             shape.update(d_qk=dims[0], d_v=dims[1], feed="whole")
+        elif dims is not None and dims[0] % LANES:
+            shape.update(d=dims[0], feed=HALF_LANE_FEED)
         if sub is not None:
             n = sub.num_q // 2
             spans = (_kv_span(sub, n + a) for a in range(n))
@@ -975,9 +1000,10 @@ def _diag_bwd(res, g, *, sm_scale, window, bq, interpret, dlse=None):
 
 
 def _one_head_dim(q, v) -> bool:
-    """The diagonal walk's kernels know one head dim; a call that scores over
-    other dims than it weighs (latent attention) keeps the band walk."""
-    return q.shape[-1] == v.shape[-1]
+    """The diagonal walk's kernels know one head dim, of whole lane widths; a
+    call that scores over other dims than it weighs (latent attention), or
+    whose heads are half a lane wide, keeps the band walk."""
+    return q.shape[-1] == v.shape[-1] and q.shape[-1] % LANES == 0
 
 
 def _forward(q, k, v, kvm, seg, causal, window, q_offset, bq, bkv, interpret):
@@ -1206,8 +1232,9 @@ def flash_attention(
             raise ValueError(
                 f"flash_attention: shapes do not tile the Pallas kernel "
                 f"({shapes}: seq blocks and head_dim must be multiples of "
-                f"{LANES}, score dims that differ from the value dims of "
-                f"{LANES // 2}); set fusions.flash_attention: false for this model"
+                f"{LANES}, or head_dim {LANES // 2}; score dims that differ from "
+                f"the value dims multiples of {LANES // 2}); set "
+                f"fusions.flash_attention: false for this model"
             )
         _warn_core_route(shapes)
         from neuronx_distributed_training_tpu.ops.attention import (

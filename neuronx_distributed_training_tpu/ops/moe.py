@@ -95,8 +95,12 @@ class MoEConfig:
     experts_held: Optional[tuple[int, int]] = None
     # "softmax" | "sigmoid": how router logits become scores.  sigmoid: the
     # router holds a ``bias`` besides ``w``, selection is by score + bias,
-    # gate weights by the scores alone (over their sum + 1e-20)
+    # gate weights by the scores alone (over their sum + ``renorm_eps``)
     score_func: str = "softmax"
+    # what the sigmoid route adds to the chosen scores' sum before it divides
+    # by it: the source's own constant (DeepSeek-V3's 1e-20; models/lfm2.py
+    # sets LFM2's 1e-6); no YAML key
+    renorm_eps: float = 1e-20
     # the selection bias's step a train step (``bias_update``); 0: never moves
     bias_update_rate: float = 0.0
 
@@ -221,7 +225,7 @@ def route(router_params, x: jax.Array, cfg: MoEConfig):
             scores + router_params["bias"]), cfg.top_k)
         probs = jnp.take_along_axis(scores, idx, axis=-1)
         if cfg.normalize_top_k_affinities:
-            probs = probs / (jnp.sum(probs, axis=-1, keepdims=True) + 1e-20)
+            probs = probs / (jnp.sum(probs, axis=-1, keepdims=True) + cfg.renorm_eps)
         return probs * cfg.routed_scaling_factor, idx, logits
     if cfg.router_type == "sinkhorn":
         # balanced assignment for selection; gate values from plain softmax

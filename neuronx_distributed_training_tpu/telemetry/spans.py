@@ -97,11 +97,16 @@ DEVICE_SCOPES: dict[str, tuple[str, ...]] = {
 #: ``moe/shared`` (``ops/moe.py``'s shared expert); ``models/kanana.py``'s
 #: ``attention/mla_latent`` (what latent attention adds beside q, the kernels
 #: and o: the down-projection, the latent's norm, the up-projection, the
-#: shared key's rope, the assembly of k).  A reader that does not
+#: shared key's rope, the assembly of k); ``models/lfm2.py``'s
+#: ``attention/short_conv`` (the whole convolution half of a layer: norm,
+#: ``in_proj``, the middle, ``out_proj``, residual), ``.../conv_gate`` inside
+#: it (the middle alone: the two gates and the taps, ``ops/short_conv.py``)
+#: and ``attention/qk_norm`` (the per-head norms of q and k).  A reader that does not
 #: know one counts its time under the scope that holds it, so nothing becomes
 #: unscoped; ``benchmark/readers/inner_scope.py`` reads one by its name.
 FAMILY_SCOPES: dict[str, tuple[str, ...]] = {
-    "attention": ("attn_full", "attn_window", "head_gate", "mla_latent"),
+    "attention": ("attn_full", "attn_window", "head_gate", "mla_latent",
+                  "short_conv", "conv_gate", "qk_norm"),
     "moe": ("shared",),
     "ce_head": ("exit_gate",),
 }

@@ -177,6 +177,7 @@ def test_what_each_family_cannot_do():
         "ouro": ["decode", "logits", "onef1b_head", "pipeline"],
         "laguna": ["decode", "onef1b_head", "pipeline"],
         "kanana": ["decode", "onef1b_head", "pipeline"],
+        "lfm2": ["decode", "onef1b_head", "pipeline"],
     }
 
 
@@ -249,13 +250,13 @@ def test_lower_layers_import_nothing_from_models(package):
 def test_callers_name_no_familys_config_class(package):
     classes = {type(toy(name)[1]).__name__ for name in FAMILY_NAMES}
     assert classes == {"LlamaConfig", "MixtralConfig", "GPTConfig", "OuroConfig",
-                       "LagunaConfig", "KananaConfig"}
+                       "LagunaConfig", "KananaConfig", "Lfm2Config"}
     for path in sorted((PKG / package).rglob("*.py")):
         names = {getattr(n, "id", None) or getattr(n, "attr", None)
                  for n in ast.walk(ast.parse(path.read_text()))}
         assert not names & classes, f"{path.relative_to(PKG)} names {names & classes}"
         family_modules = [m for m in _imports(path)
-                          if re.search(r"\.models\.(llama|mixtral|gpt|ouro|laguna|kanana)$", m)]
+                          if re.search(r"\.models\.(llama|mixtral|gpt|ouro|laguna|kanana|lfm2)$", m)]
         assert not family_modules, f"{path.relative_to(PKG)} imports {family_modules}"
 
 

@@ -54,7 +54,8 @@ def test_flash_matches_core_fwd_and_grad(sq, skv, nh, nkv, window, causal):
 
 
 def test_flash_untileable_off_tpu_warns_and_runs_core(caplog):
-    # head_dim 64 is not lane-aligned: off the TPU (toy test models) the
+    # a sequence of 64 is under one lane width (heads of 64 dims tile since
+    # PR 43, a block of 64 rows does not): off the TPU (toy test models) the
     # core path runs, and says so
     from neuronx_distributed_training_tpu.ops import flash_attention as fa
 
@@ -626,3 +627,56 @@ def test_the_call_chooses_its_walk(case):
                  "flash_dkv": (5, 6 + extra)},
         "diagonal": {"flash_fwd": (4, 5), "flash_dq": (4, 8), "flash_dkv": (4, 10)},
     }[walk], calls
+
+
+# -- heads of half a lane width (models/lfm2.py) ---------------------------------
+
+
+@pytest.mark.parametrize("window", [None, 300], ids=["causal", "window_300"])
+def test_band_walk_equals_full_walk_at_64_dim_heads(window):
+    """Heads of 64 dims are fed as they are (blocks 64 wide): the same walk,
+    bit for bit the walk over the whole range, 4 query heads a key/value head."""
+    q, k, v = _make_qkv(jax.random.PRNGKey(29), 1, 1024, 1024, 8, 2, 64, jnp.bfloat16)
+    o, lse, dq, dk, dv = _walks_agree(q, k, v, causal=True, window=window, bq=128, bkv=128,
+                                      narrower=window is not None)
+    assert o.shape == dq.shape == (1, 8, 1024, 64) and dk.shape == dv.shape == (1, 2, 1024, 64)
+
+
+def test_what_the_kernels_take_at_half_a_lane_width_and_what_they_say():
+    from neuronx_distributed_training_tpu.ops import flash_attention as fa
+    from neuronx_distributed_training_tpu.parallel import sharding as shd
+
+    assert fa.flash_tileable(8192, 8192, 64, 32, 8)              # the LFM2 cell's call
+    assert not fa.flash_tileable(8192, 8192, 32, 32, 8)          # a quarter lane: no
+    assert not fa.flash_tileable(8192, 8192, 192, 32, 32)        # one head dim past a lane: whole lanes
+    assert not fa.flash_tileable(8192, 8192, 64, 32, 8, d_v=96)
+    assert not fa.flash_tileable(8192, 8192, 64, 32, 6)          # heads that do not group
+    # half-lane heads take a square tile of 1024 (measured: PERF.md section 6, PR 43)
+    assert fa._block_sizes(8192, 8192, None, None, jnp.bfloat16, 64) == (1024, 1024)
+    assert fa._block_sizes(8192, 8192, None, None, jnp.float32, 64) == (1024, 512)
+    assert fa._block_sizes(512, 512, None, None, jnp.bfloat16, 64) == (512, 512)
+    assert fa._block_sizes(8192, 8192, 512, 2048, jnp.bfloat16, 64) == (512, 2048)
+    # what a multiple of 128 is tiled to stays what it was
+    assert fa._block_sizes(8192, 8192, None, None, jnp.bfloat16, 128) == (512, 2048)
+    assert fa._block_sizes(8192, 8192, None, None, jnp.bfloat16, 192) == (512, 1024)
+    q64 = jnp.zeros((1, 256, 4, 64), jnp.float32)
+    kv64 = jnp.zeros((1, 256, 1, 64), jnp.float32)
+    q128 = jnp.zeros((1, 256, 2, 128), jnp.float32)
+    with shd.collect_trace_facts() as facts:
+        jax.eval_shape(lambda: flash_attention(q64, kv64, kv64, block_q=128, block_kv=128,
+                                               interpret=True))
+        jax.eval_shape(lambda: flash_attention(q128, q128, q128, block_q=128, block_kv=128,
+                                               interpret=True))
+    half, whole = facts["flash_band"]
+    assert (half["d"], half["feed"], half["walk"]) == (64, "whole", "band")
+    assert not {"d", "d_qk", "d_v", "feed"} & set(whole)
+    # a window no wider than the tile keeps the band walk at 64 dims: the
+    # diagonal walk's kernels know heads of whole lane widths
+    qt = jnp.zeros((1, 4, 256, 64))
+    assert fa._takes_diagonal(qt, qt, None, None, True, 128, 0, 128, 128)
+    assert not fa._one_head_dim(qt, qt) and fa._one_head_dim(jnp.zeros((1, 2, 256, 128)),
+                                                              jnp.zeros((1, 2, 256, 128)))
+    with shd.collect_trace_facts() as facts:
+        jax.eval_shape(lambda: flash_attention(q64, kv64, kv64, sliding_window=128,
+                                               block_q=128, block_kv=128, interpret=True))
+    assert facts["flash_band"][0]["walk"] == "band"

@@ -568,3 +568,33 @@ class TestTokenShuffle:
                                    rtol=1e-5, atol=1e-6)
         np.testing.assert_array_equal(np.asarray(a0["expert_idx"]),
                                       np.asarray(a1["expert_idx"]))
+
+
+def test_the_sigmoid_routes_renormalising_epsilon_is_a_field_of_the_config():
+    """``route`` adds ``cfg.renorm_eps`` to the chosen scores' sum: 1e-20 where
+    nothing sets it (DeepSeek-V3's, the route ``models/kanana.py`` runs), what
+    a family sets otherwise (``models/lfm2.py``: 1e-6).  No YAML key reads it,
+    and the softmax routes never see it."""
+    import dataclasses
+
+    from neuronx_distributed_training_tpu.ops import moe as moe_ops
+
+    cfg = moe_ops.MoEConfig(num_experts=8, top_k=2, score_func="sigmoid")
+    assert cfg.renorm_eps == 1e-20
+    assert moe_ops.MoEConfig.from_config({"renorm_eps": 1.0, "scoring_func": "sigmoid"}
+                                         ).renorm_eps == 1e-20
+    key = jax.random.PRNGKey(0)
+    x = jax.random.normal(key, (32, 16))
+    router = {"w": jax.random.normal(jax.random.fold_in(key, 1), (16, 8)),
+              "bias": jnp.zeros((8,))}
+    probs, idx, _ = moe_ops.route(router, x, cfg)
+    big, big_idx, _ = moe_ops.route(router, x, dataclasses.replace(cfg, renorm_eps=0.5))
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(big_idx))    # selection: untouched
+    scores = jnp.take_along_axis(jax.nn.sigmoid(x @ router["w"]), idx, axis=-1)
+    total = scores.sum(-1, keepdims=True)
+    np.testing.assert_allclose(np.asarray(probs), np.asarray(scores / total), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(big), np.asarray(scores / (total + 0.5)), rtol=1e-6)
+    soft = moe_ops.MoEConfig(num_experts=8, top_k=2)
+    a, _, _ = moe_ops.route({"w": router["w"]}, x, soft)
+    b, _, _ = moe_ops.route({"w": router["w"]}, x, dataclasses.replace(soft, renorm_eps=0.5))
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -392,3 +392,42 @@ def test_remat_is_a_static_fact_of_the_run(tmp_path, granularity, expected):
                           "flash_block_kv": 128},
     })
     assert summary["remat"] == {"layers": expected}
+
+
+def test_the_convolution_stacks_scopes_sit_inside_attention(no_persistent_cache):
+    """``models/lfm2.py``: both operators run under ``attention``; the
+    convolution half of a layer under ``short_conv`` with its middle under
+    ``conv_gate``, an attention layer's head norms under ``qk_norm``, forward
+    and backward; the flash kernels (heads of 64 dims) where they always
+    are.  All three names are in ``FAMILY_SCOPES``."""
+    from neuronx_distributed_training_tpu.analysis.graph_audit import (
+        lower_step_program,
+    )
+    from neuronx_distributed_training_tpu.config.loader import load_config
+    from neuronx_distributed_training_tpu.telemetry.spans import FAMILY_SCOPES
+    from neuronx_distributed_training_tpu.trainer.loop import (
+        assemble_step_program,
+    )
+
+    cfg = load_config(str(EX / "hf_lfm2_24b_a2b_config.yaml"), {
+        **TOY, "data.synthetic": True, "model.num_attention_heads": 4,
+        "model.num_key_value_heads": 1, "model.num_layers": 4,
+        "model.layer_types": ["conv", "conv", "full_attention", "conv"],
+        "model.vocab_size": 512, "model.num_experts": 4, "model.num_experts_per_tok": 2,
+        "model.moe_intermediate_size": 128,
+        "distributed_strategy.expert_model_parallel_size": 2,
+        "exp_manager.checkpoint_callback_params": None})
+    asm = assemble_step_program(cfg, devices=jax.devices()[:2], build_data=False)
+    names = op_names(lower_step_program(asm)[1])
+    assert {"short_conv", "conv_gate", "qk_norm"} <= set(FAMILY_SCOPES["attention"])
+    for transform in ("jvp(", "transpose("):
+        assert has_scope(names, "short_conv", wrapped_by=transform, inside="attention")
+        assert has_scope(names, "conv_gate", wrapped_by=transform, inside="short_conv")
+        assert has_scope(names, "qk_norm", wrapped_by=transform, inside="attention")
+        assert has_scope(names, "mlp", wrapped_by=transform)
+        assert has_scope(names, "moe", wrapped_by=transform)
+    assert has_scope(names, "flash_fwd", inside="attention")
+    assert has_scope(names, "flash_dkv", wrapped_by="transpose(", inside="attention")
+    # the head norms belong to attention layers, the gate chain to convolution layers
+    assert not has_scope(names, "qk_norm", inside="short_conv")
+    assert not has_scope(names, "flash_fwd", inside="short_conv")

@@ -504,3 +504,68 @@ def test_the_latent_attention_cell_needs_its_dense_layer_rematerialized(topo, mo
         lambda body, cfg, *, stack, prevent_cse=False: real(body, cfg, stack=stack))
     with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
         _compile_step(topo, "hf_kanana_2_30b_a3b_config.yaml", 1, KANANA_CUT)
+
+
+# --------------------------------------------------------------------------
+# the gated short convolution / 64-dim-head attention stack (models/lfm2.py)
+# at the benchmark's cut
+# --------------------------------------------------------------------------
+
+#: the cell ``lfm2-24b-pretrain-8k-ep8`` (benchmark/configs/lfm2-24b-a2b.json):
+#: published widths, layers 0-7 (c c a c c c a c: both dense layers and six
+#: sparse ones), experts 0-7 of 64 held, 1/8 of the vocabulary, two sequences
+#: of 8192 in one micro-batch
+LFM2_CUT = {
+    "model.num_hidden_layers": 8, "model.vocab_size": 8192,
+    "model.num_experts_held": [0, 8],
+    "distributed_strategy.expert_model_parallel_size": 1,
+    "data.global_batch_size": 2,
+}
+
+
+@pytest.mark.parametrize("block_q, block_kv, fits", [
+    (None, None, True), (512, 1024, True), (512, 2048, True),
+    (1024, 2048, False), (512, 4096, False)],
+    ids=["default-1024x1024", "512x1024", "512x2048", "1024x2048-refused",
+         "512x4096-refused"])
+def test_flash_compiles_at_64_dim_heads(topo, block_q, block_kv, fits):
+    """The band walk's three kernels at the cell's shape, 32 query / 8
+    key-value heads of 64 dims fed as they are (every block's last dim is the
+    array's 64); the default is the square tile of 1024, and the tiles past
+    512 x 2048 or 1024 x 1024 need more than the 16 MiB of VMEM a kernel gets."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct((2, 8192, nh, 64), jnp.bfloat16, sharding=one_chip)
+            for nh in (32, 8, 8)]
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True, block_q=block_q,
+                                          block_kv=block_kv, interpret=False
+                                          ).astype(jnp.float32))
+
+    lower = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args)
+    if not fits:
+        with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|vmem"):
+            lower.compile()
+        return
+    text = lower.compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "bf16[2,32,8192,64]" in text and "bf16[2,32,8192,128]" not in text   # nothing padded
+
+
+def test_the_short_convolution_cell_fits_one_v5e_at_depth_8(topo):
+    """8.84 GB of state (736.9 M parameters) and two sequences of 8192: under
+    ``full`` the compiler takes the step at depth 8 (its report of temporaries
+    counts both ways through the held experts, of which a step runs one).
+    Depth 9, one more sparse convolution layer (1.03 GiB of state and 0.34 of
+    gradients), it refuses by 131 MiB, which decided the benchmark's depth
+    (PERF.md section 4; not compiled here: a minute of this file's time).
+    The two attention layers are runs of one, merged with their reruns: each
+    calls the forward kernel once."""
+    compiled = _compile_step(topo, "hf_lfm2_24b_a2b_config.yaml", 1, LFM2_CUT)
+    assert _flash_forward_calls(compiled) == 2
+    text = compiled.as_text()
+    assert "short_conv" in text and "conv_gate" in text and "qk_norm" in text
+    assert "conv_gate_fwd" in text and "conv_gate_bwd" in text
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes > 0.9 * ma.argument_size_in_bytes
+    assert 8.2 * 2**30 < ma.argument_size_in_bytes < 8.3 * 2**30
