@@ -393,9 +393,11 @@ def decoder_stack(layers, x, cos, sin, cfg: KananaConfig, policy: DtypePolicy, *
     of the layer is rematerialized and every activation of it (q and k of
     ``[b, heads, s, 192]`` among them: 1.17 GiB at the benchmark's cut) lives
     through the whole step.  With them held, the step that keeps the sparse
-    layers' kernel outputs is refused for one v5e by 148 MiB
-    (tests/test_tpu_compile.py); released, it fits, at the price of that one
-    layer's projections and MLP run forward twice."""
+    layers' kernel outputs was refused for one v5e by 148 MiB while the held
+    experts' operand was 4 x the even share of the rows; at 3 x it fits, with
+    0.46 GiB more temporaries than the released step's
+    (tests/test_tpu_compile.py).  Released, the price is that one layer's
+    projections and MLP run forward twice."""
     stats: dict = {}
     for kind in KINDS:
         if kind not in layers:

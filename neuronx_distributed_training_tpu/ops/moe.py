@@ -609,7 +609,23 @@ _exchange_experts.defvjp(_exchange_fwd, _exchange_bwd)
 #: bound, and a step past it runs the same block on successive slices of the
 #: sorted rows, keeping nothing but its inputs and running each slice forward
 #: again in the backward pass: no row dropped, no temporary past the bound.
-_HELD_ROWS = 4.0
+#: Every row of the operand is gathered, masked and scattered whether a row
+#: arrived for it or not (only the ragged dots skip the rows past the count),
+#: so its size is paid on every step: one layer at LFM2's shape (65 536 rows
+#: sorted, 8 192 the even share), forward twice and backward once as ``full``
+#: runs it, costs 27.4 ms with an operand of 4 x, 23.7 at 3 x, 21.6 at 2 x,
+#: 19.9 at 1.5 x (one v5e; PERF.md section 6, PR 45).  The value is the
+#: smallest of 1.5 / 2 / 3 that lies 1.4 x over the largest share a benchmark
+#: step has held: 1.62 (Kanana's cell, one run of ten; 1.34-1.47 the other
+#: runs' largest, 1.44 Laguna's, 1.05 LFM2's), so 2.0 would lie 1.23 x over
+#: it.  A step past the bound runs two slices where the wider operand ran
+#: one pass, each slice forward once more in the backward: at a share of 3.5
+#: a layer costs 1.29 x what the one pass of 4 x cost (47.5 ms for 36.9).
+#: Not the exchange's ``_EXCHANGE_ROWS``, though both bound a sorted-rows
+#: operand: that one is a chip's share of ``T * k`` rows against the
+#: weights' journey (``autotune/cost_model.py`` prices a plan by it), this
+#: one a share of ``T * k * held / E`` against slices.
+_HELD_ROWS = 3.0
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))

@@ -203,7 +203,8 @@ def test_three_steps_match_the_reference_in_float32(reference, trained):
     assert summary["attention_kind"] == "mla" and summary["mla_dims"] == [24, 16, 24, 8]
     assert summary["moe_experts_held"] == [0, 4, 16] and summary["moe_score_func"] == "sigmoid"
     assert summary["layer_kinds"] == {"mlp": {"dense": 1, "sparse": 3}}
-    assert summary["moe_row_bounds"] == [192]   # 4 x the even share, 2 x 32 x 3 x 4 / 16
+    # _HELD_ROWS x the even share, 2 x 32 x 3 x 4 / 16 = 48 rows
+    assert summary["moe_row_bounds"] == [int(moe_ops._HELD_ROWS * 48)] == [144]
 
 
 def test_the_bias_moves_by_the_rule_and_by_nothing_of_adamws(trained):

@@ -176,7 +176,8 @@ def test_three_adamw_steps_match_the_reference_in_float32(reference, tmp_path):
         "attention": {"full_attention": 2, "sliding_attention": 3},
         "mlp": {"dense": 1, "sparse": 4}}
     assert summary["moe_experts_held"] == [0, 4, 16]
-    assert summary["moe_row_bounds"] == [256]   # 4 x the even share of 64 rows
+    # _HELD_ROWS x the even share of 2 x 32 x 4 x 4 / 16 = 64 rows
+    assert summary["moe_row_bounds"] == [int(moe_ops._HELD_ROWS * 64)] == [192]
 
 
 # -- the comparison is tight enough: what is left out shows ---------------------
@@ -284,16 +285,72 @@ def test_a_step_past_the_bound_gives_the_same_loss_and_gradients(monkeypatch, bo
     assert worst_gap(past_grads, under_grads) < 2e-5
 
 
-def test_the_bound_is_a_multiple_of_the_even_share_and_never_over_the_rows():
+@pytest.mark.parametrize("top_k", [4, 6, 10])
+@pytest.mark.parametrize("side", [-1, 1], ids=["just-under", "just-over"])
+def test_the_bound_changes_the_path_and_not_the_result(monkeypatch, top_k, side):
+    """Rows held one short of the bound (one pass) and one past it (two
+    slices): the block's output and every gradient are those of a bound of
+    4 x the even share, which takes both in one pass."""
+    tokens, experts_n, held, h, f = 32, 32, 8, 16, 24
+    rng = np.random.default_rng(top_k)
+    # a quarter of the experts held and three quarters of the choices among
+    # them, spread evenly over the tokens: the bound exactly; then one more or less
+    rows = int(moe_ops._HELD_ROWS * tokens * top_k * held / experts_n)
+    take = np.full(tokens, rows // tokens)
+    take[:rows % tokens] += 1
+    take[-1] += side
+    idx = jnp.asarray(np.stack([np.concatenate([
+        rng.permutation(held)[:n], held + rng.permutation(experts_n - held)[:top_k - n]])
+        for n in take]), jnp.int32)
+    cfg = moe_ops.MoEConfig(num_experts=experts_n, top_k=top_k, experts_held=(0, held))
+    keys = jax.random.split(jax.random.PRNGKey(top_k), 4)
+    experts = {"gate_up": jax.random.normal(keys[0], (held, h, 2 * f)) * 0.2,
+               "down": jax.random.normal(keys[1], (held, f, h)) * 0.2}
+    x = jax.random.normal(keys[2], (tokens, h))
+    probs = jax.nn.softmax(jax.random.normal(keys[3], (tokens, top_k)))
+
+    def run():
+        def loss(experts, x, probs):
+            y, stats = moe_ops._dropless_held(experts, x, probs, idx, cfg,
+                                              compute_dtype=jnp.float32)
+            return jnp.sum(jnp.sin(y)), stats
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(
+                experts, x, probs)
+
+    (ours, stats), grads = run()
+    assert float(stats["moe/held_rows"]) == rows + side
+    assert float(stats["moe/row_bound"]) == (side > 0)
+    monkeypatch.setattr(moe_ops, "_HELD_ROWS", 4.0)
+    (wide, wide_stats), wide_grads = run()
+    assert float(wide_stats["moe/row_bound"]) == 0.0
+    assert float(ours) == pytest.approx(float(wide), rel=1e-6)
+    assert worst_gap(grads, wide_grads) < 2e-5
+
+
+@pytest.mark.parametrize("held, held_tokens, bound, by_slices", [
+    (4, 64, 192, True),     # every row held, 4 x the even share: by slices
+    (4, 48, 192, False),    # the bound exactly: one pass
+    (12, 64, 256, False),   # 3 x the even share of 192 is over the 256 rows
+], ids=["all-rows-held", "at-the-bound", "never-over-the-rows"])
+def test_the_bound_is_a_multiple_of_the_even_share_and_never_over_the_rows(
+        held, held_tokens, bound, by_slices):
+    from neuronx_distributed_training_tpu.parallel import sharding as shd
+
     z = jnp.zeros((64, 8))
-    idx = jnp.tile(jnp.arange(4)[None], (64, 1))           # every row is held
+    # a token's four choices: the first four held experts, or four held by none
+    idx = jnp.where(jnp.arange(64)[:, None] < held_tokens, jnp.arange(4)[None], 12 + jnp.arange(4))
     probs = jnp.full((64, 4), 0.25)
-    experts = {"gate_up": jnp.zeros((4, 8, 16)), "down": jnp.zeros((4, 8, 8))}
-    cfg = moe_ops.MoEConfig(num_experts=16, top_k=4, experts_held=(0, 4))
-    _, stats = moe_ops._dropless_held(experts, z, probs, idx, cfg, compute_dtype=jnp.float32)
-    # 64 x 4 rows, all held: 4 x the even share of 64; the operand holds them
-    assert float(stats["moe/held_rows"]) == 256 and float(stats["moe/held_rows_share"]) == 4.0
-    assert float(stats["moe/row_bound"]) == 0.0
+    experts = {"gate_up": jnp.zeros((held, 8, 16)), "down": jnp.zeros((held, 8, 8))}
+    cfg = moe_ops.MoEConfig(num_experts=16, top_k=4, experts_held=(0, held))
+    with shd.collect_trace_facts() as traced:
+        _, stats = moe_ops._dropless_held(experts, z, probs, idx, cfg,
+                                          compute_dtype=jnp.float32)
+    even = 64 * 4 * held / 16
+    assert traced["moe_row_bounds"] == [bound] == [min(int(moe_ops._HELD_ROWS * even), 256)]
+    assert float(stats["moe/held_rows"]) == 4 * held_tokens
+    assert float(stats["moe/held_rows_share"]) == pytest.approx(4 * held_tokens / even)
+    assert float(stats["moe/row_bound"]) == by_slices
     with pytest.raises(NotImplementedError, match="experts_held"):
         moe_ops._dropless_experts(experts, z, probs, idx, cfg, compute_dtype=jnp.float32,
                                   expert_axis="expert")

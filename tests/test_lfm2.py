@@ -241,7 +241,8 @@ def test_three_steps_match_the_reference_in_float32(reference, trained):
     assert summary["operator_kinds"] == {"conv": 6, "full_attention": 2}
     assert summary["short_conv"] == {"taps": 3, "way": "pallas", "bytes_per_token": 512}
     assert summary["moe_experts_held"] == [0, 4, 16] and summary["moe_score_func"] == "sigmoid"
-    assert summary["moe_row_bounds"] == [256]   # 4 x the even share, 2 x 32 x 4 x 4 / 16
+    # _HELD_ROWS x the even share, 2 x 32 x 4 x 4 / 16 = 64 rows
+    assert summary["moe_row_bounds"] == [int(moe_ops._HELD_ROWS * 64)] == [192]
     assert set(summary["remat"]) == {"conv_dense", "full_sparse", "conv_sparse"}
     assert all(entry["granularity"] == "full" for entry in summary["remat"].values())
 

@@ -24,6 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from neuronx_distributed_training_tpu.ops import flash_attention as fa
+from neuronx_distributed_training_tpu.ops import moe
 
 EX = os.path.join(os.path.dirname(os.path.dirname(__file__)), "examples", "conf")
 HBM_BYTES = int(15.75 * 2**30)  # what the compiler gives a v5e program
@@ -132,6 +133,14 @@ def _flash_forward_calls(compiled) -> int:
     scan's body runs once a layer)."""
     return sum("tpu_custom_call" in line and "custom-call(" in line and "flash_fwd" in line
                for line in compiled.as_text().splitlines())
+
+
+def _held_rows_operand(text: str) -> set:
+    """The row counts of the bf16 results of the ragged dots in a compiled
+    step's ``text``: where a range of the experts is held, the sorted-rows
+    operand of either way (``ops/moe.py::_HELD_ROWS`` x the even share)."""
+    return {int(rows) for rows in re.findall(
+        r"= bf16\[(\d+),\d+\]\S* custom-call\(.*ragged", text)}
 
 
 STEP_CASES = {
@@ -302,7 +311,6 @@ def test_dropless_block_is_partitioned_by_tokens_on_v5e(topo):
 
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from neuronx_distributed_training_tpu.ops import moe
     from neuronx_distributed_training_tpu.parallel import sharding as shd
     from neuronx_distributed_training_tpu.parallel.mesh import (
         MeshConfig,
@@ -429,9 +437,12 @@ def test_the_mixed_stacks_cell_fits_one_v5e_under_full_only(topo):
     # merged with its rerun); a fourth, the window layers' rerun, before the
     # kernel's outputs were kept
     assert _flash_forward_calls(compiled) == 3
+    # the even share is 8192 x 10 x 8 / 256 rows
+    assert _held_rows_operand(compiled.as_text()) == {int(moe._HELD_ROWS * 2560)} == {7680}
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes > 0.9 * ma.argument_size_in_bytes
     assert 8.9 * 2**30 < ma.argument_size_in_bytes < 9.2 * 2**30
+    assert ma.temp_size_in_bytes <= 8_303_040_000   # at an operand of 4 x (10 240 rows)
     with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
         _compile_step(topo, "hf_laguna_s_2_1_config.yaml", 1, {
             **LAGUNA_CUT, "model.activations_checkpoint_granularity": "selective"})
@@ -485,25 +496,32 @@ def test_the_latent_attention_cell_fits_one_v5e_under_full(topo):
     # the dense layer's and the sparse scan's; their reruns made three before
     # the kernel's outputs were kept
     assert _flash_forward_calls(compiled) == 2
+    # the even share is 16 384 x 6 x 16 / 128 rows
+    assert _held_rows_operand(compiled.as_text()) == {int(moe._HELD_ROWS * 12288)} == {36864}
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes > 0.9 * ma.argument_size_in_bytes
     assert 7.6 * 2**30 < ma.argument_size_in_bytes < 7.8 * 2**30
+    assert ma.temp_size_in_bytes <= 11_426_572_288   # at an operand of 4 x (49 152 rows)
 
 
-def test_the_latent_attention_cell_needs_its_dense_layer_rematerialized(topo, monkeypatch):
+def test_the_latent_attention_cell_keeps_less_with_its_dense_layer_rematerialized(
+        topo, monkeypatch):
     """Why ``models/kanana.py`` checkpoints a run of one layer with
     ``prevent_cse``: merged with its rerun, as every other stack's run of one
-    is, the dense layer keeps 1.17 GiB of activations through the step, and
-    with the five sparse layers' kernel outputs kept beside them the compiler
-    refuses the step for one v5e (15.89 GiB of 15.75)."""
+    is, the dense layer keeps its activations through the step beside the
+    five sparse layers' kernel outputs: 11.57 GB of temporaries where the
+    step with the barrier reports 11.08.  While the held experts' operand was
+    4 x the even share that decided the fit (the merged step was refused for
+    one v5e, 15.89 GiB of 15.75); at 3 x it fits, and the barrier buys
+    0.46 GiB of room."""
     from neuronx_distributed_training_tpu.models import llama
 
     real = llama.checkpoint_layer
     monkeypatch.setattr(
         llama, "checkpoint_layer",
         lambda body, cfg, *, stack, prevent_cse=False: real(body, cfg, stack=stack))
-    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
-        _compile_step(topo, "hf_kanana_2_30b_a3b_config.yaml", 1, KANANA_CUT)
+    merged = _compile_step(topo, "hf_kanana_2_30b_a3b_config.yaml", 1, KANANA_CUT)
+    assert merged.memory_analysis().temp_size_in_bytes > 11_081_480_192 + 0.3 * 2**30
 
 
 # --------------------------------------------------------------------------
@@ -566,6 +584,9 @@ def test_the_short_convolution_cell_fits_one_v5e_at_depth_8(topo):
     text = compiled.as_text()
     assert "short_conv" in text and "conv_gate" in text and "qk_norm" in text
     assert "conv_gate_fwd" in text and "conv_gate_bwd" in text
+    # the even share is 16 384 x 4 x 8 / 64 rows
+    assert _held_rows_operand(text) == {int(moe._HELD_ROWS * 8192)} == {24576}
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes > 0.9 * ma.argument_size_in_bytes
     assert 8.2 * 2**30 < ma.argument_size_in_bytes < 8.3 * 2**30
+    assert ma.temp_size_in_bytes <= 9_650_934_784   # at an operand of 4 x (32 768 rows)
