@@ -591,7 +591,12 @@ def audit_dtypes(report: AuditReport, ctx: AuditContext,
     WIDENING convert from bf16 is the policy's own promotion (the f32
     softmax path meeting bf16 values — data is still bf16-precise) and is
     not flagged; the rule targets dots where both operands are genuinely
-    f32-valued, i.e. the compute-dtype cast never happened."""
+    f32-valued, i.e. the compute-dtype cast never happened.  No missed cast
+    either: a sum written as a product with a table of ones, float32 on
+    purpose (``ops/ssd.py``'s running sum of ``dt a`` inside a chunk,
+    ``ops/norm.py``'s mean squares of a group).  It is known as the router's
+    dot is, by an operand: one of the model config's ``sum_tables`` (the
+    tables' shapes), multiplied at ``Precision.HIGHEST``."""
     if jnp.dtype(ctx.policy.compute_dtype) != jnp.dtype(jnp.bfloat16):
         return
     if not stablehlo_text:
@@ -617,6 +622,15 @@ def audit_dtypes(report: AuditReport, ctx: AuditContext,
                 return True
         return False
 
+    sum_tables = getattr(ctx.model_cfg, "sum_tables", None)
+    seq_len = int((ctx.cfg.get("data", {}) or {}).get("seq_length", 0) or 0)
+    tables = {"x".join(map(str, t)) + "xf32"
+              for t in (sum_tables(seq_len) if sum_tables else ())}
+
+    def sum_as_product(line: str, *type_strs: str) -> bool:
+        return ("precision = [HIGHEST, HIGHEST]" in line
+                and any(t in tables for t in type_strs))
+
     hits = 0
     # MLIR SSA names (%N) are function-scoped: the widened-convert set is
     # rebuilt per func.func block so a convert in one function cannot
@@ -633,7 +647,8 @@ def audit_dtypes(report: AuditReport, ctx: AuditContext,
             e2 = m.group(4).rsplit("x", 1)[-1]
             if (e1 == "f32" and e2 == "f32"
                     and lhs_name not in widened and rhs_name not in widened
-                    and not router_like(m.group(3), m.group(4))):
+                    and not router_like(m.group(3), m.group(4))
+                    and not sum_as_product(line, m.group(3), m.group(4))):
                 hits += 1
                 if hits <= max_findings:
                     report.add(

@@ -61,9 +61,11 @@ MLP_TYPES = ("dense", "sparse")
 ATTENTION_SCOPES = {"full_attention": "attn_full", "sliding_attention": "attn_window"}
 
 
-def kind_name(attention: str, mlp: str) -> str:
-    """The stack a layer of this kind lies in: ``layers/<name>``."""
-    return f"{attention.split('_')[0]}_{mlp}"
+def kind_name(*kind: str) -> str:
+    """The stack a layer of this kind lies in, ``layers/<name>``: ``(attention,
+    mlp)`` here and in models/lfm2.py, one part where a layer is one mixer
+    (models/nemotron_h.py)."""
+    return "_".join((kind[0].split("_")[0],) + kind[1:])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -522,6 +524,31 @@ def run_stacks(layers, carry, kinds, run_of):
         carry, stats = jax.lax.scan(one_period, carry, xs)
         all_stats += stats
     return carry, all_stats
+
+
+def stats_by_kind(kinds, all_stats) -> dict[tuple, dict]:
+    """``run_stacks``'s stacked stats regrouped: kind -> each entry ``[the
+    kind's layers, ...]`` in the order of the kind's stack."""
+    found: dict[tuple, list] = {}
+    at = 0
+    for segment in stack_plan(kinds):
+        if segment[0] == "run":
+            found.setdefault(segment[1], []).append(all_stats[at])
+            at += 1
+            continue
+        _, _, period = segment
+        runs: dict[tuple, list] = {}
+        for kind, _ in period:
+            runs.setdefault(kind, []).append(all_stats[at])
+            at += 1
+        for kind, parts in runs.items():
+            # ``[periods, the run's layers, ...]`` each: a kind's runs side by
+            # side inside a period, then the periods in a row
+            joined = jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=1), *parts)
+            found.setdefault(kind, []).append(jax.tree_util.tree_map(
+                lambda a: a.reshape((-1,) + a.shape[2:]), joined))
+    return {kind: jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
+            for kind, parts in found.items()}
 
 
 def _period_takes(period):

@@ -69,7 +69,7 @@ from neuronx_distributed_training_tpu.models.laguna import (
     _kind_layers,
     kind_name,
     run_stacks,
-    stack_plan,
+    stats_by_kind,
 )
 from neuronx_distributed_training_tpu.ops import linear as linear_ops
 from neuronx_distributed_training_tpu.ops import moe as moe_ops
@@ -397,31 +397,6 @@ def _decoder_layer(lp, x, cos, sin, cfg: Lfm2Config, policy: DtypePolicy,
     return x, stats
 
 
-def _stats_by_kind(kinds, all_stats) -> dict[tuple[str, str], dict]:
-    """``run_stacks``'s stacked stats regrouped: kind -> each entry ``[the
-    kind's layers, ...]`` in the order of the kind's stack."""
-    found: dict[tuple[str, str], list] = {}
-    at = 0
-    for segment in stack_plan(kinds):
-        if segment[0] == "run":
-            found.setdefault(segment[1], []).append(all_stats[at])
-            at += 1
-            continue
-        _, _, period = segment
-        runs: dict[tuple[str, str], list] = {}
-        for kind, _ in period:
-            runs.setdefault(kind, []).append(all_stats[at])
-            at += 1
-        for kind, parts in runs.items():
-            # ``[periods, the run's layers, ...]`` each: a kind's runs side by
-            # side inside a period, then the periods in a row
-            joined = jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=1), *parts)
-            found.setdefault(kind, []).append(jax.tree_util.tree_map(
-                lambda a: a.reshape((-1,) + a.shape[2:]), joined))
-    return {kind: jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
-            for kind, parts in found.items()}
-
-
 def decoder_stack(layers, x, cos, sin, cfg: Lfm2Config, policy: DtypePolicy, *,
                   attention_mask=None, segment_ids=None):
     """The whole stack by ``stack_plan`` -> ``(x, {sparse kind: its layers'
@@ -441,7 +416,7 @@ def decoder_stack(layers, x, cos, sin, cfg: Lfm2Config, policy: DtypePolicy, *,
         return lambda x, stack: jax.lax.scan(body, x, stack)
 
     x, all_stats = run_stacks(layers, x, cfg.kinds, run_of)
-    return x, {kind: stats for kind, stats in _stats_by_kind(cfg.kinds, all_stats).items()
+    return x, {kind: stats for kind, stats in stats_by_kind(cfg.kinds, all_stats).items()
                if stats}
 
 

@@ -279,7 +279,7 @@ def _attention_block(lp, x, cos, sin, cfg: LlamaConfig, policy: DtypePolicy,
     ``x`` and applied to the op's output before ``o``.  Leaves
     ``lp["q_norm"]`` / ``lp["k_norm"]`` (``[head_dim]``, models/lfm2.py) are
     an RMS norm of every query / key head before the rope, one learned scale
-    for all the heads."""
+    for all the heads.  ``cos`` None: q and k go to the op unrotated."""
     b, s, h = x.shape
     nh, nkv, d = num_heads or cfg.num_attention_heads, cfg.kv_heads, cfg.head_size
     if sliding_window is _CONFIG_WINDOW:
@@ -299,8 +299,9 @@ def _attention_block(lp, x, cos, sin, cfg: LlamaConfig, policy: DtypePolicy,
         with jax.named_scope("qk_norm"):
             q = norm_ops.apply_rms_norm(lp["q_norm"], q, eps=cfg.rms_norm_eps)
             k = norm_ops.apply_rms_norm(lp["k_norm"], k, eps=cfg.rms_norm_eps)
-    q = rope_ops.apply_rope(q, cos, sin)
-    k = rope_ops.apply_rope(k, cos, sin)
+    if cos is not None:   # None: no position embedding (models/nemotron_h.py)
+        q = rope_ops.apply_rope(q, cos, sin)
+        k = rope_ops.apply_rope(k, cos, sin)
     out = attn_ops.attention(
         q, k, v,
         impl=cfg.attention_impl,

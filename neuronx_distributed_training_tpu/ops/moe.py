@@ -50,7 +50,9 @@ the dropped-vs-dropless validation at ``training_orchestrator.py:60-102``):
   ungated; ``routed_scaling_factor`` scales the routed sum against it.
 
 SwiGLU experts (``glu_mlp`` in the reference): w_gate/w_up fused as one
-``[E, h, 2*ff]`` tensor, w_down ``[E, ff, h]``.
+``[E, h, 2*ff]`` tensor, w_down ``[E, ff, h]``.  ``MoEConfig.expert_act:
+relu2`` (models/nemotron_h.py): no gate, ``down(relu(up x)^2)``; the leaf
+``gate_up`` is then the up matrix alone, ``[E, h, ff]``.
 """
 
 from __future__ import annotations
@@ -62,6 +64,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _gmm, tgmm as _tgmm
 from jax.sharding import PartitionSpec as P
 
 from neuronx_distributed_training_tpu.parallel import sharding as shd
@@ -103,6 +106,14 @@ class MoEConfig:
     renorm_eps: float = 1e-20
     # the selection bias's step a train step (``bias_update``); 0: never moves
     bias_update_rate: float = 0.0
+    # the function of every expert and of the shared expert (``_EXPERT_ACTS``),
+    # set by the family; no YAML key
+    expert_act: str = "swiglu"
+
+    @property
+    def act(self):
+        """The experts' function of their first matmul's result."""
+        return _EXPERT_ACTS[self.expert_act][0]
 
     @property
     def experts_resident(self) -> int:
@@ -143,13 +154,16 @@ class MoEConfig:
 
 def init_moe_params(key: jax.Array, hidden: int, ffn: int, cfg: MoEConfig,
                     dtype=jnp.float32, stddev: float = 0.02):
-    """Router + fused SwiGLU expert weights, expert-major ``[E, ...]``."""
+    """Router + expert weights, expert-major ``[E, ...]``: ``gate_up`` the
+    fused SwiGLU pair, or the up matrix alone where the experts have no gate
+    (``cfg.expert_act``)."""
     kr, kgu, kd = jax.random.split(key, 3)
     e, held = cfg.num_experts, cfg.experts_resident
+    up = ffn * _EXPERT_ACTS[cfg.expert_act][1]
     params = {
         "router": {"w": (jax.random.normal(kr, (hidden, e)) * stddev).astype(jnp.float32)},
         "experts": {
-            "gate_up": (jax.random.normal(kgu, (held, hidden, 2 * ffn)) * stddev).astype(dtype),
+            "gate_up": (jax.random.normal(kgu, (held, hidden, up)) * stddev).astype(dtype),
             "down": (jax.random.normal(kd, (held, ffn, hidden)) * stddev).astype(dtype),
         },
     }
@@ -289,14 +303,89 @@ def weighted_router_loss(router_logits: jax.Array, idx: jax.Array, cfg: MoEConfi
 # ---------------------------------------------------------------------------
 
 
-def _swiglu_experts(expert_params, x_e: jax.Array, compute_dtype) -> jax.Array:
-    """Dense per-expert SwiGLU: x_e [E, cap, h] -> [E, cap, h]."""
+def _swiglu(gu: jax.Array) -> jax.Array:
+    gate, up = jnp.split(gu, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+def _relu2(up: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(up))
+
+
+#: ``MoEConfig.expert_act`` -> (the function of the first matmul's result,
+#: that result's width over the expert's)
+_EXPERT_ACTS = {"swiglu": (_swiglu, 2), "relu2": (_relu2, 1)}
+
+
+#: XLA's TPU ``ragged-dot`` is on a slow path where a width of the dot is no
+#: whole multiple of this, 1.7-2.3 x slower (one v5e, 6 144 real rows of 18 432
+#: in 8 groups, K 2688, forward / forward + backward in ms: 1792 wide 2.63 /
+#: 8.50, 1856 4.60 / 14.09, 2048 2.04 / 7.28), and the slow path's time
+#: follows the rows the groups hold three times as steeply: such widths go
+#: through megablox's grouped matmuls instead (Pallas: tiles of rows, each
+#: visited once a group it holds; a width that is no whole tile is masked
+#: inside the kernel, nothing is padded; the same shape 2.3-2.9 x faster:
+#: PERF.md section 6, PR 46)
+_RAGGED_COLS = 256
+
+#: (rows, contracted, columns) a tile of ``gmm``, (rows, the result's rows,
+#: its columns) of ``tgmm``: the fastest of four tried at 18 432 x 2688 x
+#: 1856 and its transposes (PERF.md section 6, PR 46); an operand whose rows
+#: are no whole tiles stays with XLA
+_GMM_TILES = (256, 2688, 512)
+_TGMM_TILES = (512, 896, 1024)
+
+
+def _tiles(tiles: tuple, rows: int, a: int, b: int) -> Optional[tuple]:
+    """The megablox tiling for ``rows`` of widths ``a`` and ``b``, or ``None``
+    where the dot is XLA's: by the call's shapes alone."""
+    odd = any(w > _RAGGED_COLS and w % _RAGGED_COLS for w in (a, b))
+    if not odd or rows % _TGMM_TILES[0]:
+        return None
+    return (tiles[0], *(min(t, 128 * math.ceil(w / 128)) for t, w in zip(tiles[1:], (a, b))))
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"   # jaxlint: disable=JL102
+
+
+def _rows_dot(xs: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
+              transposed: bool = False) -> jax.Array:
+    """``[rows, a] x [groups, a, b] -> [rows, b]``, each row against its
+    group's matrix (``transposed``: ``w`` is ``[groups, b, a]``).  The rows
+    past ``sum(group_sizes)`` are left unwritten by either way."""
+    a, b = w.shape[2:0:-1] if transposed else w.shape[1:]
+    tiles = _tiles(_GMM_TILES, xs.shape[0], a, b)
+    if tiles is None:
+        return jax.lax.ragged_dot(xs, w.swapaxes(1, 2) if transposed else w, group_sizes)
+    return _gmm(xs, w, group_sizes, preferred_element_type=xs.dtype, tiling=tiles,
+                transpose_rhs=transposed, interpret=_interpret())
+
+
+#: ``lax.ragged_dot``'s transpose for the grouped operand: rows contracted
+#: group by group, ``[rows, a] x [rows, b] -> [groups, a, b]``
+_WEIGHT_GRAD = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=(0,), rhs_group_dimensions=())
+
+
+def _weights_dot(xs: jax.Array, ys: jax.Array, group_sizes: jax.Array, dtype) -> jax.Array:
+    """``[rows, a] x [rows, b] -> [groups, a, b]`` in ``dtype``: the rows
+    contracted group by group (``_rows_dot``'s transpose for ``w``)."""
+    tiles = _tiles(_TGMM_TILES, xs.shape[0], xs.shape[1], ys.shape[1])
+    if tiles is None:
+        return jax.lax.ragged_dot_general(xs, ys, group_sizes, _WEIGHT_GRAD,
+                                          preferred_element_type=dtype)
+    return _tgmm(xs.T, ys, group_sizes, preferred_element_type=dtype, tiling=tiles,
+                 interpret=_interpret())
+
+
+def _dense_experts(expert_params, x_e: jax.Array, compute_dtype, act) -> jax.Array:
+    """Dense per-expert MLP: x_e [E, cap, h] -> [E, cap, h]."""
     gu = jnp.einsum(
         "ech,ehf->ecf", x_e, expert_params["gate_up"].astype(compute_dtype)
     )
-    gate, up = jnp.split(gu, 2, axis=-1)
-    act = jax.nn.silu(gate) * up
-    return jnp.einsum("ecf,efh->ech", act, expert_params["down"].astype(compute_dtype))
+    return jnp.einsum("ecf,efh->ech", act(gu), expert_params["down"].astype(compute_dtype))
 
 
 def moe_dropped(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat16):
@@ -324,40 +413,36 @@ def moe_dropped(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloa
         combine = jnp.einsum("tk,tke,tkec->tec", probs.astype(jnp.float32), keep, pos_cap)
         x_e = jnp.einsum("tec,th->ech", dispatch.astype(compute_dtype), x.astype(compute_dtype))
     with jax.named_scope("experts"):
-        y_e = _swiglu_experts(params["experts"], x_e, compute_dtype)
+        y_e = _dense_experts(params["experts"], x_e, compute_dtype, cfg.act)
     with jax.named_scope("combine"):
         y = jnp.einsum("tec,ech->th", combine.astype(compute_dtype), y_e)
     return y.astype(x.dtype), (probs, idx, logits)
 
 
 def _expert_rows(x, probs, order, group_sizes, gu_w, down_w, *, k: int,
-                 count=None):
+                 count=None, act):
     """The sorted (token, choice) rows ``order`` of ``x`` through their
-    experts (``lax.ragged_dot`` over the groups ``group_sizes`` of ``gu_w`` /
+    experts (``_rows_dot`` over the groups ``group_sizes`` of ``gu_w`` /
     ``down_w``), weighted by their gate and scatter-added onto ``x``'s tokens.
     Returns ``(y like x, (gu, ys))``: the pre-activations and the expert
     outputs per row, what ``_expert_rows_back`` needs kept.
 
+    ``act``: the experts' function of ``gu`` (``_EXPERT_ACTS``).
     ``count``: where ``order`` can hold more rows than the groups do, the
-    number they hold.  XLA's TPU ``ragged-dot`` skips the rows past
-    ``sum(group_sizes)``, reads and leaves them unwritten (XLA:CPU writes
-    zeros), so they are zeroed going in and coming out."""
+    number they hold.  XLA's TPU ``ragged-dot`` and the tiled kernels skip
+    the rows past ``sum(group_sizes)``, read and leave them unwritten
+    (XLA:CPU writes zeros), so they are zeroed going in and coming out."""
     head = functools.partial(_head_rows, count=count)
     # inner scopes of "moe": telemetry.spans.DEVICE_SCOPES
     with jax.named_scope("dispatch"):
         token_of = order // k  # token index per sorted row
         xs = head(x[token_of])  # [rows, h] gathered rows
     with jax.named_scope("experts"):
-        gu = jax.lax.ragged_dot(xs, gu_w, group_sizes)
-        ys = head(jax.lax.ragged_dot(_swiglu(gu), down_w, group_sizes))  # [rows, h]
+        gu = _rows_dot(xs, gu_w, group_sizes)
+        ys = head(_rows_dot(act(gu), down_w, group_sizes))  # [rows, h]
     with jax.named_scope("combine"):
         w = probs.reshape(-1)[order].astype(x.dtype)  # gate weight per row
         return jnp.zeros_like(x).at[token_of].add(ys * w[:, None]), (gu, ys)
-
-
-def _swiglu(gu: jax.Array) -> jax.Array:
-    gate, up = jnp.split(gu, 2, axis=-1)
-    return jax.nn.silu(gate) * up
 
 
 def _head_rows(a: jax.Array, count) -> jax.Array:
@@ -367,15 +452,8 @@ def _head_rows(a: jax.Array, count) -> jax.Array:
     return jnp.where((jnp.arange(a.shape[0]) < count)[:, None], a, 0)
 
 
-#: ``lax.ragged_dot``'s transpose for the grouped operand: rows contracted
-#: group by group, ``[rows, a] x [rows, b] -> [groups, a, b]``
-_WEIGHT_GRAD = jax.lax.RaggedDotDimensionNumbers(
-    dot_dimension_numbers=(((0,), (0,)), ((), ())),
-    lhs_ragged_dimensions=(0,), rhs_group_dimensions=())
-
-
 def _expert_rows_back(ct, kept, x, probs, order, group_sizes, gu_w, down_w, *,
-                      k: int, count=None, grad_dtype):
+                      k: int, count=None, grad_dtype, act):
     """The cotangents of ``_expert_rows`` for ``x``, ``probs``, ``gu_w`` and
     ``down_w`` from ``ct``, that of ``y``, and ``kept``; the weights' come
     straight out of the kernel's float32 accumulator in ``grad_dtype``.
@@ -391,15 +469,12 @@ def _expert_rows_back(ct, kept, x, probs, order, group_sizes, gu_w, down_w, *,
         d_probs = jnp.zeros(probs.size, probs.dtype).at[order].add(d_w).reshape(probs.shape)
         d_ys = head(ct_rows * w[:, None])
     with jax.named_scope("experts"):
-        act, act_back = jax.vjp(_swiglu, gu)
-        d_down = jax.lax.ragged_dot_general(
-            act, d_ys, group_sizes, _WEIGHT_GRAD, preferred_element_type=grad_dtype)
-        (d_gu,) = act_back(jax.lax.ragged_dot(
-            d_ys, down_w.swapaxes(1, 2), group_sizes))
-        d_gate_up = jax.lax.ragged_dot_general(
-            head(x[token_of]), d_gu, group_sizes, _WEIGHT_GRAD,
-            preferred_element_type=grad_dtype)
-        d_xs = head(jax.lax.ragged_dot(d_gu, gu_w.swapaxes(1, 2), group_sizes))
+        acted, act_back = jax.vjp(act, gu)
+        d_down = _weights_dot(acted, d_ys, group_sizes, grad_dtype)
+        (d_gu,) = act_back(_rows_dot(d_ys, down_w, group_sizes, transposed=True))
+        xs = head(x[token_of])
+        d_gate_up = _weights_dot(xs, d_gu, group_sizes, grad_dtype)
+        d_xs = head(_rows_dot(d_gu, gu_w, group_sizes, transposed=True))
     with jax.named_scope("dispatch"):
         return jnp.zeros_like(x).at[token_of].add(d_xs), d_probs, d_gate_up, d_down
 
@@ -511,6 +586,7 @@ def _exchange_sides(cfg: MoEConfig, bound: int, axis: str, compute_dtype,
     ``kept`` the ``(gu, ys)`` of ``bound`` rows on both sides."""
     e, k = cfg.num_experts, cfg.top_k
     e_local = e // jax.lax.axis_size(axis)
+    act = cfg.act
 
     def gathered(x, probs, chosen):
         """Every peer's tokens, and their rows sorted by this chip's
@@ -524,7 +600,8 @@ def _exchange_sides(cfg: MoEConfig, bound: int, axis: str, compute_dtype,
 
     def rows_forward(weights, experts, x, probs, chosen):
         x, probs, order, group_sizes, count = gathered(x, probs, chosen)
-        y, kept = _expert_rows(x, probs, order, group_sizes, *weights, k=k, count=count)
+        y, kept = _expert_rows(x, probs, order, group_sizes, *weights, k=k, count=count,
+                               act=act)
         with jax.named_scope("combine"):
             return _home_sum(y, axis), kept
 
@@ -534,7 +611,7 @@ def _exchange_sides(cfg: MoEConfig, bound: int, axis: str, compute_dtype,
             ct = _peers_rows(ct, axis)
         d_x, d_probs, d_gu, d_down = _expert_rows_back(
             ct, kept, x_all, probs_all, order, group_sizes, *weights,
-            k=k, count=count, grad_dtype=experts["gate_up"].dtype)
+            k=k, count=count, grad_dtype=experts["gate_up"].dtype, act=act)
         with jax.named_scope("dispatch"):
             return ({"gate_up": d_gu, "down": d_down},
                     _home_sum(d_x, axis).astype(x.dtype), _home_sum(d_probs, axis))
@@ -553,14 +630,14 @@ def _exchange_sides(cfg: MoEConfig, bound: int, axis: str, compute_dtype,
 
     def weights_forward(weights, experts, x, probs, chosen):
         weights, xc, order, group_sizes = own(weights, x, chosen)
-        y, kept = _expert_rows(xc, probs, order, group_sizes, *weights, k=k)
+        y, kept = _expert_rows(xc, probs, order, group_sizes, *weights, k=k, act=act)
         return y, tuple(jnp.pad(a, ((0, bound - t * k), (0, 0))) for a in kept)
 
     def weights_backward(ct, kept, weights, experts, x, probs, chosen):
         weights, xc, order, group_sizes = own(weights, x, chosen)
         d_x, d_probs, *d_weights = _expert_rows_back(
             ct, [a[:t * k] for a in kept], xc, probs, order, group_sizes,
-            *weights, k=k, grad_dtype=reduce_dtype)
+            *weights, k=k, grad_dtype=reduce_dtype, act=act)
         with jax.named_scope("experts"):
             d_gu, d_down = (
                 _on_auto_axes(lambda g: jax.lax.psum_scatter(
@@ -628,8 +705,8 @@ _exchange_experts.defvjp(_exchange_fwd, _exchange_bwd)
 _HELD_ROWS = 3.0
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _held_experts(experts, x, probs, chosen, k: int, bound: int, compute_dtype):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _held_experts(experts, x, probs, chosen, k: int, bound: int, compute_dtype, act):
     """The dropless block's routed half where the program holds a range of the
     experts alone: ``experts`` their weights (any float dtype; their
     gradients leave in it, float32 straight from the kernel), ``chosen``
@@ -638,10 +715,10 @@ def _held_experts(experts, x, probs, chosen, k: int, bound: int, compute_dtype):
     row is sorted last and left out: nothing stands in for the absent chips.
     Rows held at most ``bound``: one pass, ``(gu, ys)`` and the sort kept;
     more: slices (``_HELD_ROWS``)."""
-    return _held_pass(0, k, bound, compute_dtype, experts, x, probs, chosen)[0]
+    return _held_pass(0, k, bound, compute_dtype, act, experts, x, probs, chosen)[0]
 
 
-def _held_sides(k: int, bound: int, compute_dtype, held: int, f2: int, h: int):
+def _held_sides(k: int, bound: int, compute_dtype, act, held: int, f2: int, h: int):
     """``((forward, backward) under the bound, (forward, backward) past
     it)`` of ``_held_experts``, in ``_exchange_sides``'s shapes."""
 
@@ -655,14 +732,14 @@ def _held_sides(k: int, bound: int, compute_dtype, held: int, f2: int, h: int):
     def under_forward(experts, x, probs, chosen):
         order, sizes = _sorted_rows(chosen, held, bound)
         y, kept = _expert_rows(x.astype(compute_dtype), probs, order, sizes,
-                               *cast(experts), k=k, count=jnp.sum(sizes))
+                               *cast(experts), k=k, count=jnp.sum(sizes), act=act)
         return y, (*kept, order, sizes)
 
     def under_backward(ct, kept, experts, x, probs, chosen):
         *kept, order, sizes = kept
         return grads(x, *_expert_rows_back(
             ct, kept, x.astype(compute_dtype), probs, order, sizes, *cast(experts),
-            k=k, count=jnp.sum(sizes), grad_dtype=experts["gate_up"].dtype))
+            k=k, count=jnp.sum(sizes), grad_dtype=experts["gate_up"].dtype, act=act))
 
     def slices(chosen):
         """``(n, slice_of)``: the sorted held rows as ``n`` slices of
@@ -687,7 +764,7 @@ def _held_sides(k: int, bound: int, compute_dtype, held: int, f2: int, h: int):
         def one(j, y):
             order, sizes = slice_of(j)
             return y + _expert_rows(xc, probs, order, sizes, *weights, k=k,
-                                    count=jnp.sum(sizes))[0]
+                                    count=jnp.sum(sizes), act=act)[0]
 
         # nothing kept: the other side's shapes, empty
         return (jax.lax.fori_loop(0, n, one, jnp.zeros_like(xc)),
@@ -702,10 +779,11 @@ def _held_sides(k: int, bound: int, compute_dtype, held: int, f2: int, h: int):
         def one(j, acc):
             order, sizes = slice_of(j)
             count = jnp.sum(sizes)
-            _, kept_j = _expert_rows(xc, probs, order, sizes, *weights, k=k, count=count)
+            _, kept_j = _expert_rows(xc, probs, order, sizes, *weights, k=k, count=count,
+                                     act=act)
             return jax.tree_util.tree_map(jnp.add, acc, _expert_rows_back(
                 ct, kept_j, xc, probs, order, sizes, *weights, k=k, count=count,
-                grad_dtype=grad_dtype))
+                grad_dtype=grad_dtype, act=act))
 
         zero = (jnp.zeros_like(xc), jnp.zeros_like(probs),
                 jnp.zeros(experts["gate_up"].shape, grad_dtype),
@@ -715,12 +793,12 @@ def _held_sides(k: int, bound: int, compute_dtype, held: int, f2: int, h: int):
     return (under_forward, under_backward), (past_forward, past_backward)
 
 
-def _held_pass(back: int, k, bound, compute_dtype, *operands):
+def _held_pass(back: int, k, bound, compute_dtype, act, *operands):
     """The forward (0) or backward (1) pass of ``_held_experts``: under the
     bound or past it, by the count of the rows held."""
     experts, x, _, chosen = operands[-4:]
     held = experts["gate_up"].shape[0]
-    under, past = _held_sides(k, bound, compute_dtype, held,
+    under, past = _held_sides(k, bound, compute_dtype, act, held,
                               experts["gate_up"].shape[2], x.shape[1])
     if bound >= chosen.shape[0]:  # the bound holds every case
         return under[back](*operands)
@@ -760,7 +838,7 @@ def _dropless_held(experts, x, probs, idx, cfg: MoEConfig, *, compute_dtype):
     if facts is not None:
         facts["moe_experts_held"] = [lo, hi, cfg.num_experts]
         facts["moe_row_bounds"] = [bound]
-    y = _held_experts(experts, x, probs, chosen, k, bound, compute_dtype)
+    y = _held_experts(experts, x, probs, chosen, k, bound, compute_dtype, cfg.act)
     stats = {"moe/held_rows": rows, "moe/held_rows_share": rows / even,
              "moe/row_bound": rows > bound}
     return y, jax.tree_util.tree_map(lambda v: v.astype(jnp.float32), stats)
@@ -813,7 +891,7 @@ def _dropless_experts(experts, x: jax.Array, probs: jax.Array, idx: jax.Array,
         weights = _cast_experts(experts, compute_dtype)
         order, group_sizes = _sorted_rows(idx.reshape(-1), e, t * k)
         return _expert_rows(x.astype(compute_dtype), probs, order, group_sizes,
-                            *weights, k=k)[0], {}
+                            *weights, k=k, act=cfg.act)[0], {}
 
     ep = jax.lax.axis_size(expert_axis)
     e_local = e // ep
@@ -940,12 +1018,13 @@ def _dropless_on_mesh(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype,
     return y.astype(x.dtype), idx, logits, stats
 
 
-def _shared_expert(shared, x: jax.Array, compute_dtype) -> jax.Array:
-    """A SwiGLU every token passes, computed once beside the routed sum and
-    added to it ungated (``shared``: ``gate_up`` / ``down`` linears)."""
+def _shared_expert(shared, x: jax.Array, compute_dtype, act=_swiglu) -> jax.Array:
+    """An expert every token passes, computed once beside the routed sum and
+    added to it ungated (``shared``: ``gate_up`` / ``down`` linears; ``act``
+    as the routed experts')."""
     with jax.named_scope("shared"):
         gu = x.astype(compute_dtype) @ shared["gate_up"]["w"].astype(compute_dtype)
-        return (_swiglu(gu) @ shared["down"]["w"].astype(compute_dtype)).astype(x.dtype)
+        return (act(gu) @ shared["down"]["w"].astype(compute_dtype)).astype(x.dtype)
 
 
 def _shuffle_permutation(t: int, group: int) -> jnp.ndarray:
@@ -990,7 +1069,7 @@ def moe_block(params, x: jax.Array, cfg: MoEConfig, *, compute_dtype=jnp.bfloat1
                 params, x, cfg, compute_dtype=compute_dtype,
                 reduce_dtype=reduce_dtype, act_spec=act_spec)
             if "shared" in params:
-                y = y + _shared_expert(params["shared"], x, compute_dtype)
+                y = y + _shared_expert(params["shared"], x, compute_dtype, cfg.act)
             aux = {"router_logits": logits, "expert_idx": idx, "stats": stats}
             if cfg.score_func == "sigmoid":
                 # the loads the selection bias answers to (``bias_update``)

@@ -1,5 +1,8 @@
-"""The middle of a gated short convolution (LFM2's ``conv`` layers): two
-input-dependent gates around a depthwise causal convolution of a few taps.
+"""Depthwise causal convolutions of a few taps: the middle of a gated short
+convolution (LFM2's ``conv`` layers: two input-dependent gates around the
+taps; most of this file), and the plain one with a bias and ``silu`` that a
+Mamba-2 mixer runs over its ``x``, ``B`` and ``C`` channels (``causal_conv``,
+at the end: its own pair of kernels on the same tiles, halo and ``keep``).
 
 A layer's operator is ``in_proj`` ``[hidden, 3 x hidden]``, this middle and
 ``out_proj`` ``[hidden, hidden]`` (models/lfm2.py); the two matmuls are plain.
@@ -110,6 +113,8 @@ def _rows_down(prev8, cur, shift: int):
     rolled = pltpu.roll(cur, shift, 0)
     row = jax.lax.broadcasted_iota(jnp.int32, prev8.shape, 0)
     head = jnp.where(row < shift, pltpu.roll(prev8, shift, 0), rolled[:SUBLANES])
+    if cur.shape[0] == SUBLANES:   # a halo block moved down: nothing after the head
+        return head
     return jnp.concatenate([head, rolled[SUBLANES:]], axis=0)
 
 
@@ -132,13 +137,44 @@ def _gate_products(x_ref, xp_ref, c: int):
     return x, x[:, :c] * x[:, 2 * c:], xp[:, :c] * xp[:, 2 * c:]
 
 
+def _conv_sum(x, xp, keep, w, bias, k):
+    """``bias + sum_d w[k - 1 - d] * keep[:, d] * x_{t-d}`` of a tile (``xp``
+    the 8 rows before it; ``bias`` a row or None), float32."""
+    acc = bias
+    for d in range(k):   # tap k - 1 - d weighs x_{t-d}
+        term = w[k - 1 - d:k - d] * (keep[:, d:d + 1] * _rows_down(xp, x, d))
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _taps_back(x, xp, keep, keepn, w, dacc, daccn, k, with_sum: bool):
+    """``_conv_sum``'s backward pass over a tile: ``dacc`` the sum's cotangent
+    (``daccn``: of the 8 rows after, which read this tile's last rows) ->
+    ``(the sum itself where with_sum, x's cotangent, the taps' gradient rows,
+    tap 0 last)``."""
+    conv = dx = None
+    rows = []
+    for d in range(k):
+        back = _rows_down(xp, x, d)                       # x_{t-d}
+        kept = keep[:, d:d + 1] * dacc                    # what x_{t-d} receives from t
+        tap = w[k - 1 - d:k - d]
+        if with_sum:
+            term = tap * (keep[:, d:d + 1] * back)
+            conv = term if conv is None else conv + term
+        ahead = tap * _rows_up(kept, keepn[:, d:d + 1] * daccn, d)   # ... read at t - d
+        dx = ahead if dx is None else dx + ahead
+        rows.append(jnp.sum(kept * back, axis=0, keepdims=True))
+    return conv, dx, rows
+
+
+def _tap_rows(rows: list, k: int, c: int) -> list:
+    """The taps' gradient as the 8 rows of its operand: tap 0 first."""
+    return rows[::-1] + [jnp.zeros((SUBLANES - k, c), jnp.float32)] * (k < SUBLANES)
+
+
 def _fwd_kernel(x_ref, xp_ref, keep_ref, w_ref, y_ref, *, c, k):
     x, g, gp = _gate_products(x_ref, xp_ref, c)
-    keep, w = keep_ref[0], w_ref[...]
-    acc = None
-    for d in range(k):   # tap k - 1 - d weighs g_{t-d}
-        term = w[k - 1 - d:k - d] * (keep[:, d:d + 1] * _rows_down(gp, g, d))
-        acc = term if acc is None else acc + term
+    acc = _conv_sum(g, gp, keep_ref[0], w_ref[...], None, k)
     y_ref[0] = (x[:, c:2 * c] * acc).astype(y_ref.dtype)
 
 
@@ -151,47 +187,48 @@ def _bwd_kernel(x_ref, xp_ref, xn_ref, keep_ref, keepn_ref, dy_ref, dyn_ref, w_r
     # the 8 rows after: nothing past the sequence's end
     after = jnp.where(pl.program_id(1) < last, 1.0, 0.0)
     dcn = _f32(dyn_ref[0]) * _f32(xn_ref[0][:, c:2 * c]) * after
-    keepn = keepn_ref[0]
-    conv = dg = None
-    taps_grad = []
-    for d in range(k):
-        back = _rows_down(gp, g, d)                       # g_{t-d}
-        kept = keep[:, d:d + 1] * dc                      # what g_{t-d} receives from t
-        tap = w[k - 1 - d:k - d]
-        term = tap * (keep[:, d:d + 1] * back)
-        conv = term if conv is None else conv + term
-        ahead = tap * _rows_up(kept, keepn[:, d:d + 1] * dcn, d)   # ... read at t - d
-        dg = ahead if dg is None else dg + ahead
-        taps_grad.append(jnp.sum(kept * back, axis=0, keepdims=True))
+    conv, dg, taps_grad = _taps_back(g, gp, keep, keepn_ref[0], w, dc, dcn, k, True)
     dx_ref[0, :, :c] = (dg * x[:, 2 * c:]).astype(dx_ref.dtype)
     dx_ref[0, :, c:2 * c] = (dy * conv).astype(dx_ref.dtype)
     dx_ref[0, :, 2 * c:] = (dg * x[:, :c]).astype(dx_ref.dtype)
-    rows = taps_grad[::-1] + [jnp.zeros((SUBLANES - k, c), jnp.float32)] * (k < SUBLANES)
-    dw_ref[0, 0] = jnp.concatenate(rows, axis=0)
+    dw_ref[0, 0] = jnp.concatenate(_tap_rows(taps_grad, k, c), axis=0)
 
 
 def _specs(ts: int, s: int):
-    """Index maps of a tile's own rows and of the 8 rows before and after it
-    (clamped at the sequence's ends, where ``keep`` and ``last`` discount them)."""
+    """``(rows, before, after, vec)``: specs of a tile's own rows, of the 8
+    rows before and after it (clamped at the sequence's ends, where ``keep``
+    and ``last`` discount them) and of the taps' 8 rows, each ``width`` wide.
+    Over a grid of (batch, tiles) the width is all there is; over a third
+    axis, blocks of channels, ``blocked`` says whether the operand has them
+    (``keep`` has not)."""
     per = ts // SUBLANES
 
-    def rows(width):
-        return pl.BlockSpec((1, ts, width), lambda bi, ti: (bi, ti, 0))
+    def at(ci, blocked):
+        return ci[0] if ci and blocked else 0
 
-    def before(width):
-        return pl.BlockSpec((1, SUBLANES, width),
-                            lambda bi, ti: (bi, jnp.maximum(ti * per - 1, 0), 0))
+    def rows(width, blocked=True):
+        return pl.BlockSpec((1, ts, width),
+                            lambda bi, ti, *ci: (bi, ti, at(ci, blocked)))
 
-    def after(width):
+    def before(width, blocked=True):
         return pl.BlockSpec(
             (1, SUBLANES, width),
-            lambda bi, ti: (bi, jnp.minimum((ti + 1) * per, s // SUBLANES - 1), 0))
+            lambda bi, ti, *ci: (bi, jnp.maximum(ti * per - 1, 0), at(ci, blocked)))
 
-    return rows, before, after
+    def after(width, blocked=True):
+        return pl.BlockSpec(
+            (1, SUBLANES, width),
+            lambda bi, ti, *ci: (bi, jnp.minimum((ti + 1) * per, s // SUBLANES - 1),
+                                 at(ci, blocked)))
+
+    def vec(width):
+        return pl.BlockSpec((SUBLANES, width), lambda bi, ti, *ci: (0, at(ci, True)))
+
+    return rows, before, after, vec
 
 
-def _params():
-    return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel"),
+def _params(grid_axes: int = 2):
+    return pltpu.CompilerParams(dimension_semantics=("parallel",) * grid_axes,
                                 vmem_limit_bytes=_VMEM_LIMIT)
 
 
@@ -202,13 +239,12 @@ def _padded_taps(taps):
 def _forward(bcz, taps, keep, ts, interpret):
     b, s, c3 = bcz.shape
     c, k = c3 // 3, taps.shape[0]
-    rows, before, _ = _specs(ts, s)
+    rows, before, _, vec = _specs(ts, s)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, c=c, k=k),
         name="conv_gate_fwd",
         grid=(b, s // ts),
-        in_specs=[rows(c3), before(c3), rows(SUBLANES),
-                  pl.BlockSpec((SUBLANES, c), lambda bi, ti: (0, 0))],
+        in_specs=[rows(c3), before(c3), rows(SUBLANES), vec(c)],
         out_specs=rows(c),
         out_shape=jax.ShapeDtypeStruct((b, s, c), bcz.dtype),
         compiler_params=_params(),
@@ -220,7 +256,7 @@ def _backward(bcz, taps, keep, dy, ts, interpret):
     b, s, c3 = bcz.shape
     c, k = c3 // 3, taps.shape[0]
     tiles = s // ts
-    rows, before, after = _specs(ts, s)
+    rows, before, after, vec = _specs(ts, s)
     # the backward rule is traced apart from the call: it names its scope itself
     with jax.named_scope("conv_gate"):
         dx, dw = pl.pallas_call(
@@ -228,7 +264,7 @@ def _backward(bcz, taps, keep, dy, ts, interpret):
             name="conv_gate_bwd",
             grid=(b, tiles),
             in_specs=[rows(c3), before(c3), after(c3), rows(SUBLANES), after(SUBLANES),
-                      rows(c), after(c), pl.BlockSpec((SUBLANES, c), lambda bi, ti: (0, 0))],
+                      rows(c), after(c), vec(c)],
             out_specs=[rows(c3),
                        pl.BlockSpec((1, 1, SUBLANES, c), lambda bi, ti: (bi, ti, 0, 0))],
             out_shape=[jax.ShapeDtypeStruct(bcz.shape, bcz.dtype),
@@ -257,20 +293,26 @@ def _conv_bwd(ts, interpret, res, dy):
 _conv.defvjp(_conv_fwd, _conv_bwd)
 
 
-def _on_mesh(bcz, taps, keep, ts, interpret):
-    """The kernels made legal on a mesh (a Mosaic call is not partitioned
-    while a mesh axis is automatic): per shard of the batch, the taps whole;
-    a token needs nothing of another batch row, so the body holds no
-    collective."""
+_ROWS = P(DATA_AXES, None, None)
+
+
+def _per_batch_shard(fn, operands, specs):
+    """``fn(*operands)`` made legal on a mesh (a Mosaic call is not
+    partitioned while a mesh axis is automatic): per shard of the batch
+    (``_ROWS``), what ``specs`` says whole; a token needs nothing of another
+    batch row, so the body holds no collective."""
     mesh, manual = shd.region_mesh()
     if mesh is None:
-        return _conv(bcz, taps, keep, ts, interpret)
-    rows = P(DATA_AXES, None, None)
+        return fn(*operands)
     return shd.shard_map(
-        lambda x, w, kp: _conv(x, w, kp, ts, interpret), mesh=mesh,
-        in_specs=(rows, P(None, None), rows), out_specs=rows,
+        fn, mesh=mesh, in_specs=specs, out_specs=_ROWS,
         axis_names=frozenset(mesh.axis_names) - manual if manual else frozenset(),
-        check_vma=False)(bcz, taps, keep)
+        check_vma=False)(*operands)
+
+
+def _on_mesh(bcz, taps, keep, ts, interpret):
+    return _per_batch_shard(lambda x, w, kp: _conv(x, w, kp, ts, interpret),
+                            (bcz, taps, keep), (_ROWS, P(None, None), _ROWS))
 
 
 def gated_short_conv(bcz: jax.Array, taps: jax.Array, *,
@@ -303,3 +345,151 @@ def gated_short_conv(bcz: jax.Array, taps: jax.Array, *,
             bcz = jnp.pad(bcz, ((0, 0), (0, pad), (0, 0)))
             keep = jnp.pad(keep, ((0, 0), (0, pad), (0, 0)))
         return _on_mesh(bcz, taps, keep, ts, interpret)[:, :s]
+
+
+# ---------------------------------------------------------------------------
+# the plain convolution with a bias and ``silu`` (a Mamba-2 mixer's)
+# ---------------------------------------------------------------------------
+
+#: ``run_summary.json``'s ``mamba_conv.way``: how ``causal_conv`` is computed
+CONV_WAY = "pallas"
+#: channels of a tile of ``causal_conv`` (the gated middle's tiles hold all of
+#: a layer's channels, three times; 6144 channels at once would leave 80 rows)
+_CONV_CHANNELS = 2048
+
+
+def _act(acc, silu: bool):
+    return acc * jax.nn.sigmoid(acc) if silu else acc
+
+
+def _cc_fwd_kernel(x_ref, xp_ref, keep_ref, w_ref, b_ref, y_ref, *, k, silu):
+    acc = _conv_sum(_f32(x_ref[0]), _f32(xp_ref[0]), keep_ref[0], w_ref[...],
+                    b_ref[0:1], k)
+    y_ref[0] = _act(acc, silu).astype(y_ref.dtype)
+
+
+def _cc_bwd_kernel(x_ref, xp_ref, xn_ref, keep_ref, keepn_ref, dy_ref, dyn_ref, w_ref, b_ref,
+                   dx_ref, dw_ref, *, k, silu, last):
+    x, xp, xn = _f32(x_ref[0]), _f32(xp_ref[0]), _f32(xn_ref[0])
+    keep, keepn, w, bias = keep_ref[0], keepn_ref[0], w_ref[...], b_ref[0:1]
+
+    def through_act(dy, acc):
+        if not silu:
+            return dy
+        sig = jax.nn.sigmoid(acc)
+        return dy * (sig * (1.0 + acc * (1.0 - sig)))
+
+    dacc = through_act(_f32(dy_ref[0]), _conv_sum(x, xp, keep, w, bias, k))
+    # the 8 rows after (their sum reads this tile's last rows): nothing past
+    # the sequence's end
+    after = jnp.where(pl.program_id(1) < last, 1.0, 0.0)
+    daccn = through_act(_f32(dyn_ref[0]),
+                        _conv_sum(xn, x[-SUBLANES:], keepn, w, bias, k)) * after
+    _, dx, rows = _taps_back(x, xp, keep, keepn, w, dacc, daccn, k, False)
+    dx_ref[0] = dx.astype(dx_ref.dtype)
+    c = x.shape[1]
+    # rows 0 .. k - 1 the taps' gradient, row 8 the bias's
+    dw_ref[0, 0] = jnp.concatenate(
+        _tap_rows(rows, k, c)
+        + [jnp.sum(dacc, axis=0, keepdims=True), jnp.zeros((SUBLANES - 1, c), jnp.float32)],
+        axis=0)
+
+
+def _cc_forward(x, taps, bias, keep, ts, tc, silu, interpret):
+    b, s, c = x.shape
+    rows, before, _, vec = _specs(ts, s)
+    return pl.pallas_call(
+        functools.partial(_cc_fwd_kernel, k=taps.shape[0], silu=silu),
+        name="mamba_conv_fwd",
+        grid=(b, s // ts, c // tc),
+        in_specs=[rows(tc), before(tc), rows(SUBLANES, False), vec(tc), vec(tc)],
+        out_specs=rows(tc),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=_params(3),
+        interpret=interpret,
+    )(x, x, keep, _padded_taps(taps), _padded_taps(bias[None]))
+
+
+def _cc_backward(x, taps, bias, keep, dy, ts, tc, silu, interpret):
+    b, s, c = x.shape
+    k, tiles = taps.shape[0], s // ts
+    rows, before, after, vec = _specs(ts, s)
+    # the backward rule is traced apart from the call: it names its scope itself
+    with jax.named_scope("mamba_conv"):
+        dx, dw = pl.pallas_call(
+            functools.partial(_cc_bwd_kernel, k=k, silu=silu, last=tiles - 1),
+            name="mamba_conv_bwd",
+            grid=(b, tiles, c // tc),
+            in_specs=[rows(tc), before(tc), after(tc), rows(SUBLANES, False),
+                      after(SUBLANES, False), rows(tc), after(tc), vec(tc), vec(tc)],
+            out_specs=[rows(tc), pl.BlockSpec((1, 1, 2 * SUBLANES, tc),
+                                              lambda bi, ti, ci: (bi, ti, 0, ci))],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                       jax.ShapeDtypeStruct((b, tiles, 2 * SUBLANES, c), jnp.float32)],
+            compiler_params=_params(3),
+            interpret=interpret,
+        )(x, x, x, keep, keep, dy, dy, _padded_taps(taps), _padded_taps(bias[None]))
+        dw = jnp.sum(dw, axis=(0, 1))
+        return dx, dw[:k].astype(taps.dtype), dw[SUBLANES].astype(bias.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _cc(x, taps, bias, keep, ts, tc, silu, interpret):
+    return _cc_forward(x, taps, bias, keep, ts, tc, silu, interpret)
+
+
+def _cc_fwd(x, taps, bias, keep, ts, tc, silu, interpret):
+    return _cc_forward(x, taps, bias, keep, ts, tc, silu, interpret), (x, taps, bias, keep)
+
+
+def _cc_bwd(ts, tc, silu, interpret, res, dy):
+    x, taps, bias, keep = res
+    return (*_cc_backward(x, taps, bias, keep, dy, ts, tc, silu, interpret),
+            jnp.zeros_like(keep))
+
+
+_cc.defvjp(_cc_fwd, _cc_bwd)
+
+
+def causal_conv(x: jax.Array, taps: jax.Array, bias: Optional[jax.Array] = None, *,
+                silu: bool = False, attention_mask: Optional[jax.Array] = None,
+                segment_ids: Optional[jax.Array] = None,
+                interpret: Optional[bool] = None) -> jax.Array:
+    """``x [b, s, c]``, ``taps [K, c]`` (the taps lead, as above), ``bias [c]``
+    or None -> ``y_t = act(sum_{j < K} w[j] x_{t - (K - 1) + j} + bias)`` in
+    ``x``'s dtype, ``x`` zero before the sequence's start, ``act`` ``silu`` or
+    nothing; products, sum and ``silu`` in float32, rounded once.  Masks as
+    ``gated_short_conv``'s (``_keep``): a padded position counts as zero, the
+    taps are cut at a document's start.
+
+    One way (``CONV_WAY``): a Pallas pair over tiles of ``[256 rows,
+    _CONV_CHANNELS]`` with the gated middle's halo of 8 rows, index clamps and
+    ``keep`` operand; forward ``x`` read and ``y`` written, backward ``x`` and
+    ``y``'s cotangent read and ``x``'s written, the sum run again from ``x``:
+    nothing but the operands is kept.  Left to XLA the chain (four shifted
+    multiply-adds, the bias, ``silu``) moved 7 x its bytes forward and 9 x
+    forward and backward at the benchmark's shape (PERF.md section 6, PR 46)."""
+    b, s, c = x.shape
+    k = taps.shape[0]
+    if taps.shape[1] != c:
+        raise ValueError(f"causal_conv: taps for {taps.shape[1]} channels, x has {c}")
+    if not 1 <= k <= MAX_TAPS:
+        raise ValueError(f"causal_conv: {k} taps; the kernels' halo is one block of "
+                         f"{SUBLANES} rows, so 1 to {MAX_TAPS} taps")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"   # jaxlint: disable=JL102
+    tc = next(n for n in (_CONV_CHANNELS, 1024, 512, 256, LANES, c) if c % n == 0)
+    if not interpret and tc % LANES:
+        raise ValueError(f"causal_conv: {c} channels; on a TPU the kernels' tiles are "
+                         f"whole lanes (multiples of {LANES})")
+    if bias is None:
+        bias = jnp.zeros((c,), taps.dtype)
+    ts = _tile_rows(s, tc)
+    pad = -s % ts
+    keep = _keep(b, s, k, attention_mask, segment_ids)
+    if pad:   # whole tiles: rows past the end see nothing and are cut off again
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+        keep = jnp.pad(keep, ((0, 0), (0, pad), (0, 0)))
+    return _per_batch_shard(
+        lambda xx, w, bb, kp: _cc(xx, w, bb, kp, ts, tc, silu, interpret),
+        (x, taps, bias, keep), (_ROWS, P(None, None), P(None), _ROWS))[:, :s]

@@ -101,12 +101,18 @@ DEVICE_SCOPES: dict[str, tuple[str, ...]] = {
 #: ``attention/short_conv`` (the whole convolution half of a layer: norm,
 #: ``in_proj``, the middle, ``out_proj``, residual), ``.../conv_gate`` inside
 #: it (the middle alone: the two gates and the taps, ``ops/short_conv.py``)
-#: and ``attention/qk_norm`` (the per-head norms of q and k).  A reader that does not
+#: and ``attention/qk_norm`` (the per-head norms of q and k);
+#: ``models/nemotron_h.py``'s ``attention/mamba`` (a whole Mamba-2 layer: norm,
+#: ``in_proj``, convolution, scan, gated norm, ``out_proj``, residual) with,
+#: inside it, ``mamba_conv`` (the convolution with its bias and ``silu``),
+#: ``ssd_scan`` (``ops/ssd.py``'s call, softplus and decays included) and
+#: ``gated_norm`` (the gate and the grouped norm).  A reader that does not
 #: know one counts its time under the scope that holds it, so nothing becomes
 #: unscoped; ``benchmark/readers/inner_scope.py`` reads one by its name.
 FAMILY_SCOPES: dict[str, tuple[str, ...]] = {
     "attention": ("attn_full", "attn_window", "head_gate", "mla_latent",
-                  "short_conv", "conv_gate", "qk_norm"),
+                  "short_conv", "conv_gate", "qk_norm",
+                  "mamba", "mamba_conv", "ssd_scan", "gated_norm"),
     "moe": ("shared",),
     "ce_head": ("exit_gate",),
 }

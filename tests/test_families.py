@@ -178,6 +178,7 @@ def test_what_each_family_cannot_do():
         "laguna": ["decode", "onef1b_head", "pipeline"],
         "kanana": ["decode", "onef1b_head", "pipeline"],
         "lfm2": ["decode", "onef1b_head", "pipeline"],
+        "nemotron_h": ["decode", "onef1b_head", "pipeline"],
     }
 
 
@@ -250,13 +251,13 @@ def test_lower_layers_import_nothing_from_models(package):
 def test_callers_name_no_familys_config_class(package):
     classes = {type(toy(name)[1]).__name__ for name in FAMILY_NAMES}
     assert classes == {"LlamaConfig", "MixtralConfig", "GPTConfig", "OuroConfig",
-                       "LagunaConfig", "KananaConfig", "Lfm2Config"}
+                       "LagunaConfig", "KananaConfig", "Lfm2Config", "NemotronHConfig"}
     for path in sorted((PKG / package).rglob("*.py")):
         names = {getattr(n, "id", None) or getattr(n, "attr", None)
                  for n in ast.walk(ast.parse(path.read_text()))}
         assert not names & classes, f"{path.relative_to(PKG)} names {names & classes}"
         family_modules = [m for m in _imports(path)
-                          if re.search(r"\.models\.(llama|mixtral|gpt|ouro|laguna|kanana|lfm2)$", m)]
+                          if re.search(r"\.models\.(llama|mixtral|gpt|ouro|laguna|kanana|lfm2|nemotron_h)$", m)]
         assert not family_modules, f"{path.relative_to(PKG)} imports {family_modules}"
 
 

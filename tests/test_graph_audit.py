@@ -14,9 +14,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from neuronx_distributed_training_tpu.analysis.graph_audit import (
     AuditContext,
+    AuditReport,
     abstract_batch,
     audit_artifacts,
     audit_config,
+    audit_dtypes,
     audit_step_program,
     expected_max_device_bytes,
     parse_alias_map,
@@ -260,6 +262,26 @@ class TestRuleInjections:
                      params=params_bf16), comp2, shlo2)
         assert not [f for f in rep2.findings if f.rule == "GA301"], \
             rep2.format()
+
+    def test_ga301_knows_a_sum_as_product_by_its_table(self, devices8):
+        """A float32 sum written as a product at ``highest`` passes where one
+        operand is a table the model config lists (``sum_tables``), and only
+        there: ``highest`` alone excuses nothing."""
+        ctx = make_ctx(mesh_of(devices8, (8,), ("data",)),
+                       policy=DtypePolicy.from_precision_config("mixed_precision"))
+        ctx.model_cfg.sum_tables = lambda seq_len: ((seq_len, seq_len),)
+
+        def f32_matmuls(side, precision="HIGHEST"):
+            rep = AuditReport()
+            audit_dtypes(rep, ctx, (
+                f"func.func @main() {{\n  %5 = stablehlo.dot_general %3, %4, contracting_dims "
+                f"= [1] x [0], precision = [{precision}, {precision}] : "
+                f"(tensor<16x{side}xf32>, tensor<{side}x{side}xf32>) -> tensor<16x{side}xf32>\n}}"))
+            return rep.stats["f32_matmuls"]
+
+        assert f32_matmuls(8) == 0          # data.seq_length of make_ctx
+        assert f32_matmuls(4) == 1
+        assert f32_matmuls(8, "DEFAULT") == 1
 
     def test_ga401_bad_specs_curated(self, devices8):
         cfg = load_config(TINY, {

@@ -598,3 +598,91 @@ def test_the_sigmoid_routes_renormalising_epsilon_is_a_field_of_the_config():
     a, _, _ = moe_ops.route({"w": router["w"]}, x, soft)
     b, _, _ = moe_ops.route({"w": router["w"]}, x, dataclasses.replace(soft, renorm_eps=0.5))
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_experts_function_is_a_field_of_the_config(held=(2, 6)):
+    """``expert_act``: ``swiglu`` where nothing sets it (what every accepted
+    family runs); ``relu2`` (``models/nemotron_h.py``): the leaf ``gate_up`` is
+    the up matrix alone and the block, forward and the hand-written backward of
+    the held path, is ``down(relu(up x)^2)`` gate-weighted, the shared expert
+    the same function (all experts held: tests/test_nemotron_h.py).  No YAML
+    key reads it."""
+    import dataclasses
+
+    from neuronx_distributed_training_tpu.ops import moe as moe_ops
+
+    base = moe_ops.MoEConfig(num_experts=8, top_k=2, score_func="sigmoid", experts_held=held)
+    assert base.expert_act == "swiglu"
+    assert moe_ops.MoEConfig.from_config({"expert_act": "relu2"}).expert_act == "swiglu"
+    cfg = dataclasses.replace(base, expert_act="relu2")
+    key = jax.random.PRNGKey(0)
+    params = moe_ops.init_moe_params(key, 16, 12, cfg, stddev=0.5)
+    n = cfg.experts_resident
+    assert params["experts"]["gate_up"].shape == (n, 16, 12)
+    assert moe_ops.init_moe_params(key, 16, 12, base)["experts"]["gate_up"].shape == (n, 16, 24)
+    params["shared"] = {"gate_up": {"w": 0.5 * jax.random.normal(jax.random.fold_in(key, 1), (16, 20))},
+                        "down": {"w": 0.5 * jax.random.normal(jax.random.fold_in(key, 2), (20, 16))}}
+    x = jax.random.normal(jax.random.fold_in(key, 3), (2, 8, 16))
+
+    def dense(params, x):
+        flat = x.reshape(-1, 16)
+        probs, idx, _ = moe_ops.route(params["router"], flat, cfg)
+        gates = jnp.zeros((flat.shape[0], 8)).at[jnp.arange(flat.shape[0])[:, None], idx].set(probs)
+        lo, hi = held or (0, 8)
+        every = jnp.einsum("tef,efh->teh", jnp.square(jax.nn.relu(
+            jnp.einsum("th,ehf->tef", flat, params["experts"]["gate_up"]))),
+            params["experts"]["down"])
+        shared = (jnp.square(jax.nn.relu(flat @ params["shared"]["gate_up"]["w"]))
+                  @ params["shared"]["down"]["w"])
+        return (jnp.einsum("te,teh->th", gates[:, lo:hi], every) + shared).reshape(x.shape)
+
+    def block(params, x):
+        return moe_ops.moe_block(params, x, cfg, compute_dtype=jnp.float32)[0]
+
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(np.asarray(block(params, x)), np.asarray(dense(params, x)),
+                                   rtol=1e-5, atol=1e-5)
+        loss = lambda f: (lambda p, x: jnp.sum(jnp.sin(f(p, x))))  # noqa: E731
+        got = jax.grad(loss(block), argnums=(0, 1))(params, x)
+        want = jax.grad(loss(dense), argnums=(0, 1))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
+
+
+def test_widths_that_are_no_whole_ragged_columns_go_through_the_tiled_kernels(monkeypatch):
+    """Where a width of the grouped dots is over ``_RAGGED_COLS`` and no whole
+    multiple of it, and the rows are whole tiles, the held experts' rows go
+    through megablox's ``gmm`` / ``tgmm`` (interpret mode here), nothing
+    padded: outputs and every cotangent equal XLA's ragged dots', with rows
+    past the groups' count in the operand; other shapes stay with XLA."""
+    k = jax.random.split(jax.random.PRNGKey(0), 5)
+    t, h, f, e, top, rows = 512, 384, 320, 6, 2, 1024
+    assert moe._tiles(moe._GMM_TILES, rows, h, f) == (256, 384, 384)
+    assert moe._tiles(moe._TGMM_TILES, rows, f, h) == (512, 384, 384)
+    assert moe._tiles(moe._GMM_TILES, rows, 2048, 1536) is None     # XLA's fast path
+    assert moe._tiles(moe._GMM_TILES, rows, 64, 48) is None         # toy widths
+    assert moe._tiles(moe._GMM_TILES, rows + 8, h, f) is None       # no whole tiles of rows
+    x = jax.random.normal(k[0], (t, h), jnp.float32)
+    probs = jax.nn.softmax(jax.random.normal(k[1], (t, top)), axis=-1)
+    idx = jax.random.randint(k[2], (t, top), 0, e)
+    gu_w = jax.random.normal(k[3], (e - 2, h, f), jnp.float32) * 0.1
+    down_w = jax.random.normal(k[4], (e - 2, f, h), jnp.float32) * 0.1
+    # experts 4 and 5 lie elsewhere: their rows sort last and are left out
+    order, sizes = moe._sorted_rows(jnp.minimum(idx.reshape(-1), e - 2), e - 2, rows)
+
+    @jax.jit
+    def both_passes(x, probs, gu_w, down_w):
+        with jax.default_matmul_precision("highest"):
+            y, kept = moe._expert_rows(x, probs, order, sizes, gu_w, down_w, k=top,
+                                       count=jnp.sum(sizes), act=moe._relu2)
+            return y, moe._expert_rows_back(
+                jnp.cos(y), kept, x, probs, order, sizes, gu_w, down_w, k=top,
+                count=jnp.sum(sizes), grad_dtype=jnp.float32, act=moe._relu2)
+
+    tiled = both_passes(x, probs, gu_w, down_w)
+    monkeypatch.setattr(moe, "_RAGGED_COLS", 1 << 20)
+    jax.clear_caches()
+    for a, b in zip(jax.tree_util.tree_leaves(tiled),
+                    jax.tree_util.tree_leaves(both_passes(x, probs, gu_w, down_w))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-4 * float(jnp.max(jnp.abs(b))))

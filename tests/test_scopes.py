@@ -431,3 +431,45 @@ def test_the_convolution_stacks_scopes_sit_inside_attention(no_persistent_cache)
     # the head norms belong to attention layers, the gate chain to convolution layers
     assert not has_scope(names, "qk_norm", inside="short_conv")
     assert not has_scope(names, "flash_fwd", inside="short_conv")
+
+
+def test_the_mamba_layers_scopes_sit_inside_attention(no_persistent_cache):
+    """``models/nemotron_h.py``: a Mamba-2 layer runs whole under
+    ``attention/mamba`` with its convolution under ``mamba_conv``, the scan
+    under ``ssd_scan`` and the gate and grouped norm under ``gated_norm``,
+    forward and backward; an attention layer under ``attention`` with the
+    flash kernels where they always are; a sparse layer under ``moe`` with the
+    shared expert inside; no layer opens ``mlp``.  All four names are in
+    ``FAMILY_SCOPES``."""
+    from neuronx_distributed_training_tpu.analysis.graph_audit import (
+        lower_step_program,
+    )
+    from neuronx_distributed_training_tpu.config.loader import load_config
+    from neuronx_distributed_training_tpu.telemetry.spans import FAMILY_SCOPES
+    from neuronx_distributed_training_tpu.trainer.loop import (
+        assemble_step_program,
+    )
+
+    cfg = load_config(str(EX / "hf_nemotron3_nano_30b_a3b_config.yaml"), {
+        **TOY, "data.synthetic": True, "model.num_attention_heads": 4,
+        "model.num_key_value_heads": 1, "model.head_dim": 128, "model.num_layers": 3,
+        "model.hybrid_override_pattern": "ME*", "model.mamba_num_heads": 4,
+        "model.mamba_head_dim": 16, "model.ssm_state_size": 16, "model.n_groups": 2,
+        "model.chunk_size": 32,
+        "model.vocab_size": 512, "model.n_routed_experts": 4, "model.num_experts_per_tok": 2,
+        "model.moe_intermediate_size": 128, "model.moe_shared_expert_intermediate_size": 128,
+        "distributed_strategy.expert_model_parallel_size": 2,
+        "exp_manager.checkpoint_callback_params": None})
+    asm = assemble_step_program(cfg, devices=jax.devices()[:2], build_data=False)
+    names = op_names(lower_step_program(asm)[1])
+    assert {"mamba", "mamba_conv", "ssd_scan", "gated_norm"} <= set(FAMILY_SCOPES["attention"])
+    for transform in ("jvp(", "transpose("):
+        assert has_scope(names, "mamba", wrapped_by=transform, inside="attention")
+        for inner in ("mamba_conv", "ssd_scan", "gated_norm"):
+            assert has_scope(names, inner, wrapped_by=transform, inside="mamba")
+        assert has_scope(names, "moe", wrapped_by=transform)
+        assert has_scope(names, "shared", wrapped_by=transform, inside="moe")
+        assert not has_scope(names, "mlp", wrapped_by=transform)
+    assert has_scope(names, "flash_fwd", inside="attention")
+    assert has_scope(names, "flash_dkv", wrapped_by="transpose(", inside="attention")
+    assert not has_scope(names, "flash_fwd", inside="mamba")

@@ -590,3 +590,50 @@ def test_the_short_convolution_cell_fits_one_v5e_at_depth_8(topo):
     assert ma.alias_size_in_bytes > 0.9 * ma.argument_size_in_bytes
     assert 8.2 * 2**30 < ma.argument_size_in_bytes < 8.3 * 2**30
     assert ma.temp_size_in_bytes <= 9_650_934_784   # at an operand of 4 x (32 768 rows)
+
+
+# --------------------------------------------------------------------------
+# the single-mixer stack (models/nemotron_h.py) at the benchmark's cut
+# --------------------------------------------------------------------------
+
+#: the cell ``nemotron3-nano-pretrain-8k-ep16``
+#: (benchmark/configs/nemotron-3-nano-30b-a3b.json): published widths, the
+#: pattern's first nine layers (MEMEM*EME), experts 0-7 of 128 held, 1/8 of the
+#: vocabulary, two sequences of 8192 in one micro-batch
+NEMOTRON_CUT = {
+    "model.num_hidden_layers": 9, "model.vocab_size": 16384,
+    "model.num_experts_held": [0, 8],
+    "distributed_strategy.expert_model_parallel_size": 1,
+    "data.global_batch_size": 2,
+}
+
+
+def test_the_state_space_cell_fits_one_v5e_at_depth_9(topo):
+    """8.0 GB of state (667 M parameters) and two sequences of 8192: under
+    ``full`` the compiler takes the step at depth 9 with the scan walking
+    blocks of 4 chunks that keep their inputs only (all 64 chunks at once it
+    reported 0.69 GiB more and rematerialized 44 values by itself; depth 10,
+    one more Mamba-2 layer, is accepted too: PERF.md section 4; not compiled
+    here).  The one attention layer calls the forward kernel once, 16 query
+    heads a key/value head at 128 dims with no rope; the held experts' operand
+    is 3 x the even share of 16 384 x 6 x 8 / 128 rows, 1856 wide, unpadded,
+    through the tiled grouped matmuls."""
+    compiled = _compile_step(topo, "hf_nemotron3_nano_30b_a3b_config.yaml", 1, NEMOTRON_CUT)
+    assert _flash_forward_calls(compiled) == 1
+    text = compiled.as_text()
+    for scope in ("mamba", "mamba_conv", "ssd_scan", "gated_norm"):
+        assert scope in text
+    # widths of 2688 and 1856 are no whole multiples of 256: the held rows go
+    # through megablox's tiled kernels, four gmm and two tgmm a pass there
+    # and back, and no ragged dot is left (ops/moe.py::_tiles)
+    assert _held_rows_operand(text) == set() and "ragged" not in text
+    tiled = re.findall(r"%(t?gmm)\.\d+ = (\S+?)\{\S* custom-call\(", text)
+    assert {shape for _, shape in tiled} == {
+        "bf16[18432,1856]", "bf16[18432,2688]", "f32[8,2688,1856]", "f32[8,1856,2688]"}
+    assert int(moe._HELD_ROWS * 6144) == 18432
+    assert "bf16[18432,1920]" not in text and "bf16[18432,2816]" not in text  # nothing padded
+    assert "bf16[2,32,8192,128]" in text and "bf16[2,2,8192,128]" in text    # 32 / 2 heads as fed
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes > 0.9 * ma.argument_size_in_bytes
+    assert 7.4 * 2**30 < ma.argument_size_in_bytes < 7.5 * 2**30
+    assert ma.temp_size_in_bytes < 8.2 * 2**30
