@@ -49,7 +49,7 @@ def test_explicit_override_wins(monkeypatch, config_updates):
 def test_two_nxdt_train_runs_share_the_env_cache(tmp_path):
     """Two ``nxdt-train`` runs in one tree with the cache placed from outside:
     the first fills ``JAX_COMPILATION_CACHE_DIR`` and the second reuses its
-    entries (adds none)."""
+    entry of the step (adds none)."""
     cache = tmp_path / "cache"
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "JAX_COMPILATION_CACHE_DIR": str(cache),
@@ -64,10 +64,12 @@ def test_two_nxdt_train_runs_share_the_env_cache(tmp_path):
              "--set", "trainer.max_steps=2",
              "--set", "exp_manager.create_checkpoint_callback=false"],
             env=env, check=True, capture_output=True, timeout=600)
-        return {p.name for p in cache.iterdir()}
+        # the step's entries only: a compile becomes an entry when it took 1 s
+        # (``configure_compilation_cache``), which for the small programs
+        # around the step depends on how loaded the machine is
+        return {p.name for p in cache.iterdir()
+                if "train_step" in p.name and "-atime" not in p.name}
 
     first = run("a")
-    assert any("train_step" in n for n in first), sorted(first)
-    second = run("b")
-    assert {n for n in second if "-atime" not in n} \
-        == {n for n in first if "-atime" not in n}
+    assert first
+    assert run("b") == first

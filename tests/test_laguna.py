@@ -2,31 +2,23 @@
 with their own head counts, rotary embeddings and a per-head gate, a dense
 first MLP, then routed experts of which this program may hold a range, beside
 a shared expert.  Held against the benchmark's plain reference
-(``benchmark/references/laguna.py``, float32, nothing of the program); the
-shares of the experts shown to add up to the whole; each of seven omissions
-shown to fail the parity the first test holds; the flash kernels held against
-core attention at the two GQA groups; the held rows' bound shown to change
-nothing but the path."""
-
-import dataclasses
-import importlib
-import json
-from pathlib import Path
+(``benchmark/references/laguna.py``, float32, nothing of the program) by the
+rungs of ``tests/family_ladder.py``, each of seven omissions shown to fail the
+parity the first holds and the shares of the experts shown to add up to the
+whole; the flash kernels held against core attention at the two GQA groups;
+the held rows' bound shown to change nothing but the path."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.harness import check as checks
+import family_ladder
+from family_ladder import FP32, worst_gap
 from neuronx_distributed_training_tpu.models import laguna
-from neuronx_distributed_training_tpu.ops import attention as attn_ops
 from neuronx_distributed_training_tpu.ops import moe as moe_ops
 from neuronx_distributed_training_tpu.ops import rope as rope_ops
-from neuronx_distributed_training_tpu.ops.flash_attention import flash_attention
-from neuronx_distributed_training_tpu.utils.dtypes import DtypePolicy
 
-ROOT = Path(__file__).resolve().parents[1]
 ROPE = {"full_attention": {"rope_theta": 500000, "rope_type": "yarn", "factor": 128,
                            "original_max_position_embeddings": 8192, "beta_slow": 1,
                            "beta_fast": 32, "attention_factor": 1.4852030263919618,
@@ -46,238 +38,92 @@ MODEL = dict(
     moe_intermediate_size=32, shared_expert_intermediate_size=32,
     moe_routed_scaling_factor=2.5, norm_topk_prob=True, router_aux_loss_coef=0.001,
     activations_checkpoint_granularity="full")
-OPTIM = {"lr": 1e-3, "weight_decay": 0.1, "betas": [0.9, 0.95], "eps": 1e-8,
-         "sched": {"warmup_steps": 0, "max_steps": 100}}
-FP32 = DtypePolicy.from_precision_config({"type": "fp32"})
 SEQ = 32
+_H, _D = 64, 16
+_FULL = 2 * _H * (4 + 4) * _D + 2 * 4 * _D * _H + 2 * _H * 4 + 4 * 4 * _D * (4097 / 2)
+_KEYS = (8 * 9 / 2 + (4096 - 8) * 8) / 4096          # the window caps a token's keys
+_SLIDING = 2 * _H * (6 + 4) * _D + 2 * 6 * _D * _H + 2 * _H * 6 + 4 * 6 * _D * _KEYS
 
-
-@pytest.fixture(scope="module")
-def reference():
-    return importlib.import_module("benchmark.references.laguna")
-
-
-def config(**over):
-    return laguna.LagunaConfig.from_config({**MODEL, **over}, {})
-
-
-def tokens(seed=1, rows=2, seq=SEQ):
-    return jax.random.randint(jax.random.PRNGKey(seed), (rows, seq), 0, MODEL["vocab_size"])
-
-
-def batch_of(toks):
-    return {"input_ids": toks, "labels": toks}
-
-
-def spread(params, seed=9):
-    """Norm scales moved off their initial 1 and every other weight grown
-    fivefold, so that attention is far from uniform and a gate, a window or a
-    rotation left out shows."""
-    def leaf(path, x):
-        name = "/".join(str(getattr(p, "key", p)) for p in path)
-        if "norm" in name:
-            key = jax.random.fold_in(jax.random.PRNGKey(seed), sum(map(ord, name)))
-            return x + 0.1 * jax.random.normal(key, x.shape, x.dtype)
-        return x * (1.0 if "embed" in name or "lm_head" in name else 5.0)
-    return jax.tree_util.tree_map_with_path(leaf, params)
-
-
-def value_and_grads(fn, params):
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.value_and_grad(fn))(params)
-
-
-def worst_gap(a, b):
-    """Largest relative gap of two gradient trees, leaf by leaf."""
-    return max(float(jnp.linalg.norm(x - y) / (jnp.linalg.norm(y) + 1e-30))
-               for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)))
-
-
-# -- against the reference ----------------------------------------------------
-
-
-def test_the_seeded_weights_are_the_references_leaf_for_leaf(reference):
-    cfg = config()
-    key = jax.random.PRNGKey(11)
-    mine, theirs = laguna.init_params(key, cfg, FP32), reference.init_params(MODEL, key)
-    assert reference.leaf_names(mine) == reference.leaf_names(theirs)
-    for a, b in zip(jax.tree_util.tree_leaves(mine), jax.tree_util.tree_leaves(theirs)):
-        assert a.shape == b.shape and bool(jnp.all(a == b))
-    assert sorted(mine["layers"]) == ["full_dense", "full_sparse", "sliding_sparse"]
+TOY = family_ladder.Toy(
+    module=laguna, config_class=laguna.LagunaConfig, reference="laguna", model=MODEL, seq=SEQ,
+    omissions=("head_gate", "window", "partial_rotary", "attention_factor", "scale", "renorm",
+               "shared"),
     # the two kinds' projections differ in shape; the held experts are 4 of 16
-    assert mine["layers"]["sliding_sparse"]["attn"]["qkv"]["w"].shape == (3, 64, (6 + 4) * 16)
-    assert mine["layers"]["full_sparse"]["attn"]["qkv"]["w"].shape == (1, 64, (4 + 4) * 16)
-    assert mine["layers"]["full_sparse"]["mlp"]["experts"]["down"].shape == (1, 4, 32, 64)
-    assert mine["layers"]["full_sparse"]["mlp"]["router"]["w"].shape == (1, 64, 16)
-    specs = laguna.param_specs(cfg)
-    assert jax.tree_util.tree_structure(
-        specs, is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec)
-    ) == jax.tree_util.tree_structure(mine)
-    for spec, leaf in zip(
-            jax.tree_util.tree_leaves(specs, is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec)),
-            jax.tree_util.tree_leaves(mine)):
-        assert len(spec) == leaf.ndim
-
-
-@pytest.mark.parametrize("granularity", [None, "selective", "full"])
-def test_loss_and_every_gradient_match_the_reference_in_float32(reference, granularity):
-    cfg = config(activations_checkpoint_granularity=granularity)
-    params = spread(laguna.init_params(jax.random.PRNGKey(3), cfg, FP32))
-    toks = tokens()
-    loss, grads = value_and_grads(
-        lambda p: laguna.forward(p, batch_of(toks), cfg, FP32)[0], params)
-    c = reference.dims(MODEL)
-    ref_loss, ref_grads = value_and_grads(
-        lambda p: reference.microbatch_loss(p, toks, c), params)
-    assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
-    for name, g, r in zip(reference.leaf_names(grads), jax.tree_util.tree_leaves(grads),
-                          jax.tree_util.tree_leaves(ref_grads)):
-        assert float(jnp.linalg.norm(g - r)) <= 2e-5 * float(jnp.linalg.norm(r)), name
-
-
-def test_three_adamw_steps_match_the_reference_in_float32(reference, tmp_path):
-    """``Trainer.from_config(cfg).fit()`` in float32 against ``reference.run``:
-    the losses of three steps and the parameters' change, leaf by leaf."""
-    from neuronx_distributed_training_tpu.config.loader import load_config
-    from neuronx_distributed_training_tpu.data.loader import DataModule
-    from neuronx_distributed_training_tpu.trainer.loop import Trainer
-
-    seed, rows = 5, 2
-    steps = [np.asarray(tokens(seed=100 + k, rows=rows)) for k in range(3)]
-
-    class Rows(DataModule):
-        def fetch_rows(self, idx):
-            return {"input_ids": np.stack([steps[i // rows][i % rows] for i in idx])}
-
-    cfg = load_config({
-        "seed": seed, "model": {**MODEL, "optim": {"name": "adamw_fp32OptState", **OPTIM}},
-        "distributed_strategy": {"tensor_model_parallel_size": 1},
-        "data": {"global_batch_size": rows, "micro_batch_size": rows, "seq_length": SEQ},
-        "trainer": {"max_steps": 3, "log_every_n_steps": 1, "gradient_clip_val": 1.0},
-        "exp_manager": {"exp_dir": str(tmp_path), "name": "laguna"},
-        "precision": {"type": "fp32"}})
-    trainer = Trainer.from_config(cfg, data_module=Rows(1 << 10, rows),
-                                  devices=jax.devices()[:1], enable_checkpointing=False)
-    with jax.default_matmul_precision("highest"):
-        trainer.fit()
-    rows_logged = [json.loads(line) for line in
-                   open(Path(trainer.exp.log_dir) / "metrics.jsonl")]
-    ref = reference.run(MODEL, OPTIM, 1.0, [s[None] for s in steps], seed)
-    assert [r["loss"] for r in rows_logged] == pytest.approx(ref["loss"], rel=1e-5)
-    dparam = checks.parameter_change_norms(reference, trainer.params, MODEL, seed)
-    assert max(checks.leaf_gaps(dparam, ref["dparam"]).values()) < 2e-4
-    for r in rows_logged:
-        assert r["moe/row_bound"] == 0.0 and r["moe/held_rows"] > 0
-        assert r["moe/held_rows_share"] == pytest.approx(
-            r["moe/held_rows"] / (rows * SEQ * 4 * 4 / 16), rel=1e-6)
-        assert 0 < r["router_aux_loss"] < 0.01
-    summary = json.load(open(Path(trainer.exp.log_dir) / "run_summary.json"))
-    assert summary["layer_kinds"] == {
-        "attention": {"full_attention": 2, "sliding_attention": 3},
-        "mlp": {"dense": 1, "sparse": 4}}
-    assert summary["moe_experts_held"] == [0, 4, 16]
-    # _HELD_ROWS x the even share of 2 x 32 x 4 x 4 / 16 = 64 rows
-    assert summary["moe_row_bounds"] == [int(moe_ops._HELD_ROWS * 64)] == [192]
-
-
-# -- the comparison is tight enough: what is left out shows ---------------------
-
-OMISSIONS = ["head_gate", "window", "partial_rotary", "attention_factor", "scale",
-             "renorm", "shared"]
+    shapes={"layers/full_dense/attn/qkv/w": (1, 64, (4 + 4) * 16),
+            "layers/sliding_sparse/attn/qkv/w": (3, 64, (6 + 4) * 16),
+            "layers/full_sparse/attn/qkv/w": (1, 64, (4 + 4) * 16),
+            "layers/full_sparse/mlp/experts/down": (1, 4, 32, 64),
+            "layers/full_sparse/mlp/router/w": (1, 64, 16)},
+    refusals={
+        "pipeline": ({}, {"pipeline_model_parallel_size": 2}, "pipeline_model_parallel_size"),
+        "tensor": ({}, {"tensor_model_parallel_size": 2}, "tensor_model_parallel_size"),
+        "context": ({}, {"context_parallel_size": 2}, "context_parallel_size"),
+        "held-under-ep": ({}, {"expert_model_parallel_size": 2}, "num_experts_held"),
+        "held-range": ({"num_experts_held": [4, 20]}, {}, "num_experts_held"),
+        "heads-differ-in-a-kind": ({"num_attention_heads_per_layer": [4, 6, 6, 5, 4]}, {},
+                                   "num_attention_heads_per_layer"),
+        "heads-over-kv": ({"num_attention_heads_per_layer": {"sliding_attention": 5}}, {},
+                          "num_key_value_heads"),
+        "short-list": ({"layer_types": ["full_attention"] * 3}, {}, "layer_types"),
+        "unknown-mlp": ({"mlp_layer_types": ["dense", "sparse", "sparse", "sparse", "moe"]}, {},
+                        "mlp_layer_types"),
+        "no-window": ({"sliding_window": None}, {}, "sliding_window"),
+        "gating": ({"gating": "per-element"}, {}, "gating"),
+        "rope-type": ({"rope_parameters": {**ROPE, "full_attention": {"rope_type": "llama3"}}},
+                      {}, "rope_type")},
+    # 4 slots a token x 4 of 16 held = 1 expected slot, + the shared expert
+    flops=(({}, {"attention": 2 * _FULL + 3 * _SLIDING,
+                 "mlp": 6 * _H * 128 + 4 * 6 * _H * (32 + 1 * 32),
+                 "router": 4 * 2 * _H * 16, "head": 2 * _H * 256}),
+           ({"num_experts_held": None}, {"mlp": 6 * _H * 128 + 4 * 6 * _H * (32 + 4 * 32)})),
+    shares=(("full_sparse", 2), ("full_sparse", 4)),
+    summary={"layer_kinds": {"attention": {"full_attention": 2, "sliding_attention": 3},
+                             "mlp": {"dense": 1, "sparse": 4}},
+             "moe_experts_held": [0, 4, 16],
+             # _HELD_ROWS x the even share of 2 x 32 x 4 x 4 / 16 = 64 rows
+             "moe_row_bounds": [int(moe_ops._HELD_ROWS * 64)]},
+    example=("hf_laguna_s_2_1_config.yaml",
+             ("layer_types", "mlp_layer_types", "num_attention_heads_per_layer", "rope_parameters"),
+             {"model.num_hidden_layers": 9,
+              "model.num_attention_heads_per_layer": [4, 6, 6, 6] * 12},
+             {"layer_kinds": {"attention": {"full_attention": 3, "sliding_attention": 6},
+                              "mlp": {"dense": 1, "sparse": 8}}}))
 
 
 @pytest.fixture(scope="module")
-def parity(reference):
-    """The program's float32 loss and gradients on spread-out weights, and a
-    comparison of them with the reference's with something left out."""
-    cfg = config()
-    params = spread(laguna.init_params(jax.random.PRNGKey(7), cfg, FP32))
-    toks = tokens(seed=4)
-    loss, grads = value_and_grads(
-        lambda p: laguna.forward(p, batch_of(toks), cfg, FP32)[0], params)
-    c = reference.dims(MODEL)
-
-    def against(left_out=()):
-        ref_loss, ref_grads = value_and_grads(
-            lambda p: reference.microbatch_loss(p, toks, c, left_out=left_out), params)
-        return abs(float(loss) - float(ref_loss)), worst_gap(grads, ref_grads)
-
-    return against
+def programs():
+    return family_ladder.Programs(TOY)
 
 
-def test_nothing_left_out_is_parity(parity):
-    loss_gap, grad_gap = parity()
-    assert loss_gap < 1e-5 and grad_gap < 5e-5
+class TestLadder(family_ladder.Ladder):
+    toy = TOY
+
+    def test_the_toy_runs_held_rows_are_a_share_of_the_even_share(self, trained):
+        for r in trained["logged"]:
+            assert r["moe/held_rows_share"] == pytest.approx(
+                r["moe/held_rows"] / (2 * SEQ * 4 * 4 / 16), rel=1e-6)
+            assert 0 < r["router_aux_loss"] < 0.01
 
 
-@pytest.mark.parametrize("omission", OMISSIONS)
-def test_an_omission_fails_parity(parity, omission):
-    """Each part of the layer that the configuration states, left out of the
-    reference alone, moves a gradient leaf by a hundred times the rounding."""
-    loss_gap, grad_gap = parity(left_out=(omission,))
-    assert grad_gap > 5e-3, (omission, loss_gap, grad_gap)
-
-
-# -- the experts' shares add up -------------------------------------------------
-
-
-@pytest.mark.parametrize("shares", [2, 4])
-def test_the_shares_routed_parts_and_one_shared_expert_make_the_uncut_layer(reference, shares):
-    """A sparse layer's MLP output with all 16 experts in one program equals
-    the sum over ``shares`` chips of what each makes of the experts it holds,
-    plus the shared expert counted once; and both equal the uncut reference."""
-    cfg = config(num_experts_held=None)
-    layer = jax.tree_util.tree_map(
-        lambda a: a[0], spread(laguna.init_params(jax.random.PRNGKey(2), cfg, FP32))
-        ["layers"]["full_sparse"]["mlp"])
-    assert layer["experts"]["gate_up"].shape[0] == 16
-    z = jax.random.normal(jax.random.PRNGKey(3), (2, SEQ, 64), jnp.float32)
-
-    def block(params, held):
-        moe = dataclasses.replace(cfg.moe, experts_held=held)
-        with jax.default_matmul_precision("highest"):
-            return moe_ops.moe_block(params, z, moe, compute_dtype=jnp.float32)[0]
-
-    whole = block(layer, None)
-    per = 16 // shares
-    routed = sum(block({"router": layer["router"], "experts": jax.tree_util.tree_map(
-        lambda a, s=s: a[s * per:(s + 1) * per], layer["experts"])},
-        (s * per, (s + 1) * per)) for s in range(shares))
-    shared = moe_ops._shared_expert(layer["shared"], z, jnp.float32)
-    np.testing.assert_allclose(np.asarray(routed + shared), np.asarray(whole),
-                               rtol=1e-4, atol=1e-5)
-    c = {**reference.dims(MODEL), "lo": 0, "hi": 16}
-    with jax.default_matmul_precision("highest"):
-        uncut, _ = reference.expert_block(layer, z.reshape(-1, 64), c,
-                                          reference.plain._matmul(None))
-    np.testing.assert_allclose(np.asarray(whole).reshape(-1, 64), np.asarray(uncut),
-                               rtol=1e-4, atol=1e-5)
-    # a share alone is not the layer: most of a token's experts lie elsewhere
-    assert float(jnp.linalg.norm(block(
-        {**layer, "experts": jax.tree_util.tree_map(lambda a: a[:per], layer["experts"])},
-        (0, per)) - whole)) > 0.1 * float(jnp.linalg.norm(whole))
+config = TOY.config
 
 
 # -- the held rows' bound -------------------------------------------------------
 
 
 @pytest.mark.parametrize("bound", [0.25, 0.6], ids=["many-slices", "two-slices"])
-def test_a_step_past_the_bound_gives_the_same_loss_and_gradients(monkeypatch, bound):
+def test_a_step_past_the_bound_gives_the_same_loss_and_gradients(programs, monkeypatch, bound):
     """Forced past the bound (a bound under the rows held), the block runs
     slices of the sorted rows; the loss and every gradient are those of the
     one pass under the bound, and the counter says which way a step went."""
     cfg = config()
-    params = spread(laguna.init_params(jax.random.PRNGKey(5), cfg, FP32))
-    batch = batch_of(tokens(seed=6))
-
-    def run():
-        with jax.default_matmul_precision("highest"):
-            return jax.jit(jax.value_and_grad(
-                lambda p: laguna.forward(p, batch, cfg, FP32), has_aux=True))(params)
-
-    (under, under_aux), under_grads = run()
-    monkeypatch.setattr(moe_ops, "_HELD_ROWS", bound)
-    (past, past_aux), past_grads = run()
+    params, toks = programs.weights(5), programs.tokens(6)
+    (under, under_aux), under_grads = programs.program(params, toks)
+    monkeypatch.setattr(moe_ops, "_HELD_ROWS", bound)    # read when traced: a program of its own
+    with jax.default_matmul_precision("highest"):
+        (past, past_aux), past_grads = jax.jit(jax.value_and_grad(
+            lambda p: laguna.forward(p, {"input_ids": toks, "labels": toks}, cfg, FP32),
+            has_aux=True))(params)
     assert float(under_aux["moe/row_bound"]) == 0.0 and float(past_aux["moe/row_bound"]) == 1.0
     assert float(past_aux["moe/held_rows"]) == float(under_aux["moe/held_rows"])
     assert float(past_aux["moe/held_rows_share"]) > bound
@@ -362,28 +208,9 @@ def test_the_bound_is_a_multiple_of_the_even_share_and_never_over_the_rows(
 @pytest.mark.parametrize("nh, window", [(12, 64), (18, 64), (12, None)],
                          ids=["group-6-window", "group-9-window", "group-6-causal"])
 def test_flash_matches_core_at_gqa_groups_of_6_and_9(nh, window):
-    """The flash kernels, interpret mode, against core attention: 2 kv heads
-    shared by groups of 6 and 9 query heads, a window of 64 under key tiles of
-    128 (narrower than the tile, as 512 is under the default 2048)."""
-    ks = jax.random.split(jax.random.PRNGKey(nh), 4)
-    q = jax.random.normal(ks[0], (1, 256, nh, 128), jnp.float32)
-    k = jax.random.normal(ks[1], (1, 256, 2, 128), jnp.float32)
-    v = jax.random.normal(ks[2], (1, 256, 2, 128), jnp.float32)
-    ct = jax.random.normal(ks[3], (1, 256, nh, 128), jnp.float32)
-
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v) * ct)
-
-    flash = lambda q, k, v: flash_attention(  # noqa: E731
-        q, k, v, causal=True, sliding_window=window, block_q=128, block_kv=128, interpret=True)
-    core = lambda q, k, v: attn_ops.core_attention(  # noqa: E731
-        q, k, v, causal=True, sliding_window=window)
-    with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(np.asarray(flash(q, k, v)), np.asarray(core(q, k, v)),
-                                   rtol=2e-4, atol=2e-4)
-        for a, b in zip(jax.grad(loss(flash), (0, 1, 2))(q, k, v),
-                        jax.grad(loss(core), (0, 1, 2))(q, k, v)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3)
+    """2 kv heads shared by groups of 6 and 9 query heads, a window of 64 under
+    key tiles of 128 (narrower than the tile, as 512 is under the default 2048)."""
+    family_ladder.flash_matches_core(nh, 1, nh, 2, 128, 128, window=window)
 
 
 # -- the rotary embeddings -------------------------------------------------------
@@ -429,7 +256,7 @@ def test_the_published_stack_is_three_scans():
     assert laguna.stack_plan([f] * 6) == [("run", f, 6)]
 
 
-def test_a_stack_of_two_periods_runs_as_the_layers_one_by_one(reference):
+def test_a_stack_of_two_periods_runs_as_the_layers_one_by_one(programs):
     """1 + 8 layers (a prefix and two periods): the scan over periods takes
     each kind's layers from its stack in layer order."""
     deep = {**MODEL, "num_hidden_layers": 9,
@@ -437,38 +264,9 @@ def test_a_stack_of_two_periods_runs_as_the_layers_one_by_one(reference):
             "mlp_layer_types": MODEL["mlp_layer_types"] + ["sparse"] * 4}
     cfg = laguna.LagunaConfig.from_config(deep, {})
     assert [seg[0] for seg in laguna.stack_plan(cfg.kinds)] == ["run", "periods"]
-    params = spread(laguna.init_params(jax.random.PRNGKey(8), cfg, FP32))
-    toks = tokens(seed=3)
-    loss, grads = value_and_grads(
-        lambda p: laguna.forward(p, batch_of(toks), cfg, FP32)[0], params)
-    c = reference.dims(deep)
-    ref_loss, ref_grads = value_and_grads(
-        lambda p: reference.microbatch_loss(p, toks, c), params)
-    assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
-    assert worst_gap(grads, ref_grads) < 5e-5
-
-
-# -- what is not wired is refused by name ---------------------------------------
-
-
-@pytest.mark.parametrize("model, ds, named", [
-    ({}, {"pipeline_model_parallel_size": 2}, "pipeline_model_parallel_size"),
-    ({}, {"tensor_model_parallel_size": 2}, "tensor_model_parallel_size"),
-    ({}, {"context_parallel_size": 2}, "context_parallel_size"),
-    ({}, {"expert_model_parallel_size": 2}, "num_experts_held"),
-    ({"num_experts_held": [4, 20]}, {}, "num_experts_held"),
-    ({"num_attention_heads_per_layer": [4, 6, 6, 5, 4]}, {}, "num_attention_heads_per_layer"),
-    ({"num_attention_heads_per_layer": {"sliding_attention": 5}}, {}, "num_key_value_heads"),
-    ({"layer_types": ["full_attention"] * 3}, {}, "layer_types"),
-    ({"mlp_layer_types": ["dense", "sparse", "sparse", "sparse", "moe"]}, {}, "mlp_layer_types"),
-    ({"sliding_window": None}, {}, "sliding_window"),
-    ({"gating": "per-element"}, {}, "gating"),
-    ({"rope_parameters": {**ROPE, "full_attention": {"rope_type": "llama3"}}}, {}, "rope_type"),
-], ids=["pipeline", "tensor", "context", "held-under-ep", "held-range", "heads-differ-in-a-kind",
-        "heads-over-kv", "short-list", "unknown-mlp", "no-window", "gating", "rope-type"])
-def test_the_config_refuses_by_the_keys_name(model, ds, named):
-    with pytest.raises(ValueError, match=named):
-        laguna.LagunaConfig.from_config({**MODEL, **model}, ds)
+    found = programs.against(weights=8, tokens=3, model=deep)
+    assert found["loss"] == pytest.approx(found["ref_loss"], rel=2e-6)
+    assert found["worst"] < 5e-5
 
 
 def test_a_depth_under_the_lists_runs_the_leading_layers():
@@ -491,52 +289,3 @@ def test_window_layers_take_key_tiles_no_wider_than_their_window():
     narrow = config(sliding_window=512, fusions={"flash_attention": True, "flash_block_kv": 256})
     assert narrow.block_kv("sliding_attention") == 256 == narrow.block_kv("full_attention")
     assert config().block_kv("full_attention") is None
-
-
-def test_the_flops_count_caps_the_window_and_counts_the_held_slots():
-    cfg = config()
-    bd = laguna.flops_breakdown(cfg, 4096)
-    h, d = 64, 16
-    full = 2 * h * (4 + 4) * d + 2 * 4 * d * h + 2 * h * 4 + 4 * 4 * d * (4097 / 2)
-    keys = (8 * 9 / 2 + (4096 - 8) * 8) / 4096
-    sliding = 2 * h * (6 + 4) * d + 2 * 6 * d * h + 2 * h * 6 + 4 * 6 * d * keys
-    assert bd["attention"] == pytest.approx(2 * full + 3 * sliding, rel=1e-12)
-    # 4 slots a token x 4 of 16 held = 1 expected slot, + the shared expert
-    assert bd["mlp"] == pytest.approx(6 * h * 128 + 4 * 6 * h * (32 + 1 * 32), rel=1e-12)
-    assert bd["router"] == 4 * 2 * h * 16 and bd["head"] == 2 * h * 256
-    uncut = laguna.flops_breakdown(config(num_experts_held=None), 4096)
-    assert uncut["mlp"] == pytest.approx(6 * h * 128 + 4 * 6 * h * (32 + 4 * 32), rel=1e-12)
-
-
-# -- through nxdt-train -----------------------------------------------------------
-
-
-def test_the_example_config_trains_at_toy_counts_on_the_cpu_mesh(tmp_path, devices8):
-    """``examples/conf/hf_laguna_s_2_1_config.yaml`` at toy counts through
-    ``Trainer.from_config(cfg).fit()`` on ep 4 x dp 2: every expert resident
-    somewhere, the rows exchanged between the chips that hold them."""
-    from neuronx_distributed_training_tpu.config.loader import load_config
-    from neuronx_distributed_training_tpu.trainer.loop import Trainer
-
-    toy = {f"model.{k}": v for k, v in MODEL.items()
-           if k not in ("architecture", "num_experts_held", "layer_types", "mlp_layer_types",
-                        "num_attention_heads_per_layer", "rope_parameters")}
-    cfg = load_config(str(ROOT / "examples/conf/hf_laguna_s_2_1_config.yaml"), {
-        **toy, "model.num_hidden_layers": 9, "model.fusions.flash_attention": False,
-        "model.num_attention_heads_per_layer": [4, 6, 6, 6] * 12,
-        "distributed_strategy.expert_model_parallel_size": 4,
-        "data.synthetic": True, "data.seq_length": SEQ, "data.global_batch_size": 8,
-        "trainer.max_steps": 3, "trainer.log_every_n_steps": 1,
-        "exp_manager.exp_dir": str(tmp_path), "exp_manager.resume_if_exists": False,
-        "exp_manager.checkpoint_callback_params": None,
-        "debug": {"validate_sharding": True}})
-    trainer = Trainer.from_config(cfg, devices=devices8, enable_checkpointing=False)
-    trainer.fit()
-    log_dir = Path(trainer.exp.log_dir)
-    rows = [json.loads(line) for line in open(log_dir / "metrics.jsonl")]
-    assert [r["step"] for r in rows] == [1, 2, 3]
-    assert all(np.isfinite(r["loss"]) and r["moe/recv_rows_share_max"] >= 1.0 for r in rows)
-    summary = json.load(open(log_dir / "run_summary.json"))
-    assert summary["model_family"] == "LagunaConfig"
-    assert summary["layer_kinds"]["attention"] == {"full_attention": 3, "sliding_attention": 6}
-    assert summary["moe_token_shards"] == 8 and "moe_experts_held" not in summary

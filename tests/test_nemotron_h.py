@@ -3,17 +3,16 @@ state-space mixers, sigmoid-routed non-gated ``relu2`` experts beside a shared
 expert, grouped-query attention with no position embedding, under an untied
 head.  Held against the benchmark's plain reference
 (``benchmark/references/nemotron_h.py``, float32, nothing of the program, the
-scan as the recurrence itself); ``ops/ssd.py`` held against an explicit
-recurrence over positions at a length that is no multiple of the chunk, with
-left padding and packed documents too; the convolution with its bias and
-``silu`` against a loop; each of four omissions shown to fail the parity the
-first test holds; the shares of all held ranges shown to add up to the whole;
-the accepted families' programs shown untouched."""
+scan as the recurrence itself) by the rungs of ``tests/family_ladder.py``, each
+of four omissions and an omitted bias update shown to fail the parity the
+first holds and the shares of all held ranges shown to add up to the whole;
+``ops/ssd.py`` held against an explicit recurrence over positions at a length
+that is no multiple of the chunk, with left padding and packed documents too;
+the convolution with its bias and ``silu`` against a loop; the accepted
+families' programs shown untouched."""
 
 import dataclasses
 import hashlib
-import importlib
-import json
 from pathlib import Path
 
 import jax
@@ -21,8 +20,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.harness import check as checks
+import family_ladder
 from benchmark.reference import leaf_names
+from family_ladder import FP32, worst_gap
 from neuronx_distributed_training_tpu.models import nemotron_h as nh
 from neuronx_distributed_training_tpu.models.family import resolve
 from neuronx_distributed_training_tpu.models.laguna import stack_plan
@@ -32,15 +32,15 @@ from neuronx_distributed_training_tpu.ops import norm as norm_ops
 from neuronx_distributed_training_tpu.ops import short_conv as conv_ops
 from neuronx_distributed_training_tpu.ops import ssd as ssd_ops
 from neuronx_distributed_training_tpu.optim import adamw
-from neuronx_distributed_training_tpu.utils.dtypes import DtypePolicy
 
-#: the published shape at toy widths: the benchmark's depth 9 (MEMEM*EME), 4
-#: Mamba heads of 8 in 2 groups with a state of 16, 4 taps, chunks of 8, 4 query
+#: the published shape at toy widths: every kind of layer, the two that carry
+#: more than weights twice and as one period of the plan (``MEME*``), 4 Mamba
+#: heads of 8 in 2 groups with a state of 16, 4 taps, chunks of 8, 4 query
 #: heads on 1 key/value head of 16 dims, 16 experts of 24 of which a token
 #: takes 3 and 4 are held, a shared expert of 48, an untied head
 MODEL = dict(
-    architecture="nemotron_h", vocab_size=256, hidden_size=64, num_hidden_layers=9,
-    hybrid_override_pattern="MEMEM*EMEMEM*", mamba_num_heads=4, mamba_head_dim=8,
+    architecture="nemotron_h", vocab_size=256, hidden_size=64, num_hidden_layers=5,
+    hybrid_override_pattern="MEME*", mamba_num_heads=4, mamba_head_dim=8,
     ssm_state_size=16, n_groups=2, conv_kernel=4, chunk_size=8, use_conv_bias=True,
     num_attention_heads=4, num_key_value_heads=1, head_dim=16, layer_norm_epsilon=1e-5,
     n_routed_experts=16, num_experts_per_tok=3, num_experts_held=[0, 4],
@@ -48,95 +48,86 @@ MODEL = dict(
     norm_topk_prob=True, routed_scaling_factor=2.5, router_bias_update_rate=0.001,
     initializer_range=0.02, tie_word_embeddings=False,
     activations_checkpoint_granularity="full")
-OPTIM = {"lr": 1e-3, "weight_decay": 0.1, "betas": [0.9, 0.95], "eps": 1e-8,
-         "sched": {"warmup_steps": 0, "max_steps": 100}}
-FP32 = DtypePolicy.from_precision_config({"type": "fp32"})
 SEQ = 28   # no multiple of the chunk
-BIAS = ("layers", "moe", "mlp", "router", "bias")
+MIXER, MOE = "layers/mamba/mixer/", "layers/moe/mlp/"
+_H = 64
+_MAMBA = 2 * _H * (32 + 96 + 4) + 2 * 32 * _H + 2 * 4 * 96 + 6 * 32 * 16
+_ATTENTION = 2 * _H * (4 + 2) * 16 + 2 * 4 * 16 * _H + 4 * 4 * 16 * 4097 / 2
+
+TOY = family_ladder.Toy(
+    module=nh, config_class=nh.NemotronHConfig, reference="nemotron_h", model=MODEL, seq=SEQ,
+    bias=(("layers", "moe", "mlp", "router", "bias"),),
+    #: what this family brings (the taps, the route's bias and the renormalising
+    #: are held by tests/test_lfm2.py and tests/test_kanana.py on the code they share)
+    omissions=("conv_silu", "gate", "norm_groups", "skip"),
+    leaf_tol=5e-5, moved=("norm", "head_scales", "conv/bias"), unscaled=("embed",),
+    shapes={
+        "lm_head/w": (64, 256),                                        # untied
+        MIXER + "in_proj/w": (2, 64, 32 + (32 + 2 * 2 * 16) + 4),
+        MIXER + "conv/w": (2, 4, 96), MIXER + "conv/bias": (2, 96),
+        MIXER + "out_proj/w": (2, 32, 64),
+        "layers/attention/attn/qkv/w": (1, 64, (4 + 2) * 16),
+        MOE + "experts/gate_up": (2, 4, 64, 24),                       # 4 of 16 held; no gate
+        MOE + "experts/down": (2, 4, 24, 64), MOE + "shared/gate_up/w": (2, 64, 48),
+        MOE + "router/w": (2, 64, 16)},
+    refusals={
+        "pipeline": ({}, {"pipeline_model_parallel_size": 2}, "pipeline_model_parallel_size"),
+        "tensor": ({}, {"tensor_model_parallel_size": 2}, "tensor_model_parallel_size"),
+        "context": ({}, {"context_parallel_size": 2}, "context_parallel_size.*state S"),
+        "sequence-parallel": ({}, {"sequence_parallel": True}, "sequence_parallel.*state S"),
+        "held-under-ep": ({}, {"expert_model_parallel_size": 2}, "num_experts_held"),
+        "held-range": ({"num_experts_held": [4, 20]}, {}, "num_experts_held"),
+        "dense-mlp-layer": ({"hybrid_override_pattern": "M-M*E"}, {}, "'-'.*dense MLP"),
+        "unknown-layer": ({"hybrid_override_pattern": "MXM*E"}, {}, "unknown"),
+        "short-pattern": ({"hybrid_override_pattern": "MEM"}, {}, "hybrid_override_pattern has 3"),
+        "n-group": ({"n_group": 2}, {}, "n_group"),
+        "topk-group": ({"topk_group": 2}, {}, "topk_group"),
+        "proj-bias": ({"mamba_proj_bias": True}, {}, "mamba_proj_bias"),
+        "no-conv-bias": ({"use_conv_bias": False}, {}, "use_conv_bias"),
+        "gated-experts": ({"mlp_hidden_act": "silu"}, {}, "mlp_hidden_act"),
+        "bias-never-moves": ({"router_bias_update_rate": 0.0}, {}, "router_bias_update_rate")},
+    # 3 slots a token x 4 of 16 held = 0.75 expected slots, beside the shared expert
+    flops=(({}, {"attention": 2 * _MAMBA + _ATTENTION, "mlp": 2 * 4 * _H * (24 * 0.75 + 48),
+                 "router": 2 * 2 * _H * 16, "head": 2 * _H * 256}),),
+    shares=(("moe", 16),),
+    summary={"model_family": "NemotronHConfig",
+             "layer_kinds": {"mamba": 2, "moe": 2, "attention": 1},
+             "ssd": {"heads": 4, "head_dim": 8, "state": 16, "groups": 2, "chunk": 8,
+                     "way": ssd_ops.WAY, "bytes_per_token": 2 * (2 * 32 + 2 * 32 + 4)},
+             "mamba_conv": {"taps": 4, "channels": 96, "way": conv_ops.CONV_WAY},
+             "moe_expert_act": "relu2", "attention_positions": "none",
+             "moe_experts_held": [0, 4, 16], "moe_score_func": "sigmoid",
+             # _HELD_ROWS x the even share, 2 x 28 x 3 x 4 / 16 = 42 rows, in eights
+             "moe_row_bounds": [8 * int(np.ceil(moe_ops._HELD_ROWS * 42 / 8))]},
+    example=("hf_nemotron3_nano_30b_a3b_config.yaml", (), {"data.micro_batch_size": 1},
+             {"layer_kinds": {"mamba": 2, "moe": 2, "attention": 1}}))
 
 
 @pytest.fixture(scope="module")
-def reference():
-    return importlib.import_module("benchmark.references.nemotron_h")
+def programs():
+    return family_ladder.Programs(TOY)
 
 
-def config(**over):
-    return nh.NemotronHConfig.from_config({**MODEL, **over}, {})
+class TestLadder(family_ladder.BiasLadder):
+    toy = TOY
+
+    def test_the_seeded_scalars_of_a_head_and_every_stack_rematerialized_whole(
+            self, programs, trained):
+        scales = programs.weights(11, spread=False)["layers"]["mamba"]["mixer"]["head_scales"]
+        np.testing.assert_allclose(np.asarray(scales["A_log"][0]), np.log(np.arange(1, 5)),
+                                   rtol=1e-6)
+        step = np.logaddexp(np.asarray(scales["dt_bias"], np.float64), 0.0)     # softplus
+        assert 0.001 * 0.999 <= step.min() and step.max() <= 0.1 * 1.001
+        remat = trained["summary"]["remat"]
+        assert set(remat) == {"mamba", "moe", "attention"}
+        assert all(entry["granularity"] == "full" for entry in remat.values())
 
 
-def tokens(seed=1, rows=2, seq=SEQ):
-    return jax.random.randint(jax.random.PRNGKey(seed), (rows, seq), 0, MODEL["vocab_size"])
+config = TOY.config
 
 
-def batch_of(toks):
-    return {"input_ids": toks, "labels": toks}
-
-
-def at(tree, path):
-    for key in path:
-        tree = tree[key]
-    return tree
-
-
-def spread(params, seed=9):
-    """Norm scales, the convolution's bias and the per-head scalars moved off
-    their initial values, every other weight grown fivefold and the selection
-    bias off 0, so that a norm, a gate, a bias or the skip left out shows."""
-    def leaf(path, x):
-        name = "/".join(str(getattr(p, "key", p)) for p in path)
-        key = jax.random.fold_in(jax.random.PRNGKey(seed), sum(map(ord, name)))
-        if "norm" in name or "head_scales" in name or name.endswith("conv/bias"):
-            return x + 0.1 * jax.random.normal(key, x.shape, x.dtype)
-        if name.endswith("router/bias"):
-            return 0.1 * jax.random.normal(key, x.shape, x.dtype)
-        return x * (1.0 if "embed" in name else 5.0)
-    return jax.tree_util.tree_map_with_path(leaf, params)
-
-
-def worst_gap(a, b):
-    """Largest relative gap of two gradient trees, leaf by leaf."""
-    return max(float(jnp.linalg.norm(x - y) / (jnp.linalg.norm(y) + 1e-30))
-               for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)))
-
-
-# -- against the reference ----------------------------------------------------
-
-
-def test_the_seeded_weights_are_the_references_leaf_for_leaf(reference):
-    cfg = config()
-    key = jax.random.PRNGKey(11)
-    mine = jax.jit(lambda k: nh.init_params(k, cfg, FP32))(key)
-    theirs = jax.jit(lambda k: reference.init_params(MODEL, k))(key)
-    assert reference.leaf_names(mine) == reference.leaf_names(theirs)
-    for name, a, b in zip(reference.leaf_names(mine), jax.tree_util.tree_leaves(mine),
-                          jax.tree_util.tree_leaves(theirs)):
-        assert a.shape == b.shape and bool(jnp.all(a == b)), name
-    assert sorted(mine["layers"]) == ["attention", "mamba", "moe"]
-    assert mine["lm_head"]["w"].shape == (64, 256)                      # untied
-    mixer = mine["layers"]["mamba"]["mixer"]
-    assert mixer["in_proj"]["w"].shape == (4, 64, 32 + (32 + 2 * 2 * 16) + 4)
-    assert mixer["conv"]["w"].shape == (4, 4, 96) and mixer["conv"]["bias"].shape == (4, 96)
-    assert mixer["out_proj"]["w"].shape == (4, 32, 64)
-    np.testing.assert_allclose(np.asarray(mixer["head_scales"]["A_log"][0]),
-                               np.log(np.arange(1, 5)), rtol=1e-6)
-    step = np.asarray(jax.nn.softplus(mixer["head_scales"]["dt_bias"]))
-    assert 0.001 * 0.999 <= step.min() and step.max() <= 0.1 * 1.001
-    assert mine["layers"]["attention"]["attn"]["qkv"]["w"].shape == (1, 64, (4 + 2) * 16)
-    mlp = mine["layers"]["moe"]["mlp"]
-    assert mlp["experts"]["gate_up"].shape == (4, 4, 64, 24)            # 4 of 16 held; no gate
-    assert mlp["experts"]["down"].shape == (4, 4, 24, 64)
-    assert mlp["shared"]["gate_up"]["w"].shape == (4, 64, 48)
-    assert mlp["router"]["w"].shape == (4, 64, 16) and not np.any(np.asarray(mlp["router"]["bias"]))
-    specs = nh.param_specs(cfg)
-    is_spec = lambda x: isinstance(x, jax.sharding.PartitionSpec)  # noqa: E731
-    assert jax.tree_util.tree_structure(specs, is_leaf=is_spec) == jax.tree_util.tree_structure(mine)
-    for spec, leaf in zip(jax.tree_util.tree_leaves(specs, is_leaf=is_spec),
-                          jax.tree_util.tree_leaves(mine)):
-        assert len(spec) == leaf.ndim
-
-
-def test_the_stack_plan_and_what_takes_no_weight_decay():
-    cfg = config()
+def test_the_stack_plan_and_what_takes_no_weight_decay(programs):
+    cfg = config(num_hidden_layers=9, hybrid_override_pattern="MEMEM*EMEMEM*")   # the benchmark's
     assert [k for (k,) in cfg.kinds] == ["mamba", "moe", "mamba", "moe", "mamba", "attention",
                                          "moe", "mamba", "moe"]
     plan = stack_plan(cfg.kinds)
@@ -148,7 +139,8 @@ def test_the_stack_plan_and_what_takes_no_weight_decay():
         {**MODEL, "num_hidden_layers": 52,
          "hybrid_override_pattern": "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"}, {})
     assert (full.count("mamba"), full.count("moe"), full.count("attention")) == (23, 23, 6)
-    params = nh.init_params(jax.random.PRNGKey(0), cfg, FP32)
+    assert [seg[0] for seg in stack_plan(config().kinds)] == ["periods", "run"]        # the toy's
+    params = programs.weights(0, spread=False)
     mask = adamw.decay_mask(params, adamw.AdamWConfig())
     free = {n for n, m in zip(leaf_names(params), jax.tree_util.tree_leaves(mask)) if m == 0.0}
     assert free == {
@@ -157,157 +149,6 @@ def test_the_stack_plan_and_what_takes_no_weight_decay():
         "layers/mamba/mixer/conv/bias", "layers/mamba/mixer/head_scales/A_log",
         "layers/mamba/mixer/head_scales/D", "layers/mamba/mixer/head_scales/dt_bias",
         "layers/moe/mlp/router/bias"}
-
-
-#: the benchmark's nine layers, and the three kinds once (what the omissions run)
-DEPTHS = {"nine": MODEL, "three": {**MODEL, "num_hidden_layers": 3,
-                                   "hybrid_override_pattern": "ME*"}}
-
-
-@pytest.fixture(scope="module")
-def parity(reference):
-    """``against(depth, left_out)``: the program's float32 loss and gradients
-    on spread-out weights beside the reference's with something left out."""
-    mine: dict = {}
-
-    def program(depth):
-        if depth not in mine:
-            model = DEPTHS[depth]
-            cfg = nh.NemotronHConfig.from_config(model, {})
-            params = spread(nh.init_params(jax.random.PRNGKey(7), cfg, FP32))
-            toks = tokens(seed=4)
-            with jax.default_matmul_precision("highest"):
-                (loss, aux), grads = jax.jit(jax.value_and_grad(
-                    lambda p: nh.forward(p, batch_of(toks), cfg, FP32), has_aux=True))(params)
-            mine[depth] = (model, params, toks, loss, grads, aux)
-        return mine[depth]
-
-    def against(depth, left_out=()):
-        model, params, toks, loss, grads, aux = program(depth)
-        c = reference.dims(model)
-        with jax.default_matmul_precision("highest"):
-            (ref_loss, loads), ref_grads = jax.jit(jax.value_and_grad(
-                lambda p: reference.microbatch_loss(p, toks, c, left_out=left_out),
-                has_aux=True))(params)
-        return {"loss": (float(loss), float(ref_loss)), "grads": (grads, ref_grads),
-                "loads": (aux[nh.COUNTS], loads), "gap": worst_gap(grads, ref_grads)}
-
-    return against
-
-
-def test_loss_and_every_gradient_match_the_reference_in_float32(reference, parity):
-    """At the benchmark's depth under ``full``, whose plan holds a periodic
-    segment: the loads come back in the order of the sparse stack."""
-    found = parity("nine")
-    loss, ref_loss = found["loss"]
-    assert loss == pytest.approx(ref_loss, rel=2e-6)
-    grads, ref_grads = found["grads"]
-    for name, g, r in zip(reference.leaf_names(grads), jax.tree_util.tree_leaves(grads),
-                          jax.tree_util.tree_leaves(ref_grads)):
-        assert float(jnp.linalg.norm(g - r)) <= 5e-5 * float(jnp.linalg.norm(r)), name
-    # the bias steers and is never weighed: its gradient is exactly zero on both sides;
-    # and the loads the rule reads are the reference's, layer for layer, expert for expert
-    assert not np.any(np.asarray(at(grads, BIAS))) and not np.any(np.asarray(at(ref_grads, BIAS)))
-    loads, ref_loads = found["loads"]
-    np.testing.assert_array_equal(np.asarray(loads), np.asarray(ref_loads))
-    assert float(jnp.sum(loads)) == 4 * 2 * SEQ * 3                    # layers x tokens x k
-
-
-#: what this family brings (the taps, the route's bias and the renormalising
-#: are held by tests/test_lfm2.py and tests/test_kanana.py on the code they share)
-OMISSIONS = ["conv_silu", "gate", "norm_groups", "skip"]
-
-
-@pytest.mark.parametrize("omission", [None] + OMISSIONS)
-def test_an_omission_fails_parity(parity, omission):
-    """Each part of a layer that the configuration states, left out of the
-    reference alone, moves a gradient leaf by a hundred times the rounding."""
-    if omission is None:
-        found = parity("three")
-        assert abs(found["loss"][0] - found["loss"][1]) < 1e-5 and found["gap"] < 5e-5
-        return
-    assert parity("three", left_out=(omission,))["gap"] > 5e-3, omission
-
-
-#: every kind, the two that carry more than weights twice (``MEM*E``): what
-#: three steps through the trainer need (the benchmark's nine layers are the
-#: parity test's)
-FIVE = {**MODEL, "num_hidden_layers": 5, "hybrid_override_pattern": "MEM*E"}
-
-
-@pytest.fixture(scope="module")
-def trained(reference, tmp_path_factory):
-    """``Trainer.from_config(cfg).fit()`` in float32, three steps of two
-    micro-batches, beside ``reference.run`` on the same rows."""
-    from neuronx_distributed_training_tpu.config.loader import load_config
-    from neuronx_distributed_training_tpu.data.loader import DataModule
-    from neuronx_distributed_training_tpu.trainer.loop import Trainer
-
-    seed, rows = 5, 4
-    steps = [np.asarray(tokens(seed=100 + k, rows=rows)) for k in range(3)]
-
-    class Rows(DataModule):
-        def fetch_rows(self, idx):
-            return {"input_ids": np.stack([steps[i // rows][i % rows] for i in idx])}
-
-    cfg = load_config({
-        "seed": seed, "model": {**FIVE, "optim": {"name": "adamw_fp32OptState", **OPTIM}},
-        "distributed_strategy": {"tensor_model_parallel_size": 1},
-        "data": {"global_batch_size": rows, "micro_batch_size": 2, "seq_length": SEQ},
-        "trainer": {"max_steps": 3, "log_every_n_steps": 1, "gradient_clip_val": 1.0},
-        "exp_manager": {"exp_dir": str(tmp_path_factory.mktemp("nemotron_h")),
-                        "name": "nemotron_h"},
-        "precision": {"type": "fp32"}})
-    trainer = Trainer.from_config(cfg, data_module=Rows(1 << 10, rows),
-                                  devices=jax.devices()[:1], enable_checkpointing=False)
-    with jax.default_matmul_precision("highest"):
-        trainer.fit()
-    log_dir = Path(trainer.exp.log_dir)
-    logged = [json.loads(line) for line in open(log_dir / "metrics.jsonl")]
-    ref = reference.run(FIVE, OPTIM, 1.0, [s.reshape(2, 2, SEQ) for s in steps], seed)
-    return trainer, logged, json.load(open(log_dir / "run_summary.json")), ref, seed
-
-
-def test_three_steps_match_the_reference_in_float32(reference, trained):
-    """The losses of three steps and the parameters' change, leaf by leaf, the
-    selection bias among them: three steps of the rule on both sides."""
-    trainer, logged, summary, ref, seed = trained
-    assert [r["loss"] for r in logged] == pytest.approx(ref["loss"], rel=1e-5)
-    dparam = checks.parameter_change_norms(reference, trainer.params, FIVE, seed)
-    gaps = checks.leaf_gaps(dparam, ref["dparam"])
-    assert max(gaps.values()) < 2e-4, max(gaps, key=gaps.get)
-    assert dparam["layers/moe/mlp/router/bias"] > 0.001 * np.sqrt(2 * 16) * 0.5
-    grad1 = checks.first_gradient_norms(reference, trainer.opt_state, 0.9)
-    assert set(grad1) == set(ref["grad1"])
-    for r in logged:
-        assert r["moe/row_bound"] == 0.0 and r["moe/held_rows"] > 0
-        assert 1.0 <= r["moe/load_max_share"] < 16 / 3 and "moe/held_rows_share" in r
-        assert not any(k.startswith("moe_expert_counts") for k in r)
-    assert [r["moe/bias_abs_max"] for r in logged] == pytest.approx([0.0, 0.001, 0.002])
-    assert summary["model_family"] == "NemotronHConfig"
-    assert summary["layer_kinds"] == {"mamba": 2, "moe": 2, "attention": 1}
-    assert summary["ssd"] == {"heads": 4, "head_dim": 8, "state": 16, "groups": 2, "chunk": 8,
-                              "way": ssd_ops.WAY, "bytes_per_token": 2 * (2 * 32 + 2 * 32 + 4)}
-    assert summary["mamba_conv"] == {"taps": 4, "channels": 96, "way": conv_ops.CONV_WAY}
-    assert summary["moe_expert_act"] == "relu2" and summary["attention_positions"] == "none"
-    assert summary["moe_experts_held"] == [0, 4, 16] and summary["moe_score_func"] == "sigmoid"
-    # _HELD_ROWS x the even share, 2 x 28 x 3 x 4 / 16 = 42 rows, in eights
-    assert summary["moe_row_bounds"] == [8 * int(np.ceil(moe_ops._HELD_ROWS * 42 / 8))] == [128]
-    assert set(summary["remat"]) == {"mamba", "moe", "attention"}
-    assert all(entry["granularity"] == "full" for entry in summary["remat"].values())
-
-
-def test_the_bias_moves_by_the_rule_and_by_nothing_of_adamws(trained):
-    """After three steps every element of the bias is a whole number of steps
-    of 0.001 (no decay, no moment's step mixed in) and the optimizer's moments
-    for it are exactly zero."""
-    trainer, *_ = trained
-    steps = np.asarray(at(trainer.params, BIAS), np.float64) / 0.001
-    np.testing.assert_allclose(steps, np.round(steps), atol=1e-3)
-    assert set(np.round(steps).astype(int).ravel()) <= {-3, -2, -1, 0, 1, 2, 3}
-    assert np.any(np.round(steps) != 0)
-    for moment in ("mu", "nu"):
-        assert not np.any(np.asarray(at(trainer.opt_state[moment], BIAS)))
 
 
 # -- the scan -------------------------------------------------------------------
@@ -393,10 +234,9 @@ def test_the_carried_state_is_float32(monkeypatch):
     assert "length=16" in text and "length=4" in text and "length=512" not in text
 
 
-def test_a_changed_token_moves_nothing_before_it():
-    cfg = nh.NemotronHConfig.from_config(DEPTHS["three"], {})
-    params = spread(nh.init_params(jax.random.PRNGKey(1), cfg, FP32))
-    toks = tokens(seed=2, rows=1)
+def test_a_changed_token_moves_nothing_before_it(programs):
+    cfg, params = config(), programs.weights(1)
+    toks = programs.tokens(2, rows=1)
     changed = toks.at[0, 13].set((toks[0, 13] + 1) % 256)
     logits = jax.jit(lambda t: nh.forward(params, {"input_ids": t}, cfg, FP32)[0])
     with jax.default_matmul_precision("highest"):
@@ -405,21 +245,21 @@ def test_a_changed_token_moves_nothing_before_it():
     assert np.all(np.any(a[0, 13:] != b[0, 13:], axis=-1))      # the state carries it to the end
 
 
-def test_left_padding_and_packed_documents_reach_all_three_mixers():
+def test_left_padding_and_packed_documents_reach_all_three_mixers(programs):
     """A left-padded row's real positions read what the row reads unpadded; a
     document packed behind another reads what it reads alone."""
-    cfg = nh.NemotronHConfig.from_config(
-        {**DEPTHS["three"], "activations_checkpoint_granularity": None}, {})
-    params = spread(nh.init_params(jax.random.PRNGKey(1), cfg, FP32))
-    toks = tokens(seed=3, rows=1, seq=24)
+    cfg, params = config(activations_checkpoint_granularity=None), programs.weights(1)
+    toks = programs.tokens(3, rows=1, seq=24)
+    rows = jnp.array([[0] * 8 + [1] * 16])
+
+    @jax.jit
+    def logits():
+        run = lambda **batch: nh.forward(params, batch, cfg, FP32)[0]  # noqa: E731
+        return (run(input_ids=toks[:, 8:]), run(input_ids=toks, attention_mask=rows),
+                run(input_ids=toks, segment_ids=rows))
+
     with jax.default_matmul_precision("highest"):
-        alone = np.asarray(nh.forward(params, {"input_ids": toks[:, 8:]}, cfg, FP32)[0])
-        mask = jnp.array([[0] * 8 + [1] * 16])
-        padded = np.asarray(nh.forward(
-            params, {"input_ids": toks, "attention_mask": mask}, cfg, FP32)[0])
-        packed = np.asarray(nh.forward(
-            params, {"input_ids": toks, "segment_ids": jnp.array([[0] * 8 + [1] * 16])},
-            cfg, FP32)[0])
+        alone, padded, packed = map(np.asarray, logits())
     np.testing.assert_allclose(padded[0, 8:], alone[0], rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(packed[0, 8:], alone[0], rtol=2e-4, atol=2e-5)
 
@@ -509,51 +349,32 @@ def test_the_gated_norm_gates_first_and_norms_inside_each_group():
 # -- the experts ------------------------------------------------------------------
 
 
-def test_relu2_experts_and_the_shares_of_all_sixteen_held_ranges_make_the_layer(reference):
+def test_relu2_experts_are_a_dense_sum_over_the_experts_beside_the_shared_expert(programs):
     """A sparse layer with all 16 non-gated experts in one program equals a
-    dense sum over the experts plus the shared expert; and the sum over 16
-    chips of what each makes of the one expert it holds, with the shared
-    expert counted once; and the uncut reference."""
-    cfg = config(num_experts_held=None)
-    layer = jax.tree_util.tree_map(
-        lambda a: a[0], spread(nh.init_params(jax.random.PRNGKey(2), cfg, FP32))
-        ["layers"]["moe"]["mlp"])
-    assert layer["experts"]["gate_up"].shape == (16, 64, 24)
-    z = jax.random.normal(jax.random.PRNGKey(3), (2, SEQ, 64), jnp.float32)
+    dense sum over the experts, each weighed by the route's gate, plus the
+    shared expert (the shares of the sixteen held ranges are the ladder's)."""
+    uncut = {**MODEL, "num_experts_held": None}
+    moe = nh.NemotronHConfig.from_config(uncut, {}).moe
 
-    def block(params, held):
-        moe = dataclasses.replace(cfg.moe, experts_held=held)
-        with jax.default_matmul_precision("highest"):
-            return moe_ops.moe_block(params, z, moe, compute_dtype=jnp.float32)
-
-    whole, whole_aux = block(layer, None)
-    flat = z.reshape(-1, 64)
-    with jax.default_matmul_precision("highest"):
-        probs, idx, _ = moe_ops.route(layer["router"], flat, cfg.moe)
+    @jax.jit
+    def both(params, key):
+        layer = jax.tree_util.tree_map(lambda a: a[0], params["layers"]["moe"]["mlp"])
+        assert layer["experts"]["gate_up"].shape == (16, 64, 24)
+        z = jax.random.normal(key, (2, SEQ, 64), jnp.float32)
+        whole, _ = moe_ops.moe_block(layer, z, moe, compute_dtype=jnp.float32)
+        flat = z.reshape(-1, 64)
+        probs, idx, _ = moe_ops.route(layer["router"], flat, moe)
         gates = jnp.zeros((flat.shape[0], 16)).at[jnp.arange(flat.shape[0])[:, None], idx].set(probs)
         every = jnp.einsum("tef,efh->teh", jnp.square(jax.nn.relu(
             jnp.einsum("th,ehf->tef", flat, layer["experts"]["gate_up"]))),
             layer["experts"]["down"])
         shared = (jnp.square(jax.nn.relu(flat @ layer["shared"]["gate_up"]["w"]))
                   @ layer["shared"]["down"]["w"])
-        dense = jnp.einsum("te,teh->th", gates, every) + shared
-    np.testing.assert_allclose(np.asarray(whole).reshape(-1, 64), np.asarray(dense),
-                               rtol=1e-4, atol=1e-5)
-    routed = {k: v for k, v in layer.items() if k != "shared"}
-    parts = [block({**routed, "experts": jax.tree_util.tree_map(
-        lambda a, e=e: a[e:e + 1], layer["experts"])}, (e, e + 1)) for e in range(16)]
-    total = sum(y for y, _ in parts) + shared.reshape(z.shape)
-    np.testing.assert_allclose(np.asarray(total), np.asarray(whole), rtol=1e-4, atol=1e-5)
-    for _, aux in parts:    # every chip routes over all 16 and counts the same loads
-        np.testing.assert_array_equal(np.asarray(aux["expert_counts"]),
-                                      np.asarray(whole_aux["expert_counts"]))
-    c = reference.dims(MODEL)
+        return whole.reshape(-1, 64), jnp.einsum("te,teh->th", gates, every) + shared
+
     with jax.default_matmul_precision("highest"):
-        uncut, loads = reference.expert_block(layer, flat, c, reference.plain._matmul(None),
-                                              held=(0, 16))
-    np.testing.assert_allclose(np.asarray(whole).reshape(-1, 64), np.asarray(uncut),
-                               rtol=1e-4, atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(loads), np.asarray(whole_aux["expert_counts"]))
+        whole, dense = both(programs.weights(2, uncut), family_ladder.key_of(3))
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(dense), rtol=1e-4, atol=1e-5)
 
 
 # -- attention with no position embedding ---------------------------------------------
@@ -601,7 +422,8 @@ def accepted_text(arch, extra):
         "architecture": arch, "vocab_size": 96, "hidden_size": 32, "intermediate_size": 64,
         "num_layers": 2, "num_hidden_layers": 2, "num_attention_heads": 4,
         "num_key_value_heads": 2, "activations_checkpoint_granularity": None, **extra}})
-    params = family.init_params(jax.random.PRNGKey(0), cfg, FP32)
+    # shapes are all a lowering needs: nothing is drawn
+    params = jax.eval_shape(lambda: family.init_params(jax.random.PRNGKey(0), cfg, FP32))
     batch = {"input_ids": jnp.zeros((2, 16), jnp.int32), "labels": jnp.zeros((2, 16), jnp.int32)}
     return jax.jit(jax.grad(lambda p: family.loss(cfg, FP32)(p, batch, None)[0])).lower(
         params).as_text()
@@ -627,34 +449,7 @@ def test_swiglu_is_the_default_and_relu2_another_program():
     assert relu2["experts"]["down"].shape == swiglu["experts"]["down"].shape == (4, 8, 16)
 
 
-# -- what is not wired is refused by name ---------------------------------------
-
-
-@pytest.mark.parametrize("model, ds, named", [
-    ({}, {"pipeline_model_parallel_size": 2}, "pipeline_model_parallel_size"),
-    ({}, {"tensor_model_parallel_size": 2}, "tensor_model_parallel_size"),
-    ({}, {"context_parallel_size": 2}, "context_parallel_size.*state S"),
-    ({}, {"sequence_parallel": True}, "sequence_parallel.*state S"),
-    ({}, {"expert_model_parallel_size": 2}, "num_experts_held"),
-    ({"num_experts_held": [4, 20]}, {}, "num_experts_held"),
-    ({"hybrid_override_pattern": "M-M*EMEME"}, {}, "'-'.*dense MLP"),
-    ({"hybrid_override_pattern": "MXM*EMEME"}, {}, "unknown"),
-    ({"hybrid_override_pattern": "MEM"}, {}, "hybrid_override_pattern has 3"),
-    ({"n_group": 2}, {}, "n_group"),
-    ({"topk_group": 2}, {}, "topk_group"),
-    ({"mamba_proj_bias": True}, {}, "mamba_proj_bias"),
-    ({"use_conv_bias": False}, {}, "use_conv_bias"),
-    ({"mlp_hidden_act": "silu"}, {}, "mlp_hidden_act"),
-    ({"router_bias_update_rate": 0.0}, {}, "router_bias_update_rate"),
-], ids=["pipeline", "tensor", "context", "sequence-parallel", "held-under-ep", "held-range",
-        "dense-mlp-layer", "unknown-layer", "short-pattern", "n-group", "topk-group",
-        "proj-bias", "no-conv-bias", "gated-experts", "bias-never-moves"])
-def test_the_config_refuses_by_the_keys_name(model, ds, named):
-    with pytest.raises(ValueError, match=named):
-        nh.NemotronHConfig.from_config({**MODEL, **model}, ds)
-
-
-def test_the_family_says_what_it_cannot_and_counts_its_flops():
+def test_the_family_says_what_it_cannot():
     family, cfg = resolve({"model": MODEL})
     assert family is nh.FAMILY and cfg.family is family
     with pytest.raises(NotImplementedError, match="cached decode.*three kinds of state"):
@@ -665,11 +460,3 @@ def test_the_family_says_what_it_cannot_and_counts_its_flops():
         nh.FAMILY.head(cfg, FP32)
     from neuronx_distributed_training_tpu.tools import convert
     assert "nemotron" not in Path(convert.__file__).read_text()     # HF conversion: not wired
-    bd = nh.flops_breakdown(cfg, 4096)
-    h = 64
-    mamba = 2 * h * (32 + 96 + 4) + 2 * 32 * h + 2 * 4 * 96 + 6 * 32 * 16
-    attention = 2 * h * (4 + 2) * 16 + 2 * 4 * 16 * h + 4 * 4 * 16 * 4097 / 2
-    assert bd["attention"] == pytest.approx(4 * mamba + attention, rel=1e-12)
-    # 3 slots a token x 4 of 16 held = 0.75 expected slots, beside the shared expert
-    assert bd["mlp"] == pytest.approx(4 * 4 * h * (24 * 0.75 + 48), rel=1e-12)
-    assert bd["router"] == 4 * 2 * h * 16 and bd["head"] == 2 * h * 256
