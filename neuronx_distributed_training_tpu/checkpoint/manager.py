@@ -30,18 +30,13 @@ from typing import Any, Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from neuronx_distributed_training_tpu.telemetry.spans import timed_import
-
-# start-up timeline: ``startup.imports_s["orbax.checkpoint"]``
-with timed_import("orbax.checkpoint"):
-    import orbax.checkpoint as ocp
-
 from neuronx_distributed_training_tpu.checkpoint import integrity as ck_integrity
 from neuronx_distributed_training_tpu.checkpoint.integrity import (
     CheckpointIntegrityError,
     IntegrityConfig,
     SaveAuditor,
 )
+from neuronx_distributed_training_tpu.telemetry.spans import timed_import
 
 logger = logging.getLogger(__name__)
 
@@ -207,6 +202,11 @@ class Checkpointer:
 
     def __init__(self, config: CheckpointConfig, *, keep_last: bool = True):
         self.config = config
+        # orbax is loaded here, by the first ``Checkpointer`` of the process,
+        # and not with the package: a run that builds none never pays for it
+        # (``startup.imports_s["orbax.checkpoint"]``)
+        with timed_import("orbax.checkpoint"):
+            import orbax.checkpoint as ocp
         directory = resolve_checkpoint_dir(config.dir)
         preservation = None
         if config.save_top_k > 0:
@@ -283,6 +283,8 @@ class Checkpointer:
         starts.  The verdict application is a non-blocking snapshot: an
         audit still in flight never delays (or deadlocks) a save, emergency
         or periodic."""
+        import orbax.checkpoint as ocp
+
         if self._auditor is not None:
             # the implicit wait also commits any in-flight async save, so
             # the steps kicked to the auditor are guaranteed on disk; orbax
@@ -603,6 +605,8 @@ class Checkpointer:
         ``None``), or ``None`` when the checkpoint predates manifests (or no
         checkpoint exists).  Template-free: safe to call before any model
         state exists — the restart-time replanner's first read."""
+        import orbax.checkpoint as ocp
+
         step = step if step is not None else self.latest_step()
         if step is None:
             return None
@@ -641,6 +645,8 @@ class Checkpointer:
         restores walk back past corrupt steps (:meth:`verified_latest_step`);
         an explicitly requested corrupt ``step`` raises
         :class:`CheckpointIntegrityError` instead of restoring bad bytes."""
+        import orbax.checkpoint as ocp
+
         icfg = self.config.integrity
         do_verify = (icfg.enabled and icfg.verify_restore
                      if verify is None else bool(verify))
@@ -720,6 +726,8 @@ class Checkpointer:
         default — the warm-start source is usually someone else's run dir
         (or a converter's output, which has no sidecar and restores as
         legacy); renaming steps there is not this run's call."""
+        import orbax.checkpoint as ocp
+
         icfg = self.config.integrity
         do_verify = (icfg.enabled and icfg.verify_restore
                      if verify is None else bool(verify))

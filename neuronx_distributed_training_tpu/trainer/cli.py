@@ -122,10 +122,12 @@ def main() -> None:
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
 
-    # the module's imports that cost (the loop's bring orbax) and the
-    # backend's first use are phases of the start-up timeline
-    # (docs/observability.md "Start-up timeline"), each bracketed where it
-    # stands; the few milliseconds of the later ones lie between phases
+    # the module's imports that cost (the loop's bring the models, the
+    # optimizer and the data modules; orbax waits for the first
+    # ``Checkpointer``) and the backend's first use are phases of the
+    # start-up timeline (docs/observability.md "Start-up timeline"), each
+    # bracketed where it stands; the few milliseconds of the later ones lie
+    # between phases
     from neuronx_distributed_training_tpu.telemetry.spans import (
         startup_add,
         startup_phase,

@@ -12,11 +12,14 @@ from __future__ import annotations
 
 import json
 import logging
+import os
+import socket
 import time
 from pathlib import Path
 from typing import Any, Optional
 
 from neuronx_distributed_training_tpu.telemetry import TelemetryConfig
+from neuronx_distributed_training_tpu.telemetry.spans import timed_import
 from neuronx_distributed_training_tpu.utils.io import atomic_write_json
 from neuronx_distributed_training_tpu.utils.perf import Throughput, mfu as _mfu
 
@@ -166,15 +169,7 @@ class ExpManager:
         self._tb = None
         if create_tensorboard_logger:
             try:
-                from neuronx_distributed_training_tpu.telemetry.spans import (
-                    timed_import,
-                )
-
-                # startup.imports_s["torch.utils.tensorboard"]
-                with timed_import("torch.utils.tensorboard"):
-                    from torch.utils.tensorboard import SummaryWriter
-
-                self._tb = SummaryWriter(log_dir=str(self.log_dir / "tb"))
+                self._tb = _ScalarEvents(self.log_dir / "tb")
             except Exception as e:  # noqa: BLE001 — TB is optional observability
                 logger.warning("TensorBoard logger unavailable: %s", e)
         self._wandb = None
@@ -469,8 +464,7 @@ class ExpManager:
                 if peak_tf > 0:
                     flat["mfu"] = _mfu(per_chip, step_flops, peak_tf)
         if self._tb is not None:
-            for k, v in flat.items():
-                self._tb.add_scalar(k, v, step)
+            self._tb.add_scalars(step, flat)
         if self._wandb is not None:
             self._wandb.log(flat, step=step)
         if self._mlflow is not None:
@@ -527,8 +521,8 @@ class ExpManager:
             if summary is not None:
                 self._record_trace_summary(summary)
         if self._tb is not None:
-            self._tb.flush()
             self._tb.close()
+            self._tb = None
         if self._wandb is not None:
             self._wandb.finish()
         if self._mlflow is not None:
@@ -537,6 +531,49 @@ class ExpManager:
             logging.getLogger().removeHandler(self._file_handler)
             self._file_handler.close()
             self._file_handler = None
+
+
+class _ScalarEvents:
+    """The TensorBoard sink: a boundary's scalars as one ``Event`` of a
+    ``tfevents`` file under ``logdir``, each a ``simple_value`` under its
+    metric key, on tensorboard's own queue-and-thread writer (a queue of 10,
+    a flush every 120 s).  It loads neither ``torch`` nor ``tensorflow``,
+    whose import was 21-23 s of a start-up (``PERF.md`` section 6, PR 48)."""
+
+    def __init__(self, logdir: Path):
+        with timed_import("tensorboard.summary.writer.event_file_writer"):
+            from tensorboard.compat.proto import event_pb2, summary_pb2
+            from tensorboard.summary.writer import event_file_writer as efw
+            from tensorboard.summary.writer.record_writer import RecordWriter
+
+        self._event, self._summary = event_pb2.Event, summary_pb2.Summary
+        logdir.mkdir(parents=True, exist_ok=True)
+        # ``EventFileWriter`` itself opens its file through
+        # ``tensorboard.compat.tf``, which imports tensorflow where it is
+        # installed (9 s): its file name and its parts over a plain file
+        name = "events.out.tfevents.%010d.%s.%s.%s" % (
+            time.time(), socket.gethostname(), os.getpid(),
+            efw._global_uid.get())
+        self._writer = efw._AsyncWriter(
+            RecordWriter(open(logdir / name, "wb")),
+            max_queue_size=10, flush_secs=120)
+        self._add(self._event(
+            file_version="brain.Event:2",
+            source_metadata=event_pb2.SourceMetadata(writer=__name__)))
+        self._writer.flush()
+
+    def _add(self, event: Any) -> None:
+        event.wall_time = time.time()
+        self._writer.write(event.SerializeToString())
+
+    def add_scalars(self, step: int, scalars: dict[str, float]) -> None:
+        value = self._summary.Value
+        self._add(self._event(step=int(step), summary=self._summary(value=[
+            value(tag=k, simple_value=v) for k, v in scalars.items()])))
+
+    def close(self) -> None:
+        """Write what is queued, then close the file."""
+        self._writer.close()
 
 
 def _is_scalar(v: Any) -> bool:

@@ -33,9 +33,10 @@ from neuronx_distributed_training_tpu.telemetry.spans import (
     startup_phase,
 )
 
-# the module's imports (orbax's among them, checkpoint/manager.py) are a
-# phase of the start-up timeline; under ``nxdt-train`` the CLI's bracket is
-# open around this one, which then counts there
+# the module's imports are a phase of the start-up timeline (orbax is not
+# among them: the first ``Checkpointer`` loads it, checkpoint/manager.py);
+# under ``nxdt-train`` the CLI's bracket is open around this one, which then
+# counts there
 with startup_phase("startup/imports"):
     from neuronx_distributed_training_tpu.checkpoint import (
         CheckpointConfig,

@@ -640,12 +640,9 @@ class TestNonScalarSinks:
             def __init__(self):
                 self.scalars = []
 
-            def add_scalar(self, k, v, step):
-                assert isinstance(v, float)
-                self.scalars.append((k, v, step))
-
-            def flush(self):
-                pass
+            def add_scalars(self, step, flat):
+                assert all(isinstance(v, float) for v in flat.values())
+                self.scalars += [(k, v, step) for k, v in flat.items()]
 
             def close(self):
                 pass

@@ -311,6 +311,8 @@ def test_the_first_fit_writes_the_timeline_and_it_adds_up(first_and_second_fit):
     assert set(section) == {
         "origin", "to_first_step_s", "seconds", "unattributed_pct", "phases",
         "imports_s", "compiles", "compile_cache"}
+    # no Checkpointer was built, so nothing loaded orbax
+    assert "orbax.checkpoint" not in section["imports_s"]
     # the old keys of compile_events, and the phase beside them
     events = summary["compile_events"]
     assert events and all(
