@@ -686,7 +686,7 @@ def audit_artifacts(
 
     This is the shared core: the pre-flight CLI calls it on an abstract
     lowering, the trainer's compile census calls it on the very executable
-    about to run, bench.py on its measured step."""
+    about to run."""
     from neuronx_distributed_training_tpu.telemetry.census import (
         hlo_texts_from_compiled,
     )
@@ -726,8 +726,8 @@ def audit_artifacts(
 def audit_executable(ctx: AuditContext, compiled: Any, lowered: Any = None,
                      *, log=None, config_name: str = "") -> AuditReport:
     """One-call wrapper for callers holding a live ``(lowered, compiled)``
-    pair — the trainer's in-loop census audit and bench.py share this so
-    the as_text fallback and finding logging cannot drift apart."""
+    pair (the trainer's in-loop census audit): the as_text fallback and
+    finding logging live in one place."""
     stablehlo = ""
     if lowered is not None:
         try:

@@ -93,16 +93,6 @@ class AuditReport:
             "stats": self.stats,
         }
 
-    def summary(self) -> dict[str, Any]:
-        """The compact verdict block bench.py embeds in its JSON line."""
-        out: dict[str, Any] = {
-            "verdict": self.worst() or "clean",
-            "rule_hits": self.by_severity(),
-        }
-        if "donation_coverage" in self.stats:
-            out["donation_coverage"] = self.stats["donation_coverage"]
-        return out
-
     def format(self) -> str:
         lines = []
         name = f" [{self.config}]" if self.config else ""

@@ -16,8 +16,8 @@ Given a model config and a chip count, the planner:
    ``memory_analysis()`` bytes and the real collective census, discards plans
    that fail the audit, and emits a :class:`PlanReport`.
 
-Surfaces: ``tools/plan.py`` CLI, ``nxdt-train --autotune``, and
-``bench.py --plan-topk`` (which scores the cost model against reality).
+Surfaces: ``tools/plan.py`` CLI and ``nxdt-train --autotune``.  Nothing
+scores the cost model's ranking against a chip yet (``ROADMAP.md`` C1).
 ``docs/autotuning.md`` is the manual.
 """
 
@@ -25,7 +25,6 @@ from neuronx_distributed_training_tpu.autotune.cost_model import (  # noqa: F401
     PlanEstimate,
     estimate_hbm_bytes,
     estimate_plan,
-    kendall_tau,
     overlap_from_trace_summary,
     resolve_overlap,
 )

@@ -322,7 +322,7 @@ def hbm_calibration_from_memory_summary(summary: Any) -> dict[str, float]:
         if pred and measured > 0:
             out[cat] = _clamp_ratio(measured / float(pred))
     # the total ratio is the headline predicted-vs-actual audit number
-    # (reported, and what PC502 gates on)
+    # (reported, not applied per-category)
     if peak and predicted.get("total"):
         out["total"] = _clamp_ratio(float(peak) / float(predicted["total"]))
     if not out:
@@ -754,7 +754,7 @@ def estimate_plan(facts: ModelFacts, plan: Plan, topo: ChipTopology,
 
     # ---- bubble ----
     # per-schedule fill/drain multiplier (parallel.pipeline.bubble_multiplier
-    # — one table shared with run_summary/bench telemetry): (pp-1)/nm for
+    # — one table shared with run_summary telemetry): (pp-1)/nm for
     # plain 1f1b / vp=1 wavefront, /(nm*vp) under a virtual pipeline,
     # /(3*nm) for the zero-bubble split's residual warmup third
     bubble = 0.0
@@ -848,28 +848,3 @@ def collective_byte_volumes(facts: ModelFacts, plan: Plan
         }
 
     return out
-
-
-# --------------------------------------------------------------------------
-# rank agreement (bench.py --plan-topk)
-# --------------------------------------------------------------------------
-
-
-def kendall_tau(a: list[float], b: list[float]) -> Optional[float]:
-    """Kendall rank correlation between two paired score lists (tau-a; ties
-    count as discordant-neutral).  None for fewer than 2 pairs."""
-    n = min(len(a), len(b))
-    if n < 2:
-        return None
-    conc = disc = 0
-    for i in range(n):
-        for j in range(i + 1, n):
-            da = a[i] - a[j]
-            db = b[i] - b[j]
-            s = da * db
-            if s > 0:
-                conc += 1
-            elif s < 0:
-                disc += 1
-    total = n * (n - 1) / 2
-    return (conc - disc) / total

@@ -1,7 +1,7 @@
 """The planner: rank the lattice, audit the survivors, emit a PlanReport.
 
 ``plan_config`` is the one-call entry every surface uses (``tools/plan.py``,
-``nxdt-train --autotune``, ``bench.py --plan-topk``):
+``nxdt-train --autotune``):
 
 1. load + validate the YAML, extract :class:`~.space.ModelFacts`;
 2. enumerate the legal lattice and score every plan analytically
@@ -109,7 +109,7 @@ class PlanReport:
     #: measured facts the calibration source carried beyond overlap
     #: (exposed collective seconds, measured pipeline bubble fraction) —
     #: the audit trail that keeps planner priors auditable, not trusted
-    #: (analysis.perf_contract residuals; docs/observability.md)
+    #: (docs/observability.md)
     calibration_facts: Optional[dict] = None
     #: measured/prior HBM ratios the ranking priced with (a
     #: ``telemetry.memory`` capture via ``--calibrate-from
@@ -156,7 +156,7 @@ class PlanReport:
         return d
 
     def summary(self) -> dict[str, Any]:
-        """Compact block for run_summary.json / bench JSON lines."""
+        """Compact block for run_summary.json."""
         w = self.winner
         return {
             "chips": self.chips,
@@ -517,8 +517,7 @@ def plan_config(
     if calibration_facts is not None and w is not None \
             and calibration_facts.get("bubble_fraction_measured") is not None \
             and w.estimate.step_seconds > 0:
-        # audit the winner's bubble price against the measured fraction —
-        # the residual analysis.perf_contract's PC302 gates on
+        # audit the winner's bubble price against the measured fraction
         predicted = w.estimate.bubble_seconds / w.estimate.step_seconds
         calibration_facts["winner_bubble_fraction_predicted"] = round(
             predicted, 6)

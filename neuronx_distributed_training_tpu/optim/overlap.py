@@ -1,11 +1,10 @@
 """Engineered compute/comms overlap for the ZeRO-1 update.
 
-The measurement plane (per-class achieved overlap from device traces, the
-PC201/PC202 exposed-seconds ratchets) says the step is bandwidth-bound at
-scale; this module is the *engineering* side: it turns the monolithic
-step-boundary ZeRO-1 collectives into scheduled, bucketed pieces the XLA
-latency-hiding scheduler can actually hide (cf. DeepCompile's
-compiler-driven decomposition of ZeRO collectives, and the weight-update
+The measurement plane (per-class achieved overlap from device traces) says
+the step is bandwidth-bound at scale; this module is the *engineering* side:
+it turns the monolithic step-boundary ZeRO-1 collectives into scheduled,
+bucketed pieces the XLA latency-hiding scheduler can actually hide (cf.
+DeepCompile's compiler-driven decomposition of ZeRO collectives, and the weight-update
 sharding analysis in arXiv:2004.13336).
 
 Three levers, all opt-in via ``distributed_strategy.overlap``:

@@ -938,7 +938,7 @@ def bubble_multiplier(schedule: Optional[str], pp: int, nm: int,
 def predicted_bubble_fraction(schedule: Optional[str], pp: int, nm: int,
                               vp: int = 1) -> float:
     """Predicted idle fraction of TOTAL pipelined step time — the telemetry
-    number (``run_summary.json`` / bench JSON ``bubble_fraction_predicted``);
+    number (``run_summary.json`` ``bubble_fraction_predicted``);
     0.0 when pp == 1.
 
     For the manual-vjp schedules this is derived from the COMPACTED work

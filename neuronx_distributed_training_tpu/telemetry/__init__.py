@@ -77,7 +77,6 @@ from neuronx_distributed_training_tpu.telemetry.step_timeline import (
 from neuronx_distributed_training_tpu.telemetry.trace import (
     TraceCapture,
     TraceConfig,
-    trace_steps,
 )
 from neuronx_distributed_training_tpu.telemetry.trace_analysis import (
     analyze_trace_dir,
@@ -129,6 +128,5 @@ __all__ = [
     "pipeline_facts",
     "tensorstats_state_specs",
     "tensorstats_update",
-    "trace_steps",
     "tree_bytes_by_subsystem",
 ]

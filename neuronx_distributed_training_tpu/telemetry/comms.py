@@ -1,6 +1,6 @@
 """Interconnect observatory: measured collective bandwidth.
 
-Every other roofline term is measured and gated — overlap (telemetry.trace),
+Every other roofline term is measured — overlap (telemetry.trace),
 HBM (telemetry.memory), bubbles (step timeline) — but the comms term itself
 was priced purely from the static ``ici_bandwidth_bytes`` tables in
 ``autotune/topology.py``.  This module closes that gap in three layers
@@ -612,10 +612,9 @@ def write_comms_summary(summary: Mapping[str, Any],
 
 
 def bench_comms_facts(summary: Mapping[str, Any]) -> dict:
-    """The perf-contract facts block out of a comms summary: per-axis
-    fitted bandwidth (+ measured/prior ratio) and per-class best achieved
-    bus Gb/s across the sweep — what ``perf_facts_from_bench`` picks up and
-    PC204 gates against the committed ``cpu_comms`` baseline."""
+    """The ``comms`` block of ``tools/comms_bench.py``'s JSON line out of a
+    comms summary: per-axis fitted bandwidth (+ measured/prior ratio) and
+    per-class best achieved bus Gb/s across the sweep."""
     prior = float((summary.get("prior") or {}).get(
         "ici_bandwidth_bytes") or 0.0)
     axes: dict[str, Any] = {}

@@ -36,9 +36,8 @@ measured observables (``exp_manager.telemetry.memory``):
   ``memory_analysis`` bytes, and the planner's predicted HBM breakdown for
   the resolved plan — predicted-vs-actual in one artifact.
 
-- **the loop closed** — ``analysis.perf_contract`` gates measured peaks
-  (PC501 growth ratchet, PC502 measured > predicted x calibration band),
-  and ``tools/plan.py --calibrate-from memory_summary.json`` feeds the
+- **the loop closed** — ``tools/plan.py --calibrate-from
+  memory_summary.json`` feeds the
   measured per-subsystem peaks back into the HBM model's transient
   constants as per-topology calibration ratios
   (``autotune.cost_model.hbm_calibration_from_memory_summary``).
@@ -920,7 +919,7 @@ def measured_hbm_categories(summary: Mapping[str, Any]
     """``(per-device measured bytes by hbm_breakdown category, per-device
     measured peak)`` out of a memory summary — the measured side of every
     predicted-vs-measured consumer (planner calibration, the report's
-    table, PC502's facts).
+    table).
 
     Tree bytes are exact and beat the stack-derived attribution for the
     state classes; attribution/tree sums span ALL local devices and divide

@@ -21,8 +21,8 @@ Chrome traces ``telemetry.trace`` already captures:
   window.
 - **measured bubble fraction** — idle lane-time over total lane-time inside
   the step windows: ``1 - busy / (lanes x window)``.  Beside the predicted
-  fraction it turns the bubble into a *residual* the perf contracts
-  (``analysis.perf_contract``, PC301/PC302) can gate.
+  fraction it turns the bubble into a *residual* the planner's calibration
+  audit trail reports.
 - **straggler attribution** — the lane with the largest busy time bounds
   the step; its share names the stage to rebalance.
 

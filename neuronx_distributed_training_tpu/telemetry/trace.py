@@ -22,7 +22,7 @@ dispatch-ahead contract tests hold with the knob on or off.
 
 The profiler session is process-global in jax — only one trace can be live.
 ``start_session``/``stop_session`` guard it with an owner token so this
-capture, ``trace_steps`` and teardown can never double-start or double-stop
+capture and teardown can never double-start or double-stop
 it (a ``stop_trace`` on an already-closed session raises deep in teardown
 otherwise).  The reference's ``exp_manager.profile_start_step`` /
 ``profile_num_steps`` is an alias that builds this block with ``keep_raw``
@@ -255,7 +255,7 @@ class TraceCapture:
                 except Exception as e:  # noqa: BLE001 — telemetry only
                     logger.warning("comms bandwidth join failed: %s", e)
             # atomic (temp + rename): a kill mid-write must not leave torn
-            # JSON for the report tools / perf-contract extraction to choke on
+            # JSON for the report tools to choke on
             from neuronx_distributed_training_tpu.utils.io import (
                 atomic_write_json,
             )
@@ -275,40 +275,3 @@ class TraceCapture:
             if not self.cfg.keep_raw:
                 shutil.rmtree(self.raw_dir, ignore_errors=True)
         return self.summary
-
-
-def trace_steps(step_fn, num_steps: int, out_dir: str | Path, *,
-                top_k: int = 15, keep_raw: bool = False,
-                owner: str = "telemetry.trace_steps",
-                pipeline: Optional[Mapping[str, Any]] = None
-                ) -> Optional[dict[str, Any]]:
-    """Capture ``num_steps`` calls of ``step_fn(step)`` under one trace
-    window and return the analyzed summary (None when the profiler session
-    is unavailable).  The bench's ``--trace`` path: each call is wrapped in
-    a ``StepTraceAnnotation`` so per-step attribution works the same way it
-    does inside the trainer."""
-    import jax
-
-    out_dir = Path(out_dir)
-    if not start_session(str(out_dir), owner):
-        if not keep_raw:  # the caller's capture dir must not leak
-            shutil.rmtree(out_dir, ignore_errors=True)
-        return None
-    try:
-        for i in range(num_steps):
-            with jax.profiler.StepTraceAnnotation("train", step_num=i):
-                step_fn(i)
-    finally:
-        stop_session(owner)
-    try:
-        from neuronx_distributed_training_tpu.telemetry.trace_analysis import (
-            analyze_trace_dir,
-        )
-
-        return analyze_trace_dir(out_dir, top_k=top_k, pipeline=pipeline)
-    except Exception as e:  # noqa: BLE001 — a failed parse is a None, not a crash
-        logger.warning("trace analysis failed: %s", e)
-        return None
-    finally:
-        if not keep_raw:
-            shutil.rmtree(out_dir, ignore_errors=True)

@@ -334,9 +334,8 @@ class ExpManager:
         comms = summary.get("comms")
         if isinstance(comms, dict):
             # the achieved-bandwidth join is a run fact too: per-class
-            # achieved_gbps/efficiency at the top level for the perf
-            # contract's PC204 extraction, and comms/* scalars through
-            # every sink (and the fleet beacon's metric pick)
+            # achieved_gbps/efficiency at the top level, and comms/* scalars
+            # through every sink (and the fleet beacon's metric pick)
             section["comms"] = comms
             try:
                 from neuronx_distributed_training_tpu.telemetry.comms import (

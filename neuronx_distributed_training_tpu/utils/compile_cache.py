@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point that compiles (``nxdt-train``, ``bench.py``,
-``chip_smoke.py``): where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+One rule for every entry point that compiles (``nxdt-train``, ``chip_smoke.py``,
+``benchmark/run.py``): where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
 itself and no directory is set in code; where it is not, the cache sits at one
 fixed path inside the checkout.  The path is part of the cache key, so a
 temporary or per-process directory would never hit."""

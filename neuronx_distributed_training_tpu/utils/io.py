@@ -1,7 +1,7 @@
 """Crash-safe file I/O helpers.
 
 ``run_summary.json`` / ``trace_summary.json`` / ``fleet_summary.json`` are
-read by resume paths, report tools, and the bench artifact chain — a
+read by resume paths, report tools and the benchmark's readers — a
 SIGKILL landing mid-write (preemption, OOM-killer, the elastic drill's kill
 injector) must never leave a truncated JSON document for them to choke on.
 ``atomic_write_json`` serializes FIRST (an unserializable value raises

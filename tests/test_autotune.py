@@ -18,7 +18,6 @@ from neuronx_distributed_training_tpu.autotune import (
     Plan,
     enumerate_plans,
     estimate_plan,
-    kendall_tau,
     plan_config,
     resolve_topology,
 )
@@ -325,13 +324,6 @@ class TestCostModel:
         assert tp8.hbm_breakdown["params"] < tp1.hbm_breakdown["params"]
         assert tp8.comms_breakdown.get("tp", 0) > \
             tp1.comms_breakdown.get("tp", 0)
-
-    def test_kendall_tau(self):
-        assert kendall_tau([1, 2, 3], [10, 20, 30]) == 1.0
-        assert kendall_tau([1, 2, 3], [30, 20, 10]) == -1.0
-        assert kendall_tau([1.0], [2.0]) is None
-        assert kendall_tau([1, 2, 3, 4], [10, 20, 40, 30]) == pytest.approx(
-            4 / 6)
 
 
 # ---------------------------------------------------------------------------

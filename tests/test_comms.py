@@ -7,9 +7,8 @@ seeded-slow-device skew detector, the worked degraded-link alert rule
 firing through the real alert engine, the committed hand-computable
 ``comms_summary`` fixture (byte-stable ratchet), the live CPU-mesh sweep on
 virtual devices, the planner calibration round-trip (fixture AND
-live-captured summary), PC204 fault injection + the committed ``cpu_comms``
-baseline, quant-readiness savings provenance, fleet beacon/spread wiring,
-and the CLI smokes (tools/comms_bench.py, tools/comms_report.py).
+live-captured summary), quant-readiness savings provenance, fleet
+beacon/spread wiring, and the CLI smokes (tools/comms_bench.py, tools/comms_report.py).
 
 Run ``python tests/test_comms.py --regen-fixture`` to regenerate the
 committed fixture after changing ``build_fixture()`` — the ratchet test
@@ -23,7 +22,6 @@ from pathlib import Path
 
 import pytest
 
-from neuronx_distributed_training_tpu.analysis import perf_contract as pc
 from neuronx_distributed_training_tpu.telemetry import comms
 
 FIXTURE = Path(__file__).parent / "data" / "comms_summary_fixture.json"
@@ -541,139 +539,6 @@ class TestCalibration:
 
 
 # ---------------------------------------------------------------------------
-# perf contract: PC204 fault injection + the committed cpu_comms baseline
-# ---------------------------------------------------------------------------
-
-
-def _comms_line(**over):
-    block = {
-        "classes": {"all-gather": {"achieved_gbps": 0.8,
-                                   "efficiency": 0.4}},
-        "axes": {"dp": {"bandwidth_gbps": 0.5, "latency_us": 100.0,
-                        "bandwidth_ratio": 0.25}},
-        "peak_bandwidth_gbps": 2.0,
-    }
-    block.update(over)
-    return {"metric": "comms_bench_sweep", "value": 0.25,
-            "unit": "min_axis_bandwidth_measured_over_prior",
-            "device": "cpu", "comms": block}
-
-
-def _cfacts(**over):
-    return pc.perf_facts_from_bench(_comms_line(**over))
-
-
-def _rules(report):
-    return {f.rule for f in report.findings}
-
-
-class TestPerfContractComms:
-    def test_extraction_normalizes_both_shapes(self):
-        f = _cfacts()
-        assert f["comms"]["classes"]["all-gather"]["achieved_gbps"] == 0.8
-        assert f["comms"]["axes"]["dp"]["bandwidth_gbps"] == 0.5
-        assert f["comms"]["peak_bandwidth_gbps"] == 2.0
-        # the trainer's trace-summary shape rides the same key
-        t = pc.perf_facts_from_trace_summary({
-            "achieved_overlap": 0.5, "exposed_collective_seconds": 0.01,
-            "overlap_by_class": {},
-            "comms": {"classes": {"all-reduce": {"achieved_gbps": 1.5,
-                                                 "efficiency": 0.75}}}})
-        assert t["comms"]["classes"]["all-reduce"]["efficiency"] == 0.75
-
-    def test_run_summary_fallback(self, tmp_path):
-        # no trace window fired but the trainer still wrote the comms
-        # section into run_summary.json: the facts must carry it
-        (tmp_path / "run_summary.json").write_text(json.dumps({
-            "n_chips": 8,
-            "comms": {"classes": {"all-gather": {"achieved_gbps": 0.9}}}}))
-        (tmp_path / "trace_summary.json").write_text(json.dumps({
-            "achieved_overlap": 0.5, "exposed_collective_seconds": 0.01,
-            "overlap_by_class": {}}))
-        f = pc.perf_facts_from_run(tmp_path)
-        assert f["comms"]["classes"]["all-gather"]["achieved_gbps"] == 0.9
-
-    def test_default_key(self):
-        assert pc.default_key(_cfacts()) == "cpu_comms"
-
-    def test_in_band_drift_is_clean(self):
-        new = _cfacts(classes={"all-gather": {"achieved_gbps": 0.7,
-                                              "efficiency": 0.35}})
-        assert not pc.diff_facts(_cfacts(), new).findings
-
-    def test_pc204_per_class_drop_names_class(self):
-        new = _cfacts(classes={"all-gather": {"achieved_gbps": 0.3,
-                                              "efficiency": 0.15}})
-        rep = pc.diff_facts(_cfacts(), new)
-        assert _rules(rep) == {"PC204"}
-        f = rep.findings[0]
-        assert f.location == "all-gather" and f.severity == "error"
-        assert "0.8" in f.message and "0.3" in f.message
-        assert rep.failed("error")
-
-    def test_pc204_per_axis_drop_names_axis(self):
-        new = _cfacts(axes={"dp": {"bandwidth_gbps": 0.2,
-                                   "latency_us": 100.0,
-                                   "bandwidth_ratio": 0.1}})
-        rep = pc.diff_facts(_cfacts(), new)
-        assert _rules(rep) == {"PC204"}
-        assert rep.findings[0].location == "dp"
-        assert "dp-axis bandwidth" in rep.findings[0].message
-
-    def test_pc110_improvement_is_info(self):
-        new = _cfacts(classes={"all-gather": {"achieved_gbps": 1.6,
-                                              "efficiency": 0.8}},
-                      axes={"dp": {"bandwidth_gbps": 1.0,
-                                   "latency_us": 50.0,
-                                   "bandwidth_ratio": 0.5}})
-        rep = pc.diff_facts(_cfacts(), new)
-        assert _rules(rep) == {"PC110"}
-        assert not rep.failed("error")
-
-    def test_noise_band_respected(self):
-        new = _cfacts(classes={"all-gather": {"achieved_gbps": 0.3,
-                                              "efficiency": 0.15}})
-        rep = pc.diff_facts(_cfacts(), new, noise={"comms_bw_frac": 0.9})
-        assert not rep.findings
-
-    def test_residual_report_comms_bandwidth_row(self):
-        est = {"step_seconds": 0.10, "compute_seconds": 0.07,
-               "comms_seconds": 0.02, "bubble_seconds": 0.01}
-        r = pc.residual_report(est, _cfacts())
-        row = r["comms_bandwidth"]
-        assert row["peak_gbps"] == 2.0
-        assert row["achieved_gbps_by_class"] == {"all-gather": 0.8}
-        assert row["mean_efficiency"] == pytest.approx(0.4)
-        # the row is always present; without comms it says so with Nones
-        empty = pc.residual_report(est, {"step_seconds": 0.15})
-        assert empty["comms_bandwidth"]["peak_gbps"] is None
-
-    def test_bench_verdict_ratchets(self, tmp_path):
-        pc.update_baseline("cpu_comms", _cfacts(), baselines_dir=tmp_path)
-        assert pc.bench_verdict("cpu_comms", _cfacts(),
-                                baselines_dir=tmp_path)["verdict"] == "clean"
-        v = pc.bench_verdict(
-            "cpu_comms",
-            _cfacts(classes={"all-gather": {"achieved_gbps": 0.1}}),
-            baselines_dir=tmp_path)
-        assert v["verdict"] == "error"
-        assert v["findings"][0]["rule"] == "PC204"
-
-    def test_committed_cpu_comms_baseline(self):
-        # the verify-gate baseline shipped with the repo: self-check must
-        # land clean, and the noise band must stay CPU-jitter wide
-        snap = pc.load_baseline("cpu_comms")
-        assert snap is not None, \
-            "missing committed baseline: python tools/comms_bench.py " \
-            "--smoke then tools/perf_contract.py --update-baselines"
-        facts = snap["facts"]
-        assert facts["comms"]["axes"], "baseline carries no per-axis fit"
-        assert facts["comms"]["classes"]
-        assert snap["noise"]["comms_bw_frac"] >= 0.5
-        assert pc.bench_verdict("cpu_comms", facts)["verdict"] == "clean"
-
-
-# ---------------------------------------------------------------------------
 # quant-readiness: savings provenance (measured wire rate vs static)
 # ---------------------------------------------------------------------------
 
@@ -813,7 +678,7 @@ class TestCommsReportCLI:
 
 
 class TestCommsBenchCLI:
-    def test_sweep_writes_summary_and_contract_line(self, tmp_path, capsys):
+    def test_sweep_writes_summary_and_json_line(self, tmp_path, capsys):
         mod = _load_tool("comms_bench")
         rc = mod.main(["--sizes", "4096,16384", "--reps", "1",
                        "--warmup", "1", "--no-skew",
@@ -827,10 +692,9 @@ class TestCommsBenchCLI:
         payload = json.loads((tmp_path / "bench.json").read_text())
         assert payload["metric"] == "comms_bench_sweep"
         assert payload["value"] > 0
-        assert payload["perf_contract"]["key"] == "cpu_comms"
         assert payload["comms"]["axes"]["dp"]["bandwidth_gbps"] > 0
         out = capsys.readouterr().out
-        assert "interconnect sweep" in out and "perf contract" in out
+        assert "interconnect sweep" in out
 
 
 if __name__ == "__main__":

@@ -849,7 +849,7 @@ def test_same_world_autotune_respects_checkpoint_layout(tmp_path, devices8,
 
 
 # ---------------------------------------------------------------------------
-# report surfaces: metrics_report elastic trail + bench drill pickup
+# report surfaces: metrics_report elastic trail
 # ---------------------------------------------------------------------------
 
 
@@ -893,17 +893,3 @@ class TestReportSurfaces:
 
         assert metrics_report.elastic_section({}) == ""
         assert metrics_report.elastic_section({"elastic": {}}) == ""
-
-    def test_bench_picks_up_last_drill(self, tmp_path, monkeypatch):
-        """bench.py's JSON line carries restart_cost_seconds +
-        goodput_fraction from the last completed drill."""
-        import bench
-
-        monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-        assert bench.load_last_drill() == {}  # no drill ran: empty
-        (tmp_path / "bench_results").mkdir()
-        (tmp_path / "bench_results" / "last_drill.json").write_text(
-            json.dumps({"ok": True, "restart_cost_seconds": 0.07,
-                        "goodput_fraction": 0.11, "mode": "kill"}))
-        drill = bench.load_last_drill()
-        assert drill["ok"] and drill["restart_cost_seconds"] == 0.07

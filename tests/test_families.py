@@ -5,7 +5,8 @@ records are held here, family by family and capability by capability:
 1. every example config names a family whose ``config_from`` takes its blocks;
 2. each capability works at toy size or refuses in a sentence that names the
    family and the capability;
-3. the FLOPs scalar is the sum of the family's breakdown;
+3. the FLOPs scalar is the sum of the family's breakdown, and at each cell
+   of ``BENCHMARK.json`` it is the benchmark's own count;
 4. the arrows point one way: nothing under ``parallel/``, ``ops/``, ``optim/``,
    ``utils/`` imports ``models``, and ``trainer/``, ``autotune/``, ``config/``
    name no family's config class;
@@ -223,6 +224,38 @@ def test_the_looped_stack_multiplies_its_work():
     assert flops_for_model(ocfg, 128) == 2 * flops_for_model(ocfg.llama, 128)
     assert ocfg.family.run_facts(ocfg, {"num_microbatches": 3}) == {
         "loop_passes": 2, "layer_applications_per_step": 12}
+
+
+def _cells():
+    """The benchmark's cells; the one whose counts are known to differ is a
+    strict xfail, so that the mend (a changed ``mfu``) is a PR of its own."""
+    names = [w["name"] for w in json.loads(
+        (PKG.parent / "BENCHMARK.json").read_text())["workloads"]]
+    window = pytest.mark.xfail(strict=True, reason=(
+        "llama.flops_breakdown's attention term counts seq_len/2 keys with "
+        "no sliding_window (utils/perf.py::_attention_flops_per_token): 1.27x "
+        "benchmark/flops.py::mean_visible_keys at seq 32768, window 4096; "
+        "ROADMAP.md C1"))
+    return [pytest.param(n, marks=window) if n == "mistral7b-pretrain-32k"
+            else n for n in names]
+
+
+@pytest.mark.parametrize("cell_name", _cells())
+def test_in_loop_flops_are_the_benchmarks(cell_name):
+    """Two counts of what a trained token requires, the run's own (``mfu`` in
+    ``metrics.jsonl``, ``fwd_flops_per_token`` in ``run_summary.json``) and
+    the benchmark's (``mfu_pct`` in the ledger), at the cell's published
+    widths and sequence length: they may differ by conventions worth under
+    0.1 % (``seq/2`` keys against ``(seq + 1)/2``), and by no term."""
+    from benchmark.harness.cell import load_cell
+
+    cell = load_cell(cell_name)
+    seq = int(cell.traffic["seq_length"])
+    _, model_cfg = resolve(load_config(
+        json.loads(json.dumps(cell.config["trainer_config"]))))
+    ours = perf.train_step_flops_per_token(flops_for_model(model_cfg, seq))
+    theirs = cell.operations.train_flops_per_token(cell.model, seq)["total"]
+    assert ours == pytest.approx(theirs, rel=1e-3)
 
 
 # -- 4. the arrows point one way ----------------------------------------------

@@ -25,24 +25,3 @@ def test_entry_compiles(devices8):
 
 def test_dryrun_multichip_8(devices8):
     graft.dryrun_multichip(8)
-
-
-class TestBenchConfig:
-    """bench.py pure helpers (driver-contract logic)."""
-
-    def test_layer_budget_regime_ordering(self):
-        import bench
-
-        hbm = 16 << 30
-        mixed = bench.layer_budget(hbm, 18.0)
-        bf16 = bench.layer_budget(hbm, 8.0)
-        assert 1 <= mixed <= bf16 <= 32
-        # tied embeddings buy layers back vs untied
-        assert bench.layer_budget(hbm, 18.0, tied=True) >= bench.layer_budget(
-            hbm, 18.0, tied=False)
-
-    def test_layer_budget_floor_and_cap(self):
-        import bench
-
-        assert bench.layer_budget(1 << 30, 18.0) == 1  # never 0
-        assert bench.layer_budget(1 << 44, 8.0) == 32  # full model cap

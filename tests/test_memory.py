@@ -1,6 +1,6 @@
 """Memory observability (telemetry.memory): pprof parsing + attribution,
-knob validation, boundary sampling, the OOM drill, PC501/PC502, planner
-HBM calibration, and the live tiny-llama fit() smoke.
+knob validation, boundary sampling, the OOM drill, planner HBM calibration,
+and the live tiny-llama fit() smoke.
 
 Run ``python tests/test_memory.py --regen-fixture`` to regenerate the
 committed pprof fixture after changing the generator below — the
@@ -587,90 +587,85 @@ class TestTreeBytes:
 
 
 # ---------------------------------------------------------------------------
-# PC501 / PC502 fault injections (analysis.perf_contract)
+# the compiled step's resident state, to the byte
 # ---------------------------------------------------------------------------
 
 
-def _facts(**over):
-    base = {
-        "version": 1,
-        "workload": {"source": "bench", "device": "cpu"},
-        "step_time_ms": 100.0, "mfu": 0.07, "tokens_per_sec": 5000.0,
-        "achieved_overlap": None, "exposed_collective_seconds": None,
-        "overlap_by_class": {}, "bubble_fraction_measured": None,
-        "bubble_fraction_predicted": None, "peak_hbm_bytes": 1e9,
-        "hbm_headroom_fraction": 0.5, "predicted_hbm_bytes": None,
-        "residuals": None,
-    }
-    base.update(over)
-    return base
+@pytest.mark.parametrize("tp", [1, 2, 4])
+@pytest.mark.parametrize("zero1", [True, False], ids=["zero1", "no-zero1"])
+@pytest.mark.parametrize("regime", ["mixed_precision", "bf16SR", "autocast",
+                                    "fp32"])
+def test_compiled_step_arguments_are_the_state_trees(devices8, regime, zero1,
+                                                     tp):
+    """What a device holds between steps, three ways that must agree to the
+    byte: the compiled step's argument bytes (``memory_analysis()``: the
+    state trees and a device's rows of the batch; the step's key is unused
+    without dropout and pruned), ``tree_bytes_by_subsystem`` over the trees
+    as the trainer lays them out, and the regime's own arithmetic: params in
+    the param dtype, two float32 moments and, where the params are bfloat16,
+    a float32 master copy, each of those over dp under ZeRO-1."""
+    import math
 
+    from jax.sharding import NamedSharding
 
-class TestPerfContractMemory:
-    def test_pc501_fires_on_peak_growth(self):
-        from neuronx_distributed_training_tpu.analysis.perf_contract import (
-            diff_facts,
-        )
+    from neuronx_distributed_training_tpu.analysis.graph_audit import (
+        abstract_batch,
+        abstract_opt_state,
+        lower_step_program,
+    )
+    from neuronx_distributed_training_tpu.config.loader import load_config
+    from neuronx_distributed_training_tpu.trainer.loop import (
+        assemble_step_program,
+    )
 
-        rep = diff_facts(_facts(), _facts(peak_hbm_bytes=1.2e9))
-        assert any(f.rule == "PC501" and f.severity == "error"
-                   for f in rep.findings)
+    asm = assemble_step_program(load_config({
+        "name": "argbytes", "model_source": "hf", "seed": 7,
+        "trainer": {"max_steps": 1},
+        "exp_manager": {"create_tensorboard_logger": False,
+                        "log_files": False},
+        "distributed_strategy": {"tensor_model_parallel_size": tp,
+                                 "zero1": zero1},
+        "data": {"global_batch_size": 8, "micro_batch_size": 1,
+                 "seq_length": 16, "synthetic": True},
+        "model": {"vocab_size": 64, "hidden_size": 32,
+                  "intermediate_size": 64, "num_layers": 1,
+                  "num_attention_heads": 4, "num_key_value_heads": 4,
+                  "max_position_embeddings": 16,
+                  "optim": {"name": "adamw_fp32OptState", "lr": 1e-3}},
+        "precision": {"type": regime},
+    }), build_data=False)
+    mesh = asm.mesh
+    n_dev, dp = mesh.devices.size, mesh.devices.size // tp
 
-    def test_pc501_in_band_and_improvement(self):
-        from neuronx_distributed_training_tpu.analysis.perf_contract import (
-            diff_facts,
-        )
+    def placed(tree, specs):
+        return jax.tree_util.tree_map(
+            lambda x, spec: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, spec)),
+            tree, specs)
 
-        rep = diff_facts(_facts(), _facts(peak_hbm_bytes=1.05e9))
-        assert not any(f.rule == "PC501" for f in rep.findings)
-        rep = diff_facts(_facts(), _facts(peak_hbm_bytes=0.5e9))
-        assert any(f.rule == "PC110" and "HBM" in f.message
-                   for f in rep.findings)
+    params = placed(asm.abstract_params, asm.pspecs)
+    held = tree_bytes_by_subsystem(
+        params, placed(abstract_opt_state(asm), asm.ospecs))
+    assert all(v % n_dev == 0 for v in held.values())
+    held = {k: v // n_dev for k, v in held.items()}
 
-    def test_pc501_skipped_when_either_side_missing(self):
-        from neuronx_distributed_training_tpu.analysis.perf_contract import (
-            diff_facts,
-        )
+    elements = sum(math.prod(x.sharding.shard_shape(x.shape))
+                   for x in jax.tree_util.tree_leaves(params))
+    over = dp if zero1 else 1
+    assert elements % over == 0
+    bf16_params = regime == "bf16SR"
+    expected = {"params": elements * (2 if bf16_params else 4),
+                "opt_state": 2 * 4 * elements // over + 4}  # + int32 step
+    if bf16_params:
+        expected["master"] = 4 * elements // over
+    assert held == expected
 
-        rep = diff_facts(_facts(peak_hbm_bytes=None), _facts())
-        assert not any(f.rule == "PC501" for f in rep.findings)
-
-    def test_pc502_baseline_independent(self):
-        from neuronx_distributed_training_tpu.analysis.perf_contract import (
-            check_perf,
-        )
-
-        # no baseline on disk: PC000 + the calibration gate still fires
-        rep = check_perf(
-            "nonexistent_topology_xyz",
-            _facts(peak_hbm_bytes=2e9, predicted_hbm_bytes=1e9),
-            baselines_dir=Path("/nonexistent"))
-        assert any(f.rule == "PC502" and f.severity == "error"
-                   for f in rep.findings)
-
-    def test_pc502_inside_calibration_band(self):
-        from neuronx_distributed_training_tpu.analysis.perf_contract import (
-            AuditReport,
-            DEFAULT_NOISE,
-            calibration_findings,
-        )
-
-        rep = AuditReport(config="x")
-        calibration_findings(
-            _facts(peak_hbm_bytes=1.2e9, predicted_hbm_bytes=1e9),
-            DEFAULT_NOISE, rep)
-        assert not any(f.rule == "PC502" for f in rep.findings)
-
-    def test_bench_facts_carry_memory_fields(self):
-        from neuronx_distributed_training_tpu.analysis.perf_contract import (
-            perf_facts_from_bench,
-        )
-
-        facts = perf_facts_from_bench({
-            "metric": "m", "value": 1.0, "peak_hbm_bytes": 123.0,
-            "hbm_headroom_fraction": 0.25})
-        assert facts["peak_hbm_bytes"] == 123.0
-        assert facts["hbm_headroom_fraction"] == 0.25
+    rows = {k: v.shape for k, v in abstract_batch(asm).items()}
+    assert rows == {"input_ids": (8, 16), "labels": (8, 16)}
+    batch_bytes = 2 * (8 // dp) * 16 * 4
+    _, compiled = lower_step_program(asm)
+    assert compiled.memory_analysis().argument_size_in_bytes \
+        == sum(held.values()) + batch_bytes
 
 
 # ---------------------------------------------------------------------------
@@ -716,7 +711,7 @@ class TestHbmCalibration:
     def test_total_falls_back_to_profile_per_device(self):
         # without allocator stats the profile's all-device total divides
         # by the device count: 2000/2=1000 vs 1250 -> 0.8 — the same
-        # per-device units PC502 and the baselines use
+        # per-device units the planner's prediction is in
         from neuronx_distributed_training_tpu.autotune.cost_model import (
             hbm_calibration_from_memory_summary,
         )
@@ -955,23 +950,6 @@ class TestLiveFit:
         t.fit()
         assert not hasattr(t.train_step, "lower")  # AOT-once held
         assert t.step == 5
-
-    def test_run_facts_from_memory_summary_feed_perf_contract(
-            self, tmp_path, devices8):
-        from neuronx_distributed_training_tpu.analysis.perf_contract import (
-            perf_facts_from_run,
-        )
-        from neuronx_distributed_training_tpu.trainer.loop import Trainer
-
-        t = Trainer.from_config(_fit_cfg(tmp_path),
-                                enable_checkpointing=False)
-        t.fit()
-        facts = perf_facts_from_run(Path(t.exp.log_dir))
-        # CPU reports no allocator stats, so the peak falls back to the
-        # profile's worst device; predicted comes from the stamped plan
-        assert facts["peak_hbm_bytes"] and facts["peak_hbm_bytes"] > 0
-        assert facts["predicted_hbm_bytes"] and \
-            facts["predicted_hbm_bytes"] > 0
 
 
 def _plan_raw_cfg():

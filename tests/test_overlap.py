@@ -1,8 +1,10 @@
 """Engineered overlap (``optim.overlap``): knob validation with did-you-mean,
 bucket-plan legality across the parallelism lattice, bucketed-vs-monolithic
-bitwise parity, and the XLA_FLAGS merge contract."""
+bitwise parity, the compiled update's gather counts and bytes under each
+variant, and the XLA_FLAGS merge contract."""
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -318,6 +320,167 @@ def test_prefetch_off_still_bitwise(cpu_mesh):
         lambda x, y: np.testing.assert_array_equal(np.asarray(x),
                                                    np.asarray(y)),
         (on[0], on[1]), (off[0], off[1]))
+
+
+# ---------------------------------------------------------------------------
+# What bucketing changes in the compiled update: how many gathers, not how
+# many bytes
+# ---------------------------------------------------------------------------
+
+MESHES = {"tp2": {"tensor_model_parallel_size": 2},
+          "tp4": {"tensor_model_parallel_size": 4},
+          "dp8": {},
+          "ep2": {"expert_model_parallel_size": 2}}
+
+#: bucket sizes of the three variants: none, one bucket, one a grad group
+BUCKET_MB = {"off": None, "bucketed-1": 1024.0, "bucketed-N": 1e-6}
+
+
+def _collectives(compiled):
+    """``[(kind, result elements, result bytes, op_name)]`` of a compiled
+    program's collectives.  Bytes are as the CPU backend compiles them (it
+    widens a bfloat16 collective to float32); elements are the program's."""
+    from neuronx_distributed_training_tpu.analysis.graph_audit import (
+        _SHAPE_RE,
+        _shape_bytes,
+    )
+    from neuronx_distributed_training_tpu.telemetry import census
+
+    out = []
+    for text in census.hlo_texts_from_compiled(compiled):
+        for line in text.splitlines():
+            head, _, meta = line.partition("metadata=")
+            m = census._COLLECTIVE_LINE_RE.search(head)
+            if not m:
+                continue
+            shapes = _SHAPE_RE.findall(
+                head[head.index("=") + 1: m.start("kind")])
+            name = census._OPNAME_META_RE.search(meta)
+            out.append((
+                m.group("kind"),
+                sum(math.prod(int(d) for d in dims.split(",") if d)
+                    for _, dims in shapes),
+                sum(_shape_bytes(dt, dims) for dt, dims in shapes),
+                name.group(1) if name else ""))
+    return out
+
+
+def _bytes_by_kind(collectives):
+    out = {}
+    for kind, _, nbytes, _ in collectives:
+        out[kind] = out.get(kind, 0) + nbytes
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_update(mesh_name, regime, zero1, variant, prefetch):
+    """The collectives of one step as the trainer jits it (gradients of a
+    batch sharded over dp constrained to the params' specs, then
+    ``adamw_update``, outputs pinned to the params' and moments' specs),
+    with the variant's bucket plan."""
+    mesh = build_mesh(MeshConfig(**MESHES[mesh_name]), devices=jax.devices())
+    policy = DtypePolicy.from_precision_config(regime)
+    abstract, pspecs = _tiny_tree()
+    ospecs = opt_state_specs(abstract, pspecs, mesh, zero1=zero1,
+                             policy=policy)
+    plan = None
+    if BUCKET_MB[variant] is not None:
+        plan = build_bucket_plan(abstract, pspecs, ospecs["mu"], mesh,
+                                 bucket_mb=BUCKET_MB[variant],
+                                 group_fn=_group_of)
+    ns = lambda specs: jax.tree_util.tree_map(  # noqa: E731
+        lambda spec: NamedSharding(mesh, spec), specs,
+        is_leaf=lambda x: isinstance(x, P))
+    placed = lambda shapes, specs: jax.tree_util.tree_map(  # noqa: E731
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        shapes, ns(specs))
+    params = placed(jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, policy.param_dtype),
+        abstract), pspecs)
+    opt_state = placed(jax.eval_shape(
+        functools.partial(init_opt_state, policy=policy), params), ospecs)
+    batch = jax.ShapeDtypeStruct(
+        (8,), jnp.float32,
+        sharding=NamedSharding(mesh, P(("data", "expert"))))
+
+    def loss(params, batch):
+        return sum(
+            jnp.sum(jnp.tanh(x.astype(jnp.float32)[None]
+                             * batch.reshape((-1,) + (1,) * x.ndim)))
+            for x in jax.tree_util.tree_leaves(params))
+
+    def step(params, batch, opt_state):
+        grads = jax.tree_util.tree_map(
+            lambda spec, g: shd.constrain(g, spec), pspecs,
+            jax.grad(loss)(params, batch),
+            is_leaf=lambda x: isinstance(x, P))
+        return adamw_update(params, grads, opt_state, lr=1e-3,
+                            cfg=AdamWConfig(), policy=policy,
+                            bucket_plan=plan, prefetch_ag=prefetch)
+
+    with mesh, shd.use_mesh(mesh):
+        compiled = jax.jit(
+            step, out_shardings=(ns(pspecs), ns(ospecs), None)).lower(
+                params, batch, opt_state).compile()
+    return plan, _collectives(compiled)
+
+
+@pytest.mark.parametrize("regime", ["mixed_precision", "bf16SR"])
+@pytest.mark.parametrize("mesh_name", list(MESHES))
+def test_monolithic_update_gathers_each_leaf_once(devices8, mesh_name,
+                                                  regime):
+    """The reference the variants are held to: ZeRO-1's regather is one
+    all-gather a leaf, none under the bucket scope, of exactly the leaves'
+    elements a device does not hold."""
+    _, collectives = _compiled_update(mesh_name, regime, True, "off", True)
+    gathers = [c for c in collectives if c[0] == "all-gather"]
+    assert len(gathers) == 4
+    assert not [c for c in gathers if BUCKET_AG_SCOPE in c[3]]
+    tp = MESHES[mesh_name].get("tensor_model_parallel_size", 1)
+    assert sum(c[1] for c in gathers) == 32 * 16 + 16 * 16 // tp \
+        + 16 * 32 + 16
+
+
+@pytest.mark.parametrize("prefetch", [True, False],
+                         ids=["prefetch", "no-prefetch"])
+@pytest.mark.parametrize("variant", ["bucketed-1", "bucketed-N"])
+@pytest.mark.parametrize("regime", ["mixed_precision", "bf16SR"])
+@pytest.mark.parametrize("mesh_name", list(MESHES))
+def test_bucketing_changes_gather_count_not_bytes(devices8, mesh_name, regime,
+                                                  variant, prefetch):
+    """One all-gather a bucket under ``BUCKET_AG_SCOPE``, each of exactly its
+    bucket's packed columns; a leaf sharded over ``model`` keeps its own
+    gather; and every collective kind moves the bytes the monolithic update
+    moves, with or without the prefetch chain."""
+    plan, collectives = _compiled_update(mesh_name, regime, True, variant,
+                                         prefetch)
+    _, monolithic = _compiled_update(mesh_name, regime, True, "off", True)
+    assert all(b.ag for b in plan.buckets)
+    scoped = [c for c in collectives
+              if c[0] == "all-gather" and BUCKET_AG_SCOPE in c[3]]
+    assert len(scoped) == len(plan.buckets)
+    assert sorted(c[1] for c in scoped) == sorted(
+        plan.dp_total * sum(a.cols for a in b.ag) for b in plan.buckets)
+    own_gather = 1 if "tensor_model_parallel_size" in MESHES[mesh_name] else 0
+    assert sum(c[0] == "all-gather" for c in collectives) \
+        == len(plan.buckets) + own_gather
+    assert _bytes_by_kind(collectives) == _bytes_by_kind(monolithic)
+
+
+@pytest.mark.parametrize("variant", ["bucketed-1", "bucketed-N"])
+@pytest.mark.parametrize("mesh_name", ["tp2", "dp8"])
+def test_bucketing_without_zero1_adds_no_collective(devices8, mesh_name,
+                                                    variant):
+    """Moments laid out as the params: nothing to regather, so a bucket plan
+    must leave the compiled update's collectives as they are."""
+    plan, collectives = _compiled_update(mesh_name, "mixed_precision", False,
+                                         variant, True)
+    _, monolithic = _compiled_update(mesh_name, "mixed_precision", False,
+                                     "off", True)
+    assert not any(b.ag for b in plan.buckets)
+    assert not [c for c in collectives if c[0] == "all-gather"]
+    assert sorted(c[:3] for c in collectives) \
+        == sorted(c[:3] for c in monolithic)
 
 
 # ---------------------------------------------------------------------------

@@ -510,7 +510,7 @@ class TestParityNewSchedules:
 
 
 class TestBubbleModel:
-    """The one bubble table telemetry, bench, and the autotune cost model
+    """The one bubble table telemetry and the autotune cost model
     share (``bubble_multiplier`` / ``predicted_bubble_fraction``)."""
 
     def test_classic_1f1b_and_wavefront(self):
@@ -659,6 +659,134 @@ class TestWorkTable:
             work_table("1f1b", 2, 4, vp=2)
         with pytest.raises(ValueError, match="pp > 1"):
             work_table("1f1b", 1, 4)
+
+
+def _eqns(jaxpr, out):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        out.append(eqn)
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _eqns(inner, out)
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _executed(schedule, pp, nm, vp, double_buffer=False):
+    """Run one executor on a toy stack over a pipe-only mesh and count what
+    ran: ``(stage executions, layer executions, head executions, [(length,
+    ppermutes in the body) of each scan that hops])``, all devices summed.
+
+    The mesh has the pipe axis alone, so the executor's ``shard_map`` is
+    fully manual and a ``jax.debug.callback`` in the stage fires once a
+    device each time the stage body really runs (a ``lax.cond`` branch not
+    taken fires nothing).  The wavefront is run forward only: what autodiff
+    reruns under ``jax.checkpoint`` is not the executor's to decide."""
+    hidden, vocab, layers = 8, 16, 8
+    stage_runs, head_runs = [], []
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:pp]), ("pipe",))
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    stack = {"w": jax.random.normal(k[0], (layers, hidden, hidden)) * 0.1}
+    params = {
+        "embed": jax.random.normal(k[1], (vocab, hidden)),
+        "layers": to_interleaved(stack, pp, vp) if vp > 1 else stack,
+        "scale": jnp.ones((hidden,)),
+        "head": jax.random.normal(k[2], (vocab, hidden)),
+    }
+    mbs = microbatches(k[0], nm=nm, mb=2, s=4, vocab=vocab)
+
+    def embed_fn(p, mb):
+        return p["embed"][mb["input_ids"]]
+
+    def stage_fn(lp, x, mb):
+        held = lp["w"].shape[0]
+        jax.debug.callback(lambda: stage_runs.append(held))
+        for i in range(held):
+            x = x + jnp.tanh(x @ lp["w"][i])
+        return x
+
+    def head_hidden_fn(hp, y):
+        jax.debug.callback(lambda: head_runs.append(1))
+        return y * hp["scale"]
+
+    def loss_fn(p, y, mb):
+        logits = (y * p["scale"]) @ p["head"].T
+        return (jnp.sum(jax.nn.logsumexp(logits, -1)),
+                jnp.asarray(float(mb["labels"].size)))
+
+    kw = dict(embed_fn=embed_fn, stage_fn=stage_fn, mesh=mesh,
+              virtual_pipeline_size=vp)
+    if schedule == "wavefront":
+        def fn(p, m):
+            return pipeline_loss(p, p["layers"], m, loss_fn=loss_fn, **kw)
+    else:
+        def fn(p, m):
+            return pipeline_loss_and_grad(
+                p, p["layers"], m, head_hidden_fn=head_hidden_fn,
+                head_params={"scale": p["scale"]}, head_weight=p["head"],
+                zero_bubble=schedule == "1f1b-zb",
+                double_buffer=double_buffer, **kw)
+
+    with mesh, shd.use_mesh(mesh):
+        scans = [
+            (e.params["length"],
+             sum(x.primitive.name == "ppermute"
+                 for x in _eqns(e.params["jaxpr"].jaxpr, [])))
+            for e in _eqns(jax.make_jaxpr(fn)(params, mbs).jaxpr, [])
+            if e.primitive.name == "scan"]
+        jax.block_until_ready(jax.jit(fn)(params, mbs))
+        jax.effects_barrier()
+    return (len(stage_runs), sum(stage_runs), len(head_runs),
+            [sc for sc in scans if sc[1]])
+
+
+class TestExecutorRealisesTable:
+    """What the executors run, counted: the manual-vjp tick loop executes the
+    stage body on exactly the table's forward, backward and wgrad ticks (a
+    tick no rank works on runs no stage), the head on its head ticks, in one
+    scan of ``span`` ticks whose body holds the two ring hops and the two
+    routing switches; the wavefront runs every rank on every one of its
+    ``nm*vp + pp - 1`` ticks.  The table prices a schedule by these counts
+    (``TestWorkTable`` holds the table to the planner)."""
+
+    POINTS = [(2, 4), (2, 16), (4, 8), (4, 6)]
+
+    @pytest.mark.parametrize("pp,nm", POINTS)
+    @pytest.mark.parametrize("schedule,vp", [
+        ("1f1b", 1), ("1f1b-zb", 1), ("1f1b-interleaved", 2)])
+    def test_manual_vjp_runs_the_tables_ticks(self, devices8, schedule, vp,
+                                              pp, nm):
+        stages, _, heads, scans = _executed(schedule, pp, nm, vp)
+        tc = work_table(schedule, pp, nm, vp).tick_counts()
+        assert stages == pp * (tc["f_ticks"] + tc["b_ticks"] + tc["w_ticks"])
+        assert heads == pp * tc["head_ticks"]
+        assert scans == [(tc["span"], 2 + 2 * pp)]
+
+    @pytest.mark.parametrize("pp,nm", POINTS)
+    def test_wavefront_runs_every_rank_every_tick(self, devices8, pp, nm):
+        vp = 2
+        stages, _, _, scans = _executed("wavefront", pp, nm, vp)
+        assert stages == pp * (nm * vp + pp - 1)
+        assert scans == [(nm * vp + pp - 1, 1 + 2 * pp)]
+
+    @pytest.mark.parametrize("schedule,vp", [
+        ("1f1b", 1), ("1f1b-zb", 1), ("1f1b-interleaved", 2)])
+    def test_double_buffer_moves_hops_not_work(self, devices8, schedule, vp):
+        """Hops hoisted out of their ``cond``s: as many permutes in a tick's
+        body, the same scan, the same stage and head executions."""
+        assert _executed(schedule, 2, 4, vp, double_buffer=True) \
+            == _executed(schedule, 2, 4, vp)
+
+    def test_interleaved_runs_no_more_layers_than_1f1b(self, devices8):
+        """At the point the schedules were compared at (pp 2, nm 16, vp 2):
+        twice the ticks of half the layers each, less one fill and one drain
+        tick's worth — 264 layer executions against 272."""
+        _, inter, _, _ = _executed("1f1b-interleaved", 2, 16, 2)
+        _, plain, _, _ = _executed("1f1b", 2, 16, 1)
+        assert (inter, plain) == (264, 272)
 
 
 class TestMemoryBound:

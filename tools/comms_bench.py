@@ -8,8 +8,8 @@ fits per-axis bandwidth + latency from the timed points (the same
 bus-bandwidth conventions ``autotune.cost_model._ring_seconds`` prices
 with), probes per-device timing skew, and writes a byte-stable
 ``comms_summary.json`` — the measured interconnect the planner can
-calibrate against (``tools/plan.py --calibrate-from``) and the perf
-contract gates (PC204, committed ``cpu_comms`` baseline).
+calibrate against (``tools/plan.py --calibrate-from``).  It measures and
+calibrates; it judges nothing (a time is judged by the benchmark's ledger).
 
     python tools/comms_bench.py --smoke --json -
     python tools/comms_bench.py --devices 8 --tp 2 --pp 2 --out run_dir
@@ -22,8 +22,7 @@ in the summary's ``findings`` as a ``degraded_link`` — and
 rule for the same signal (docs/observability.md 'Interconnect
 observatory').  ``--json`` writes through the shared ``tools/_jsonout.py``
 writer: with ``--json -`` the LAST stdout line is guaranteed parseable
-JSON (a bench-style line: ``metric=comms_bench_sweep`` + the
-``perf_contract`` verdict).
+JSON (one line: ``metric=comms_bench_sweep`` + the per-axis fits).
 """
 
 from __future__ import annotations
@@ -50,7 +49,7 @@ def _fmt(v, nd=3) -> str:
 
 def render(summary: dict) -> str:
     """Human rendering of a comms summary (the full table lives in
-    tools/comms_report.py — this is the bench-side echo)."""
+    tools/comms_report.py — this is the sweep-side echo)."""
     prior = dict(summary.get("prior") or {})
     lines = [f"interconnect sweep — topology={summary.get('topology')} "
              f"prior={float(prior.get('ici_bandwidth_bytes') or 0) / 1e9:g}"
@@ -109,8 +108,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--warmup", type=int, default=1,
                     help="untimed warmup calls per point (compile)")
     ap.add_argument("--smoke", action="store_true",
-                    help="fast CI shape: 64K/256K payloads, 2 reps — the "
-                         "verify-gate invocation")
+                    help="fast CI shape: 64K/256K payloads, 2 reps")
     ap.add_argument("--no-skew", dest="skew", action="store_false",
                     help="skip the per-device timing-skew probe")
     ap.add_argument("--skew-threshold", type=float, default=None,
@@ -121,12 +119,8 @@ def main(argv: list[str] | None = None) -> int:
                     help="where to write comms_summary.json (a directory "
                          "gets the canonical file name; default ./"
                          "comms_summary.json)")
-    ap.add_argument("--contract-key", default=None, metavar="NAME",
-                    help="perf-contract baseline key override (default: "
-                         "derived from the device identity, e.g. "
-                         "cpu_comms)")
     ap.add_argument("--json", metavar="PATH",
-                    help="bench-style JSON line ('-' = stdout last line, "
+                    help="one JSON line ('-' = stdout last line, "
                          "the shared tools/_jsonout contract)")
     args = ap.parse_args(argv)
 
@@ -167,9 +161,7 @@ def main(argv: list[str] | None = None) -> int:
             from _jsonout import write_json
 
             write_json({"ok": False, "metric": "comms_bench_sweep",
-                        "error": str(e),
-                        "perf_contract": {"verdict": "no_measurement"}},
-                       args.json)
+                        "error": str(e)}, args.json)
         return 2
 
     if args.smoke:
@@ -220,25 +212,6 @@ def main(argv: list[str] | None = None) -> int:
                  "AG/RS/A2A B(n-1)/n, permute B) — the same factors the "
                  "cost model's _ring_seconds prices with"),
     }
-    # the perf-contract verdict: PC204 gates the measured bandwidth against
-    # the committed per-topology baseline (cpu_comms on the CPU smoke)
-    try:
-        from neuronx_distributed_training_tpu.analysis import (
-            perf_contract as _pc,
-        )
-
-        facts = _pc.perf_facts_from_bench(payload)
-        key = args.contract_key or _pc.default_key(facts)
-        payload["perf_contract"] = _pc.bench_verdict(key, facts)
-        print(f"perf contract [{key}]: "
-              f"{payload['perf_contract']['verdict']}")
-    except Exception as e:  # noqa: BLE001 — the line must survive, but the
-        # verdict's absence must be explained
-        payload["perf_contract"] = {
-            "verdict": "unavailable",
-            "error": f"{type(e).__name__}: {e}"[:300],
-        }
-
     if args.json:
         from _jsonout import write_json
 

@@ -19,10 +19,6 @@ SimulatedPreemption` raised at the injection point — everything downstream of
 the signal (drain, manifest, replan, resharded restore, goodput accounting)
 is the REAL production path.  ``tests/test_elastic.py`` drives the same
 :func:`run_drill` entry, so the CLI and the regression suite cannot drift.
-
-A completed drill records ``restart_cost_seconds`` / ``goodput_fraction`` in
-``bench_results/last_drill.json``; ``bench.py`` picks the file up and carries
-both in its JSON line, so restart cost is visible in the bench trajectory.
 """
 
 from __future__ import annotations
@@ -39,9 +35,6 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 sys.path.insert(0, str(Path(__file__).resolve().parent))  # tools/_jsonout
 
 logger = logging.getLogger("nxdt.elastic_drill")
-
-#: where the last completed drill's headline numbers land (bench.py reads it)
-LAST_DRILL_PATH = "bench_results/last_drill.json"
 
 #: loss-trajectory pin for cross-dp resumes: the resumed run re-reduces the
 #: same global batches over a different dp grouping, so per-step losses agree
@@ -182,8 +175,8 @@ def _tree_max_diff(a: Any, b: Any) -> float:
 def run_drill(workdir: str | Path, *, at_step: int = 3, phase: str = "step",
               mode: str = "kill", world: int = 4,
               resume_world: Optional[int] = 2, total_steps: int = 6,
-              save_every: int = 2, loss_tol: float = DEFAULT_LOSS_TOL,
-              record_path: Optional[str] = None) -> dict[str, Any]:
+              save_every: int = 2,
+              loss_tol: float = DEFAULT_LOSS_TOL) -> dict[str, Any]:
     """The full drill: control run, injected fault, resume (replanned when
     the world changed), trajectory + state comparison.  Raises
     ``AssertionError`` with a diagnostic on any continuity violation.
@@ -326,8 +319,6 @@ def run_drill(workdir: str | Path, *, at_step: int = 3, phase: str = "step",
 
     report = {
         "ok": True,
-        # stamp the drill with its date — a stale
-        # drill riding later bench lines must be recognizable as stale
         "date": time.strftime("%Y-%m-%d %H:%M:%S"),
         "at_step": at_step, "phase": phase, "mode": mode,
         "world": world, "resume_world": resume_world,
@@ -343,11 +334,6 @@ def run_drill(workdir: str | Path, *, at_step: int = 3, phase: str = "step",
         "goodput_fraction": goodput.get("goodput_fraction"),
         "run_dir": str(resumed["run_dir"]),
     }
-    if record_path:
-        os.makedirs(os.path.dirname(record_path) or ".", exist_ok=True)
-        with open(record_path, "w") as f:
-            json.dump(report, f, indent=1)
-            f.write("\n")
     return report
 
 
@@ -748,8 +734,6 @@ def main(argv: Optional[list[str]] = None) -> int:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write the drill report as JSON ('-' = stdout, "
                          "last line, tools/_jsonout contract)")
-    ap.add_argument("--no-record", action="store_true",
-                    help=f"do not refresh {LAST_DRILL_PATH}")
     args = ap.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO,
@@ -775,8 +759,6 @@ def main(argv: Optional[list[str]] = None) -> int:
         import tempfile
 
         workdir = tempfile.mkdtemp(prefix="nxdt_elastic_drill_")
-    record_path = None if args.no_record else os.path.normpath(os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "..", LAST_DRILL_PATH))
     try:
         if args.control_smoke:
             # no --loss-tol here: every control-drill leg resumes at the
@@ -799,7 +781,6 @@ def main(argv: Optional[list[str]] = None) -> int:
                 world=args.world, resume_world=args.resume_world,
                 total_steps=args.steps, save_every=args.save_every,
                 loss_tol=args.loss_tol,
-                record_path=record_path,
             )
             if args.smoke:
                 # the --smoke CI gate grows a corruption leg: newest step
@@ -816,10 +797,6 @@ def main(argv: Optional[list[str]] = None) -> int:
                     for k in ("kind", "corrupted_step", "resume_step",
                               "walked_back", "max_loss_diff")
                 }
-                if record_path:
-                    with open(record_path, "w") as f:
-                        json.dump(report, f, indent=1)
-                        f.write("\n")
     except AssertionError as e:
         logger.error("drill FAILED: %s", e)
         if args.json:

@@ -267,25 +267,6 @@ def trace_section(trace: dict) -> str:
     return "\n".join(lines)
 
 
-def perf_contract_section(summary: dict) -> str:
-    """Perf-contract verdict (analysis.perf_contract): whether this line's
-    measured numbers were checked against the committed per-topology
-    baseline, and the named PC findings when any fired."""
-    pcv = summary.get("perf_contract")
-    if not isinstance(pcv, dict) or not pcv:
-        return ""
-    lines = ["", "perf contract (measured-runtime ratchet — "
-                 "docs/observability.md)"]
-    lines.append(f"  verdict               {pcv.get('verdict', '?')}"
-                 + (f"  (key {pcv['key']})" if pcv.get("key") else ""))
-    for f in pcv.get("findings") or []:
-        if isinstance(f, dict):
-            lines.append(f"    {f.get('rule', '?')}: {f.get('message', '')}")
-    if pcv.get("error"):
-        lines.append(f"  error                 {pcv['error']}")
-    return "\n".join(lines)
-
-
 def comms_section(summary: dict) -> str:
     """In-loop achieved interconnect bandwidth (telemetry.comms — the
     trainer's join of traced per-class wire seconds with the cost model's
@@ -568,7 +549,6 @@ def render(metrics_path: str | None, summary_path: str | None,
         parts.append(control_section(summary))
         parts.append(census_section(summary))
         parts.append(comms_section(summary))
-        parts.append(perf_contract_section(summary))
     parts.append(memory_section(summary, run_dir))
     parts.append(fleet_section(run_dir))
     parts.append(beacon_tail_section(run_dir))
