@@ -1,7 +1,7 @@
 """One record per model family: what the rest of the package asks of a model.
 
 ``models/llama.py``, ``mixtral.py``, ``gpt.py``, ``ouro.py``, ``laguna.py``, ``kanana.py``,
-``lfm2.py`` and ``nemotron_h.py`` each end in one
+``lfm2.py``, ``nemotron_h.py`` and ``keye.py`` each end in one
 :class:`Family` (``FAMILY``) and their config class answers ``.family`` with
 it.  The trainer, the pipeline gate, the launch planner, the FLOPs count, the
 cached decode and the config validator ask the record; none of them names a
@@ -107,6 +107,7 @@ FAMILIES: dict[str, Any] = {
     "kanana": "kanana", "deepseek_v3": "kanana",
     "lfm2": "lfm2", "lfm2_moe": "lfm2",
     "nemotron_h": "nemotron_h",
+    "keye": "keye", "keyevl2": "keye",
 }
 
 

@@ -106,13 +106,19 @@ DEVICE_SCOPES: dict[str, tuple[str, ...]] = {
 #: ``in_proj``, convolution, scan, gated norm, ``out_proj``, residual) with,
 #: inside it, ``mamba_conv`` (the convolution with its bias and ``silu``),
 #: ``ssd_scan`` (``ops/ssd.py``'s call, softplus and decays included) and
-#: ``gated_norm`` (the gate and the grouped norm).  A reader that does not
+#: ``gated_norm`` (the gate and the grouped norm); ``models/keye.py``'s
+#: ``attention/indexer`` (the learned selection's three projections, the index
+#: key's LayerNorm, the rope and the index scores of every causal pair),
+#: ``attention/select`` (each query's threshold and the mask of the keys it
+#: keeps: ``ops/sparse_attention.py``) and ``attention/indexer_loss`` (the main
+#: attention's head-mean probabilities, the KL and their backward).  A reader that does not
 #: know one counts its time under the scope that holds it, so nothing becomes
 #: unscoped; ``benchmark/readers/inner_scope.py`` reads one by its name.
 FAMILY_SCOPES: dict[str, tuple[str, ...]] = {
     "attention": ("attn_full", "attn_window", "head_gate", "mla_latent",
                   "short_conv", "conv_gate", "qk_norm",
-                  "mamba", "mamba_conv", "ssd_scan", "gated_norm"),
+                  "mamba", "mamba_conv", "ssd_scan", "gated_norm",
+                  "indexer", "select", "indexer_loss"),
     "moe": ("shared",),
     "ce_head": ("exit_gate",),
 }

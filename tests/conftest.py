@@ -60,7 +60,7 @@ def _every_case_has_a_limit(request):
 LONG_FILES = (
     "tests/test_tpu_compile.py", "tests/benchmark/test_benchmark_control.py",
     "tests/test_lfm2.py", "tests/test_laguna.py", "tests/test_flash_attention.py",
-    "tests/test_nemotron_h.py", "tests/test_kanana.py",
+    "tests/test_nemotron_h.py", "tests/test_kanana.py", "tests/test_keye.py",
     "tests/benchmark/test_benchmark_rehearsal.py", "tests/test_pipeline_1f1b.py",
     "tests/test_graph_contract.py")
 

@@ -59,7 +59,9 @@ def toy(name):
     """``(family, cfg, params, batch)`` at toy size; the MoE family gets
     experts, the looped one two passes."""
     extra = {"mixtral": {"moe": {"num_experts": 4, "top_k": 2, "dropless": True}},
-             "ouro": {"total_ut_steps": 2}}.get(name, {})
+             "ouro": {"total_ut_steps": 2},
+             "keye": {"num_experts": 4, "num_experts_per_tok": 2,
+                      "moe_intermediate_size": 16}}.get(name, {})
     family, cfg = resolve({"model": {**TOY_MODEL, "architecture": name, **extra}})
     params = family.init_params(jax.random.PRNGKey(0), cfg, FP32)
     ids = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 96)
@@ -180,6 +182,7 @@ def test_what_each_family_cannot_do():
         "kanana": ["decode", "onef1b_head", "pipeline"],
         "lfm2": ["decode", "onef1b_head", "pipeline"],
         "nemotron_h": ["decode", "onef1b_head", "pipeline"],
+        "keye": ["decode", "onef1b_head", "pipeline"],
     }
 
 
@@ -216,7 +219,7 @@ def test_flops_scalar_is_the_sum_of_the_breakdown(name):
     assert tuple(bd) == perf.FLOPS_COMPONENTS and bd == family.flops_breakdown(cfg, 128)
     assert all(v >= 0 for v in bd.values()) and bd["attention"] > 0 and bd["head"] > 0
     assert flops_for_model(cfg, 128) == pytest.approx(sum(bd.values()), rel=1e-12)
-    assert (bd["router"] > 0) == (name == "mixtral")
+    assert (bd["router"] > 0) == (name in ("mixtral", "keye"))   # the toys with experts
 
 
 def test_the_looped_stack_multiplies_its_work():
@@ -284,13 +287,14 @@ def test_lower_layers_import_nothing_from_models(package):
 def test_callers_name_no_familys_config_class(package):
     classes = {type(toy(name)[1]).__name__ for name in FAMILY_NAMES}
     assert classes == {"LlamaConfig", "MixtralConfig", "GPTConfig", "OuroConfig",
-                       "LagunaConfig", "KananaConfig", "Lfm2Config", "NemotronHConfig"}
+                       "LagunaConfig", "KananaConfig", "Lfm2Config", "NemotronHConfig",
+                       "KeyeConfig"}
     for path in sorted((PKG / package).rglob("*.py")):
         names = {getattr(n, "id", None) or getattr(n, "attr", None)
                  for n in ast.walk(ast.parse(path.read_text()))}
         assert not names & classes, f"{path.relative_to(PKG)} names {names & classes}"
         family_modules = [m for m in _imports(path)
-                          if re.search(r"\.models\.(llama|mixtral|gpt|ouro|laguna|kanana|lfm2|nemotron_h)$", m)]
+                          if re.search(r"\.models\.(llama|mixtral|gpt|ouro|laguna|kanana|lfm2|nemotron_h|keye)$", m)]
         assert not family_modules, f"{path.relative_to(PKG)} imports {family_modules}"
 
 

@@ -661,3 +661,57 @@ def test_the_state_space_cell_fits_one_v5e_at_depth_9(compile_step):
     assert ma.alias_size_in_bytes > 0.9 * ma.argument_size_in_bytes
     assert 7.4 * 2**30 < ma.argument_size_in_bytes < 7.5 * 2**30
     assert ma.temp_size_in_bytes < 8.2 * 2**30
+
+
+# --------------------------------------------------------------------------
+# attention whose keys a learned indexer chooses (models/keye.py) at the
+# benchmark's cut
+# --------------------------------------------------------------------------
+
+#: the cell ``keye-vl2-30b-pretrain-8k-ep8``
+#: (benchmark/configs/keye-vl-2.0-30b-a3b.json): published widths, six layers,
+#: experts 0-15 of 128 held, 1/8 of the vocabulary, two sequences of 8192 in
+#: one micro-batch
+KEYE_CUT = {
+    "model.num_hidden_layers": 6, "model.vocab_size": 18992,
+    "model.num_experts_held": [0, 16],
+    "distributed_strategy.expert_model_parallel_size": 1,
+    "data.global_batch_size": 2,
+}
+
+
+def test_the_learned_selection_cell_fits_one_v5e_at_depth_6(compile_step):
+    """7.9 GB of state (659 M parameters) and two sequences of 8192: under
+    ``full`` the compiler takes the step at depth 6 (depth 7, 1.08 GiB of state
+    and 0.36 of gradients more, is refused at 16.73 of 15.75 GiB: PERF.md
+    section 4; not compiled here).  The family's own masked flash kernels
+    (32 MiB of VMEM each: at tiles of 512 x 2048 the dkv kernel with a block of
+    the int8 mask beside its operands needs 18.33 MiB) are called once a layer
+    each, the forward's outputs kept across the rematerialized layer; the
+    selection kernel (Mosaic accepts its 64 rows x up to 8192 float32 scores
+    in VMEM) by the 12 chunks whose keys pass ``topk`` and the kernel that sums
+    the heads' probabilities by all 16, in the layer's forward and in its
+    rerun; the held experts' rows, 768 and 2048 wide, stay with XLA's ragged
+    dot at 3 x the even share of 16 384 x 8 x 16 / 128 rows."""
+    compiled = compile_step("hf_keye_vl2_30b_a3b_config.yaml", 1, KEYE_CUT)
+    assert _flash_forward_calls(compiled) == 1
+    text = compiled.as_text()
+
+    def calls(kernel):
+        return [line for line in text.splitlines() if "tpu_custom_call" in line
+                and "custom-call(" in line and f"/{kernel}/" in line]
+
+    assert [len(calls(f"flash_sel_{kind}")) for kind in ("fwd", "dq", "dkv")] == [1, 1, 1]
+    assert "s8[2,8192,8192]" in calls("flash_sel_dkv")[0]          # the mask as an operand
+    selects = calls("dsa_select")
+    assert len(selects) == 2 * 12 and len(calls("dsa_probs")) == 2 * 16
+    # a chunk's scores in, its mask out: from 2560 keys (the first to select) to 8192
+    assert any("s32[2,512,2560]" in line for line in selects)
+    assert any("s32[2,512,8192]" in line for line in selects)
+    for scope in ("indexer", "select", "indexer_loss", "qk_norm"):
+        assert scope in text
+    assert _held_rows_operand(text) == {int(moe._HELD_ROWS * 16384)} == {49152}
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes > 0.9 * ma.argument_size_in_bytes
+    assert 7.3 * 2**30 < ma.argument_size_in_bytes < 7.4 * 2**30
+    assert ma.temp_size_in_bytes < 11.7 * 2**30     # 11.45: both ways through the held experts
