@@ -363,7 +363,8 @@ def forward(params, batch: dict[str, jax.Array], cfg: KeyeConfig, policy: DtypeP
             attention_mask=attention_mask, segment_ids=segment_ids)
         return (x, losses + layer_losses), stats
 
-    body = llama.checkpoint_layer(body, lc, stack="layers")
+    # ``full`` keeps ``L_I``'s gradient too: the rerun forms nothing of the loss
+    body = llama.checkpoint_layer(body, lc, stack="layers", kept=sa_ops.KEPT_NAMES)
     (x, losses), stats = jax.lax.scan(
         body, (x, jnp.zeros((2,), jnp.float32)), params["layers"])
     aux: dict[str, Any] = {

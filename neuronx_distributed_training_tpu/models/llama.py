@@ -369,14 +369,15 @@ def _decoder_layer(layer_params, x, cos, sin, cfg: LlamaConfig, policy: DtypePol
 _SELECTIVE_RECOMPUTES = ("attn_scores", "attn_probs")
 
 
-def _remat_policy(granularity: Optional[str]):
+def _remat_policy(granularity: Optional[str], kept: tuple = ()):
     if granularity == "full":
         # the layer's input and the flash forward kernel's two outputs: the
         # rerun rebuilds q, k and v from the input, not the kernel's o and lse
-        # (16 ms of the MXU for 128 MiB at the latent-attention cell's shape)
+        # (16 ms of the MXU for 128 MiB at the latent-attention cell's shape);
+        # ``kept``: what else a family's own forward rules name
         from neuronx_distributed_training_tpu.ops.flash_attention import KEPT_NAMES
 
-        return jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
+        return jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES, *kept)
     if granularity == "selective":
         return jax.checkpoint_policies.save_anything_except_these_names(
             *_SELECTIVE_RECOMPUTES)
@@ -390,23 +391,26 @@ def _keeps_flash_outputs(cfg) -> bool:
     return cfg.activations_checkpoint_granularity == "full"
 
 
-def checkpoint_layer(body, cfg, *, stack: str, prevent_cse: bool = False):
+def checkpoint_layer(body, cfg, *, stack: str, prevent_cse: bool = False,
+                     kept: tuple = ()):
     """``body`` (a scanned stack's layer) rematerialized as
     ``cfg.activations_checkpoint_granularity`` says (``cfg``: a ``LlamaConfig``
     or what has its two fields read here), and what that keeps recorded among
     the trace's facts: ``remat`` of ``run_summary.json``, one entry a
-    ``stack``.  ``flash_fwd_per_layer_application``: 1 where the forward
+    ``stack``.  ``kept``: the names a family's own forward rules give what
+    ``full`` is to keep beside the flash kernel's outputs (none but for
+    ``models/keye.py``).  ``flash_fwd_per_layer_application``: 1 where the forward
     kernel's outputs are kept or nothing is rematerialized, 2 where the rerun
     calls it again (``full`` around a context-parallel body, whose calls are
     not named); absent where the attention op is not a flash kernel."""
     granularity = cfg.activations_checkpoint_granularity
-    policy = _remat_policy(granularity)
+    policy = _remat_policy(granularity, kept)
     facts = shd.trace_facts()
     if facts is not None:
         if granularity == "full":
             from neuronx_distributed_training_tpu.ops.flash_attention import KEPT_NAMES
 
-            entry = {"granularity": granularity, "kept": list(KEPT_NAMES)}
+            entry = {"granularity": granularity, "kept": [*KEPT_NAMES, *kept]}
         else:
             entry = {"granularity": granularity, "kept": "all"}
             if granularity == "selective":

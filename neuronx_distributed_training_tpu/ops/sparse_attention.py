@@ -25,7 +25,7 @@ are detached) and nothing else reaches them (the selection is a constant).
 How it runs.  Whatever the way, the sequence is walked in chunks of
 ``q_chunk`` queries by a Python loop, so chunk ``i`` sees the keys ``[0, (i +
 1) q_chunk)`` as a static shape: the index scores of a chunk (scope
-``attention/indexer``, rerun in backward, not kept) and its selection
+``attention/indexer``, never kept past their use) and its selection
 (``attention/select``; none while the chunk's keys are no more than ``topk``:
 that part of the layer IS dense causal attention).  Then (``WAYS``:
 ``flash_mask`` where ``model.fusions.flash_attention`` is set, as the kernels
@@ -39,14 +39,31 @@ of every family are asked for; ``run_summary.json`` says which a trace took):
   forward (``o``, ``lse``, kept across a rematerialized layer), dq, dkv.  The
   mask holds causality, documents and padding already; a block whose rows
   selected none of its keys is predicated off; every pair of a visited block
-  is formed and the unselected masked.  ``p`` for the indexer's loss comes
-  from the kernels' own ``lse``: a fourth kernel, a chunk at a time, sums
-  ``exp(s - lse)`` over the heads on the kept pairs (one more ``Q K^T``, under
-  ``attention/indexer_loss``; nothing of it is differentiated).
+  is formed and the unselected masked.  ``L_I`` and its gradient are taken
+  together in the forward (``_indexer_loss``), since every input of the loss
+  is detached and known there: the chunks are walked once more, and of each
+  are taken ``p`` from the kernels' own ``lse``
+  (a fourth kernel, ``dsa_probs``, sums ``exp(s - lse)`` over the heads on
+  the kept pairs: one more ``Q K^T``, under ``attention/indexer_loss``), the
+  KL of ``p`` and the chunk's index scores (their value handed on from the
+  selection), the KL's gradient on those scores and that gradient pulled
+  back through the scores (the heads' products formed again for it, under
+  ``attention/indexer``, and alive for one chunk) into ``d L_I / d (qI, kI, w)``,
+  float32.  That gradient is the only residual of a ``custom_vjp`` that ties
+  it to the loss (``_with_gradient``; ``[b, T, Hi, di]`` + ``[b, T, di]`` +
+  ``[b, T, Hi]``: 72 MB a layer at two sequences of 8192), named
+  (``KEPT_NAMES``) so that a layer rematerialized under ``full`` keeps it
+  across its rerun as ``o`` and ``lse`` are kept; the backward rule
+  multiplies it by the loss's cotangent.  The rerun rebuilds the index scores
+  and the selection (the mask is an operand of dq and dkv) and nothing of the
+  loss: ``dsa_probs`` runs once a chunk in a step, not twice
+  (``run_summary.json``: ``sparse_attention.loss_passes_per_layer_application``).
 - ``xla_chunks``: where the shapes do not tile the kernels (toy widths on the
   CPU mesh; on a TPU that raises): masked scores, softmax, values, ``p`` and
   the KL of a chunk in one rematerialised function of XLA operations, one
-  sequence at a time.  ``[heads, T, T]`` is never whole in memory either way.
+  sequence at a time, ``L_I``'s gradient by plain autodiff (tests/test_keye.py
+  holds the two ways equal).  ``[heads, T, T]`` is never whole in memory
+  either way.
 
 The threshold ``tau_t``, the ``k``-th largest of a row, is exact
 (``THRESHOLD``): a kernel that holds a block of rows in VMEM and bisects the
@@ -96,6 +113,11 @@ _INT_MIN = -(2 ** 31)
 #: ``(bq, bkv)`` block of the mask beside them pass the 16 MiB a kernel gets
 #: unasked (dkv at 512 x 2048 x 128 dims: 18.33 MiB); a v5e has 128
 _KERNEL_VMEM = 32 * 2 ** 20
+#: ``L_I``'s gradient on the indexer's three operands, taken in the layer's
+#: forward (``_indexer_loss``) and named as a residual: a layer rematerialized
+#: under a policy that holds these names (``models/keye.py`` hands them to
+#: ``llama.checkpoint_layer``) keeps them, and its rerun forms nothing of the loss
+KEPT_NAMES = ("dsa_loss_d_qi", "dsa_loss_d_ki", "dsa_loss_d_wi")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -641,6 +663,89 @@ def _chunk_probs(sel, q, k, lse, interpret):
         )(sel, q, k, lse)
 
 
+def _count(real):
+    """The real queries of ``real [b, T]``, at least 1 (float32)."""
+    return jnp.maximum(jnp.sum(real.astype(jnp.float32)), 1.0)  # jaxlint: disable=JL106
+
+
+def _loss_and_grads(qi, ki, wi, scores, sels, q, k, lse, real, operand_dtype, interpret):
+    """``L_I`` of the masked kernels' way and its gradient on ``(qi, ki, wi)``,
+    a chunk of queries at a time: ``scores`` the chunks' index scores as the
+    selection read them and ``sels`` their int8 masks (``[b, c, keys]`` each),
+    ``q [b, nh, T, d]`` / ``k [b, nkv, T, d]`` and the forward kernel's ``lse
+    [b, nh, T]``, ``real [b, T]``.  A chunk's ``p`` comes from ``dsa_probs``;
+    the KL's gradient on its scores is ``(softmax_sel(I) sum(p) - p) / (real
+    queries)`` on the real rows (the pullback of ``_kl_rows``); the scores'
+    pullback, for which the heads' products are formed again and live for this
+    chunk alone, turns it into the chunk's part of the three gradients."""
+    t = real.shape[1]
+    nh, chunk = q.shape[1], t // len(sels)
+    scores_of = functools.partial(index_scores, operand_dtype=operand_dtype)
+    probs = _per_shard(functools.partial(_chunk_probs, interpret=interpret),
+                       (_ROWS3, _ROWS4, _ROWS4, _ROWS4), _ROWS3)
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (fa.SUBLANES,))
+    n_real = _count(real)
+    kls, d_qi, d_wi, d_ki = [], [], [], jnp.zeros_like(ki)
+    for i, (m, chunk_scores) in enumerate(zip(sels, scores)):
+        rows, s = slice(i * chunk, (i + 1) * chunk), (i + 1) * chunk
+        sel = m != 0
+        with jax.named_scope("indexer_loss"):
+            # the main attention's probabilities from its own ``lse``: one more
+            # pass over the chunk's pairs
+            p = jnp.where(sel, probs(m, q[:, :, rows], k[:, :, :s], lse[:, :, rows]) / nh, 0.0)
+            kl, pull_kl = jax.vjp(lambda x, p=p, sel=sel: _kl_rows(p, x, sel), chunk_scores)
+            (d_scores,) = pull_kl(jnp.where(real[:, rows], 1.0 / n_real, 0.0))
+        with jax.named_scope("indexer"):
+            # (the value the pullback comes with is ``chunk_scores`` again: unused)
+            _, pull_scores = jax.vjp(scores_of, qi[:, rows], ki[:, :s], wi[:, rows])
+            dq, dk, dw = pull_scores(d_scores)
+            d_ki = d_ki + jnp.pad(dk, ((0, 0), (0, t - s), (0, 0)))
+        kls.append(kl)
+        d_qi.append(dq)
+        d_wi.append(dw)
+    kl = jnp.sum(jnp.where(real, jnp.concatenate(kls, axis=1), 0.0)) / n_real
+    return kl, (jnp.concatenate(d_qi, axis=1), d_ki, jnp.concatenate(d_wi, axis=1))
+
+
+@jax.custom_vjp
+def _with_gradient(loss, operands, grads):
+    """``loss`` (a value formed from detached inputs) as a function of
+    ``operands`` whose gradient is ``grads``, known already."""
+    return loss
+
+
+def _with_gradient_fwd(loss, operands, grads):
+    """The gradient is the rule's only residual, named (``KEPT_NAMES``) so
+    that a rematerialized layer keeps it across its rerun as the forward
+    kernel's ``o`` and ``lse`` are kept; what formed it is then dead there."""
+    return loss, tuple(checkpoint_name(g, name) for g, name in zip(grads, KEPT_NAMES))
+
+
+def _with_gradient_bwd(grads, g):
+    with jax.named_scope("indexer_loss"):
+        return None, tuple(g * d for d in grads), None
+
+
+_with_gradient.defvjp(_with_gradient_fwd, _with_gradient_bwd)
+
+
+def _indexer_loss(qi, ki, wi, scores, sels, q, k, lse, real, operand_dtype, interpret):
+    """``L_I``, the mean over the real queries of ``KL(p_t || softmax_{S_t}
+    I[t, .])``, of the masked kernels' way.  Every input of it is detached
+    and known in the forward, so its gradient on the indexer's three operands
+    is taken there and then, once (``_loss_and_grads``; the Python of it is
+    traced once too), and handed to the backward pass as a residual: the
+    layer's rerun calls no ``dsa_probs`` and forms no KL.  ``scores``: the
+    value of the chunks' ``index_scores`` of the same operands, which the op
+    formed for the selection, so that the loss's value costs no pass of its
+    own."""
+    qd, kd, wd, scores, q, k, lse = jax.tree_util.tree_map(
+        jax.lax.stop_gradient, (qi, ki, wi, scores, q, k, lse))
+    kl, grads = _loss_and_grads(qd, kd, wd, scores, sels, q, k, lse, real, operand_dtype,
+                                interpret)
+    return _with_gradient(kl, (qi, ki, wi), grads)
+
+
 # ---------------------------------------------------------------------------
 # the op
 # ---------------------------------------------------------------------------
@@ -688,8 +793,14 @@ def sparse_attention(q, k, v, qi, ki, wi, cfg: SparseAttentionConfig, *,
     chunk = math.gcd(t, cfg.q_chunk)
     way = way_for(cfg, t, d, nh, k.shape[2], q.dtype)
     facts = shd.trace_facts()
-    if facts is not None:   # ``run_summary.json``: the way this trace took
-        facts["sparse_attention"] = {**cfg.facts(), "way": way}
+    if facts is not None:
+        # ``run_summary.json``: the way this trace took, and how often a layer
+        # application forms ``p`` and the KL in a step: once where the loss's
+        # gradient is taken in the forward (``_indexer_loss``), else in the
+        # forward and again in the chunk's rerun
+        facts["sparse_attention"] = {
+            **cfg.facts(), "way": way,
+            "loss_passes_per_layer_application": 1 if way == "flash_mask" else 2}
     real = (jnp.ones((b, t), bool) if attention_mask is None else attention_mask > 0)
     # the indexer's function, rerun in backward: the 16 heads' products of a
     # chunk are never kept
@@ -724,18 +835,8 @@ def sparse_attention(q, k, v, qi, ki, wi, cfg: SparseAttentionConfig, *,
             lambda *a: _flash_sel(*a, bq, bkv, interpret),
             (_ROWS4, _ROWS4, _ROWS4, _ROWS3), (_ROWS4, _ROWS3))(qt, kt, vt, mask)
         out = jnp.swapaxes(o, 1, 2)
-        with jax.named_scope("indexer_loss"):
-            # the main attention's probabilities from its own ``lse``: one more
-            # pass over the chunk's pairs, all of it detached
-            qt, kt, lse = (jax.lax.stop_gradient(a) for a in (qt, kt, lse))
-            lse = jnp.broadcast_to(lse[..., None], lse.shape + (fa.SUBLANES,))
-            probs = _per_shard(functools.partial(_chunk_probs, interpret=interpret),
-                               (_ROWS3, _ROWS4, _ROWS4, _ROWS4), _ROWS3)
-            kls = []
-            for m, c in zip(masks, chunks):
-                p_sum = probs(m, qt[:, :, c.rows], kt[:, :, :c.keys], lse[:, :, c.rows])
-                kls.append(jax.checkpoint(_kl_rows)(
-                    jnp.where(c.sel, p_sum / nh, 0.0), c.scores, c.sel))
+        kl = _indexer_loss(qi, ki, wi, tuple(c.scores for c in chunks), tuple(masks), qt, kt,
+                           lse, real, compute_dtype, interpret)
     else:
         outs, kls = [], []
         for c in chunks:
@@ -744,9 +845,8 @@ def sparse_attention(q, k, v, qi, ki, wi, cfg: SparseAttentionConfig, *,
             outs.append(o)
             kls.append(kl)
         out = jnp.concatenate(outs, axis=1)
-    kl = jnp.where(real, jnp.concatenate(kls, axis=1), 0.0)
-    n_real = jnp.maximum(jnp.sum(real.astype(jnp.float32)), 1.0)  # jaxlint: disable=JL106
+        kl = jnp.sum(jnp.where(real, jnp.concatenate(kls, axis=1), 0.0)) / _count(real)
     return out, {
-        "kl": jnp.sum(kl) / n_real,
+        "kl": kl,
         "kept_pairs": sum(jnp.sum(c.sel.astype(jnp.float32)) for c in chunks),  # jaxlint: disable=JL106
         "causal_pairs": sum(c.shown for c in chunks)}

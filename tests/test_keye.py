@@ -29,13 +29,14 @@ import family_ladder
 from benchmark.reference import leaf_names
 from benchmark.references import keye as reference
 from family_ladder import FP32, key_of
-from neuronx_distributed_training_tpu.models import keye
+from neuronx_distributed_training_tpu.models import keye, llama
 from neuronx_distributed_training_tpu.models.family import resolve
 from neuronx_distributed_training_tpu.ops import attention as attention_ops
 from neuronx_distributed_training_tpu.ops import moe as moe_ops
 from neuronx_distributed_training_tpu.ops import norm as norm_ops
 from neuronx_distributed_training_tpu.ops import rope as rope_ops
 from neuronx_distributed_training_tpu.ops import sparse_attention as sa_ops
+from neuronx_distributed_training_tpu.parallel import sharding as shd
 
 #: the published shape at toy widths: 4 query / 2 key-value heads of 16 dims, 2
 #: index heads of 8 over one index key a token, 8 keys a query of 32 in chunks
@@ -103,14 +104,14 @@ TOY = family_ladder.Toy(
     summary={"model_family": "KeyeConfig",
              "sparse_attention": {"topk": 8, "index_heads": 2, "index_head_dim": 8,
                                   "way": "xla_chunks", "threshold": "pallas_bisect",
-                                  "q_chunk": 8},
+                                  "q_chunk": 8, "loss_passes_per_layer_application": 2},
              "moe_experts_held": [0, 4, 16], "moe_score_func": "softmax",
              # _HELD_ROWS x the even share, 2 x 32 x 4 x 4 / 16 = 64 rows
              "moe_row_bounds": [int(moe_ops._HELD_ROWS * 64)]},
     example=("hf_keye_vl2_30b_a3b_config.yaml", (), {"data.micro_batch_size": 1},
              {"sparse_attention": {"topk": 8, "index_heads": 2, "index_head_dim": 8,
                                    "way": "xla_chunks", "threshold": "pallas_bisect",
-                                   "q_chunk": 8}}))
+                                   "q_chunk": 8, "loss_passes_per_layer_application": 2}}))
 
 
 @pytest.fixture(scope="module")
@@ -132,6 +133,8 @@ class TestLadder(family_ladder.Ladder):
             assert 1.0 <= r["moe/load_max_share"] < 4.0
         remat = trained["summary"]["remat"]
         assert set(remat) == {"layers"} and remat["layers"]["granularity"] == "full"
+        # the flash kernel's two outputs, as in every family, and ``L_I``'s gradient
+        assert remat["layers"]["kept"] == ["flash_o", "flash_lse", *sa_ops.KEPT_NAMES]
 
 
 config = TOY.config
@@ -351,23 +354,37 @@ def test_the_shares_of_all_eight_held_ranges_make_the_layer(programs):
     ("kanana", {"n_routed_experts": 4, "num_experts_per_tok": 2, "moe_intermediate_size": 16,
                 "qk_nope_head_dim": 8, "qk_rope_head_dim": 8, "v_head_dim": 8,
                 "kv_lora_rank": 16, "router_bias_update_rate": 0.001})])
-def test_an_accepted_familys_program_knows_nothing_of_the_selection(arch, extra):
+def test_an_accepted_familys_program_knows_nothing_of_the_selection(arch, extra, monkeypatch):
     """The indexer, the selection and its loss are this family's own block
     (``models/keye.py::_attention_block``) and op: ``models/llama.py``'s block,
     the flash kernels and ``ops/moe.py`` were not touched, and an accepted
     family's loss lowers with none of the scopes, no selection kernel and no
-    indexer leaf."""
-    family, cfg = resolve({"model": {
-        "architecture": arch, "vocab_size": 96, "hidden_size": 32, "intermediate_size": 64,
-        "num_layers": 2, "num_attention_heads": 4, "num_key_value_heads": 2,
-        "activations_checkpoint_granularity": None, **extra}})
-    params = jax.eval_shape(lambda: family.init_params(jax.random.PRNGKey(0), cfg, FP32))
-    assert not any("indexer" in n for n in leaf_names(params))
+    indexer leaf.  What ``full`` keeps of its layers is what it kept before
+    this family handed ``llama.checkpoint_layer`` names of its own: the
+    gradient's text is the text under the policy as it was written then."""
+    model = {"architecture": arch, "vocab_size": 96, "hidden_size": 32, "intermediate_size": 64,
+             "num_layers": 2, "num_attention_heads": 4, "num_key_value_heads": 2, **extra}
     rows = jax.ShapeDtypeStruct((2, 16), jnp.int32)
-    text = jax.jit(lambda p, b: family.loss(cfg, FP32)(p, b, None)[0]).lower(
-        params, {"input_ids": rows, "labels": rows}).as_text(debug_info=True)
-    for name in ("indexer", "dsa_select", "indexer_loss", "attention/select"):
+
+    def lowered(granularity, grad=False, debug_info=True):
+        family, cfg = resolve({"model": {
+            **model, "activations_checkpoint_granularity": granularity}})
+        params = jax.eval_shape(lambda: family.init_params(jax.random.PRNGKey(0), cfg, FP32))
+        assert not any("indexer" in n for n in leaf_names(params))
+        loss = lambda p, b: family.loss(cfg, FP32)(p, b, None)[0]  # noqa: E731
+        return jax.jit(jax.grad(loss) if grad else loss).lower(
+            params, {"input_ids": rows, "labels": rows}).as_text(debug_info=debug_info)
+
+    text = lowered(None)
+    for name in ("indexer", "dsa_select", "indexer_loss", "attention/select", "dsa_loss"):
         assert name not in text, name
+    # (without the locations, which name the lines of this file)
+    now = lowered("full", grad=True, debug_info=False)
+    assert "dsa_loss" not in now
+    monkeypatch.setattr(llama, "_remat_policy", lambda granularity, kept=(): (
+        jax.checkpoint_policies.save_only_these_names("flash_o", "flash_lse")
+        if granularity == "full" else None))
+    assert lowered("full", grad=True, debug_info=False) == now
     root = Path(keye.__file__).resolve().parents[1]
     for path in ("models/llama.py", "ops/flash_attention.py", "ops/attention.py", "ops/moe.py"):
         assert "sparse_attention" not in (root / path).read_text(), path
@@ -466,3 +483,111 @@ def test_the_masked_flash_kernels_agree_with_the_chunks_of_xla(rows):
     assert float(stats["kept_pairs"]) == float(plain_stats["kept_pairs"])
     for mine, theirs in zip(grads, plain_grads):
         np.testing.assert_allclose(np.asarray(mine), np.asarray(theirs), rtol=2e-3, atol=2e-3)
+
+
+# -- L_I and its gradient are taken once, in the forward -----------------------------
+
+
+def _loss_operands(rows):
+    """What ``_indexer_loss`` takes at shapes that tile ``dsa_probs`` (256
+    tokens in 2 chunks of 128, 64 keys a query, the second chunk selects):
+    seeded ``qi``, ``ki``, ``wi``, ``q``, ``k``, the chunks' masks as the op
+    makes them and the ``lse`` of the masked scores."""
+    b, t, nh, nkv, d, hi, di, chunk = 2, 256, 4, 2, 64, 2, 8, 128
+    cfg = sa_ops.SparseAttentionConfig(topk=64, index_heads=hi, index_head_dim=di, q_chunk=chunk)
+    ks = jax.random.split(key_of(7), 5)
+    shapes = ((b, t, hi, di), (b, t, di), (b, t, hi), (b, nh, t, d), (b, nkv, t, d))
+    qi, ki, wi, q, k = (jax.random.normal(kk, shape) for kk, shape in zip(ks, shapes))
+    segments = real = None
+    if rows:
+        segments = np.stack([np.arange(t) // 100, np.arange(t) // 77])
+        real = np.arange(t)[None, :] >= np.array([[0], [70]])
+    visible = _visible(t, segments, real)
+    sels = []
+    for r0 in range(0, t, chunk):
+        shown = jnp.asarray(visible[:, r0:r0 + chunk, :r0 + chunk])
+        scores = sa_ops.index_scores(qi[:, r0:r0 + chunk], ki[:, :r0 + chunk], wi[:, r0:r0 + chunk])
+        sels.append((sa_ops.select(scores, shown, cfg) if r0 + chunk > cfg.topk else shown
+                     ).astype(jnp.int8))
+    keep = np.concatenate([np.pad(np.asarray(m), ((0, 0), (0, 0), (0, t - m.shape[2])))
+                           for m in sels], axis=1) != 0
+    logits = jnp.einsum("bhtd,bhsd->bhts", q, jnp.repeat(k, nh // nkv, axis=1)) / np.sqrt(d)
+    lse = jax.nn.logsumexp(jnp.where(keep[:, None], logits, sa_ops.NEG_INF), axis=-1)
+    lse = jnp.where(keep.any(-1)[:, None], lse, sa_ops.NEG_INF)
+    real = jnp.ones((b, t), bool) if real is None else jnp.asarray(real)
+    return (qi, ki, wi), (tuple(sels), q, k, lse, real), chunk
+
+
+def _unfused_loss(qi, ki, wi, sels, q, k, lse, real, chunk):
+    """``L_I`` as the parent composed it, for plain autodiff: a chunk's index
+    scores, ``_chunk_probs``, ``_kl_rows``, the mean over the real queries."""
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (8,))
+    kls = []
+    for i, m in enumerate(sels):
+        rows, s = slice(i * chunk, (i + 1) * chunk), (i + 1) * chunk
+        p_sum = sa_ops._chunk_probs(m, q[:, :, rows], k[:, :, :s], lse[:, :, rows], True)
+        scores = sa_ops.index_scores(qi[:, rows], ki[:, :s], wi[:, rows])
+        kls.append(sa_ops._kl_rows(jnp.where(m != 0, p_sum / q.shape[1], 0.0), scores, m != 0))
+    return jnp.sum(jnp.where(real, jnp.concatenate(kls, axis=1), 0.0)) / sa_ops._count(real)
+
+
+@pytest.mark.parametrize("rows", [None, "packed-and-padded"], ids=str)
+def test_the_loss_rule_gives_the_gradient_plain_autodiff_takes(rows):
+    """``_indexer_loss`` takes ``L_I``'s gradient on ``qi``, ``ki``, ``wi`` in
+    the forward and multiplies it by the cotangent in its backward rule
+    (nothing comes through the scores' values it is handed):
+    value and gradients are plain autodiff's of the unfused composition to
+    float32 round-off, under a cotangent that is not 1, also with packed
+    documents and left padding (a padded query counts for nothing)."""
+    leaves, rest, chunk = _loss_operands(rows)
+
+    def grads(loss):
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(lambda *a: 2.5 * loss(*a), argnums=(0, 1, 2)))(
+                *leaves)
+
+    def scores(qi, ki, wi):     # as the op hands them on from the selection
+        return tuple(sa_ops.index_scores(qi[:, r0:r0 + chunk], ki[:, :r0 + chunk],
+                                         wi[:, r0:r0 + chunk]) for r0 in range(0, qi.shape[1], chunk))
+
+    mine, mine_grads = grads(
+        lambda *a: sa_ops._indexer_loss(*a, scores(*a), *rest, jnp.float32, True))
+    plain, plain_grads = grads(lambda *a: _unfused_loss(*a, *rest, chunk))
+    assert float(mine) == pytest.approx(float(plain), rel=1e-6) and float(mine) > 0
+    for got, want in zip(mine_grads, plain_grads):
+        scale = float(jnp.max(jnp.abs(want)))
+        assert scale > 0
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                                   atol=1e-6 * scale)
+    if rows:   # row 1's first 70 tokens are padding: no gradient reaches them
+        assert not np.any(np.asarray(mine_grads[0])[1, :70])
+        assert not np.any(np.asarray(mine_grads[1])[1, :70])
+
+
+@pytest.mark.parametrize("named", [True, False], ids=["named", "name-left-out"])
+def test_a_rematerialized_layer_forms_the_loss_once_because_the_gradient_is_named(named):
+    """Under ``jax.checkpoint`` with the layer's policy (``llama._remat_policy``
+    with this family's names, as ``models/keye.py`` asks for it) the gradient's
+    jaxpr calls ``dsa_probs`` once a chunk: the gradient taken in the forward
+    is kept as the rule's residual and the rerun forms nothing of the loss.  With the names left out of the
+    policy the rerun has to build the residual again: twice a chunk."""
+    b, t, nh, nkv, d, hi, di = 1, 256, 2, 1, 64, 2, 8
+    cfg = sa_ops.SparseAttentionConfig(topk=64, index_heads=hi, index_head_dim=di, q_chunk=128)
+    shapes = ((b, t, nh, d), (b, t, nkv, d), (b, t, nkv, d), (b, t, hi, di), (b, t, di),
+              (b, t, hi))
+    policy = llama._remat_policy("full", sa_ops.KEPT_NAMES if named else ())
+
+    def layer(*a):
+        o, stats = sa_ops.sparse_attention(*a, cfg)
+        return jnp.sum(o) + stats["kl"]
+
+    with shd.collect_trace_facts() as facts:
+        text = str(jax.make_jaxpr(
+            jax.grad(jax.checkpoint(layer, policy=policy), argnums=tuple(range(6))))(
+            *(jax.ShapeDtypeStruct(shape, jnp.float32) for shape in shapes)))
+    # the fact ``run_summary.json`` carries: this way goes through the rule
+    assert facts["sparse_attention"]["way"] == "flash_mask"
+    assert facts["sparse_attention"]["loss_passes_per_layer_application"] == 1
+    chunks = t // cfg.q_chunk
+    assert len(re.findall(r"name=dsa_probs\b", text)) == (chunks if named else 2 * chunks)
+    assert len(re.findall(r"name=flash_sel_fwd\b", text)) == 1

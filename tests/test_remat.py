@@ -70,7 +70,7 @@ def program(model, granularity):
 @pytest.fixture
 def keeps_nothing(monkeypatch):
     """``full`` as it was: ``nothing_saveable``, the kernel's outputs unnamed."""
-    monkeypatch.setattr(llama, "_remat_policy", lambda granularity: (
+    monkeypatch.setattr(llama, "_remat_policy", lambda granularity, kept=(): (
         jax.checkpoint_policies.nothing_saveable if granularity == "full" else None))
     monkeypatch.setattr(llama, "_keeps_flash_outputs", lambda cfg: False)
 
