@@ -394,9 +394,9 @@ def decoder_stack(layers, x, cos, sin, cfg: KananaConfig, policy: DtypePolicy, *
     ``[b, heads, s, 192]`` among them: 1.17 GiB at the benchmark's cut) lives
     through the whole step.  With them held, the step that keeps the sparse
     layers' kernel outputs was refused for one v5e by 148 MiB while the held
-    experts' operand was 4 x the even share of the rows; at 3 x it fits, with
-    0.46 GiB more temporaries than the released step's
-    (tests/test_tpu_compile.py).  Released, the price is that one layer's
+    experts' operand was 4 x the even share of the rows; since 3 x it fits,
+    with 0.36 GiB more temporaries than the released step's (0.46 at 3 x;
+    tests/test_tpu_compile.py).  Released, the price is that one layer's
     projections and MLP run forward twice."""
     stats: dict = {}
     for kind in KINDS:

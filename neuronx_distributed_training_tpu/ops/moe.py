@@ -43,8 +43,9 @@ the dropped-vs-dropless validation at ``training_orchestrator.py:60-102``):
 - **a held range** (``MoEConfig.experts_held``): the program is one chip of
   an expert-parallel deployment, alone.  It routes over all the experts,
   holds a range of them and multiplies the rows that chose one of those
-  (``_held_experts``: a static operand of ``_HELD_ROWS`` x the even share,
-  slices of the sorted rows past it, no row dropped); the other chips' rows
+  (``_held_experts``: a sorted-rows operand chosen each step from the count
+  of the rows that arrived, ``_HELD_ROWS`` x the even share, more than one
+  slice of the wide one past it, no row dropped); the other chips' rows
   are left out and nothing stands in for them.  **A shared expert**
   (``params["shared"]``) is computed once beside the routed sum and added
   ungated; ``routed_scaling_factor`` scales the routed sum against it.
@@ -677,50 +678,71 @@ def _exchange_bwd(*args):
 _exchange_experts.defvjp(_exchange_fwd, _exchange_bwd)
 
 
-#: the sorted-rows operand of a block that holds a range of the experts
-#: (``MoEConfig.experts_held``), as a multiple of the rows it receives when
-#: routing is even, ``T * k * held / E``.  What it receives is the data's (one
-#: sequence's tokens lean to the same experts: PERF.md section 7), anything
-#: up to ``T * min(k, held)``, which as an operand with its ``gu`` would cost
-#: the step more memory than the held experts' weights.  So the operand is a
-#: bound, and a step past it runs the same block on successive slices of the
-#: sorted rows, keeping nothing but its inputs and running each slice forward
-#: again in the backward pass: no row dropped, no temporary past the bound.
-#: Every row of the operand is gathered, masked and scattered whether a row
-#: arrived for it or not (only the ragged dots skip the rows past the count),
-#: so its size is paid on every step: one layer at LFM2's shape (65 536 rows
-#: sorted, 8 192 the even share), forward twice and backward once as ``full``
-#: runs it, costs 27.4 ms with an operand of 4 x, 23.7 at 3 x, 21.6 at 2 x,
-#: 19.9 at 1.5 x (one v5e; PERF.md section 6, PR 45).  The value is the
-#: smallest of 1.5 / 2 / 3 that lies 1.4 x over the largest share a benchmark
-#: step has held: 1.62 (Kanana's cell, one run of ten; 1.34-1.47 the other
-#: runs' largest, 1.44 Laguna's, 1.05 LFM2's), so 2.0 would lie 1.23 x over
-#: it.  A step past the bound runs two slices where the wider operand ran
-#: one pass, each slice forward once more in the backward: at a share of 3.5
-#: a layer costs 1.29 x what the one pass of 4 x cost (47.5 ms for 36.9).
+#: the sorted-rows operands of a block that holds a range of the experts
+#: (``MoEConfig.experts_held``), narrow and wide, as multiples of the rows it
+#: receives when routing is even, ``T * k * held / E``.  What it receives is
+#: the data's (one sequence's tokens lean to the same experts: PERF.md section
+#: 7), anything up to ``T * min(k, held)``, which as an operand with its
+#: ``gu`` would cost the step more memory than the held experts' weights.
+#: Every row of an operand is gathered, masked and scattered whether a row
+#: arrived for it or not (only the grouped dots skip the rows past the
+#: count), so its size is paid on every step it is used: one layer at LFM2's |
+#: Kanana's shape (65 536 | 98 304 rows sorted, 8 192 | 12 288 the even
+#: share), forward twice and backward once as ``full`` runs it, at a share
+#: of 1.05 costs 16.7 | 18.5 ms in one pass over 1.25 x, 17.8 | 20.2 over
+#: 1.5 x, 19.9 | 23.3 over 2 x, 23.0 | 27.6 over 3 x (one v5e; PERF.md
+#: section 6, PR 52).  So the operand is chosen on the device each step from
+#: the count of the rows that arrived.  Up to the narrow multiple: one pass
+#: over that operand, ``(gu, ys)`` and the sort kept for the backward.  Past
+#: it: the same block on successive slices of the sorted rows, each of the
+#: wide multiple, keeping nothing but its inputs and running each slice
+#: forward again in the backward pass: no row dropped, no temporary past the
+#: wide operand.  A count between the two takes one slice, which costs the
+#: layer about what the one pass over 3 x cost it (29.0 | 30.6 ms at a share
+#: of 1.7, for 25.9 | 29.9); two slices cost more at a share of 2.5 (45.0 |
+#: 48.2, for 29.1 | 32.8) and less at 3.5 (50.3 | 52.4, for the 57.6 | 65.3
+#: of two slices of 3 x).  The wide tier is a slice and not a second one pass that
+#: keeps its ``(gu, ys)``: a third branch carries its own eight copies of
+#: the grouped dots' kernels a layer kind (2.2-2.6 s more of every start to
+#: read and load them, 10 % of Kanana's ``setup_s``), and the one pass over
+#: 2 x is what makes the compiler rematerialise operations of its own in
+#: LFM2's step (9 with it, 1 without: 11 ms a step).  The narrow multiple is
+#: the smallest of 1.25 / 1.5 over the 95th percentile of the largest share
+#: a step holds in the benchmark's cells: LFM2 1.03, Laguna 1.21-1.30,
+#: Nemotron 1.19-1.49 and Kanana 1.32-1.43 by the seed (one step in 190 of
+#: either over 1.5); Keye's is 2.5-2.8, half its steps pass 1.5 in some
+#: layer, a third of one seed's pass 2.0, and its rate still rises 6.3 %
+#: (its other layers fit).  The wide multiple is 2.0:
+#: by the one-layer curve 3.0 is cheaper on Keye's steps between 2 and 3
+#: (50.3 for 61.9 ms at Keye's shape), dearer on those between 1.5 and 2
+#: (45.8 for 38.6) and past 3 (82.2 for 67.8), which its two seeds split
+#: evenly, and 2.0 holds the slices' temporaries smaller.
 #: Not the exchange's ``_EXCHANGE_ROWS``, though both bound a sorted-rows
 #: operand: that one is a chip's share of ``T * k`` rows against the
 #: weights' journey (``autotune/cost_model.py`` prices a plan by it), this
-#: one a share of ``T * k * held / E`` against slices.
-_HELD_ROWS = 3.0
+#: pair shares of ``T * k * held / E``, one pass against slices.
+_HELD_ROWS = (1.5, 2.0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _held_experts(experts, x, probs, chosen, k: int, bound: int, compute_dtype, act):
+def _held_experts(experts, x, probs, chosen, k: int, bounds: tuple, compute_dtype, act):
     """The dropless block's routed half where the program holds a range of the
     experts alone: ``experts`` their weights (any float dtype; their
     gradients leave in it, float32 straight from the kernel), ``chosen``
     ``[T * k]`` the held expert of each (token, choice) row, counted from the
     range's first, or ``held`` for a row whose expert lies elsewhere.  Such a
     row is sorted last and left out: nothing stands in for the absent chips.
-    Rows held at most ``bound``: one pass, ``(gu, ys)`` and the sort kept;
-    more: slices (``_HELD_ROWS``)."""
-    return _held_pass(0, k, bound, compute_dtype, act, experts, x, probs, chosen)[0]
+    ``bounds`` (narrow, wide): rows held at most ``narrow``: one pass over
+    that operand, ``(gu, ys)`` and the sort kept; more: slices of ``wide``,
+    one where they fit it (``_HELD_ROWS``)."""
+    return _held_pass(0, k, bounds, compute_dtype, act, experts, x, probs, chosen)[0]
 
 
-def _held_sides(k: int, bound: int, compute_dtype, act, held: int, f2: int, h: int):
-    """``((forward, backward) under the bound, (forward, backward) past
-    it)`` of ``_held_experts``, in ``_exchange_sides``'s shapes."""
+def _held_sides(k: int, bounds: tuple, compute_dtype, act, held: int, f2: int, h: int):
+    """``((forward, backward) of the one pass over the narrow of ``bounds``,
+    (forward, backward) by slices of the wide one)`` of ``_held_experts``, in
+    ``_exchange_sides``'s shapes."""
+    bound, wide = bounds
 
     def cast(experts):
         return (experts["gate_up"].astype(compute_dtype),
@@ -743,19 +765,19 @@ def _held_sides(k: int, bound: int, compute_dtype, act, held: int, f2: int, h: i
 
     def slices(chosen):
         """``(n, slice_of)``: the sorted held rows as ``n`` slices of
-        ``bound``; ``slice_of(j)`` the rows of slice ``j`` and how many of
+        ``wide``; ``slice_of(j)`` the rows of slice ``j`` and how many of
         them each group holds."""
         order, sizes = _sorted_rows(chosen, held, chosen.shape[0])
         with jax.named_scope("dispatch"):
-            order = jnp.pad(order, (0, -order.shape[0] % bound))
+            order = jnp.pad(order, (0, -order.shape[0] % wide))
             ends = jnp.cumsum(sizes)
 
             def slice_of(j):
-                lo, hi = j * bound, (j + 1) * bound
+                lo, hi = j * wide, (j + 1) * wide
                 part = jnp.clip(jnp.minimum(ends, hi) - jnp.maximum(ends - sizes, lo), 0)
-                return jax.lax.dynamic_slice_in_dim(order, lo, bound), part
+                return jax.lax.dynamic_slice_in_dim(order, lo, wide), part
 
-            return (ends[-1] + bound - 1) // bound, slice_of
+            return (ends[-1] + wide - 1) // wide, slice_of
 
     def past_forward(experts, x, probs, chosen):
         xc, weights = x.astype(compute_dtype), cast(experts)
@@ -793,16 +815,17 @@ def _held_sides(k: int, bound: int, compute_dtype, act, held: int, f2: int, h: i
     return (under_forward, under_backward), (past_forward, past_backward)
 
 
-def _held_pass(back: int, k, bound, compute_dtype, act, *operands):
-    """The forward (0) or backward (1) pass of ``_held_experts``: under the
-    bound or past it, by the count of the rows held."""
+def _held_pass(back: int, k, bounds, compute_dtype, act, *operands):
+    """The forward (0) or backward (1) pass of ``_held_experts``: the one
+    pass over the narrow operand or slices of the wide one, by the count of
+    the rows held."""
     experts, x, _, chosen = operands[-4:]
-    held = experts["gate_up"].shape[0]
-    under, past = _held_sides(k, bound, compute_dtype, act, held,
+    held, narrow = experts["gate_up"].shape[0], bounds[0]
+    under, past = _held_sides(k, bounds, compute_dtype, act, held,
                               experts["gate_up"].shape[2], x.shape[1])
-    if bound >= chosen.shape[0]:  # the bound holds every case
+    if narrow >= chosen.shape[0]:  # the narrow operand holds every case
         return under[back](*operands)
-    return jax.lax.cond(jnp.sum(chosen < held) <= bound, under[back], past[back],
+    return jax.lax.cond(jnp.sum(chosen < held) <= narrow, under[back], past[back],
                         *operands)
 
 
@@ -823,13 +846,15 @@ def _dropless_held(experts, x, probs, idx, cfg: MoEConfig, *, compute_dtype):
     """``_dropless_experts`` under ``cfg.experts_held``; ``stats``:
     ``moe/held_rows``, the rows that chose a held expert,
     ``moe/held_rows_share``, that count over the even share ``T * k * held /
-    E``, and ``moe/row_bound``, 1 where the count passed the operand's bound
-    (``_HELD_ROWS``) and the step went by slices."""
+    E``, ``moe/held_operand``, the step's sorted-rows operand over the even
+    share (``_HELD_ROWS``: the narrow one where it held the count, else the
+    wide one), and ``moe/row_bound``, 1 where the count passed the wide one
+    too and the step took more than one slice of it."""
     t, k = idx.shape
     lo, hi = cfg.experts_held
     held = hi - lo
     even = t * k * held / cfg.num_experts
-    bound = min(8 * math.ceil(_HELD_ROWS * even / 8), t * k)
+    narrow, wide = bounds = tuple(min(8 * math.ceil(m * even / 8), t * k) for m in _HELD_ROWS)
     with jax.named_scope("dispatch"):
         chosen = idx.reshape(-1) - lo
         chosen = jnp.where((chosen >= 0) & (chosen < held), chosen, held)
@@ -837,10 +862,11 @@ def _dropless_held(experts, x, probs, idx, cfg: MoEConfig, *, compute_dtype):
     facts = shd.trace_facts()
     if facts is not None:
         facts["moe_experts_held"] = [lo, hi, cfg.num_experts]
-        facts["moe_row_bounds"] = [bound]
-    y = _held_experts(experts, x, probs, chosen, k, bound, compute_dtype, cfg.act)
+        facts["moe_row_bounds"] = list(bounds)
+    y = _held_experts(experts, x, probs, chosen, k, bounds, compute_dtype, cfg.act)
     stats = {"moe/held_rows": rows, "moe/held_rows_share": rows / even,
-             "moe/row_bound": rows > bound}
+             "moe/held_operand": jnp.where(rows <= narrow, narrow, wide) / even,
+             "moe/row_bound": rows > wide}
     return y, jax.tree_util.tree_map(lambda v: v.astype(jnp.float32), stats)
 
 

@@ -413,7 +413,8 @@ class Ladder:
         assert set(trained["grad1"]) == set(ref["grad1"])
         for r in trained["logged"]:
             assert r["moe/row_bound"] == 0.0 and r["moe/held_rows"] > 0
-            assert "moe/held_rows_share" in r
+            # the narrowest operand that held the largest layer's rows
+            assert r["moe/held_rows_share"] <= r["moe/held_operand"] <= moe_ops._HELD_ROWS[-1] * 1.1
         summary = trained["summary"]
         assert {k: summary.get(k) for k in self.toy.summary} == dict(self.toy.summary)
 

@@ -84,7 +84,7 @@ TOY = family_ladder.Toy(
              "moe_experts_held": [0, 4, 16], "moe_score_func": "sigmoid",
              "layer_kinds": {"mlp": {"dense": 1, "sparse": 2}},
              # _HELD_ROWS x the even share, 2 x 32 x 3 x 4 / 16 = 48 rows
-             "moe_row_bounds": [int(moe_ops._HELD_ROWS * 48)]},
+             "moe_row_bounds": [int(m * 48) for m in moe_ops._HELD_ROWS]},
     example=("hf_kanana_2_30b_a3b_config.yaml", (), {"data.micro_batch_size": 1},
              {"attention_kind": "mla"}))
 

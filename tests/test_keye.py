@@ -107,7 +107,7 @@ TOY = family_ladder.Toy(
                                   "q_chunk": 8, "loss_passes_per_layer_application": 2},
              "moe_experts_held": [0, 4, 16], "moe_score_func": "softmax",
              # _HELD_ROWS x the even share, 2 x 32 x 4 x 4 / 16 = 64 rows
-             "moe_row_bounds": [int(moe_ops._HELD_ROWS * 64)]},
+             "moe_row_bounds": [int(m * 64) for m in moe_ops._HELD_ROWS]},
     example=("hf_keye_vl2_30b_a3b_config.yaml", (), {"data.micro_batch_size": 1},
              {"sparse_attention": {"topk": 8, "index_heads": 2, "index_head_dim": 8,
                                    "way": "xla_chunks", "threshold": "pallas_bisect",

@@ -81,7 +81,7 @@ TOY = family_ladder.Toy(
                              "mlp": {"dense": 1, "sparse": 4}},
              "moe_experts_held": [0, 4, 16],
              # _HELD_ROWS x the even share of 2 x 32 x 4 x 4 / 16 = 64 rows
-             "moe_row_bounds": [int(moe_ops._HELD_ROWS * 64)]},
+             "moe_row_bounds": [int(m * 64) for m in moe_ops._HELD_ROWS]},
     example=("hf_laguna_s_2_1_config.yaml",
              ("layer_types", "mlp_layer_types", "num_attention_heads_per_layer", "rope_parameters"),
              {"model.num_hidden_layers": 9,
@@ -111,37 +111,54 @@ config = TOY.config
 # -- the held rows' bound -------------------------------------------------------
 
 
-@pytest.mark.parametrize("bound", [0.25, 0.6], ids=["many-slices", "two-slices"])
-def test_a_step_past_the_bound_gives_the_same_loss_and_gradients(programs, monkeypatch, bound):
-    """Forced past the bound (a bound under the rows held), the block runs
-    slices of the sorted rows; the loss and every gradient are those of the
-    one pass under the bound, and the counter says which way a step went."""
+@pytest.mark.parametrize("pair, operand, by_slices", [
+    ((0.125, 0.25), 0.25, True),
+    ((0.375, 0.625), 0.625, True),
+    ((0.625, 3.0), 3.0, False),
+    ((0.625, 4.0), 4.0, False),    # 4 x the even share is every row: never a second slice
+    ((3.0, 3.5), 3.0, False),
+], ids=["many-slices", "two-slices", "between-the-two", "between-the-two-no-slices",
+        "under-the-narrow"])
+def test_a_step_past_the_bound_gives_the_same_loss_and_gradients(
+        programs, monkeypatch, pair, operand, by_slices):
+    """Whatever way a step takes (the one pass over the narrow operand, one
+    slice of the wide one, or several), the loss and every gradient are those
+    of the family's program at ``_HELD_ROWS`` as shipped, and the counters say
+    which operand the step had and whether it took more than one slice."""
     cfg = config()
     params, toks = programs.weights(5), programs.tokens(6)
     (under, under_aux), under_grads = programs.program(params, toks)
-    monkeypatch.setattr(moe_ops, "_HELD_ROWS", bound)    # read when traced: a program of its own
+    share = float(under_aux["moe/held_rows_share"])
+    assert 0.625 < share <= moe_ops._HELD_ROWS[0]
+    assert float(under_aux["moe/held_operand"]) == moe_ops._HELD_ROWS[0]
+    monkeypatch.setattr(moe_ops, "_HELD_ROWS", pair)    # read when traced: a program of its own
     with jax.default_matmul_precision("highest"):
         (past, past_aux), past_grads = jax.jit(jax.value_and_grad(
             lambda p: laguna.forward(p, {"input_ids": toks, "labels": toks}, cfg, FP32),
             has_aux=True))(params)
-    assert float(under_aux["moe/row_bound"]) == 0.0 and float(past_aux["moe/row_bound"]) == 1.0
+    assert float(under_aux["moe/row_bound"]) == 0.0
+    assert float(past_aux["moe/row_bound"]) == by_slices
+    assert float(past_aux["moe/held_operand"]) == operand
     assert float(past_aux["moe/held_rows"]) == float(under_aux["moe/held_rows"])
-    assert float(past_aux["moe/held_rows_share"]) > bound
+    assert float(past_aux["moe/held_rows_share"]) == share
     assert float(past) == pytest.approx(float(under), rel=1e-6)
     assert worst_gap(past_grads, under_grads) < 2e-5
 
 
 @pytest.mark.parametrize("top_k", [4, 6, 10])
 @pytest.mark.parametrize("side", [-1, 1], ids=["just-under", "just-over"])
-def test_the_bound_changes_the_path_and_not_the_result(monkeypatch, top_k, side):
-    """Rows held one short of the bound (one pass) and one past it (two
-    slices): the block's output and every gradient are those of a bound of
-    4 x the even share, which takes both in one pass."""
+@pytest.mark.parametrize("tier", [0, 1], ids=["narrow", "wide"])
+def test_the_bound_changes_the_path_and_not_the_result(monkeypatch, top_k, side, tier):
+    """Rows held one short of a bound and one past it (the narrow bound: the
+    one pass over the narrow operand, then one slice of the wide one; the wide
+    bound: that slice, then two): the block's output and every gradient are
+    those of one pass over all ``T * k`` rows, no branch."""
     tokens, experts_n, held, h, f = 32, 32, 8, 16, 24
     rng = np.random.default_rng(top_k)
-    # a quarter of the experts held and three quarters of the choices among
-    # them, spread evenly over the tokens: the bound exactly; then one more or less
-    rows = int(moe_ops._HELD_ROWS * tokens * top_k * held / experts_n)
+    # a quarter of the experts held and their share of the choices spread
+    # evenly over the tokens: the bound exactly; then one more or less
+    even = tokens * top_k * held / experts_n
+    rows = int(moe_ops._HELD_ROWS[tier] * even)
     take = np.full(tokens, rows // tokens)
     take[:rows % tokens] += 1
     take[-1] += side
@@ -161,26 +178,36 @@ def test_the_bound_changes_the_path_and_not_the_result(monkeypatch, top_k, side)
                                               compute_dtype=jnp.float32)
             return jnp.sum(jnp.sin(y)), stats
         with jax.default_matmul_precision("highest"):
-            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(
-                experts, x, probs)
+            f = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
+            return f(experts, x, probs), f.lower(experts, x, probs).as_text()
 
-    (ours, stats), grads = run()
+    ((ours, stats), grads), text = run()
+    took = min(tier + (side > 0), 1)
     assert float(stats["moe/held_rows"]) == rows + side
-    assert float(stats["moe/row_bound"]) == (side > 0)
-    monkeypatch.setattr(moe_ops, "_HELD_ROWS", 4.0)
-    (wide, wide_stats), wide_grads = run()
+    assert float(stats["moe/held_operand"]) == moe_ops._HELD_ROWS[took]
+    assert float(stats["moe/row_bound"]) == (tier == 1 and side > 0)
+    assert text.count("stablehlo.case") == 2       # a branch a pass
+    monkeypatch.setattr(moe_ops, "_HELD_ROWS", (4.0, 4.0))
+    ((wide, wide_stats), wide_grads), wide_text = run()
     assert float(wide_stats["moe/row_bound"]) == 0.0
+    assert float(wide_stats["moe/held_operand"]) == 4.0
+    assert "stablehlo.case" not in wide_text
     assert float(ours) == pytest.approx(float(wide), rel=1e-6)
     assert worst_gap(grads, wide_grads) < 2e-5
 
 
-@pytest.mark.parametrize("held, held_tokens, bound, by_slices", [
-    (4, 64, 192, True),     # every row held, 4 x the even share: by slices
-    (4, 48, 192, False),    # the bound exactly: one pass
-    (12, 64, 256, False),   # 3 x the even share of 192 is over the 256 rows
-], ids=["all-rows-held", "at-the-bound", "never-over-the-rows"])
+@pytest.mark.parametrize("held, held_tokens, bounds, operand, by_slices, branch", [
+    (4, 64, [96, 128], 128, True, 1),     # every row held, 4 x the even share: two slices
+    (4, 24, [96, 128], 96, False, 1),     # the narrow bound exactly: its one pass
+    (4, 25, [96, 128], 128, False, 1),    # four rows past it: one slice of the wide one
+    (4, 32, [96, 128], 128, False, 1),    # the wide bound exactly
+    (4, 33, [96, 128], 128, True, 1),     # four rows past it: a second slice
+    (8, 64, [192, 256], 256, False, 1),   # 2 x the even share of 128 is every row: one slice
+    (12, 64, [256, 256], 256, False, 0),  # 1.5 x the even share of 192 is over the 256 rows
+], ids=["all-rows-held", "at-the-narrow-bound", "past-the-narrow-bound", "at-the-wide-bound",
+        "past-the-wide-bound", "wide-is-every-row", "never-over-the-rows"])
 def test_the_bound_is_a_multiple_of_the_even_share_and_never_over_the_rows(
-        held, held_tokens, bound, by_slices):
+        held, held_tokens, bounds, operand, by_slices, branch):
     from neuronx_distributed_training_tpu.parallel import sharding as shd
 
     z = jnp.zeros((64, 8))
@@ -189,14 +216,22 @@ def test_the_bound_is_a_multiple_of_the_even_share_and_never_over_the_rows(
     probs = jnp.full((64, 4), 0.25)
     experts = {"gate_up": jnp.zeros((held, 8, 16)), "down": jnp.zeros((held, 8, 8))}
     cfg = moe_ops.MoEConfig(num_experts=16, top_k=4, experts_held=(0, held))
+
+    def block(experts, z, probs, idx):
+        return moe_ops._dropless_held(experts, z, probs, idx, cfg, compute_dtype=jnp.float32)
+
     with shd.collect_trace_facts() as traced:
-        _, stats = moe_ops._dropless_held(experts, z, probs, idx, cfg,
-                                          compute_dtype=jnp.float32)
+        _, stats = block(experts, z, probs, idx)
     even = 64 * 4 * held / 16
-    assert traced["moe_row_bounds"] == [bound] == [min(int(moe_ops._HELD_ROWS * even), 256)]
+    assert traced["moe_row_bounds"] == bounds == [
+        min(int(m * even), 256) for m in moe_ops._HELD_ROWS]
     assert float(stats["moe/held_rows"]) == 4 * held_tokens
     assert float(stats["moe/held_rows_share"]) == pytest.approx(4 * held_tokens / even)
+    assert float(stats["moe/held_operand"]) == pytest.approx(operand / even)
     assert float(stats["moe/row_bound"]) == by_slices
+    # a narrow operand that holds every case lowers with no branch
+    text = jax.jit(block).lower(experts, z, probs, idx).as_text()
+    assert text.count("stablehlo.case") == branch
     with pytest.raises(NotImplementedError, match="experts_held"):
         moe_ops._dropless_experts(experts, z, probs, idx, cfg, compute_dtype=jnp.float32,
                                   expert_axis="expert")

@@ -91,7 +91,7 @@ TOY = family_ladder.Toy(
              "short_conv": {"taps": 3, "way": "pallas", "bytes_per_token": 512},
              "moe_experts_held": [0, 4, 16], "moe_score_func": "sigmoid",
              # _HELD_ROWS x the even share, 2 x 32 x 4 x 4 / 16 = 64 rows
-             "moe_row_bounds": [int(moe_ops._HELD_ROWS * 64)]},
+             "moe_row_bounds": [int(m * 64) for m in moe_ops._HELD_ROWS]},
     example=("hf_lfm2_24b_a2b_config.yaml", (), {"data.micro_batch_size": 1},
              {"operator_kinds": {"conv": 3, "full_attention": 1}}))
 

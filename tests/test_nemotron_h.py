@@ -98,7 +98,7 @@ TOY = family_ladder.Toy(
              "moe_expert_act": "relu2", "attention_positions": "none",
              "moe_experts_held": [0, 4, 16], "moe_score_func": "sigmoid",
              # _HELD_ROWS x the even share, 2 x 28 x 3 x 4 / 16 = 42 rows, in eights
-             "moe_row_bounds": [8 * int(np.ceil(moe_ops._HELD_ROWS * 42 / 8))]},
+             "moe_row_bounds": [8 * int(np.ceil(m * 42 / 8)) for m in moe_ops._HELD_ROWS]},
     example=("hf_nemotron3_nano_30b_a3b_config.yaml", (), {"data.micro_batch_size": 1},
              {"layer_kinds": {"mamba": 2, "moe": 2, "attention": 1}}))
 
